@@ -22,16 +22,20 @@ by ``python -m paddle_tpu.profiler.bundle`` — under
 import json
 import os
 
+import jax
 import numpy as np
 
 import paddle_tpu as paddle
-from paddle_tpu.inference import LLMEngine
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.profiler import FlightRecorder
 from paddle_tpu.serving import AsyncLLMServer, ReplicaRouter
+from paddle_tpu.serving.cluster import tp_engine
 
 
-def build_engine():
+def build_engine(replica=0):
+    """One replica's engine, pinned to its own device: a one-device
+    ("tp",) mesh places the weights, the pools and the step programs
+    there (replicas wrap when there are fewer devices than replicas)."""
     paddle.seed(0)
     cfg = LlamaConfig(vocab_size=512, hidden_size=128,
                       intermediate_size=256, num_hidden_layers=2,
@@ -41,7 +45,9 @@ def build_engine():
     model.eval()
     # the ship path rides the KV tier's gather/scatter: paged + fused
     # are required on both ends (import_kv validates the geometry)
-    return LLMEngine(model, max_batch=4, max_seq_len=128, chunk_size=32,
+    devs = jax.devices()
+    return tp_engine(model, tp=1, devices=[devs[replica % len(devs)]],
+                     max_batch=4, max_seq_len=128, chunk_size=32,
                      cache_impl="paged", block_size=16, scheduler="fused",
                      sampling_seed=7)
 
@@ -56,7 +62,7 @@ def main():
     ref = [r.token_ids for r in
            build_engine().generate(prompts, max_new_tokens=12)]
 
-    replicas = [AsyncLLMServer(build_engine(), replica=i,
+    replicas = [AsyncLLMServer(build_engine(i), replica=i,
                                flight_recorder=FlightRecorder())
                 for i in range(2)]
     with ReplicaRouter(replicas,

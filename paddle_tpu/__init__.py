@@ -15,6 +15,25 @@ import jax as _jax
 # CPU-only kernels.
 _jax.config.update("jax_enable_x64", True)
 
+
+def _configure_compile_cache():
+    """Place JAX's persistent compilation cache. ``JAX_COMPILATION_CACHE_DIR``
+    wins when set (JAX reads it itself — nothing is set in code); otherwise
+    the cache lives at ``<checkout>/.jax_cache``. The path is part of the
+    cache key's environment, so it is FIXED: never a tempdir, pid or time.
+    THE one place a cache directory is chosen — bench.py children, the
+    examples, __graft_entry__.py and chip_smoke.py all get it by importing
+    this package. A config update does not initialise a backend."""
+    import os
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    _jax.config.update("jax_compilation_cache_dir",
+                       os.path.join(checkout, ".jax_cache"))
+
+
+_configure_compile_cache()
+
 import numpy as _np  # noqa: E402
 
 from .core import dtype as _dtype_mod  # noqa: E402

@@ -12,7 +12,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from ...core.jax_compat import shard_map  # version-adapted (core/jax_compat.py)
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ...core.tensor import Tensor, dispatch

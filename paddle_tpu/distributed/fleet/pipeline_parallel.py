@@ -353,6 +353,8 @@ class PipelineParallel:
         n_batch = int(np.prod([mesh.jax_mesh().shape[a]
                                for a in batch_axes])) if batch_axes else 1
         decay_flags = tuple(bool(optimizer._decay_mask(p)) for p in trainable)
+        from ...optimizer.optimizer import stored_placements
+        fused_ctx = stored_placements(read_values(trainable))
 
         def dp_shard(a, dim):
             """Pin a batch-like dim to the data-like axes (dp + ZeRO sharding
@@ -422,7 +424,8 @@ class PipelineParallel:
 
             loss_val, grads = jax.value_and_grad(loss_of)(list(param_vals))
             new_pv, new_slots = optimizer.apply_updates(
-                list(param_vals), grads, list(slot_vals), lr, step_i, decay_flags)
+                list(param_vals), grads, list(slot_vals), lr, step_i,
+                decay_flags, fused_ctx=fused_ctx)
             return loss_val, new_pv, new_slots
 
         return jax.jit(step_fn, donate_argnums=(0, 1))

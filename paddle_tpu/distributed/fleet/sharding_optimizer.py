@@ -234,22 +234,28 @@ class DygraphShardingOptimizer:
         return AccPlacement(NamedSharding(self._mesh(), plan.spec), False, 0)
 
     # -- the pure sharded update (runs under jit) -----------------------------
-    def apply_updates(self, vals, grads, slots, lr, step, decay_flags):
+    def apply_updates(self, vals, grads, slots, lr, step, decay_flags,
+                      fused_ctx=None):
+        """``fused_ctx``: the caller's stored placements
+        (``optimizer.stored_placements``) — used for params this wrapper
+        does not re-plan; planned params run on their ZeRO shards."""
         inner = self._inner
         plans = self._plans_for(vals)
         mesh = self._mesh()
         if all(pl is None for pl in plans):
             return type(inner).apply_updates(inner, vals, grads, slots, lr,
-                                             step, decay_flags)
+                                             step, decay_flags,
+                                             fused_ctx=fused_ctx)
         if inner._grad_clip is not None:
             grads = inner._grad_clip.apply(vals, grads)
 
+        stored = fused_ctx if fused_ctx is not None else [None] * len(vals)
         t_vals, t_grads, fused_ctx = [], [], []
-        for v, g, pl in zip(vals, grads, plans):
+        for v, g, pl, st in zip(vals, grads, plans, stored):
             if pl is None or g is None:
                 t_vals.append(v)
                 t_grads.append(g)
-                fused_ctx.append(None)
+                fused_ctx.append(st)
                 continue
             if pl.flat:
                 v = jnp.pad(jnp.ravel(v), (0, pl.pad_to - v.size))
@@ -267,7 +273,7 @@ class DygraphShardingOptimizer:
             # GSPMD can't partition a pallas_call, so we partition for it
             fused_ctx.append((mesh, pl.spec)
                              if any(s is not None for s in tuple(pl.spec))
-                             else None)
+                             else st)
 
         # inner update on the stored (sharded/flat) forms; clip already done
         saved_clip = inner._grad_clip
@@ -299,8 +305,10 @@ class DygraphShardingOptimizer:
             out_slots.append(ns)
         return out_vals, out_slots
 
-    def _traced_update(self, vals, grads, slots, lr, step, decay_flags):
-        return self.apply_updates(vals, grads, slots, lr, step, decay_flags)
+    def _traced_update(self, vals, grads, slots, lr, step, decay_flags,
+                       fused_ctx=None):
+        return self.apply_updates(vals, grads, slots, lr, step, decay_flags,
+                                  fused_ctx=fused_ctx)
 
     # -- checkpoint portability ----------------------------------------------
     def state_dict(self):

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..core.jax_compat import shard_map  # version-adapted (core/jax_compat.py)
+from jax import shard_map
 
 
 def _psum(y, axis):

@@ -16,7 +16,7 @@ import math
 
 import numpy as np
 import jax
-from .....core.jax_compat import shard_map  # version-adapted (core/jax_compat.py)
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .....core.tensor import Tensor, dispatch

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor, functional_mode, no_grad
 from ..core import random as _random
 from ..nn.layer_base import Layer
+from ..optimizer.optimizer import stored_placements
 from .functional_call import collect_state, bind_state, read_values
 
 
@@ -481,17 +482,14 @@ class TrainStep:
             return self._call_accumulate(*batch)
         opt = self.optimizer
         dyn, static_key, layout, treedef = _split_leaves(batch)
-        from ..core.flags import flag_value
-        key = (static_key, layout, treedef,
-               tuple((tuple(v.shape), str(v.dtype)) for v in dyn),
-               bool(flag_value("use_fused_adamw")),
-               bool(flag_value("adamw_stochastic_rounding")))
+        param_vals = read_values(self.params)
+        fused_ctx = stored_placements(param_vals)
+        key = self._step_key(dyn, static_key, layout, treedef, fused_ctx)
 
         if key not in self._cache:
             self._cache[key] = self._build_step_jit(static_key, layout,
-                                                    treedef)
+                                                    treedef, fused_ctx)
 
-        param_vals = read_values(self.params)
         slot_vals = [opt._slots[id(p)] for p in self.params]
         buf_vals = read_values(self.buffers)
         frozen_vals = read_values(self.frozen)
@@ -510,7 +508,17 @@ class TrainStep:
             b._value = nv
         return Tensor(loss_val)
 
-    def _build_step_jit(self, static_key, layout, treedef):
+    def _step_key(self, dyn, static_key, layout, treedef, fused_ctx):
+        """Cache key of the single-step program. ``fused_ctx`` is the
+        params' ``stored_placements``: the fused optimizer kernel is built
+        for them, so a re-placed model gets a new program."""
+        from ..core.flags import flag_value
+        return (static_key, layout, treedef,
+                tuple((tuple(v.shape), str(v.dtype)) for v in dyn),
+                bool(flag_value("use_fused_adamw")),
+                bool(flag_value("adamw_stochastic_rounding")), fused_ctx)
+
+    def _build_step_jit(self, static_key, layout, treedef, fused_ctx=None):
         """The fused fwd+bwd+update program for one batch signature."""
         opt = self.optimizer
         decay_flags = tuple(bool(opt._decay_mask(p)) for p in self.params)
@@ -527,7 +535,8 @@ class TrainStep:
             (loss_val, new_bufs), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(param_vals)
             new_pv, new_slots = opt.apply_updates(
-                param_vals, grads, slot_vals, lr, step_i, decay_flags)
+                param_vals, grads, slot_vals, lr, step_i, decay_flags,
+                fused_ctx=fused_ctx)
             return loss_val, new_pv, new_slots, new_bufs
 
         donate = (0, 1, 2) if self.donate else ()
@@ -578,15 +587,14 @@ class TrainStep:
                 jnp.asarray(1, jnp.int32)).compile()
             return grad_compiled, update_compiled
 
-        jitted = self._build_step_jit(static_key, layout, treedef)
         # share the jit with __call__'s cache: a later real step with the
         # same signature reuses this trace instead of recompiling
-        from ..core.flags import flag_value
-        key = (static_key, layout, treedef,
-               tuple((tuple(v.shape), str(v.dtype)) for v in dyn),
-               bool(flag_value("use_fused_adamw")),
-               bool(flag_value("adamw_stochastic_rounding")))
-        self._cache.setdefault(key, jitted)
+        fused_ctx = stored_placements(param_vals)
+        key = self._step_key(dyn, static_key, layout, treedef, fused_ctx)
+        if key not in self._cache:
+            self._cache[key] = self._build_step_jit(static_key, layout,
+                                                    treedef, fused_ctx)
+        jitted = self._cache[key]
         slot_vals = [opt._slots[id(p)] for p in self.params]
         lr = jnp.asarray(opt.get_lr(), jnp.float32)
         step_i = jnp.asarray(1, jnp.int32)
@@ -632,6 +640,7 @@ class TrainStep:
         decay_flags = tuple(bool(opt._decay_mask(p)) for p in self.params)
         K = self.accumulate_steps
         shapes = tuple(tuple(p.shape) for p in self.params)
+        fused_ctx = stored_placements(read_values(self.params))
 
         def update_fn(param_vals, slot_vals, acc_vals, lr, step_i):
             # keep the fp32 mean — both the generic multi-precision path
@@ -649,7 +658,8 @@ class TrainStep:
                     a = jnp.reshape(a[:size], shp)
                 grads.append(a / K)
             return opt.apply_updates(param_vals, grads, slot_vals, lr,
-                                     step_i, decay_flags)
+                                     step_i, decay_flags,
+                                     fused_ctx=fused_ctx)
 
         donate = (0, 1, 2) if self.donate else (2,)
         return jax.jit(update_fn, donate_argnums=donate)

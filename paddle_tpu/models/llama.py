@@ -23,7 +23,9 @@ import jax.numpy as jnp
 
 from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
 from ..nn import functional as F
+from ..nn.functional.attention import flash_tp_context
 from ..core.tensor import Tensor, dispatch, functional_mode
+from ..jit.functional_call import stored_sharding
 from .lora import active_lora
 from .. import ops
 
@@ -513,12 +515,31 @@ class LlamaAttention(Layer):
             k = ops.concat([kv_cache[0], k], axis=1)
             v = ops.concat([kv_cache[1], v], axis=1)
             kv_cache = (k, v)
-        out = F.scaled_dot_product_attention(
-            q, k, v, attn_mask=attn_mask, is_causal=(attn_mask is None),
-            training=self.training)
+        with flash_tp_context(self._heads_tp()):
+            out = F.scaled_dot_product_attention(
+                q, k, v, attn_mask=attn_mask, is_causal=(attn_mask is None),
+                training=self.training)
         out = ops.reshape(out, [b, s, self.num_heads * self.head_dim])
         out = o_proj(out)
         return (out, kv_cache) if kv_cache is not None else out
+
+    def _heads_tp(self):
+        """(mesh, axis) when this layer's heads are stored sharded over a
+        mesh axis — a column-parallel q projection (``llama_tp_spec``) —
+        else None. The flash kernel is a Mosaic call, which GSPMD cannot
+        partition: under that layout it must shard_map over the axis."""
+        from jax.sharding import NamedSharding
+        weight = getattr(getattr(self, "q_proj", None), "weight", None)
+        if weight is None:
+            return None
+        sharding = stored_sharding(weight)
+        if not isinstance(sharding, NamedSharding):
+            return None
+        spec = tuple(sharding.spec)
+        axis = spec[1] if len(spec) > 1 else None
+        if axis is None or isinstance(axis, tuple):
+            return None
+        return sharding.mesh, axis
 
 
 class LlamaMLP(Layer):

@@ -162,6 +162,7 @@ def _fwd(q, k, v, causal, scale, bq, bk, dropout_p, seed_f):
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(seed_f, q, k, v)
     return out, lse
@@ -300,6 +301,7 @@ def _bwd(q, k, v, o, lse, do, causal, scale, bq, bk, dropout_p, seed_f):
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
         ],
+        name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(seed_f, q, k, v, do, lse, delta)
     if group > 1:
@@ -325,6 +327,7 @@ def _bwd(q, k, v, o, lse, do, causal, scale, bq, bk, dropout_p, seed_f):
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, Z)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        name="flash_attention_bwd_dq",
         interpret=_interpret(),
     )(seed_f, q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -381,6 +384,44 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None, dropout_p=0.0,
     if seed_f is None:
         seed_f = _zero_seed()
     return _flash_core(q, k, v, causal, scale, float(dropout_p), seed_f)
+
+
+def flash_attention_tp(q, k, v, mesh, axis, causal=False, scale=None,
+                       dropout_p=0.0, seed_f=None):
+    """:func:`flash_attention_fwd` under tensor parallelism: heads (dim 2
+    of [B, S, H, D]) split over mesh axis ``axis`` and every shard runs the
+    unmodified kernels — forward and, through the custom VJP, backward —
+    on its local heads. GSPMD cannot partition a Mosaic call (interpret
+    mode on CPU hides that: there the kernel is ordinary XLA ops), so a
+    program whose heads are sharded must come through here. kv heads split
+    alongside (GQA: q heads [h*G, (h+1)*G) follow kv head h, so an even
+    kv-head split carries its q groups with it). No collective is issued:
+    output heads stay sharded for the row-parallel o_proj to reduce. Mesh
+    axes other than ``axis`` see replicated operands."""
+    from jax.sharding import PartitionSpec as P
+
+    n = mesh.shape[axis]
+    if q.shape[2] % n or k.shape[2] % n:
+        raise ValueError(
+            f"flash_attention_tp: {q.shape[2]} q heads / {k.shape[2]} kv "
+            f"heads do not split over mesh axis {axis!r} of size {n}")
+    if seed_f is None:
+        seed_f = _zero_seed()
+    dropout_p = float(dropout_p)
+
+    def local(q_s, k_s, v_s, seed_s):
+        if dropout_p > 0.0:
+            # the dropout hash is keyed on the LOCAL (batch*head) index:
+            # fold the shard's position in so shards draw distinct masks
+            si = jax.lax.bitcast_convert_type(seed_s, jnp.int32)
+            si = si ^ (jax.lax.axis_index(axis).astype(jnp.int32)
+                       * np.int32(-1640531527))
+            seed_s = jax.lax.bitcast_convert_type(si, jnp.float32)
+        return _flash_core(q_s, k_s, v_s, causal, scale, dropout_p, seed_s)
+
+    heads = P(None, None, axis, None)
+    return jax.shard_map(local, mesh=mesh, in_specs=(heads, heads, heads, P()),
+                         out_specs=heads, check_vma=False)(q, k, v, seed_f)
 
 
 def _flash_fwd_res(q, k, v, causal, scale, dropout_p=0.0, seed_f=None):

@@ -97,6 +97,7 @@ def _fused_adamw_2d(scalars, g, m, v, mw, *, beta1, beta2, eps, out_dtype):
         # m/v/master update in place — no state copies in HBM (the outer
         # train step donates these buffers)
         input_output_aliases={2: 0, 3: 1, 4: 2},
+        name="fused_adamw",
         interpret=_interpret(),
     )(scalars, g, m, v, mw)
 
@@ -307,6 +308,7 @@ def fused_adamw_sr_update(p, g, m, v, lr, step, seed_f, *, beta1=0.9,
             jax.ShapeDtypeStruct((rows, cols), p.dtype),
         ),
         input_output_aliases={4: 0, 5: 1, 3: 2},
+        name="fused_adamw_sr",
         interpret=_interpret(),
     )(scalars, seed_f, g2, p2, m2, v2)
     return (np_.reshape(shape), nm.reshape(shape), nv.reshape(shape))

@@ -107,6 +107,7 @@ def int4_matmul(x, packed, scales, out_dtype=None):
         scratch_shapes=[pltpu.VMEM((rows_p, _OT), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="int4_matmul",
         interpret=jax.default_backend() not in ("tpu",),
     )(xe, xo, packed, scales.reshape(1, -1))
     return out[:rows]

@@ -69,9 +69,9 @@ re-quantizes IN VMEM too: the written block is merged in f32, its new
 per-head absmax scale computed in-kernel, and the int payload + scale
 store back through aliased outputs — no bf16 block ever round-trips to
 HBM. Scale granularity is deliberately per-(block, head): one f32 per
-``block_size * head_dim`` ints (<0.1% overhead), coarse enough to ride
-the scalar path, fine enough that one outlier head can't flatten the
-whole pool.
+``block_size * head_dim`` ints (<0.1% overhead), small enough that the
+whole scale array sits in VMEM for the call (see ``_scale_spec``), fine
+enough that one outlier head can't flatten the whole pool.
 """
 from __future__ import annotations
 
@@ -153,13 +153,14 @@ def kv_quantize(x, scale, quant):
     return kv_pack(q, quant)
 
 
-def kv_block_scale(x, quant, axes):
+def kv_block_scale(x, quant, axes, keepdims=False):
     """Absmax scale of one (or a batch of) f32 block(s) over ``axes``:
     THE one copy of the scale rule — the Pallas fused writes, the XLA
     dense fallback, and the engine's prefill scatter all compute the
     block scale through here, so kernel-vs-fallback parity holds to
     rounding."""
-    return jnp.max(jnp.abs(x), axis=axes) / np.float32(KV_QMAX[quant])
+    return jnp.max(jnp.abs(x), axis=axes, keepdims=keepdims) \
+        / np.float32(KV_QMAX[quant])
 
 
 def _interpret():
@@ -212,13 +213,12 @@ def current_paged_tp():
 
 
 def _tp_shard_map(fn, mesh, axis, in_specs, out_specs):
-    from ...core.jax_compat import shard_map
     if isinstance(in_specs, list):
         in_specs = tuple(in_specs)
     if isinstance(out_specs, list):
         out_specs = tuple(out_specs)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def paged_attention_decode_tp(q, k_pool, v_pool, block_tables, seq_lens,
@@ -333,7 +333,7 @@ def _kv_index_map(bs, mb):
 
 
 def _new_kv_index_map(b, h, j, tables_ref, lens_ref):
-    return (b, h, Z)
+    return (b, h, Z, Z)
 
 
 def _pool_out_index_map(bs, mb, nb):
@@ -347,28 +347,34 @@ def _pool_out_index_map(bs, mb, nb):
     return im
 
 
-def _scale_index_map(bs, mb):
-    """Per-(block, head) scale READ window of one grid step: the same
-    physical block the K/V BlockSpec maps (2-D: scales are
-    [num_blocks, Hkv])."""
-    def im(b, h, j, tables_ref, lens_ref):
-        j_last = _last_live(lens_ref, b, bs, mb)
-        jj = jnp.minimum(j, j_last)
-        return (jnp.maximum(tables_ref[b, jj], Z), h)
-    return im
+def _scale_read(s_ref, phys, h):
+    """One (block, head) scale as a [1, 1] tile off the VMEM-resident
+    [num_blocks, Hkv] scale array: a dynamic one-row load, then a lane
+    select on the head (Mosaic has no dynamic lane index)."""
+    row = s_ref[pl.ds(phys, 1), :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == h, row, np.float32(0.0)), axis=1,
+                   keepdims=True)
 
 
-def _scale_out_index_map(bs, mb, nb):
-    """Scale WRITE destination of the fused quantized write: the same
-    last-live (or scratch) block the pool out map routes to."""
-    def im(b, h, j, tables_ref, lens_ref):
-        phys = tables_ref[b, _last_live(lens_ref, b, bs, mb)]
-        return (jnp.where(phys < Z, np.int32(nb - 1), phys), h)
-    return im
+def _scale_write(s_ref, phys, h, val):
+    """Store one (block, head) scale: read-modify-write of the block's
+    row (the other heads' lanes keep their values)."""
+    row = s_ref[pl.ds(phys, 1), :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    s_ref[pl.ds(phys, 1), :] = jnp.where(lane == h, val, row)
+
+
+def _scale_spec(nb, hkv):
+    """Scale arrays ride WHOLE in VMEM (one block = the array, fetched
+    once, stored once): a (1, 1) window over [num_blocks, Hkv] breaks
+    Mosaic's block-shape rule, and the array is tiny next to the pools
+    (one f32 per block_size * head_dim ints)."""
+    return pl.BlockSpec((nb, hkv), lambda b, h, j, *refs: (Z, Z))
 
 
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
-                   bs, mb, write_new, quant=None, d_head=None):
+                   bs, mb, nb, write_new, quant=None, d_head=None):
     if quant:
         if write_new:
             (ks_ref, vs_ref, nk_ref, nv_ref, o_ref, ko_ref, vo_ref,
@@ -380,6 +386,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     bs_i = np.int32(bs)
     L = lens_ref[b]
@@ -396,13 +403,25 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    if quant and write_new:
+        # the aliased scale outputs are separate VMEM buffers: seed them
+        # from the inputs once, then read AND write the outputs only
+        @pl.when((b == Z) & (h == Z) & (j == Z))
+        def _seed_scales():
+            kso_ref[...] = ks_ref[...]
+            vso_ref[...] = vs_ref[...]
+        ks_ref, vs_ref = kso_ref, vso_ref
+
     k_blk = k_ref[0, 0]                                   # [bs, D]
     v_blk = v_ref[0, 0]
     if quant:
         # in-VMEM dequant right after the (2x/4x smaller) block DMA: the
         # attention math below is the plain f32 path
-        k_blk = kv_unpack(k_blk, quant, d_head) * ks_ref[0, 0]
-        v_blk = kv_unpack(v_blk, quant, d_head) * vs_ref[0, 0]
+        phys_r = jnp.maximum(phys, Z)
+        k_blk = kv_unpack(k_blk, quant, d_head) * _scale_read(ks_ref,
+                                                              phys_r, h)
+        v_blk = kv_unpack(v_blk, quant, d_head) * _scale_read(vs_ref,
+                                                              phys_r, h)
     if write_new:
         # merge the new token's K/V into the last live block in VMEM: the
         # attention below sees it this step, and the merged block writes
@@ -410,10 +429,8 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
         slot = L - j_last * bs_i
         row = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
         sel = (row == slot) & (j == j_last)
-        k_blk = jnp.where(sel, nk_ref[0, 0][None, :].astype(k_blk.dtype),
-                          k_blk)
-        v_blk = jnp.where(sel, nv_ref[0, 0][None, :].astype(v_blk.dtype),
-                          v_blk)
+        k_blk = jnp.where(sel, nk_ref[0, 0].astype(k_blk.dtype), k_blk)
+        v_blk = jnp.where(sel, nv_ref[0, 0].astype(v_blk.dtype), v_blk)
         if quant:
             # in-VMEM re-quantize of the merged block: new per-head
             # absmax scale, int payload + scale back through the aliased
@@ -430,8 +447,10 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
             dead = (j == j_last) & (row > slot)
             k_blk = jnp.where(dead, np.float32(0.0), k_blk)
             v_blk = jnp.where(dead, np.float32(0.0), v_blk)
-            ks_new = kv_block_scale(k_blk, quant, axes=(0, 1))
-            vs_new = kv_block_scale(v_blk, quant, axes=(0, 1))
+            ks_new = kv_block_scale(k_blk, quant, axes=(0, 1),
+                                    keepdims=True)
+            vs_new = kv_block_scale(v_blk, quant, axes=(0, 1),
+                                    keepdims=True)
             kq_new = kv_quantize(k_blk, ks_new, quant)
             vq_new = kv_quantize(v_blk, vs_new, quant)
             k_blk = jnp.where(j == j_last,
@@ -444,8 +463,10 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
         @pl.when(j == j_last)
         def _store_block():
             if quant:
-                kso_ref[0, 0] = ks_new
-                vso_ref[0, 0] = vs_new
+                # same destination rule as the pool out index map
+                dst = jnp.where(phys < Z, np.int32(nb - 1), phys)
+                _scale_write(kso_ref, dst, h, ks_new)
+                _scale_write(vso_ref, dst, h, vs_new)
                 ko_ref[0, 0] = kq_new
                 vo_ref[0, 0] = vq_new
             else:
@@ -534,18 +555,20 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
     inputs = [tables, lens, q4, k_pool, v_pool]
     io_aliases = {}
     if quant:
-        scale_spec = pl.BlockSpec((1, 1), _scale_index_map(BS, MB))
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [_scale_spec(NB, Hkv)] * 2
         inputs += [k_scale.astype(jnp.float32),
                    v_scale.astype(jnp.float32)]
     if write_new:
         # new-token K/V arrives in the model dtype regardless of pool
         # quantization — the kernel quantizes in VMEM
         nk_dt = k_pool.dtype if not quant else new_k.dtype
-        in_specs += [pl.BlockSpec((1, 1, D), _new_kv_index_map),
-                     pl.BlockSpec((1, 1, D), _new_kv_index_map)]
-        inputs += [new_k.reshape(B, Hkv, D).astype(nk_dt),
-                   new_v.reshape(B, Hkv, D).astype(nk_dt)]
+        # [B, Hkv, 1, D] with a (1, D) trailing window: Mosaic wants the
+        # last two block dims tile-aligned or equal to the array's, and a
+        # second-minor block of 1 against Hkv is neither
+        in_specs += [pl.BlockSpec((1, 1, 1, D), _new_kv_index_map),
+                     pl.BlockSpec((1, 1, 1, D), _new_kv_index_map)]
+        inputs += [new_k.reshape(B, Hkv, 1, D).astype(nk_dt),
+                   new_v.reshape(B, Hkv, 1, D).astype(nk_dt)]
         pool_spec = pl.BlockSpec((1, 1, BS, Dk),
                                  _pool_out_index_map(BS, MB, NB))
         out_specs += [pool_spec, pool_spec]
@@ -554,15 +577,14 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
         # flat input indices INCLUDE the scalar-prefetch operands
         io_aliases = {3: 1, 4: 2}
         if quant:
-            scale_out = pl.BlockSpec((1, 1),
-                                     _scale_out_index_map(BS, MB, NB))
-            out_specs += [scale_out, scale_out]
+            out_specs += [_scale_spec(NB, Hkv)] * 2
             out_shape += [jax.ShapeDtypeStruct((NB, Hkv), jnp.float32),
                           jax.ShapeDtypeStruct((NB, Hkv), jnp.float32)]
             io_aliases = {3: 1, 4: 2, 5: 3, 6: 4}
 
     kernel = functools.partial(_decode_kernel, scale=scale, bs=BS, mb=MB,
-                               write_new=write_new, quant=quant, d_head=D)
+                               nb=NB, write_new=write_new, quant=quant,
+                               d_head=D)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -581,8 +603,9 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
         # every dim sequential: scratch carries over blocks, and the fused
         # write's clamped scratch-block destinations may collide across
         # batch windows — megacore parallelism would race them
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="paged_attention_decode",
         interpret=_interpret(),
     )(*inputs)
     out = outs[0].reshape(B, Hq, D)
@@ -638,29 +661,9 @@ def _apd_pool_out_index_map(bs, mb, nb):
     return im
 
 
-def _apd_scale_index_map(bs, mb):
-    """Append-form scale READ window: the same block the K/V spec maps
-    (2-D — scales are [num_blocks, Hkv])."""
-    def im(b, h, j, tables_ref, lens_ref, qlens_ref):
-        j_last = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
-        jj = jnp.minimum(j, j_last)
-        return (jnp.maximum(tables_ref[b, jj], Z), h)
-    return im
-
-
-def _apd_scale_out_index_map(bs, mb, nb):
-    """Append-form scale WRITE destinations: the same window blocks the
-    pool out map routes to."""
-    def im(b, h, j, tables_ref, lens_ref, qlens_ref):
-        w0 = _apd_blk(lens_ref, qlens_ref, b, bs, mb, False)
-        w1 = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
-        phys = tables_ref[b, jnp.clip(j, w0, w1)]
-        return (jnp.where(phys < Z, np.int32(nb - 1), phys), h)
-    return im
-
-
 def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
-                   *rest, scale, bs, mb, s_chunk, quant=None, d_head=None):
+                   *rest, scale, bs, mb, nb, s_chunk, quant=None,
+                   d_head=None):
     if quant:
         (ks_ref, vs_ref, nk_ref, nv_ref, o_ref, ko_ref, vo_ref, kso_ref,
          vso_ref, m_ref, l_ref, acc_ref) = rest
@@ -668,6 +671,7 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         (nk_ref, nv_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref,
          acc_ref) = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     bs_i = np.int32(bs)
     L = lens_ref[b]
@@ -687,9 +691,18 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
     k_blk = k_ref[0, 0]                                       # [bs, D]
     v_blk = v_ref[0, 0]
     if quant:
+        # scale outputs seeded from the inputs once, then read and
+        # written in place (decode-kernel rule)
+        @pl.when((b == Z) & (h == Z) & (j == Z))
+        def _seed_scales():
+            kso_ref[...] = ks_ref[...]
+            vso_ref[...] = vs_ref[...]
         # in-VMEM dequant right after the block DMA (decode-kernel rule)
-        k_blk = kv_unpack(k_blk, quant, d_head) * ks_ref[0, 0]
-        v_blk = kv_unpack(v_blk, quant, d_head) * vs_ref[0, 0]
+        phys_r = jnp.maximum(phys, Z)
+        k_blk = kv_unpack(k_blk, quant, d_head) * _scale_read(kso_ref,
+                                                              phys_r, h)
+        v_blk = kv_unpack(v_blk, quant, d_head) * _scale_read(vso_ref,
+                                                              phys_r, h)
     # merge the chunk rows that land in THIS block into it in VMEM: block
     # row r holds chunk index i = j*bs + r - lens when 0 <= i < q_lens.
     # The gather is expressed as a one-hot selection matmul ([bs, S] @
@@ -699,7 +712,11 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
     row = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 0)
     ci = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 1)
     sel = ((jj * bs_i + row - L) == ci) & (ci < QL) & (ci >= Z)
-    has_new = jnp.any(sel, axis=1, keepdims=True)             # [bs, 1]
+    # block row r takes a chunk row iff its chunk index lands in
+    # [0, q_lens) — index math, not a bool reduction over ``sel`` (Mosaic
+    # has no i1 reduce)
+    idx = jj * bs_i + row[:, :1] - L                          # [bs, 1]
+    has_new = (idx >= Z) & (idx < jnp.minimum(QL, np.int32(s_chunk)))
     sel_f = sel.astype(jnp.float32)
     merged_k = jax.lax.dot_general(
         sel_f, nk_ref[0, 0].astype(jnp.float32),
@@ -727,14 +744,12 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         dead = in_window & ((jj * bs_i + row[:, :1]) >= (L + QL))
         k_blk = jnp.where(dead, np.float32(0.0), k_blk)
         v_blk = jnp.where(dead, np.float32(0.0), v_blk)
-        ks_new = kv_block_scale(k_blk, quant, axes=(0, 1))
-        vs_new = kv_block_scale(v_blk, quant, axes=(0, 1))
+        ks_new = kv_block_scale(k_blk, quant, axes=(0, 1), keepdims=True)
+        vs_new = kv_block_scale(v_blk, quant, axes=(0, 1), keepdims=True)
         kq_new = kv_quantize(k_blk, ks_new, quant)
         vq_new = kv_quantize(v_blk, vs_new, quant)
         kq_store = jnp.where(QL > Z, kq_new, k_ref[0, 0])
         vq_store = jnp.where(QL > Z, vq_new, v_ref[0, 0])
-        ks_store = jnp.where(QL > Z, ks_new, ks_ref[0, 0])
-        vs_store = jnp.where(QL > Z, vs_new, vs_ref[0, 0])
         k_blk = jnp.where(in_window,
                           kv_unpack(kq_new, quant, d_head) * ks_new, k_blk)
         v_blk = jnp.where(in_window,
@@ -743,10 +758,15 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
     @pl.when(in_window)
     def _store_block():
         if quant:
-            kso_ref[0, 0] = ks_store
-            vso_ref[0, 0] = vs_store
             ko_ref[0, 0] = kq_store
             vo_ref[0, 0] = vq_store
+
+            @pl.when(QL > Z)
+            def _store_scales():
+                # same destination rule as the pool out index map
+                dst = jnp.where(phys < Z, np.int32(nb - 1), phys)
+                _scale_write(kso_ref, dst, h, ks_new)
+                _scale_write(vso_ref, dst, h, vs_new)
         else:
             ko_ref[0, 0] = k_blk.astype(ko_ref.dtype)
             vo_ref[0, 0] = v_blk.astype(vo_ref.dtype)
@@ -849,11 +869,8 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
     # flat input indices INCLUDE the scalar-prefetch operands
     io_aliases = {4: 1, 5: 2}
     if quant:
-        scale_in = pl.BlockSpec((1, 1), _apd_scale_index_map(BS, MB))
-        scale_out = pl.BlockSpec((1, 1),
-                                 _apd_scale_out_index_map(BS, MB, NB))
-        in_specs += [scale_in, scale_in]
-        out_specs += [scale_out, scale_out]
+        in_specs += [_scale_spec(NB, Hkv)] * 2
+        out_specs += [_scale_spec(NB, Hkv)] * 2
         out_shape += [jax.ShapeDtypeStruct((NB, Hkv), jnp.float32),
                       jax.ShapeDtypeStruct((NB, Hkv), jnp.float32)]
         inputs += [k_scale.astype(jnp.float32),
@@ -864,7 +881,7 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
     inputs += [nk, nv]
 
     kernel = functools.partial(_append_kernel, scale=scale, bs=BS, mb=MB,
-                               s_chunk=S, quant=quant, d_head=D)
+                               nb=NB, s_chunk=S, quant=quant, d_head=D)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -882,8 +899,9 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
         input_output_aliases=io_aliases,
         # sequential everywhere: scratch carries over blocks and clamped
         # write destinations may collide across batch windows
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="paged_attention_append",
         interpret=_interpret(),
     )(*inputs)
     out = outs[0].reshape(B, Hkv, G, S, D)

@@ -29,6 +29,30 @@ def _is_low_precision(dtype):
     return dtype in (jnp.bfloat16, jnp.float16)
 
 
+def stored_placements(values):
+    """Per-array ``(mesh, spec)`` for ``apply_updates(fused_ctx=...)`` when
+    the arrays are stored over a multi-device mesh, else None (every array
+    on one device: the plain kernel call). The fused update is a Mosaic
+    call, which GSPMD cannot partition — interpret mode on CPU hides that —
+    so under a sharded layout it runs shard_map-wise on the stored shards;
+    arrays not on the mesh ride replicated (an empty spec). ``values`` are
+    the concrete (or abstract, sharding-carrying) stored arrays."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def mesh_of(v):
+        sh = getattr(v, "sharding", None)
+        if isinstance(sh, NamedSharding) and sh.mesh.size > 1:
+            return sh.mesh
+        return None
+
+    meshes = [mesh_of(v) for v in values]
+    mesh = next((m for m in meshes if m is not None), None)
+    if mesh is None:
+        return None
+    return tuple((mesh, v.sharding.spec if m == mesh else PartitionSpec())
+                 for v, m in zip(values, meshes))
+
+
 class Optimizer:
     def __init__(self, learning_rate=0.001, parameters=None, weight_decay=None,
                  grad_clip=None, multi_precision=True, name=None):
@@ -84,9 +108,11 @@ class Optimizer:
         """Pure: lists of arrays -> (new_vals, new_slots). Used under jit.
 
         ``fused_ctx`` (optional, aligned with vals): per-param context for the
-        fused kernel — None for the default whole-array path, or
-        ``(mesh, spec)`` to run it shard_map-wise on sharded state (set by the
-        ZeRO wrapper; replaces any process-global flag toggling)."""
+        fused kernel — None for the default whole-array path (a one-device
+        program), or ``(mesh, spec)`` to run it shard_map-wise on sharded
+        state: :func:`stored_placements` of the params (TrainStep, the
+        pipeline step, ``step()``), overridden per param by the ZeRO
+        wrapper's own plans."""
         if self._grad_clip is not None:
             grads = self._grad_clip.apply(vals, grads)
         fused = getattr(self, "_apply_fused", None)
@@ -188,11 +214,13 @@ class Optimizer:
         from ..core.flags import flag_value
         # the fused-update flag is read at trace time — key the jit cache on
         # it so set_flags toggles take effect on the next step
+        fused_ctx = stored_placements(vals)
         shape_key = tuple((v.shape, str(v.dtype)) for v in vals) + \
             (decay_flags, bool(flag_value("use_fused_adamw")),
-             bool(flag_value("adamw_stochastic_rounding")))
+             bool(flag_value("adamw_stochastic_rounding")), fused_ctx)
         if self._jit_update is None or self._jit_shape_key != shape_key:
-            fn = functools.partial(self._traced_update, decay_flags=decay_flags)
+            fn = functools.partial(self._traced_update, decay_flags=decay_flags,
+                                   fused_ctx=fused_ctx)
             self._jit_update = jax.jit(fn, donate_argnums=(0, 2))
             self._jit_shape_key = shape_key
         new_vals, new_slots = self._jit_update(vals, grads, slots, lr, step)
@@ -200,8 +228,10 @@ class Optimizer:
             p._value = nv
             self._slots[id(p)] = ns
 
-    def _traced_update(self, vals, grads, slots, lr, step, decay_flags):
-        return self.apply_updates(vals, grads, slots, lr, step, decay_flags)
+    def _traced_update(self, vals, grads, slots, lr, step, decay_flags,
+                       fused_ctx=None):
+        return self.apply_updates(vals, grads, slots, lr, step, decay_flags,
+                                  fused_ctx=fused_ctx)
 
     def clear_grad(self, set_to_zero=False):
         for p in self._parameter_list:

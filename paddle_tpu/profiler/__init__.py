@@ -314,7 +314,7 @@ def load_profiler_result(filename):
 def summarize_device_trace(events):
     """Aggregate the DEVICE tracks of an XLA chrome trace into
     ``({instr_name: {"count", "total_us"}}, module_total_us)`` — THE
-    dedupe-aware trace parser (ROUND5_NOTES "found along the way"):
+    dedupe-aware trace parser:
 
     a device lane carries THREE overlapping span families — ``jit_*``
     module spans (the true device step time), the per-instruction op

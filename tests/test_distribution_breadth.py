@@ -19,11 +19,6 @@ class TestNewDistributions:
         np.testing.assert_allclose(c.log_prob(v).numpy(), ref.numpy(),
                                    rtol=1e-5)
 
-    @pytest.mark.skipif(
-        jax.__version_info__ < (0, 5, 0),
-        reason="env-dependent (failing at seed): jax.random.binomial in "
-               "this jax (0.4.x) hits a lax.clamp float64/float32 dtype "
-               "bug under disabled x64")
     def test_binomial(self):
         b = D.Binomial(10.0, np.asarray(0.3, "float32"))
         v = P.to_tensor(np.asarray([0., 3., 10.], "float32"))

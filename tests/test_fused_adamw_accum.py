@@ -315,11 +315,6 @@ class TestStochasticRoundingAdamW:
         assert got[-1] < got[0], "SR training did not progress"
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="env-dependent (failing at seed): the ZeRO-sharded SR kernel "
-           "wrapper needs top-level jax.shard_map, absent in this jax "
-           "(0.4.x keeps it in jax.experimental)")
 def test_stochastic_rounding_under_zero_sharding():
     """SR + ZeRO composition (review finding: the generic fallback would
     DETERMINISTICALLY round bf16 and stall): the shard_map SR kernel runs on

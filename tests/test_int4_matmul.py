@@ -2,7 +2,6 @@
 Reference analog: the weight-only cutlass GEMMs behind
 nn/quant/quantized_linear.py. Runs in interpret mode off-TPU."""
 import numpy as np
-import pytest
 
 import jax.numpy as jnp
 
@@ -21,19 +20,6 @@ def _make(n_in, n_out, seed=0):
     return qw.numpy(), sc.numpy(), deq, rng
 
 
-def _pallas_tpu_has_compiler_params():
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-    except Exception:
-        return False   # no pallas TPU lowering in this build at all
-    return hasattr(pltpu, "CompilerParams")
-
-
-@pytest.mark.skipif(
-    not _pallas_tpu_has_compiler_params(),
-    reason="env-dependent (failing at seed): this jax's pallas.tpu "
-           "predates CompilerParams (only TPUCompilerParams exists), so "
-           "the int4 kernel's interpret-mode pallas_call cannot build")
 def test_matches_dequantized_reference():
     packed, sc, deq, rng = _make(2048, 512)
     for rows in (1, 5, 8):

@@ -107,8 +107,9 @@ def _quant_pools(rng, lens, grow, Hkv, D, BS, quant):
     return kc, vc, ks, vs, tables, np.asarray(lens, np.int32)
 
 
-@pytest.mark.parametrize("quant", ["int8", "int4"])
-@pytest.mark.parametrize("group", [1, 2])
+# the diagonal of {MHA, GQA} x {int8, int4}: both group widths and both
+# packings run; the off-diagonal cells repeat them
+@pytest.mark.parametrize("quant,group", [("int8", 1), ("int4", 2)])
 def test_decode_kernel_parity(rng, quant, group):
     """Quantized decode kernel vs the dense fallback (public op), block
     boundaries len % bs in {0, 1, bs-1}, GQA: outputs to online-softmax

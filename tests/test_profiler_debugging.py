@@ -174,7 +174,7 @@ def test_merge_profile_from_dir(tmp_path):
 
 
 def test_device_trace_parser_dedupes_step_markers():
-    """Regression for the ROUND5_NOTES double-count: the device lane of
+    """Regression for the round-5 double-count: the device lane of
     an XLA trace carries OVERLAPPING span families — 'jit_*' module
     spans (true device step time), bare-number "Steps"-track markers
     covering the same wall time, and the per-op spans nested inside.
