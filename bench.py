@@ -674,7 +674,7 @@ def _bench_other(model_name):
                     "acceptance_rate": (round(acc / prop, 4)
                                         if prop else None),
                     "accepted_per_step": round(
-                        eng.stats["draft_tokens_accepted"]
+                        eng.stats["spec_accepted_tokens"]
                         / max(steps, 1), 2),
                     # per-arm host-RTT share: speculation's win is
                     # FEWER host passes per token — this is the split

@@ -51,7 +51,7 @@ HOT_FUNCTIONS = frozenset({
     "_admit_waiting", "_admit_fused", "_record_dispatch",
     # serving loop: dispatch/readout wrappers, gauge sampling,
     # telemetry stamping
-    "_serve_loop", "_begin_step", "_finish_step", "_update_gauges",
+    "_serve_loop", "_serve_pass", "_begin_step", "_finish_step", "_update_gauges",
     "_feed_engine", "_on_token", "_note_admissions",
     "_sweep_cancels_and_deadlines", "_handle_done",
 })

@@ -47,6 +47,7 @@ from ..analysis import lock_watchdog as _lockwatch
 from ..core.tensor import Tensor, functional_mode
 from ..models.llama import SlotKVCache, _sample_logits_device
 from ..models.lora import lora_scope
+from ..profiler import span
 
 __all__ = ["LLMEngine", "GenerationRequest", "RequestOutput", "PendingStep",
            "PoolCapacityError", "default_engine_stats"]
@@ -60,7 +61,6 @@ def default_engine_stats():
     the full set: a hand-copied dict would silently drift the next time
     a counter is added."""
     return {"steps": 0, "prefill_chunks": 0, "tokens_generated": 0,
-            "draft_tokens_accepted": 0,
             "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
             "preemptions": 0,
             "fused_steps": 0, "multi_steps": 0,
@@ -86,13 +86,48 @@ def default_engine_stats():
             "kv_ship_out_bytes": 0, "kv_ship_in_bytes": 0,
             "swap_out_time_s": 0.0, "swap_in_time_s": 0.0,
             "decode_time_s": 0.0, "admit_time_s": 0.0,
+            "schedule_time_s": 0.0,
             "dispatch_time_s": 0.0, "host_sync_time_s": 0.0,
             "emit_time_s": 0.0,
+            # what a dispatched program computes against what was asked
+            # of it: rows of the step programs (padding included; an
+            # all-decode stride books the iterations it ran at readout),
+            # and per paged dispatch the block-table entries the
+            # attention grid walks and those of them that hold a live
+            # token after the step (host lens mirror)
+            "rows_computed": 0, "kv_grid_blocks": 0, "kv_live_blocks": 0,
+            # a (seconds, count) pair: takes a slot -> first prefill
+            # grant dispatched (accepted -> takes a slot is telemetry's
+            # queue_wait_s histogram, the server's)
+            "slot_wait_time_s": 0.0, "first_grants": 0,
+            # calls of a compiled program that found no entry in its
+            # jit cache: their wall; a program's first is traced as
+            # pt:engine.build
+            "program_build_time_s": 0.0, "programs_built": 0,
             # transfer-guard sanitizer (PADDLE_TPU_TRANSFER_CHECKS=1):
             # all-decode strides whose dispatch->readout window ran
             # under jax.transfer_guard("disallow") — each counted
             # readout is the stride's ONE permitted D2H sync
             "guarded_syncs": 0}
+
+#: the engine's host phases — THE measurement points of a step: phase ->
+#: (its ``pt:engine.*`` trace span, the stats wall sums it adds to).
+#: ``sync`` is the host blocked on the token read, a WAIT; the rest is
+#: host work.
+_PHASES = {
+    "admit": ("pt:engine.admit", ("admit_time_s",)),
+    "schedule": ("pt:engine.schedule", ("schedule_time_s",)),
+    "dispatch": ("pt:engine.dispatch", ("dispatch_time_s", "decode_time_s")),
+    "sync": ("pt:engine.sync", ("host_sync_time_s", "decode_time_s")),
+    "emit": ("pt:engine.emit", ("emit_time_s",)),
+}
+
+#: ``kind`` on a ``pt:engine.dispatch`` span and ``program`` on a
+#: ``pt:engine.build`` span are indices into these
+DISPATCH_KINDS = ("decode", "mixed", "spec")
+PROGRAMS = ("step", "multi_step", "fused_step", "spec_step", "multi_spec",
+            "prefill", "prefill_paged", "set_logits", "set_tokens",
+            "set_len", "set_pooled", "cow", "kv_gather", "kv_scatter")
 
 #: chain-hash seed for block 0 of every sequence (the "parent" of the
 #: first block) — a fixed constant so equal first blocks collide
@@ -223,7 +258,7 @@ class RequestOutput:
 
 class _Slot:
     __slots__ = ("req", "generated", "prompt_len", "prefill_pos", "inflight",
-                 "chain", "reg_blocks", "a_slot")
+                 "chain", "reg_blocks", "a_slot", "t_slot")
 
     def __init__(self, req, prompt_len, prefill_pos=None):
         self.req = req
@@ -252,6 +287,9 @@ class _Slot:
         #: what lets it allocate blocks for step N+1 before step N's
         #: readout and so pipeline at depth 2 on a full pool.
         self.inflight = 0
+        #: perf_counter when the request took this slot, until its first
+        #: prefill grant is dispatched (then None): the slot wait
+        self.t_slot = None
 
     @property
     def ramping(self):
@@ -280,7 +318,8 @@ class PendingStep:
 
     __slots__ = ("toks", "was_active", "counts", "spec", "slots",
                  "pool_done", "sched", "step_id", "fenced", "t_dispatch",
-                 "embed_done", "pooled", "verify", "offered", "guarded")
+                 "embed_done", "pooled", "verify", "offered", "guarded",
+                 "rows")
 
     def __init__(self, toks, was_active, counts, spec, slots, pool_done,
                  sched=None, fenced=None, embed_done=None, verify=None):
@@ -312,6 +351,10 @@ class PendingStep:
         #: newest one: under pipelining the readout must not
         #: synchronize on younger in-flight steps).
         self.embed_done = embed_done or []
+        #: rows each iteration of an early-exit stride computes: the
+        #: readout books them times the iterations that ran (0: the
+        #: dispatch's rows were known and booked when it was dispatched)
+        self.rows = 0
         self.pooled = None
         #: fused speculative dispatches: {slot: drafts granted} — the
         #: readout's acceptance accounting (EWMA + spec counters) and
@@ -722,6 +765,7 @@ class LLMEngine:
         self.fault_injector = None
         self._rec_ctx = None       # per-step_begin wall-split anchors
         self._rec_preempted = []   # rids parked by _preempt_slot this step
+        self._phase = None         # (name, entered at, span) — see _to
         #: compiled multi-step decode programs, keyed by stride K (one
         #: program per distinct effective stride; survives reset())
         self._multi_fns = {}
@@ -939,6 +983,90 @@ class LLMEngine:
         if self.cache_impl == "paged":
             self._check_pool_invariants()
         return self
+
+    # ------------------------------------------------------------------
+    # the measurement points: host phases and program builds
+    # ------------------------------------------------------------------
+    def _to(self, phase, **ids):
+        """Leave the engine's current host phase and enter ``phase`` (None:
+        none) — THE measurement point of step_begin/step_finish: the one
+        clock read at a boundary closes the phase left (its wall goes to
+        its ``stats`` sums, its ``pt:engine.*`` span ends) and opens the
+        one entered (``ids`` ride on its span, with ``pc_ns``, the
+        boundary on the ``perf_counter`` clock in ns, which lays every
+        perf_counter-stamped StepRecord and request event onto a
+        profile's clock). Returns the boundary's ``perf_counter()``.
+        Phases never nest; step_begin and step_finish each leave the
+        last one they entered, on the thread that entered it."""
+        now = time.perf_counter()
+        if self._phase is not None:
+            name, t0, ann = self._phase
+            for key in _PHASES[name][1]:
+                self.stats[key] += now - t0
+            ann.__exit__(None, None, None)
+        if phase is None:
+            self._phase = None
+        else:
+            if ids:
+                ids["pc_ns"] = int(now * 1e9)
+            ann = span(_PHASES[phase][0], **ids)
+            ann.__enter__()
+            self._phase = (phase, now, ann)
+        return now
+
+    def _dispatch_ids(self, kind, rows, live_tokens):
+        """What rides on a ``pt:engine.dispatch`` span. ``step_id`` is the
+        StepRecord's where a recorder is attached, else this dispatch's
+        index among the engine's own, so flight-recorder timelines and
+        the profile join by id."""
+        rec = self._rec()
+        return dict(
+            step_id=(rec.next_step_id() if rec is not None
+                     else self.stats["steps"] + self._inflight),
+            kind=DISPATCH_KINDS.index(kind), rows=int(rows),
+            live_tokens=int(live_tokens))
+
+    def _book_kv_grid(self, iterations):
+        """One paged dispatch's attention grid against what it holds: the
+        grid walks every table entry of every slot, ``iterations`` times
+        (a stride's decode iterations; 1 for a mixed step); an entry is
+        live when it holds a token once the dispatch has landed (host
+        lens mirror, so call this after the mirrors grew). Heads and
+        layers multiply both counts alike."""
+        bs = self.block_size
+        live = sum(-(-s.sched_len() // bs)
+                   for s in self.slots if s is not None)
+        self.stats["kv_grid_blocks"] += iterations * self._tables.size
+        self.stats["kv_live_blocks"] += iterations * min(
+            live, self._tables.size)
+
+    def _program(self, name, fn):
+        """``fn`` (a jitted program) with its builds booked. A build is
+        a call that found no compiled entry in ``fn``'s own jit cache —
+        it traced and compiled, or loaded from the persistent cache —
+        and jit itself says so: its cache grew over the call. The wall
+        of such a call goes to ``program_build_time_s``. The first call
+        of a program is a build before it starts, so it runs under a
+        ``pt:engine.build`` span; a later retrace (a new argument
+        structure, e.g. the first LoRA batch) is known only once the
+        call is back and is booked without one."""
+        pid, first = PROGRAMS.index(name), True
+
+        def call(*args, **kw):
+            nonlocal first
+            size, t0 = fn._cache_size(), time.perf_counter()
+            if first:
+                first = False
+                with span("pt:engine.build", program=pid):
+                    out = fn(*args, **kw)
+            else:
+                out = fn(*args, **kw)
+                if fn._cache_size() == size:
+                    return out
+            self.stats["program_build_time_s"] += time.perf_counter() - t0
+            self.stats["programs_built"] += 1
+            return out
+        return call
 
     # ------------------------------------------------------------------
     # compiled programs
@@ -1626,8 +1754,9 @@ class LLMEngine:
                          for p, cc in zip(v_pools, new_caches)]
                 return _pin_kv(k_out), _pin_kv(v_out), _pin_rep(logits_row)
 
-            self._prefill_paged_fn = jax.jit(prefill_chunk_paged,
-                                             donate_argnums=(1, 2))
+            self._prefill_paged_fn = self._program(
+                "prefill_paged", jax.jit(prefill_chunk_paged,
+                                         donate_argnums=(1, 2)))
 
             def cow_copy(k_pools, v_pools, src, dst):
                 """Copy-on-write block duplication: clone physical block
@@ -1643,7 +1772,8 @@ class LLMEngine:
                 return (_pin_kv(jax.tree_util.tree_map(cp, list(k_pools))),
                         _pin_kv(jax.tree_util.tree_map(cp, list(v_pools))))
 
-            self._cow_fn = jax.jit(cow_copy, donate_argnums=(0, 1))
+            self._cow_fn = self._program(
+                "cow", jax.jit(cow_copy, donate_argnums=(0, 1)))
 
             def kv_gather_blocks(k_pools, v_pools, idx):
                 """Host-tier STAGING gather: physical blocks ``idx`` out
@@ -1662,7 +1792,8 @@ class LLMEngine:
                 return (jax.tree_util.tree_map(g, list(k_pools)),
                         jax.tree_util.tree_map(g, list(v_pools)))
 
-            self._kv_gather_fn = jax.jit(kv_gather_blocks)
+            self._kv_gather_fn = self._program(
+                "kv_gather", jax.jit(kv_gather_blocks))
 
             def kv_scatter_blocks(k_pools, v_pools, idx, k_vals, v_vals):
                 """Host-tier restore scatter (swap-in / spill promote):
@@ -1679,8 +1810,9 @@ class LLMEngine:
                         _pin_kv(jax.tree_util.tree_map(
                             s, list(v_pools), list(v_vals))))
 
-            self._kv_scatter_fn = jax.jit(kv_scatter_blocks,
-                                          donate_argnums=(0, 1))
+            self._kv_scatter_fn = self._program(
+                "kv_scatter", jax.jit(kv_scatter_blocks,
+                                      donate_argnums=(0, 1)))
 
         def set_tokens(tokens_buf, row, slot):
             return jax.lax.dynamic_update_slice(
@@ -1698,19 +1830,26 @@ class LLMEngine:
         # NOT donated: an in-flight PendingStep may still hold this very
         # array as its pooled output (step_finish reads it after the
         # sync) — the zero-row update copies a tiny [B, H] buffer
-        self._set_pooled_fn = jax.jit(set_pooled_zero)
-        self._step_fn = jax.jit(step, donate_argnums=(1, 2, 3))
+        prog = self._program
+        self._set_pooled_fn = prog("set_pooled", jax.jit(set_pooled_zero))
+        self._step_fn = prog("step", jax.jit(step, donate_argnums=(1, 2, 3)))
         # the paged step IS the unified step with `tables` bound — one
         # traced body serves both cache backends
         self._step_paged_fn = self._step_fn
         # same trick for the fused mixed step: one traced body, the
         # `tables` arg selects dense ChunkKVCache vs PagedKVCache
-        self._fused_fn = jax.jit(fused_step, donate_argnums=(1, 2, 3))
-        self._spec_fn = jax.jit(spec_step, donate_argnums=(1, 2, 3, 12))
-        self._prefill_fn = jax.jit(prefill_chunk, donate_argnums=(1, 2))
-        self._set_logits_fn = jax.jit(set_logits, donate_argnums=(0,))
-        self._set_tokens_fn = jax.jit(set_tokens, donate_argnums=(0,))
-        self._set_len_fn = jax.jit(set_len, donate_argnums=(0,))
+        self._fused_fn = prog("fused_step", jax.jit(
+            fused_step, donate_argnums=(1, 2, 3)))
+        self._spec_fn = prog("spec_step", jax.jit(
+            spec_step, donate_argnums=(1, 2, 3, 12)))
+        self._prefill_fn = prog("prefill", jax.jit(
+            prefill_chunk, donate_argnums=(1, 2)))
+        self._set_logits_fn = prog("set_logits", jax.jit(
+            set_logits, donate_argnums=(0,)))
+        self._set_tokens_fn = prog("set_tokens", jax.jit(
+            set_tokens, donate_argnums=(0,)))
+        self._set_len_fn = prog("set_len", jax.jit(
+            set_len, donate_argnums=(0,)))
 
     def _multi_fn(self, stride):
         """The compiled multi-step decode program for ``stride`` — one
@@ -1720,8 +1859,9 @@ class LLMEngine:
         fn = self._multi_fns.get(stride)
         if fn is None:
             self._programs()
-            fn = self._multi_fns[stride] = jax.jit(
-                self._multi_step_factory(stride), donate_argnums=(1, 2, 3))
+            fn = self._multi_fns[stride] = self._program(
+                "multi_step", jax.jit(self._multi_step_factory(stride),
+                                      donate_argnums=(1, 2, 3)))
         return fn
 
     def _multi_spec_fn(self, stride):
@@ -1731,9 +1871,9 @@ class LLMEngine:
         fn = self._multi_spec_fns.get(stride)
         if fn is None:
             self._programs()
-            fn = self._multi_spec_fns[stride] = jax.jit(
-                self._multi_spec_factory(stride),
-                donate_argnums=(1, 2, 3, 14))
+            fn = self._multi_spec_fns[stride] = self._program(
+                "multi_spec", jax.jit(self._multi_spec_factory(stride),
+                                      donate_argnums=(1, 2, 3, 14)))
         return fn
 
     # ------------------------------------------------------------------
@@ -3188,7 +3328,6 @@ class LLMEngine:
         Paged mode returns False when the pool can't cover the prompt.
         ``a_slot``: the request's adapter device row (already acquired
         by the caller) — prefill KV must carry the adapter's deltas."""
-        t0 = time.perf_counter()
         self._programs()
         P = len(req.prompt_ids)
         paged = self.cache_impl == "paged"
@@ -3297,9 +3436,10 @@ class LLMEngine:
             # content (hit blocks are already registered and skip)
             self._register_upto(slot_idx, slot, P)
             self._check_pool_invariants()
-        self.stats["admit_time_s"] += time.perf_counter() - t0
+        # the whole prompt was granted here: no wait in the slot
+        self.stats["first_grants"] += 1
 
-    def _admit_fused(self, slot_idx, req, a_slot=0):
+    def _admit_fused(self, slot_idx, req, a_slot=0, t_slot=None):
         """Fused-scheduler admission: slot ASSIGNMENT plus (prefix cache
         on) the content-store probe — hit blocks attach by table writes
         and refcount bumps, the optional COW tail costs one block clone,
@@ -3307,8 +3447,8 @@ class LLMEngine:
         scheduler grants zero prefill for the shared span. No prefill
         dispatch, no other block allocation (both happen chunk-by-chunk
         inside the step scheduler); admission stays O(hit blocks) and
-        never stalls running decodes."""
-        t0 = time.perf_counter()
+        never stalls running decodes. ``t_slot``: when the request took
+        the slot (the admit phase's entry)."""
         self._programs()
         hit, chain = 0, []
         # swap-store gate, not kv_host_swap: a SHIPPED entry (import_kv)
@@ -3350,6 +3490,7 @@ class LLMEngine:
         slot.chain = chain
         slot.reg_blocks = len(chain)
         slot.a_slot = a_slot
+        slot.t_slot = t_slot
         self.slots[slot_idx] = slot
         if probe_hit:
             # only the CONTENT-STORE hit counts as a prefix hit — the
@@ -3361,7 +3502,6 @@ class LLMEngine:
                               step_id=rec.next_step_id(), value=probe_hit)
         self._admit_order[slot_idx] = self._admit_seq
         self._admit_seq += 1
-        self.stats["admit_time_s"] += time.perf_counter() - t0
 
     def _admit_waiting(self):
         fused = self.scheduler == "fused"
@@ -3413,9 +3553,11 @@ class LLMEngine:
                     # exactly the dry-pool admission shape
                     break
                 self.waiting.popleft()
-                if fused:
-                    self._admit_fused(b, req, a_slot)
-                elif self._admit(b, req, a_slot) is False:
+                t_slot = self._to("admit")
+                admitted = self._admit_fused(b, req, a_slot, t_slot) \
+                    if fused else self._admit(b, req, a_slot)
+                self._to("schedule")
+                if admitted is False:
                     # paged pool dry: requeue and wait for a retirement
                     self.waiting.appendleft(req)
                     self._release_adapter(req.adapter_id)
@@ -3443,14 +3585,14 @@ class LLMEngine:
                          dispatch_s, readout_stride=1):
         """Emit this dispatch's StepRecord (recorder attached and armed
         by step_begin) and stamp ``pending`` with its step id. The
-        admit/schedule splits come from the engine's own stats deltas
-        anchored at step_begin entry, so the record can't drift from
-        what the engine measured."""
+        admit/schedule splits are the engine's own stats deltas since
+        step_begin's entry — what its phases (:meth:`_to`) booked — so
+        the record can't drift from what the engine measured."""
         rec, ctx = self._rec(), self._rec_ctx
         if rec is None or ctx is None:
             return
-        t0, admit0, hits0, swaps0, kvin0, kvout0, shin0, shout0 = ctx
-        wall = time.perf_counter() - t0
+        t0, admit0, sched0, hits0, swaps0, kvin0, kvout0, shin0, shout0 = ctx
+        self._to("schedule")     # books the open schedule phase so far
         admit_s = self.stats["admit_time_s"] - admit0
         paged = self.cache_impl == "paged"
         preempted = tuple(self._rec_preempted) + tuple(
@@ -3463,7 +3605,7 @@ class LLMEngine:
             total_blocks=self.n_blocks if paged else None,
             pipeline_inflight=self._inflight,
             preemptions=preempted, admit_s=admit_s,
-            schedule_s=max(wall - admit_s - dispatch_s, 0.0),
+            schedule_s=self.stats["schedule_time_s"] - sched0,
             dispatch_s=dispatch_s, t_begin=t0,
             prefix_hit_tokens=(self.stats["prefix_hit_tokens"] - hits0
                                if self.prefix_cache else None),
@@ -3591,11 +3733,15 @@ class LLMEngine:
             # sibling replicas tracing through the same model object
             fi.on_step_begin(self)
         with self._dispatch_lock:
-            return self._step_begin_impl()
+            try:
+                return self._step_begin_impl()
+            finally:
+                self._to(None)
 
     def _step_begin_impl(self):
         from ..core import random as _random
 
+        t_begin = self._to("schedule")
         self._note_pool_owner()
         if self.cache_impl == "paged" and self._spill_inbox:
             # pull-on-miss arrivals land BEFORE admission so a request
@@ -3613,8 +3759,9 @@ class LLMEngine:
             # admit-stat baseline (scheduling = wall - admit - dispatch),
             # prefix-hit + adapter-swap baselines (the record carries
             # this step's deltas)
-            self._rec_ctx = (time.perf_counter(),
+            self._rec_ctx = (t_begin,
                              self.stats["admit_time_s"],
+                             self.stats["schedule_time_s"],
                              self.stats["prefix_hit_tokens"],
                              self.stats["adapter_swaps"],
                              self.stats["kv_swap_in_bytes"],
@@ -3793,7 +3940,11 @@ class LLMEngine:
         # throughput() or the serve bench's wall split. All arms DISPATCH
         # only — no host read; JAX async dispatch returns futures and the
         # transfer blocks in step_finish().
-        t0 = time.perf_counter()
+        k_iter = stride if use_multi else self.horizon
+        rows = self.B * (self.speculative_k if spec else 1)
+        t0 = self._to("dispatch", **self._dispatch_ids(
+            "spec" if spec else "decode", rows * k_iter,
+            int(active.sum()) * k_iter))
         counts = None
         if use_multi:
             fn = self._multi_fn(stride)
@@ -3832,10 +3983,11 @@ class LLMEngine:
                 self._state_vals, self._k, self._v, self._logits,
                 self._lens, active, self._rng_key,
                 temps, top_ps, eos_ids, budgets, rids, lora=lora)
-        dt = time.perf_counter() - t0
-        self.stats["dispatch_time_s"] += dt
-        self.stats["decode_time_s"] += dt
+        dt = self._to("schedule") - t0
         self._inflight += 1
+        if not use_multi:
+            # the scan runs its whole horizon whatever deactivates
+            self.stats["rows_computed"] += rows * k_iter
         sched = {}
         if self.scheduler == "fused":
             # host lens mirror for the paged pipeline: a surviving slot
@@ -3857,6 +4009,9 @@ class LLMEngine:
                      for b in np.nonzero(active)[0]
                      if self.slots[b] is not None} if spec else None))
         pending.t_dispatch = t0
+        pending.rows = rows if use_multi else 0
+        if self.cache_impl == "paged":
+            self._book_kv_grid(k_iter)
         if use_multi:
             # all-decode stride dispatched: arm the strict
             # dispatch->readout window (no-op unless
@@ -3974,7 +4129,8 @@ class LLMEngine:
                          self.capacity - 1)
                 self._fence_blocks(int(b), lo, hi, fenced)
 
-        t0 = time.perf_counter()
+        t0 = self._to("dispatch", **self._dispatch_ids(
+            "spec", self.B * Kw * stride, int(spec_qs.sum()) * stride))
         fn = self._multi_spec_fn(stride)
         if paged:
             with self._kernel_tp_ctx():
@@ -3992,9 +4148,7 @@ class LLMEngine:
                 self._lens, active, self._rng_key, temps, top_ps,
                 eos_ids, budgets, rids, spec_qs, row_caps, self._tokens,
                 lora=lora)
-        dt = time.perf_counter() - t0
-        self.stats["dispatch_time_s"] += dt
-        self.stats["decode_time_s"] += dt
+        dt = self._to("schedule") - t0
         self.stats["fused_steps"] += 1
         if stride > 1:
             self.stats["multi_steps"] += 1
@@ -4017,6 +4171,9 @@ class LLMEngine:
                               fenced=fenced, verify=verify)
         pending.t_dispatch = t0
         pending.offered = offered
+        pending.rows = self.B * Kw
+        if paged:
+            self._book_kv_grid(stride)
         if stride > 1:
             # speculative all-decode stride: same one-sync-per-stride
             # window as the dense multi-step path
@@ -4205,7 +4362,8 @@ class LLMEngine:
         spec_args = dict(tokens_buf=self._tokens, spec_ks=spec_ks) \
             if spec else {}
         counts_dev = None
-        t0 = time.perf_counter()
+        t0 = self._to("dispatch", **self._dispatch_ids(
+            "mixed", ids.size, int(q_lens.sum())))
         if self.cache_impl == "paged":
             with self._kernel_tp_ctx():
                 ret = self._fused_fn(
@@ -4233,10 +4391,10 @@ class LLMEngine:
              self._lens, self._rng_key, pooled_out) = ret
         if pooled_out is not None:
             self._pooled = pooled_out
-        dt = time.perf_counter() - t0
-        self.stats["dispatch_time_s"] += dt
-        self.stats["decode_time_s"] += dt
+        dt = self._to("schedule") - t0
         self.stats["fused_steps"] += 1
+        # every row of ``ids`` is computed, granted or padding
+        self.stats["rows_computed"] += ids.size
         # host mirrors of the scheduled growth (dispatch-time, so the
         # next step — possibly dispatched before this one's readout —
         # schedules from the post-step state)
@@ -4249,6 +4407,12 @@ class LLMEngine:
                 if spec:
                     verify[int(b)] = int(spec_ks[b])
             else:
+                if slot.t_slot is not None:
+                    # its first prefill grant: the wait in the slot ends
+                    # with this dispatch
+                    self.stats["slot_wait_time_s"] += t0 - slot.t_slot
+                    self.stats["first_grants"] += 1
+                    slot.t_slot = None
                 slot.prefill_pos += int(q_lens[b])
                 self.stats["prefill_chunks"] += 1
                 self.stats["prefill_tokens"] += int(q_lens[b])
@@ -4264,6 +4428,8 @@ class LLMEngine:
                     # device work lands — step_finish reads + retires
                     embed_done.append((int(b), slot))
         self._inflight += 1
+        if self.cache_impl == "paged":
+            self._book_kv_grid(1)
         pending = PendingStep(toks, was_active, counts_dev, spec,
                               list(self.slots), pool_done, sched=sched,
                               fenced=fenced, embed_done=embed_done,
@@ -4304,6 +4470,12 @@ class LLMEngine:
         fi = self.fault_injector
         if fi is not None:
             fi.on_step_finish(self)
+        try:
+            return self._step_finish_impl(pending)
+        finally:
+            self._to(None)
+
+    def _step_finish_impl(self, pending):
         spec = pending.spec
         rec = self._rec()
         sid = pending.step_id
@@ -4319,7 +4491,10 @@ class LLMEngine:
             slot = pending.slots[b]
             if slot is not None and self.slots[b] is slot:
                 slot.inflight = max(0, slot.inflight - n)
-        t0 = time.perf_counter()
+        # the id its dispatch span carried: the recorder's, else the
+        # count of steps finished before it
+        ids = {"step_id": sid if sid is not None else self.stats["steps"]}
+        t0 = self._to("sync", **ids)
         if spec:
             toks3 = np.asarray(pending.toks)          # [Kh, B, Kspec]
             counts_np = np.asarray(pending.counts)    # [Kh, B]
@@ -4340,9 +4515,7 @@ class LLMEngine:
         else:
             toks_np = np.asarray(pending.toks)       # [K, B] — THE transfer
             act_np = np.asarray(pending.was_active)  # [K, B]
-        dt = time.perf_counter() - t0
-        self.stats["host_sync_time_s"] += dt
-        self.stats["decode_time_s"] += dt
+        dt = self._to(None) - t0
         self.stats["steps"] += 1
         if pending.guarded:
             # THE stride's one documented D2H sync just happened — the
@@ -4386,13 +4559,13 @@ class LLMEngine:
         else:
             row_boundary = np.arange(toks_np.shape[0])
             n_exec = int(act_np.any(axis=1).sum())
+        # an early-exit stride's rows, now that the iterations it ran are
+        # known (at least the one every dispatch runs)
+        self.stats["rows_computed"] += pending.rows * max(n_exec, 1)
+        now_pc = t0 = self._to("emit", **ids)
         if toks_np.shape[0] > 1 and pending.t_dispatch is not None \
                 and n_exec > 1:
-            per_row = max(
-                time.perf_counter() - pending.t_dispatch, 0.0) / n_exec
-        now_pc = time.perf_counter()
-
-        t0 = time.perf_counter()
+            per_row = max(now_pc - pending.t_dispatch, 0.0) / n_exec
         done = list(pending.pool_done)
         spec_acc_total = spec_rej_total = 0
         for b, slot in enumerate(pending.slots):
@@ -4453,7 +4626,6 @@ class LLMEngine:
                     1 for k in range(toks_np.shape[0])
                     if act_np[k, b] and k % Ks == 0)
                 accepted = max(n_read - n_committed, 0)
-                self.stats["draft_tokens_accepted"] += accepted
                 # acceptance accounting: proposed = drafts the device
                 # actually OFFERED this slot — per-window offered widths
                 # read back from the fused programs (the in-graph
@@ -4539,8 +4711,7 @@ class LLMEngine:
             done.append(out)
             self._free_slot(b)
         self.emit_backdate_s = 0.0
-        d_emit = time.perf_counter() - t0
-        self.stats["emit_time_s"] += d_emit
+        d_emit = self._to(None) - t0
         if rec is not None and sid is not None:
             rec.finish_step(sid, dt, d_emit,
                             tuple(out.request_id for out in done),

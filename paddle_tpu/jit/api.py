@@ -10,6 +10,7 @@ of the reference's Plan/Job executor running a whole iteration.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from typing import Callable
@@ -22,6 +23,7 @@ from ..core.tensor import Tensor, functional_mode, no_grad
 from ..core import random as _random
 from ..nn.layer_base import Layer
 from ..optimizer.optimizer import stored_placements
+from ..profiler import span
 from .functional_call import collect_state, bind_state, read_values
 
 
@@ -478,34 +480,50 @@ class TrainStep:
         optimizer._ensure_slots(self.params)
 
     def __call__(self, *batch):
-        if self.accumulate_steps > 1:
-            return self._call_accumulate(*batch)
+        """One optimizer step, in a profile ``pt:train.step`` (``step``:
+        the optimizer's step count) over its host phases: ``prepare``
+        (batch leaves, state values, key, lr), ``build`` (a new batch
+        signature only: the program is made and, in the ``dispatch``
+        inside it, traced and compiled), ``dispatch`` (the jitted call)
+        and ``commit`` (the new values written back). All host work: the
+        device is waited for by whoever reads the loss."""
+        with span("pt:train.step", step=self.optimizer._step_count + 1):
+            if self.accumulate_steps > 1:
+                return self._call_accumulate(*batch)
+            return self._call_fused(*batch)
+
+    def _call_fused(self, *batch):
         opt = self.optimizer
-        dyn, static_key, layout, treedef = _split_leaves(batch)
-        param_vals = read_values(self.params)
-        fused_ctx = stored_placements(param_vals)
-        key = self._step_key(dyn, static_key, layout, treedef, fused_ctx)
+        with span("pt:train.prepare"):
+            dyn, static_key, layout, treedef = _split_leaves(batch)
+            param_vals = read_values(self.params)
+            fused_ctx = stored_placements(param_vals)
+            key = self._step_key(dyn, static_key, layout, treedef, fused_ctx)
+            slot_vals = [opt._slots[id(p)] for p in self.params]
+            buf_vals = read_values(self.buffers)
+            frozen_vals = read_values(self.frozen)
+            opt._step_count += 1
+            lr = jnp.asarray(opt.get_lr(), jnp.float32)
+            step_i = jnp.asarray(opt._step_count, jnp.int32)
+            rng_key = _random.next_key()
 
-        if key not in self._cache:
-            self._cache[key] = self._build_step_jit(static_key, layout,
-                                                    treedef, fused_ctx)
-
-        slot_vals = [opt._slots[id(p)] for p in self.params]
-        buf_vals = read_values(self.buffers)
-        frozen_vals = read_values(self.frozen)
-        opt._step_count += 1
-        lr = jnp.asarray(opt.get_lr(), jnp.float32)
-        step_i = jnp.asarray(opt._step_count, jnp.int32)
-        rng_key = _random.next_key()
-
-        loss_val, new_pv, new_slots, new_bufs = self._cache[key](
-            param_vals, slot_vals, buf_vals, frozen_vals, lr, step_i, rng_key, dyn)
-        for p, nv in zip(self.params, new_pv):
-            p._value = nv
-        for p, ns in zip(self.params, new_slots):
-            opt._slots[id(p)] = ns
-        for b, nv in zip(self.buffers, new_bufs):
-            b._value = nv
+        fn = self._cache.get(key)
+        with span("pt:train.build") if fn is None \
+                else contextlib.nullcontext():
+            if fn is None:
+                fn = self._cache[key] = self._build_step_jit(
+                    static_key, layout, treedef, fused_ctx)
+            with span("pt:train.dispatch"):
+                out = fn(param_vals, slot_vals, buf_vals, frozen_vals, lr,
+                         step_i, rng_key, dyn)
+        loss_val, new_pv, new_slots, new_bufs = out
+        with span("pt:train.commit"):
+            for p, nv in zip(self.params, new_pv):
+                p._value = nv
+            for p, ns in zip(self.params, new_slots):
+                opt._slots[id(p)] = ns
+            for b, nv in zip(self.buffers, new_bufs):
+                b._value = nv
         return Tensor(loss_val)
 
     def _step_key(self, dyn, static_key, layout, treedef, fused_ctx):
@@ -736,8 +754,9 @@ class TrainStep:
         buf_vals = read_values(self.buffers)
         frozen_vals = read_values(self.frozen)
         rng_key = _random.next_key()
-        loss_val, self._acc, new_bufs = self._grad_cache[key](
-            param_vals, self._acc, buf_vals, frozen_vals, rng_key, dyn)
+        with span("pt:train.dispatch"):
+            loss_val, self._acc, new_bufs = self._grad_cache[key](
+                param_vals, self._acc, buf_vals, frozen_vals, rng_key, dyn)
         for b, nv in zip(self.buffers, new_bufs):
             b._value = nv
         self._acc_count += 1
@@ -746,8 +765,9 @@ class TrainStep:
             opt._step_count += 1
             lr = jnp.asarray(opt.get_lr(), jnp.float32)
             step_i = jnp.asarray(opt._step_count, jnp.int32)
-            new_pv, new_slots = self._update_fn(
-                param_vals, slot_vals, self._acc, lr, step_i)
+            with span("pt:train.dispatch"):
+                new_pv, new_slots = self._update_fn(
+                    param_vals, slot_vals, self._acc, lr, step_i)
             for p, nv in zip(self.params, new_pv):
                 p._value = nv
             for p, ns in zip(self.params, new_slots):

@@ -16,6 +16,7 @@ import threading
 import time
 from enum import Enum
 
+from ._span import span  # noqa: F401
 from .timer import benchmark  # noqa: F401
 from .serving_telemetry import (  # noqa: F401
     LABELED_GAUGE_FAMILIES, LatencyHistogram, ServingTelemetry)
@@ -31,7 +32,7 @@ from .slo import (  # noqa: F401
     SLO, SLOEngine, default_detectors, evaluate_slo, format_slo_report)
 
 __all__ = [
-    "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
+    "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent", "span",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
     "summarize_device_trace",
     "SummaryView", "benchmark", "merge_profile",
@@ -79,14 +80,14 @@ _BUFFER = _EventBuffer()
 
 class RecordEvent:
     """Host-side scope event (reference: profiler/utils.py:47). While a
-    profiler is recording it also enters a jax named_scope so the span
-    shows up inside device traces under jit.
+    profiler is recording it is also a :func:`span` of the same name, so
+    it shows on the host plane of a device profile taken alongside.
 
     When NO profiler is recording, enter/exit is a single flag check —
-    no clock read, no jax import, no named_scope — so always-on
-    instrumentation (library internals wrapping hot paths in
-    RecordEvent) costs ~nothing in production. A profiler that starts
-    recording mid-event picks the event up from its NEXT entry."""
+    no clock read, no span — so always-on instrumentation (library
+    internals wrapping hot paths in RecordEvent) costs ~nothing in
+    production. A profiler that starts recording mid-event picks the
+    event up from its NEXT entry."""
 
     def __init__(self, name, event_type=None):
         self.name = name
@@ -104,21 +105,16 @@ class RecordEvent:
             self._t0 = None  # disabled fast path: nothing to undo on exit
             self._scope = None
             return self
+        self._scope = span(self.name)
+        self._scope.__enter__()
         self._t0 = time.perf_counter_ns()
-        try:
-            import jax
-            self._scope = jax.named_scope(self.name)
-            self._scope.__enter__()
-        except Exception:
-            self._scope = None
         return self
 
     def __exit__(self, *exc):
-        if self._scope is not None:
-            self._scope.__exit__(*exc)
         if self._t0 is None:
             return False  # entered while disabled: no span to record
         t1 = time.perf_counter_ns()
+        self._scope.__exit__(*exc)
         _BUFFER.add(self.name, self._t0 / 1e3, (t1 - self._t0) / 1e3,
                     threading.get_ident())
         return False

@@ -39,6 +39,8 @@ import contextlib
 import threading
 import time
 
+from ._span import span
+
 __all__ = ["LatencyHistogram", "ServingTelemetry", "STAGES", "GAUGES",
            "LABELED_GAUGE_FAMILIES"]
 
@@ -47,6 +49,14 @@ __all__ = ["LatencyHistogram", "ServingTelemetry", "STAGES", "GAUGES",
 #: "other", the loop's own bookkeeping remainder).
 STAGES = ("queue_admit", "prefill_dispatch", "schedule", "decode_dispatch",
           "host_sync", "emit", "idle", "other")
+
+#: the serve loop's trace spans (:func:`paddle_tpu.profiler.span`), by the
+#: stage whose block opens them; ``begin``/``finish``/``pass`` are asked
+#: for by name. ``pt:server.idle`` is the loop WAITING for work; every
+#: other one is host work.
+SERVER_SPANS = {"idle": "pt:server.idle", "queue_admit": "pt:server.admit_queue",
+                "other": "pt:server.sweep", "begin": "pt:server.begin",
+                "finish": "pt:server.finish", "pass": "pt:server.pass"}
 
 #: point-in-time gauges the serve loop samples each pass (pool gauges
 #: stay 0 on the dense engine; budget utilization needs the flight
@@ -240,6 +250,8 @@ class ServingTelemetry:
 
     def __init__(self, replica=None):
         self._lock = threading.Lock()
+        #: the innermost open stage()'s booked seconds (serve-loop thread)
+        self._open_stage = None
         self.replica = replica
         #: extension names declared via register(); they survive reset()
         self._extra = {"stage": set(), "counter": set(), "gauge": set()}
@@ -314,12 +326,26 @@ class ServingTelemetry:
             self.stage_s[name] += dt
 
     @contextlib.contextmanager
-    def stage(self, name):
+    def stage(self, name, span_name=None):
+        """Time the block into stage ``name`` and show it in a profile as
+        the ``pt:server.*`` span of ``span_name`` (default: the stage's
+        own) — one enter/exit feeds both. ``name`` gets the block's SELF
+        time: a stage opened inside it (the serve loop's thread only
+        opens stages) takes its own wall out of it, and so do the seconds
+        the block books to other stages itself (the engine's own splits
+        of a step) into the one-item list this yields."""
+        booked, outer = [0.0], self._open_stage
+        self._open_stage = booked
         t0 = time.perf_counter()
         try:
-            yield
+            with span(SERVER_SPANS[span_name or name]):
+                yield booked
         finally:
-            self.add_stage(name, time.perf_counter() - t0)
+            dt = time.perf_counter() - t0
+            self._open_stage = outer
+            if outer is not None:
+                outer[0] += dt
+            self.add_stage(name, dt - booked[0])
 
     def inc(self, name, n=1):
         with self._lock:

@@ -694,54 +694,63 @@ class AsyncLLMServer:
                     return
 
     def _serve_loop(self):
-        tel = self.telemetry
         # the in-flight dispatch window, oldest first: up to
         # pipeline_depth step_begin()s run ahead of the oldest sync
         # (depth 2 reproduces the pre-deque loop's exact call sequence:
         # begin, begin, finish | begin, finish | ...)
         pending = collections.deque()
         while True:
-            # the watchdog heartbeat: ONE monotonic read per pass (the
-            # whole supervision-off/on overhead budget rides on this
-            # line staying this cheap)
-            self._heartbeat = time.monotonic()
-            self._hung = False
-            # "other" covers the loop's own bookkeeping (cancel/
-            # deadline sweeps, finish routing, gauge sampling) so the
-            # attribution explains the busy wall to >= 0.9, not ~0.7
-            with tel.stage("other"):
-                self._sweep_cancels_and_deadlines()
-                self._update_gauges()
-            with tel.stage("queue_admit"):
-                self._feed_engine()
-                self._mark_admission_stalls()
-            # THE pipelined-dispatch move: fill the in-flight window
-            # before blocking on the oldest step's token transfer
-            while len(pending) < self.pipeline_depth:
-                try:
-                    nxt = self._begin_step()
-                except PoolCapacityError as e:
-                    # exactly the head-request-can-never-admit signal
-                    # (its prompt outgrew the paged pool): fail THAT
-                    # request, not the server. Any other error (device,
-                    # compile) falls to the supervisor.
-                    self._fail_head_waiting(e)
-                    break
-                if nxt is None:
-                    break
-                pending.append(nxt)
-            if not pending:
-                if self._stopping and not self.num_outstanding() \
-                        and len(self._queue) == 0:
+            # one loop iteration: in a profile the parent of every other
+            # pt:server.* span and, through them, of the engine's; in the
+            # attribution its self time, the loop's own glue between the
+            # stages below, is "other"
+            with self.telemetry.stage("other", "pass"):
+                if self._serve_pass(pending):
                     return
-                with tel.stage("idle"):
-                    self._work_evt.wait(self.poll_interval_s)
-                    self._work_evt.clear()
-                continue
-            done = self._finish_step(pending.popleft())
-            if done:
-                with tel.stage("other"):
-                    self._handle_done(done)
+
+    def _serve_pass(self, pending):
+        """One iteration of the serve loop over the in-flight window
+        ``pending``; True when the loop is to end."""
+        tel = self.telemetry
+        # the watchdog heartbeat: ONE monotonic read per pass (the
+        # whole supervision-off/on overhead budget rides on this
+        # line staying this cheap)
+        self._heartbeat = time.monotonic()
+        self._hung = False
+        # "other" covers the loop's own bookkeeping (cancel/
+        # deadline sweeps, finish routing, gauge sampling) so the
+        # attribution explains the busy wall to >= 0.9, not ~0.7
+        with tel.stage("other"):
+            self._sweep_cancels_and_deadlines()
+            self._update_gauges()
+        with tel.stage("queue_admit"):
+            self._feed_engine()
+            self._mark_admission_stalls()
+        # THE pipelined-dispatch move: fill the in-flight window
+        # before blocking on the oldest step's token transfer
+        while len(pending) < self.pipeline_depth:
+            try:
+                nxt = self._begin_step()
+            except PoolCapacityError as e:
+                # exactly the head-request-can-never-admit signal
+                # (its prompt outgrew the paged pool): fail THAT
+                # request, not the server. Any other error (device,
+                # compile) falls to the supervisor.
+                self._fail_head_waiting(e)
+                break
+            if nxt is None:
+                break
+            pending.append(nxt)
+        if not pending:
+            if self._stopping and not self.num_outstanding() \
+                    and len(self._queue) == 0:
+                return True
+            with tel.stage("idle"):
+                self._work_evt.wait(self.poll_interval_s)
+                self._work_evt.clear()
+            return False
+        self._finish_step(pending.popleft())
+        return False
 
     def _recover(self, exc):
         """Crash handler. Returns True when the serve loop should
@@ -929,15 +938,14 @@ class AsyncLLMServer:
                                            "kv_swap_saved_tokens",
                                            "kv_spill_blocks",
                                            "kv_promote_blocks")}
-        t0 = time.perf_counter()
-        pending = eng.step_begin()
-        wall = time.perf_counter() - t0
-        d_admit = eng.stats["admit_time_s"] - s_admit
-        d_disp = eng.stats["dispatch_time_s"] - s_disp
+        with tel.stage("schedule", "begin") as booked:
+            pending = eng.step_begin()
+            d_admit = eng.stats["admit_time_s"] - s_admit
+            d_disp = eng.stats["dispatch_time_s"] - s_disp
+            booked[0] += d_admit + d_disp
         d_ptok = eng.stats["prefill_tokens"] - s_ptok
         tel.add_stage("prefill_dispatch", d_admit)
         tel.add_stage("decode_dispatch", d_disp)
-        tel.add_stage("schedule", max(wall - d_admit - d_disp, 0.0))
         if d_ptok:
             tel.inc("prefill_tokens", d_ptok)
         for key, before in s_pfx.items():
@@ -959,7 +967,8 @@ class AsyncLLMServer:
 
     def _finish_step(self, pending):
         """engine.step_finish() with its wall split into the device→host
-        token sync and the readout/emit remainder."""
+        token sync and the readout/emit remainder, then the routing of
+        what finished (the remainder's "other")."""
         eng, tel = self.engine, self.telemetry
         s_sync = eng.stats["host_sync_time_s"]
         s_emit = eng.stats["emit_time_s"]
@@ -967,19 +976,23 @@ class AsyncLLMServer:
         # where the engine learns which drafts committed)
         s_spec = {k: eng.stats[k] for k in ("spec_proposed_tokens",
                                             "spec_accepted_tokens")}
-        t0 = time.perf_counter()
-        done = eng.step_finish(pending)
-        wall = time.perf_counter() - t0
-        d_sync = eng.stats["host_sync_time_s"] - s_sync
-        d_emit = eng.stats["emit_time_s"] - s_emit
-        tel.add_stage("host_sync", d_sync)
-        tel.add_stage("emit", d_emit)
-        tel.add_stage("other", max(wall - d_sync - d_emit, 0.0))
-        tel.inc("engine_steps")
-        for key, before in s_spec.items():
-            if eng.stats[key] > before:
-                tel.inc(key, eng.stats[key] - before)
-        return done
+        with tel.stage("other", "finish") as booked:
+            done = eng.step_finish(pending)
+            # the step's device buffers go HERE, before anyone hears of
+            # its results: freeing them lets other threads run, and a
+            # client woken first would find the engine still mid-step
+            del pending
+            d_sync = eng.stats["host_sync_time_s"] - s_sync
+            d_emit = eng.stats["emit_time_s"] - s_emit
+            booked[0] += d_sync + d_emit
+            tel.add_stage("host_sync", d_sync)
+            tel.add_stage("emit", d_emit)
+            tel.inc("engine_steps")
+            for key, before in s_spec.items():
+                if eng.stats[key] > before:
+                    tel.inc(key, eng.stats[key] - before)
+            if done:
+                self._handle_done(done)
 
     def _admission_estimate_s(self):
         """Telemetry-estimated latency a fresh submission pays before its

@@ -115,7 +115,7 @@ def test_greedy_parity_matrix(tiny_model, greedy_ref, cache_impl, prefix,
     out = [o.token_ids for o in eng.generate(prompts, max_new_tokens=10)]
     assert out == ref
     assert eng.stats["spec_proposed_tokens"] > 0
-    assert eng.stats["draft_tokens_accepted"] > 0  # repetitive prompt
+    assert eng.stats["spec_accepted_tokens"] > 0  # repetitive prompt
     if stride > 1:
         assert eng.stats["multi_steps"] > 0        # stride composition
     if cache_impl == "paged":

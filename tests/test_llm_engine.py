@@ -264,7 +264,7 @@ model changed?) — pick a new search seed"
                         chunk_size=16, speculative_k=6)
         (out,) = eng.generate([p], max_new_tokens=n)
         assert out.token_ids == _greedy_ref(tiny_model, p, n)
-        assert eng.stats["draft_tokens_accepted"] > 0
+        assert eng.stats["spec_accepted_tokens"] > 0
         assert eng.stats["steps"] < n
 
     def test_sampling_slot_decodes_beside_greedy(self, tiny_model):
@@ -301,7 +301,7 @@ model changed?) — pick a new search seed"
         # up to horizon*speculative_k tokens per step: a repetitive stream
         # must beat plain horizon=3 (24/3 = 8 steps)
         assert eng.stats["steps"] < 8
-        assert eng.stats["draft_tokens_accepted"] > 0
+        assert eng.stats["spec_accepted_tokens"] > 0
 
 
 def test_lookup_draft_device():
@@ -345,8 +345,8 @@ def test_spec_coupled_acceptance_sampled_token_exact(tiny_model):
     assert got == want
     # acceptance accounting feeds the telemetry counters
     assert spec.stats["spec_proposed_tokens"] > 0
-    assert spec.stats["spec_accepted_tokens"] == \
-        spec.stats["draft_tokens_accepted"]
+    assert 0 <= spec.stats["spec_accepted_tokens"] <= \
+        spec.stats["spec_proposed_tokens"]
 
 
 @pytest.mark.slow   # tier-1 wall budget (PR 14): TP parity stays

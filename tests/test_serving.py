@@ -258,6 +258,42 @@ def test_latency_histogram_quantiles_and_prometheus():
     assert any(line.startswith("x_seconds_sum") for line in lines)
 
 
+def test_a_steps_buffers_are_freed_before_its_results_are_routed(dense_eng):
+    """Results are routed (handles finished, clients woken) only once the
+    finished step's PendingStep is gone: freeing its device buffers lets
+    other threads run, and a client woken before that finds the engine
+    still mid-step (an audit of a live replica's pool then races it)."""
+    import gc
+    eng = _fresh(dense_eng)
+    server = AsyncLLMServer(eng, max_queue_size=16)
+    last, alive_at_routing = [], []
+    step_finish, handle_done = eng.step_finish, server._handle_done
+
+    def spy_finish(pending):
+        last[:] = [id(pending), type(pending)]
+        return step_finish(pending)
+
+    def spy_done(done):
+        # with a later step in flight (the loop's own name for the last
+        # begun step is then that one). No step begins between a finish
+        # and its routing, so an object of that type at that address is
+        # the finished step itself
+        if eng._inflight:
+            alive_at_routing.append(any(
+                id(o) == last[0] for o in gc.get_objects()
+                if type(o) is last[1]))
+        return handle_done(done)
+    eng.step_finish, server._handle_done = spy_finish, spy_done
+    try:
+        with server:
+            for h in [server.submit(p, max_new_tokens=5)
+                      for p in _prompts(3, (7, 12, 5))]:
+                h.result(timeout=240)
+    finally:
+        eng.step_finish = step_finish
+    assert alive_at_routing and not any(alive_at_routing)
+
+
 def test_telemetry_snapshot_schema_and_attribution(dense_eng):
     """The snapshot carries every named stage, the latency histograms,
     and an attribution that explains (nearly) all of a busy serve
