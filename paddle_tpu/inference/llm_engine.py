@@ -96,6 +96,11 @@ def default_engine_stats():
             # attention grid walks and those of them that hold a live
             # token after the step (host lens mirror)
             "rows_computed": 0, "kv_grid_blocks": 0, "kv_live_blocks": 0,
+            # per mixed paged dispatch: (row tile, table entry) pairs the
+            # append kernel computes for this step's (lens, q_lens)
+            # against every row tile x every entry of every slot (the
+            # kernel module's own count, paged_attention.append_tile_steps)
+            "attn_tile_steps": 0, "attn_tile_steps_grid": 0,
             # a (seconds, count) pair: takes a slot -> first prefill
             # grant dispatched (accepted -> takes a slot is telemetry's
             # queue_wait_s histogram, the server's)
@@ -1014,25 +1019,43 @@ class LLMEngine:
             self._phase = (phase, now, ann)
         return now
 
-    def _dispatch_ids(self, kind, rows, live_tokens):
+    def _dispatch_ids(self, kind, rows, live_tokens, live_tiles=None):
         """What rides on a ``pt:engine.dispatch`` span. ``step_id`` is the
         StepRecord's where a recorder is attached, else this dispatch's
         index among the engine's own, so flight-recorder timelines and
-        the profile join by id."""
+        the profile join by id. ``live_tiles``: a mixed paged step's
+        ``attn_tile_steps``."""
         rec = self._rec()
-        return dict(
+        ids = dict(
             step_id=(rec.next_step_id() if rec is not None
                      else self.stats["steps"] + self._inflight),
             kind=DISPATCH_KINDS.index(kind), rows=int(rows),
             live_tokens=int(live_tokens))
+        if live_tiles is not None:
+            ids["live_tiles"] = int(live_tiles)
+        return ids
+
+    def _attn_tile_steps(self, q_lens):
+        """``(run, grid)`` row-tile steps of the append kernel for a
+        mixed paged step granting ``q_lens``, per kv head and layer (they
+        multiply both alike): the kernel module's own count over the
+        host's lens mirror, so call this BEFORE the mirrors grow."""
+        from ..ops.kernels.paged_attention import append_tile_steps
+        c = self.model.config
+        lens = [0 if s is None else s.sched_len() for s in self.slots]
+        return append_tile_steps(
+            lens, q_lens, c.num_attention_heads // c.num_key_value_heads,
+            self.chunk, self.block_size, self._tables.shape[1])
 
     def _book_kv_grid(self, iterations):
         """One paged dispatch's attention grid against what it holds: the
-        grid walks every table entry of every slot, ``iterations`` times
-        (a stride's decode iterations; 1 for a mixed step); an entry is
-        live when it holds a token once the dispatch has landed (host
-        lens mirror, so call this after the mirrors grew). Heads and
-        layers multiply both counts alike."""
+        walk visits every table entry of every slot, ``iterations`` times
+        (a stride's decode iterations; 1 for a mixed step) — the append
+        kernel's too: it skips the work of a dead entry, not the entry
+        (one grid step for all of its kv heads); an entry is live when it
+        holds a token once the dispatch has landed (host lens mirror, so
+        call this after the mirrors grew). Layers multiply both counts
+        alike, and so do heads in the decode kernel."""
         bs = self.block_size
         live = sum(-(-s.sched_len() // bs)
                    for s in self.slots if s is not None)
@@ -4362,8 +4385,11 @@ class LLMEngine:
         spec_args = dict(tokens_buf=self._tokens, spec_ks=spec_ks) \
             if spec else {}
         counts_dev = None
+        tiles = self._attn_tile_steps(q_lens) \
+            if self.cache_impl == "paged" else None
         t0 = self._to("dispatch", **self._dispatch_ids(
-            "mixed", ids.size, int(q_lens.sum())))
+            "mixed", ids.size, int(q_lens.sum()),
+            live_tiles=tiles and tiles[0]))
         if self.cache_impl == "paged":
             with self._kernel_tp_ctx():
                 ret = self._fused_fn(
@@ -4430,6 +4456,8 @@ class LLMEngine:
         self._inflight += 1
         if self.cache_impl == "paged":
             self._book_kv_grid(1)
+            self.stats["attn_tile_steps"] += tiles[0]
+            self.stats["attn_tile_steps_grid"] += tiles[1]
         pending = PendingStep(toks, was_active, counts_dev, spec,
                               list(self.slots), pool_done, sched=sched,
                               fenced=fenced, embed_done=embed_done,

@@ -54,8 +54,13 @@ sequence appends ``q_lens[b]`` new positions at ``seq_lens[b]``, every
 query row attends causally to its own chunk prefix plus all prior pooled
 KV, and the whole chunk's K/V writes back to the pools in-kernel (the
 write can span several blocks; each overlapped block is merged in VMEM
-and stored through the aliased pool outputs). Same gating: TPU fast path
-behind ``FLAGS_use_paged_attention``, dense append fallback on CPU.
+and stored through the aliased pool outputs). Its work follows the
+scalar-prefetched ``(seq_lens, q_lens)``: rows position-major so live rows
+are a prefix, row tiles of a derived size of which only the live and
+causally visible run, every kv head of a table entry in one grid step, the
+merge for window blocks only (see :func:`paged_attention_append`). Same
+gating: TPU fast path behind ``FLAGS_use_paged_attention``, dense append
+fallback on CPU.
 
 **Quantized KV pools** (``quant="int8"|"int4"``, the serving engine's
 ``kv_cache_dtype``): the physical pools store int8 (or int4
@@ -620,6 +625,110 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
 # append attention: q_len = chunk (the fused prefill+decode mixed step)
 # ---------------------------------------------------------------------------
 
+#: most query rows in one row tile. A (tile, block, head) update costs
+#: ~0.35 us before its rows cost anything on a v5e (the K block is the
+#: MXU's stationary operand and an f32 product loads it several times), so
+#: tiles want to be tall; past 256 rows the tile's f32 scores and
+#: accumulator spill and a full chunk runs slower than untiled (PERF.md
+#: section 6, PR 26: 128 / 256 / 512 rows = 8.1 / 5.4 / 7.1 ms a call)
+_ROW_TILE_MAX = 256
+#: rows a tile runs when its live rows end inside them (see _row_subtile)
+_ROW_SUBTILE = 32
+#: VMEM the append call plans its per-step buffers into (a v5e core has
+#: 128 MiB; the compiler's own default limit for a kernel is 16 MiB and
+#: the call raises it to what it planned, see ``_append_vmem_bytes``)
+_APPEND_VMEM_BUDGET = 40 << 20
+
+
+def _row_tile(g, s):
+    """Rows of one query row tile of a slot's ``g * s`` rows: the largest
+    multiple of 16 (a packed bf16 sublane tile) up to ``_ROW_TILE_MAX``
+    that divides them, or all of them when they are few or no such size
+    divides them. Static shapes only: no option sets it."""
+    rows = g * s
+    if rows <= _ROW_TILE_MAX:
+        return rows
+    for tr in range(_ROW_TILE_MAX - _ROW_TILE_MAX % 16, 15, -16):
+        if rows % tr == 0:
+            return tr
+    return rows
+
+
+def _row_subtile(tr):
+    """Rows a tile runs when its live rows end inside them: one native
+    (32, 128) tile, the tallest among the pool dtypes' — a decode row's
+    ``g`` rows or a verify window's fit it at the usual group sizes —
+    or the whole tile where that does not divide it."""
+    return _ROW_SUBTILE if tr % _ROW_SUBTILE == 0 else tr
+
+
+def _append_vmem_bytes(hb, g, s, d, bs, dk, q_isz, pool_isz, new_isz):
+    """VMEM one grid step of the append call holds with ``hb`` kv heads a
+    step: q and out tiles, pool blocks in and out and the chunk's K/V
+    (each double-buffered by the pipeline), and the f32 scratch (m and l
+    are one lane wide and pad to 128)."""
+    rows = g * s
+    q_out = 2 * 2 * hb * rows * d * q_isz
+    pools = 2 * 2 * 2 * hb * bs * dk * pool_isz
+    new = 2 * 2 * hb * s * d * new_isz
+    scratch = hb * rows * (d + 2 * 128) * 4
+    return q_out + pools + new + scratch
+
+
+def _heads_per_step(hkv, *shape):
+    """KV heads one grid step serves: every head of a block when their
+    buffers fit ``_APPEND_VMEM_BUDGET`` (one DMA brings the block's heads,
+    which lie together in the pool, and the grid is ``hkv`` times
+    shorter), else the largest divisor of ``hkv`` that fits."""
+    for hb in range(hkv, 1, -1):
+        if hkv % hb == 0 and \
+                _append_vmem_bytes(hb, *shape) <= _APPEND_VMEM_BUDGET:
+            return hb
+    return 1
+
+
+def _div_i32(a, b):
+    # lax.div keeps i32 under x64 (see _last_live)
+    return jax.lax.div(a, np.int32(b))
+
+
+def _tile_span(L, QL, jj, g, bs, tr, xp, div):
+    """THE skip rule of the append kernel: the row tiles ``[t_lo, t_end)``
+    of a slot that table entry ``jj`` has work for. Rows are
+    position-major (row ``i * g + q_head``), so the ``QL * g`` live rows
+    are a prefix and ``t_end`` tiles cover it; row ``r`` sees kv position
+    ``p`` iff ``(p - L) * g <= r``, so a block that starts ``d`` positions
+    into the chunk is wholly masked for the tiles before row ``d * g``'s.
+    The kernel (traced i32 scalars) and :func:`append_tile_steps` (numpy
+    arrays) both call this, so the counter cannot drift from the rule."""
+    t_end = div(QL * g + (tr - 1), tr)
+    t_lo = div(xp.maximum(jj * bs - L, 0) * g, tr)
+    return t_lo, t_end
+
+
+def append_tile_steps(seq_lens, q_lens, group, chunk, block_size,
+                      max_blocks):
+    """``(run, grid)`` of one :func:`paged_attention_append` call, per kv
+    head: ``run`` = (row tile, table entry) pairs the kernel computes —
+    for each slot with ``q_lens > 0``, over the entries up to the block of
+    its window's last position, the tiles of :func:`_tile_span` — and
+    ``grid`` = every row tile against every entry of every slot, which is
+    what a kernel blind to ``(seq_lens, q_lens)`` would compute. Host
+    side, numpy; a ``-1`` entry inside a live context (the kernel skips
+    it) is not looked for: the scheduler allocates before it grants."""
+    L = np.asarray(seq_lens, np.int64).reshape(-1, 1)
+    QL = np.minimum(np.asarray(q_lens, np.int64), chunk).reshape(-1, 1)
+    tr = _row_tile(group, chunk)
+    j_last = np.minimum((L + np.maximum(QL - 1, 0)) // block_size,
+                        max_blocks - 1)
+    jj = np.arange(max_blocks, dtype=np.int64)[None, :]
+    t_lo, t_end = _tile_span(L, QL, jj, group, block_size, tr, np,
+                             np.floor_divide)
+    walked = (jj <= j_last) & (QL > 0)
+    run = int(np.sum(np.where(walked, t_end - t_lo, 0)))
+    return run, int(L.size * max_blocks * (group * chunk // tr))
+
+
 def _apd_blk(lens_ref, qlens_ref, b, bs, mb, last):
     """Block index of the append window's first (``last=False``) or last
     (``last=True``) written position, clamped into the table. q_lens == 0
@@ -630,20 +739,25 @@ def _apd_blk(lens_ref, qlens_ref, b, bs, mb, last):
     return jnp.minimum(jax.lax.div(pos, np.int32(bs)), np.int32(mb - 1))
 
 
+def _apd_walk(lens_ref, qlens_ref, b, j, bs, mb):
+    """Table entry grid step ``j`` of slot ``b`` reads: its own up to the
+    window's last block, that block again past it (the mapped block does
+    not change, so the dead tail issues no copy) — and for an idle slot
+    (q_lens 0) the boundary block at every step: it has one block to
+    carry through and no context to walk."""
+    j_last = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
+    return jnp.where(qlens_ref[b] > Z, jnp.minimum(j, j_last), j_last)
+
+
 def _apd_q_index_map(b, h, j, tables_ref, lens_ref, qlens_ref):
     return (b, h, Z, Z)
 
 
 def _apd_kv_index_map(bs, mb):
     def im(b, h, j, tables_ref, lens_ref, qlens_ref):
-        j_last = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
-        jj = jnp.minimum(j, j_last)          # dead tail re-maps to last live
+        jj = _apd_walk(lens_ref, qlens_ref, b, j, bs, mb)
         return (jnp.maximum(tables_ref[b, jj], Z), h, Z, Z)
     return im
-
-
-def _apd_new_index_map(b, h, j, tables_ref, lens_ref, qlens_ref):
-    return (b, h, Z, Z)
 
 
 def _apd_pool_out_index_map(bs, mb, nb):
@@ -662,143 +776,212 @@ def _apd_pool_out_index_map(bs, mb, nb):
 
 
 def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
-                   *rest, scale, bs, mb, nb, s_chunk, quant=None,
-                   d_head=None):
+                   *rest, scale, bs, mb, nb, s_chunk, g, tr, ts, hb,
+                   quant=None):
+    """One grid step = one table entry of one slot, for ``hb`` kv heads.
+    All vector work sits under a ``pl.when`` read from the slot's
+    ``(seq_lens, q_lens)``: a step that is neither live nor in the append
+    window does none."""
     if quant:
         (ks_ref, vs_ref, nk_ref, nv_ref, o_ref, ko_ref, vo_ref, kso_ref,
          vso_ref, m_ref, l_ref, acc_ref) = rest
     else:
         (nk_ref, nv_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref,
          acc_ref) = rest
+    f32 = jnp.float32
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    hg = pl.program_id(1)
     j = pl.program_id(2)
     bs_i = np.int32(bs)
+    tr_i = np.int32(tr)
+    d = q_ref.shape[3]
     L = lens_ref[b]
-    QL = qlens_ref[b]
+    QL = jnp.minimum(qlens_ref[b], np.int32(s_chunk))
     j_last = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
     w0 = _apd_blk(lens_ref, qlens_ref, b, bs, mb, False)
-    jj = jnp.minimum(j, j_last)
+    jj = _apd_walk(lens_ref, qlens_ref, b, j, bs, mb)
     phys = tables_ref[b, jj]
+    phys_r = jnp.maximum(phys, Z)
+    # same destination rule as the pool out index map
+    dst = jnp.where(phys < Z, np.int32(nb - 1), phys)
     live = (j <= j_last) & (phys >= Z) & (QL > Z)
+    in_window = (j >= w0) & (j <= j_last)
+    t_lo, t_end = _tile_span(L, QL, jj, g, bs, tr, jnp, _div_i32)
+    h0 = hg * np.int32(hb)                 # first kv head of this step
+
+    def heads(fn):
+        """``fn(h)`` on each kv head of this step. ``h`` is the loop's
+        carry: its own index is an i64 under x64 (static bounds), which
+        Mosaic cannot convert."""
+        def body(_, h):
+            fn(h)
+            return h + np.int32(1)
+        jax.lax.fori_loop(0, hb, body, Z)
+
+    def tiles(lo, hi, fn):
+        """``fn(r0)``, ``r0`` a tile's first row, on the row tiles
+        [lo, hi) (traced i32 bounds: the loop runs the tiles the scalars
+        name and no others)."""
+        def body(t, c):
+            fn(pl.multiple_of(t * tr_i, tr))
+            return c
+        jax.lax.fori_loop(lo, hi, body, Z)
 
     @pl.when(j == Z)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        def head(h):
+            def tile(r0):
+                rows = pl.ds(r0, tr)
+                m_ref[h, rows, :] = jnp.full((tr, 1), NEG_INF, f32)
+                l_ref[h, rows, :] = jnp.zeros((tr, 1), f32)
+                acc_ref[h, rows, :] = jnp.zeros((tr, d), f32)
+            tiles(Z, t_end, tile)
+        heads(head)
 
-    k_blk = k_ref[0, 0]                                       # [bs, D]
-    v_blk = v_ref[0, 0]
     if quant:
         # scale outputs seeded from the inputs once, then read and
         # written in place (decode-kernel rule)
-        @pl.when((b == Z) & (h == Z) & (j == Z))
+        @pl.when((b == Z) & (hg == Z) & (j == Z))
         def _seed_scales():
             kso_ref[...] = ks_ref[...]
             vso_ref[...] = vs_ref[...]
+
+    def dequant(blk, s_ref, h):
         # in-VMEM dequant right after the block DMA (decode-kernel rule)
-        phys_r = jnp.maximum(phys, Z)
-        k_blk = kv_unpack(k_blk, quant, d_head) * _scale_read(kso_ref,
-                                                              phys_r, h)
-        v_blk = kv_unpack(v_blk, quant, d_head) * _scale_read(vso_ref,
-                                                              phys_r, h)
-    # merge the chunk rows that land in THIS block into it in VMEM: block
-    # row r holds chunk index i = j*bs + r - lens when 0 <= i < q_lens.
-    # The gather is expressed as a one-hot selection matmul ([bs, S] @
-    # [S, D] — MXU-friendly; Mosaic has no per-row dynamic gather), so
-    # attention sees the whole new chunk this step and the merged block
-    # writes back through the aliased pool outputs.
-    row = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 1)
-    sel = ((jj * bs_i + row - L) == ci) & (ci < QL) & (ci >= Z)
-    # block row r takes a chunk row iff its chunk index lands in
-    # [0, q_lens) — index math, not a bool reduction over ``sel`` (Mosaic
-    # has no i1 reduce)
-    idx = jj * bs_i + row[:, :1] - L                          # [bs, 1]
-    has_new = (idx >= Z) & (idx < jnp.minimum(QL, np.int32(s_chunk)))
-    sel_f = sel.astype(jnp.float32)
-    merged_k = jax.lax.dot_general(
-        sel_f, nk_ref[0, 0].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    merged_v = jax.lax.dot_general(
-        sel_f, nv_ref[0, 0].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    k_blk = jnp.where(has_new, merged_k.astype(k_blk.dtype), k_blk)
-    v_blk = jnp.where(has_new, merged_v.astype(v_blk.dtype), v_blk)
-    in_window = (j >= w0) & (j <= j_last)
-    if quant:
-        # in-VMEM re-quantize of each window block: old rows re-round
-        # under the merged block's new absmax scale (drift-free when the
-        # max is unchanged: absmax quantization round-trips its own grid
-        # exactly). DEAD ROWS — positions at or past the window's new
-        # end (stale content of a reused freed block) — are ZEROED
-        # before the scale so a dirty block's garbage can't inflate it
-        # (decode-kernel rule; quantized output must not depend on
-        # pool-reuse history). A q_lens==0 slot writes nothing: its
-        # boundary block stores back its ORIGINAL payload + scale (the
-        # unquantized path's "stored back unchanged" contract — no
-        # zeroing, no re-round). Attention reads the ROUND-TRIPPED
-        # values — this step's logits equal a later re-read of the
-        # stored cache, and match the dense fallback bit-for-bit.
-        dead = in_window & ((jj * bs_i + row[:, :1]) >= (L + QL))
-        k_blk = jnp.where(dead, np.float32(0.0), k_blk)
-        v_blk = jnp.where(dead, np.float32(0.0), v_blk)
-        ks_new = kv_block_scale(k_blk, quant, axes=(0, 1), keepdims=True)
-        vs_new = kv_block_scale(v_blk, quant, axes=(0, 1), keepdims=True)
-        kq_new = kv_quantize(k_blk, ks_new, quant)
-        vq_new = kv_quantize(v_blk, vs_new, quant)
-        kq_store = jnp.where(QL > Z, kq_new, k_ref[0, 0])
-        vq_store = jnp.where(QL > Z, vq_new, v_ref[0, 0])
-        k_blk = jnp.where(in_window,
-                          kv_unpack(kq_new, quant, d_head) * ks_new, k_blk)
-        v_blk = jnp.where(in_window,
-                          kv_unpack(vq_new, quant, d_head) * vs_new, v_blk)
+        return kv_unpack(blk, quant, d) * _scale_read(s_ref, phys_r, h0 + h)
 
     @pl.when(in_window)
-    def _store_block():
-        if quant:
-            ko_ref[0, 0] = kq_store
-            vo_ref[0, 0] = vq_store
+    def _merge_and_store():
+        # merge the chunk rows that land in THIS block into it in VMEM:
+        # block row r holds chunk index i = j*bs + r - lens when
+        # 0 <= i < q_lens. The gather is expressed as a one-hot selection
+        # matmul ([bs, S] @ [S, D] — MXU-friendly; Mosaic has no per-row
+        # dynamic gather), so attention sees the whole new chunk this
+        # step and the merged block writes back through the aliased pool
+        # outputs. Only window blocks pay it.
+        row = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 0)
+        ci = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 1)
+        sel_f = (((jj * bs_i + row - L) == ci) & (ci < QL)).astype(f32)
+        # block row r takes a chunk row iff its chunk index lands in
+        # [0, q_lens) — index math, not a bool reduction over ``sel``
+        # (Mosaic has no i1 reduce)
+        idx = jj * bs_i + row[:, :1] - L                      # [bs, 1]
+        has_new = (idx >= Z) & (idx < QL)
 
-            @pl.when(QL > Z)
-            def _store_scales():
-                # same destination rule as the pool out index map
-                dst = jnp.where(phys < Z, np.int32(nb - 1), phys)
-                _scale_write(kso_ref, dst, h, ks_new)
-                _scale_write(vso_ref, dst, h, vs_new)
-        else:
-            ko_ref[0, 0] = k_blk.astype(ko_ref.dtype)
-            vo_ref[0, 0] = v_blk.astype(vo_ref.dtype)
+        def merged(blk, new_ref, h):
+            m = jax.lax.dot_general(
+                sel_f, new_ref[0, h].astype(f32), (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)
+            return jnp.where(has_new, m.astype(blk.dtype), blk)
 
-    g_s = q_ref.shape[2]                                      # G * S rows
+        def head(h):
+            if not quant:
+                ko_ref[0, h] = merged(k_ref[0, h], nk_ref, h)
+                vo_ref[0, h] = merged(v_ref[0, h], nv_ref, h)
+                return
+            # in-VMEM re-quantize of each window block: old rows re-round
+            # under the merged block's new absmax scale (drift-free when
+            # the max is unchanged: absmax quantization round-trips its
+            # own grid exactly). DEAD ROWS — positions at or past the
+            # window's new end (stale content of a reused freed block) —
+            # are ZEROED before the scale so a dirty block's garbage
+            # can't inflate it (decode-kernel rule; quantized output must
+            # not depend on pool-reuse history). A q_lens==0 slot writes
+            # nothing: its boundary block stores back its ORIGINAL
+            # payload + scale (the unquantized path's "stored back
+            # unchanged" contract — no zeroing, no re-round). Attention
+            # then reads the stored payload under the stored scale — the
+            # ROUND-TRIPPED values, so this step's logits equal a later
+            # re-read of the cache, and match the dense fallback
+            # bit-for-bit.
+            for src, new_ref, s_ref, out in ((k_ref, nk_ref, kso_ref, ko_ref),
+                                             (v_ref, nv_ref, vso_ref, vo_ref)):
+                blk = merged(dequant(src[0, h], s_ref, h), new_ref, h)
+                blk = jnp.where(idx >= QL, np.float32(0.0), blk)
+                s_new = kv_block_scale(blk, quant, axes=(0, 1),
+                                       keepdims=True)
+                out[0, h] = jnp.where(QL > Z, kv_quantize(blk, s_new, quant),
+                                      src[0, h])
 
-    @pl.when(live)
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32) * np.float32(scale)  # [G*S, D]
-        s = jax.lax.dot_general(q, k_blk.astype(jnp.float32),
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        # query row r is chunk index i = r % S at absolute position
-        # lens + i; causal against pooled history AND its own chunk
-        r = jax.lax.broadcasted_iota(jnp.int32, (g_s, bs), 0)
-        i_chunk = jax.lax.rem(r, np.int32(s_chunk))
-        pos = jj * bs_i + jax.lax.broadcasted_iota(jnp.int32, (g_s, bs), 1)
-        s = jnp.where(pos <= L + i_chunk, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+                @pl.when(QL > Z)
+                def _store_scale():
+                    _scale_write(s_ref, dst, h0 + h, s_new)
+        heads(head)
+
+    def attend(kr, vr, masked):
+        """Online-softmax update of the row tiles [t_lo, t_end) against
+        this step's block, read from ``kr``/``vr``. ``masked``: the block
+        reaches into the chunk, so the causal mask applies; a block of
+        the pooled history is visible to every live row."""
+        def head(h):
+            k_blk, v_blk = kr[0, h], vr[0, h]
+            if quant:
+                k_blk = dequant(k_blk, kso_ref, h)
+                v_blk = dequant(v_blk, vso_ref, h)
+            k_f = k_blk.astype(f32)
+
+            def update(r0, n):
+                rows = pl.ds(r0, n)
+                q = q_ref[0, h, rows, :].astype(f32) * np.float32(scale)
+                s = jax.lax.dot_general(q, k_f, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=f32)
+                if masked:
+                    # row r (chunk index r // g) sees kv position p iff
+                    # (p - lens) * g <= r — no vector division
+                    rel = jj * bs_i - L + jax.lax.broadcasted_iota(
+                        jnp.int32, (n, bs), 1)
+                    r = r0 + jax.lax.broadcasted_iota(jnp.int32, (n, bs), 0)
+                    s = jnp.where(rel * np.int32(g) <= r, s, NEG_INF)
+                m_prev = m_ref[h, rows, :]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[h, rows, :] = l_ref[h, rows, :] * alpha + jnp.sum(
+                    p, axis=1, keepdims=True)
+                acc_ref[h, rows, :] = acc_ref[h, rows, :] * alpha + \
+                    jax.lax.dot_general(
+                        p.astype(v_blk.dtype), v_blk,
+                        (((1,), (0,)), ((), ())), preferred_element_type=f32)
+                m_ref[h, rows, :] = m_new
+
+            def tile(r0):
+                if ts == tr:
+                    return update(r0, tr)
+                # a tile whose live rows end inside its first ``ts`` (a
+                # decode row's, a verify window's, a chunk's tail) runs
+                # those alone; its other rows keep their initial state
+                # and finalize to zeros
+                short = QL * np.int32(g) - r0 <= np.int32(ts)
+                pl.when(short)(lambda: update(r0, ts))
+                pl.when(jnp.logical_not(short))(lambda: update(r0, tr))
+            tiles(t_lo, t_end, tile)
+        heads(head)
+
+    # a window block is read where the merge just stored it (the aliased
+    # out buffers: merged, and for quantized pools round-tripped)
+    @pl.when(live & in_window)
+    def _attend_window():
+        attend(ko_ref, vo_ref, True)
+
+    @pl.when(live & jnp.logical_not(in_window))
+    def _attend_history():
+        attend(k_ref, v_ref, False)
 
     @pl.when(j == np.int32(mb - 1))
     def _finalize():
-        l = jnp.maximum(l_ref[...], np.float32(1e-30))
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        def head(h):
+            def live_tile(r0):
+                rows = pl.ds(r0, tr)
+                l = jnp.maximum(l_ref[h, rows, :], np.float32(1e-30))
+                o_ref[0, h, rows, :] = (acc_ref[h, rows, :] / l).astype(
+                    o_ref.dtype)
+
+            def idle_tile(r0):
+                o_ref[0, h, pl.ds(r0, tr), :] = jnp.zeros((tr, d),
+                                                          o_ref.dtype)
+            tiles(Z, t_end, live_tile)
+            tiles(t_end, np.int32(q_ref.shape[2] // tr), idle_tile)
+        heads(head)
 
 
 def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
@@ -822,6 +1005,25 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
     have blocks allocated to cover the window (the fused scheduler does);
     a -1 target writes to the pool's trailing scratch block.
 
+    **The work follows (seq_lens, q_lens)**, both scalar-prefetched. Query
+    rows of a kv head's group are laid POSITION-major (row ``i * G +
+    q_head``), so a slot's live rows are the prefix ``[0, q_lens * G)`` of
+    its ``G * S``; the kernel walks them in row tiles of
+    :func:`_row_tile` rows (derived from G and S alone: at most
+    ``_ROW_TILE_MAX``, a multiple of 16 that divides ``G * S``; no option)
+    and, per table entry, runs only the tiles under ``q_lens * G`` that
+    the entry's block is not wholly causally masked for
+    (:func:`_tile_span`; :func:`append_tile_steps` counts the same rule
+    on the host). A tile whose live rows end inside its first
+    :func:`_row_subtile` rows computes those alone: a decode row (q_lens
+    1) costs one such short tile a block, a full chunk every tile. Rows
+    of tiles never run come back as zeros. One grid step serves every kv head of a table entry
+    (:func:`_heads_per_step`: as many as fit VMEM), its heads in one DMA;
+    entries past the window's last block re-map to it (no copy) and do no
+    vector work; the merge of the chunk into a block runs for window
+    blocks only; an idle slot (q_lens 0) carries its boundary block
+    through unchanged and walks nothing.
+
     Returns (out [B, S, Hq, D] in q.dtype, k_pool, v_pool).
 
     ``quant`` + ``k_scale``/``v_scale`` [num_blocks, Hkv]: quantized
@@ -839,29 +1041,52 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
         assert k_scale is None and v_scale is None
         assert D == Dk, (q.shape, k_pool.shape)
     assert Hq % Hkv == 0, f"GQA needs Hq % Hkv == 0, got {Hq=} {Hkv=}"
+    return _append_call(
+        q, k_pool, v_pool, block_tables, seq_lens, q_lens, new_k, new_v,
+        k_scale, v_scale,
+        scale=float(scale) if scale is not None else 1.0 / math.sqrt(D),
+        quant=quant, interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "quant", "interpret"),
+                   inline=True)
+def _append_call(q, k_pool, v_pool, block_tables, seq_lens, q_lens, new_k,
+                 new_v, k_scale, v_scale, *, scale, quant, interpret):
+    """:func:`paged_attention_append`'s transposes and Pallas call.
+    Jitted so that a model's layers, which call it with the same shapes,
+    share ONE trace of the kernel (a bare ``pallas_call`` re-traces its
+    kernel at every call site: 16 layers cost 16 traces in the step
+    program's build), and ``inline``: left as a call in the step program,
+    XLA ran the 16-layer mixed step 3.5 ms slower on the v5e (PERF.md
+    section 6, PR 26)."""
+    B, S, Hq, D = q.shape
+    NB, Hkv, BS, Dk = k_pool.shape
     G = Hq // Hkv
     MB = block_tables.shape[1]
-    scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
 
-    # [B, S, Hq, D] -> [B, Hkv, G*S, D]: row r = g*S + i (head-major, so
-    # the q-head split matches the decode kernel's (Hkv, G) grouping)
+    # [B, S, Hq, D] -> [B, Hkv, S*G, D]: row r = i*G + g of kv head h is
+    # position i of q head h*G + g (position-major, so live rows are a
+    # prefix; the (Hkv, G) grouping is the decode kernel's)
     nk_dt = k_pool.dtype if not quant else new_k.dtype
-    q4 = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, Hkv, G * S, D)
+    q4 = jnp.transpose(q.reshape(B, S, Hkv, G, D),
+                       (0, 2, 1, 3, 4)).reshape(B, Hkv, S * G, D)
     nk = jnp.transpose(new_k, (0, 2, 1, 3)).astype(nk_dt)
     nv = jnp.transpose(new_v, (0, 2, 1, 3)).astype(nk_dt)
     tables = block_tables.astype(jnp.int32)
     lens = seq_lens.astype(jnp.int32)
     qlens = q_lens.astype(jnp.int32)
 
-    pool_spec = pl.BlockSpec((1, 1, BS, Dk),
+    tr = _row_tile(G, S)
+    shape = (G, S, D, BS, Dk, q.dtype.itemsize, k_pool.dtype.itemsize,
+             jnp.dtype(nk_dt).itemsize)
+    hb = _heads_per_step(Hkv, *shape)
+    pool_spec = pl.BlockSpec((1, hb, BS, Dk),
                              _apd_pool_out_index_map(BS, MB, NB))
-    in_specs = [
-        pl.BlockSpec((1, 1, G * S, D), _apd_q_index_map),
-        pl.BlockSpec((1, 1, BS, Dk), _apd_kv_index_map(BS, MB)),
-        pl.BlockSpec((1, 1, BS, Dk), _apd_kv_index_map(BS, MB)),
-    ]
-    out_specs = [pl.BlockSpec((1, 1, G * S, D), _apd_q_index_map),
-                 pool_spec, pool_spec]
+    kv_spec = pl.BlockSpec((1, hb, BS, Dk), _apd_kv_index_map(BS, MB))
+    q_spec = pl.BlockSpec((1, hb, G * S, D), _apd_q_index_map)
+    new_spec = pl.BlockSpec((1, hb, S, D), _apd_q_index_map)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    out_specs = [q_spec, pool_spec, pool_spec]
     out_shape = [jax.ShapeDtypeStruct((B, Hkv, G * S, D), q.dtype),
                  jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                  jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)]
@@ -876,23 +1101,23 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
         inputs += [k_scale.astype(jnp.float32),
                    v_scale.astype(jnp.float32)]
         io_aliases = {4: 1, 5: 2, 6: 3, 7: 4}
-    in_specs += [pl.BlockSpec((1, 1, S, D), _apd_new_index_map),
-                 pl.BlockSpec((1, 1, S, D), _apd_new_index_map)]
+    in_specs += [new_spec, new_spec]
     inputs += [nk, nv]
 
     kernel = functools.partial(_append_kernel, scale=scale, bs=BS, mb=MB,
-                               nb=NB, s_chunk=S, quant=quant, d_head=D)
+                               nb=NB, s_chunk=S, g=G, tr=tr,
+                               ts=_row_subtile(tr), hb=hb, quant=quant)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, Hkv, MB),
+            grid=(B, Hkv // hb, MB),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((G * S, 1), jnp.float32),   # running max m
-                pltpu.VMEM((G * S, 1), jnp.float32),   # running norm l
-                pltpu.VMEM((G * S, D), jnp.float32),   # output accumulator
+                pltpu.VMEM((hb, G * S, 1), jnp.float32),  # running max m
+                pltpu.VMEM((hb, G * S, 1), jnp.float32),  # running norm l
+                pltpu.VMEM((hb, G * S, D), jnp.float32),  # out accumulator
             ],
         ),
         out_shape=out_shape,
@@ -900,12 +1125,14 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
         # sequential everywhere: scratch carries over blocks and clamped
         # write destinations may collide across batch windows
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20,
+                                 _append_vmem_bytes(hb, *shape) + (16 << 20))),
         name="paged_attention_append",
-        interpret=_interpret(),
+        interpret=interpret,
     )(*inputs)
-    out = outs[0].reshape(B, Hkv, G, S, D)
-    out = jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Hq, D)
+    out = outs[0].reshape(B, Hkv, S, G, D)
+    out = jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, Hq, D)
     if quant:
         return out, outs[1], outs[2], outs[3], outs[4]
     return out, outs[1], outs[2]

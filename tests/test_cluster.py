@@ -209,6 +209,41 @@ def test_tp_kernel_append_parity(tp_mesh, rng):
                                        rtol=1e-5, atol=1e-5)
 
 
+def test_tp_kernel_append_follows_q_lens(tp_mesh, rng):
+    """The mixed step in miniature under shard_map — a full chunk, a
+    decode row, an idle slot with a wiped table row — at a shape with two
+    row tiles a kv head (96 positions x 4 q heads, tiles of 192): each
+    shard's one-head kernel walks the tiles and blocks the unsharded
+    four-head kernel does."""
+    from paddle_tpu.ops.kernels.paged_attention import (
+        paged_attention_append, paged_attention_append_tp)
+    B, S, Hq, Hkv, D, BS, MB = 3, 96, 16, 4, 16, 8, 18
+    lens = np.array([40, 100, 9], np.int32)
+    qlens = np.array([S, 1, 0], np.int32)
+    tables = np.full((B, MB), -1, np.int32)
+    tables[0, :17] = np.arange(17)
+    tables[1, :13] = 17 + np.arange(13)
+    NB = 31                                  # 30 real blocks + the scratch
+    kp = rng.standard_normal((NB, Hkv, BS, D)).astype(np.float32)
+    vp = rng.standard_normal((NB, Hkv, BS, D)).astype(np.float32)
+    qa = rng.standard_normal((B, S, Hq, D)).astype(np.float32)
+    nk = rng.standard_normal((B, S, Hkv, D)).astype(np.float32)
+    nv = rng.standard_normal((B, S, Hkv, D)).astype(np.float32)
+    ref = paged_attention_append(qa, kp.copy(), vp.copy(), tables, lens,
+                                 qlens, nk, nv)
+    got = paged_attention_append_tp(qa, kp.copy(), vp.copy(), tables, lens,
+                                    qlens, nk, nv, tp_mesh)
+    valid = np.arange(S)[None, :] < qlens[:, None]
+    np.testing.assert_allclose(np.asarray(ref[0])[valid],
+                               np.asarray(got[0])[valid],
+                               rtol=1e-5, atol=1e-5)
+    for r, g in zip(ref[1:], got[1:]):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+    # the chunk landed: positions 40..135 of slot 0 hold new_k
+    blk, row = tables[0, 40 // BS], 40 % BS
+    np.testing.assert_array_equal(np.asarray(got[1])[blk, :, row], nk[0, 0])
+
+
 # ---------------------------------------------------------------------------
 # Level 2 — the ReplicaRouter
 # ---------------------------------------------------------------------------

@@ -150,18 +150,17 @@ def test_decode_kernel_parity(rng, quant, group):
     np.testing.assert_allclose(np.asarray(vs2)[:-1], rvs[:-1], rtol=1e-6)
 
 
-@pytest.mark.parametrize("quant", ["int8", "int4"])
-def test_append_kernel_parity(rng, quant):
-    """Quantized append kernel vs the dense fallback: q_lens covering
-    {0 (idle slot), 1 (decode-shaped), mid, full chunk}, windows
-    crossing block boundaries; pools + scales bit-exact, valid output
-    rows to tolerance."""
-    Hkv, D, BS, S = 2, 32, 8, 8
-    Hq = 4
-    lens = [16, 17, 7, 3]
-    q_lens = np.asarray([0, 1, 5, 8], np.int32)
+def _assert_quant_append_parity(rng, quant, lens, q_lens, S=8, Hq=4, Hkv=2,
+                                wiped=None):
+    """Quantized append kernel (interpret mode) vs the dense fallback
+    (public op): pools + scales bit-exact but for the scratch block, valid
+    output rows to tolerance. ``wiped``: a slot whose table row is -1."""
+    D, BS = 32, 8
+    q_lens = np.asarray(q_lens, np.int32)
     kc, vc, ks, vs, tables, lens_ = _quant_pools(
         rng, lens, q_lens, Hkv, D, BS, quant)
+    if wiped is not None:
+        tables[wiped, :] = -1
     B = len(lens)
     qa = rng.standard_normal((B, S, Hq, D)).astype(np.float32)
     ka = rng.standard_normal((B, S, Hkv, D)).astype(np.float32)
@@ -192,6 +191,40 @@ def test_append_kernel_parity(rng, quant):
     np.testing.assert_array_equal(np.asarray(vc3)[:-1], rvc3[:-1])
     np.testing.assert_allclose(np.asarray(ks3)[:-1], rks3[:-1], rtol=1e-6)
     np.testing.assert_allclose(np.asarray(vs3)[:-1], rvs3[:-1], rtol=1e-6)
+
+
+@pytest.mark.parametrize("quant", ["int8", "int4"])
+def test_append_kernel_parity(rng, quant):
+    """Quantized append kernel vs the dense fallback: q_lens covering
+    {0 (idle slot), 1 (decode-shaped), mid, full chunk}, windows
+    crossing block boundaries; pools + scales bit-exact, valid output
+    rows to tolerance."""
+    _assert_quant_append_parity(rng, quant, [16, 17, 7, 3], [0, 1, 5, 8])
+
+
+# the mixes tests/test_paged_attention.py::_FOLLOWS holds the unquantized
+# kernel to, at its shape (96 positions x 4 q heads = two row tiles of 192):
+# name -> (lens, q_lens, wiped slot)
+_FOLLOWS = {
+    "cell_step_miniature": ([40, 201, 77, 9, 0], [96, 1, 1, 0, 0], 3),
+    "rows_not_a_multiple_of_the_tile": ([3, 50, 64, 11], [50, 9, 8, 49],
+                                        None),
+    "window_straddles_two_and_three_blocks": ([6, 7, 15], [5, 12, 17], None),
+    "verify_window_k_plus_1": ([33, 64, 95], [5, 3, 1], None),
+    "first_chunk_from_empty": ([0, 0, 0], [96, 40, 1], None),
+    "every_slot_idle": ([12, 0, 31], [0, 0, 0], 2),
+}
+
+
+@pytest.mark.parametrize("name", list(_FOLLOWS))
+@pytest.mark.parametrize("quant", ["int8", "int4"])
+def test_append_kernel_follows_q_lens_over_quantized_pools(rng, quant, name):
+    """The re-quantizing merge runs for window blocks only and the walk
+    skips what (seq_lens, q_lens) rule out: same pools, scales and live
+    rows as the dense fallback on every mix."""
+    lens, q_lens, wiped = _FOLLOWS[name]
+    _assert_quant_append_parity(rng, quant, lens, q_lens, S=96, Hq=8,
+                                wiped=wiped)
 
 
 def test_scale_update_on_fused_write(rng):

@@ -214,7 +214,10 @@ def _append_oracle(q, kc, vc, tables, lens, qlens, kn, vn):
 
 
 def _assert_append_parity(q, kc, vc, tables, lens, qlens, kn, vn,
-                          rtol=2e-5, atol=2e-5):
+                          rtol=2e-5, atol=2e-5, real_blocks=None):
+    """``real_blocks``: compare the pools' first that-many blocks only
+    (a wiped -1 table row parks a block in the trailing scratch block,
+    which the fallback drops)."""
     ref_out, ref_kc, ref_vc = _append_oracle(q, kc, vc, tables, lens,
                                              qlens, kn, vn)
     out, kc2, vc2 = paged_attention_append(
@@ -229,10 +232,13 @@ def _assert_append_parity(q, kc, vc, tables, lens, qlens, kn, vn,
                 np.asarray(out, np.float32)[b, :n].reshape(n, -1),
                 np.asarray(ref_out[b, :n], np.float32), rtol=rtol,
                 atol=atol)
-    np.testing.assert_array_equal(np.asarray(kc2, np.float32),
-                                  np.asarray(ref_kc, np.float32))
-    np.testing.assert_array_equal(np.asarray(vc2, np.float32),
-                                  np.asarray(ref_vc, np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(kc2, np.float32)[:real_blocks],
+        np.asarray(ref_kc, np.float32)[:real_blocks])
+    np.testing.assert_array_equal(
+        np.asarray(vc2, np.float32)[:real_blocks],
+        np.asarray(ref_vc, np.float32)[:real_blocks])
+    return np.asarray(out, np.float32)
 
 
 @pytest.mark.parametrize("group", [1, 4])
@@ -305,6 +311,82 @@ def test_append_decode_special_case_matches_decode_kernel(rng):
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_array_equal(np.asarray(kc_a), np.asarray(kc_d))
     np.testing.assert_array_equal(np.asarray(vc_a), np.asarray(vc_d))
+
+
+# The shapes the kernel's (seq_lens, q_lens)-following makes special. S = 96
+# positions x 4 q heads a kv head = 384 rows = two row tiles of 192
+# (_row_tile), so live-row prefixes end inside, at and across a tile, the
+# 32-row short path runs, and window blocks skip the tiles they are wholly
+# masked for. name -> (lens, q_lens, slot whose table row is wiped or None)
+_FOLLOWS = {
+    # doc_batch's mixed step in miniature: one slot ramping by a full chunk,
+    # decode rows, idle slots, one of them freed (stale lens, -1 table row)
+    "cell_step_miniature": ([40, 201, 77, 130, 9, 0], [96, 1, 1, 1, 0, 0], 4),
+    # q_lens * G = 200, 36, 32, 196 rows against tiles of 192 and 32
+    "rows_not_a_multiple_of_the_tile": ([3, 50, 64, 11], [50, 9, 8, 49],
+                                        None),
+    # block 8: [6, 11) lies in two blocks, [7, 19) and [15, 32) in three
+    "window_straddles_two_and_three_blocks": ([6, 7, 15], [5, 12, 17], None),
+    # speculative verify grants: q_lens = k + 1 drafts, shrunk per slot
+    "verify_window_k_plus_1": ([33, 64, 95, 18], [5, 5, 3, 1], None),
+    "first_chunk_from_empty": ([0, 0, 0], [96, 40, 1], None),
+    "every_slot_idle": ([12, 0, 31], [0, 0, 0], 2),
+}
+
+
+@pytest.mark.parametrize("name", list(_FOLLOWS))
+def test_append_follows_q_lens_and_seq_lens(name, rng):
+    """Kernel (interpret mode) vs the dense append fallback on the mixes
+    above: outputs of live rows AND both pools; rows of row tiles that
+    never run come back as zeros."""
+    from paddle_tpu.ops.kernels.paged_attention import _row_tile
+    lens, qlens, wiped = _FOLLOWS[name]
+    S, G = 96, 4
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, lens, qlens, Hq=2 * G, Hkv=2, S=S)
+    if wiped is not None:
+        tables[wiped, :] = -1
+    out = _assert_append_parity(
+        q, kc, vc, tables, lens, qlens, kn, vn,
+        real_blocks=None if wiped is None else kc.shape[0] - 1)
+    tr = _row_tile(G, S)
+    assert tr == 192
+    for b, n in enumerate(qlens):
+        first_idle = -(-int(n) * G // tr) * tr // G   # position, tile-aligned
+        assert not out[b, first_idle:].any()
+
+
+@pytest.mark.parametrize("G,S,BS", [(4, 96, 8), (4, 128, 16), (3, 128, 8),
+                                    (1, 8, 8), (8, 64, 32)])
+def test_append_tile_steps_is_the_brute_force_count(G, S, BS, rng):
+    """``append_tile_steps`` (the counter behind ``engine.stats``, and the
+    kernel's own skip rule through ``_tile_span``) against a count made
+    row by row and key by key: a (row tile, table entry) pair runs iff the
+    slot appends something, the entry lies at or before the block of the
+    window's last position, and some LIVE row of the tile sees some key of
+    the entry's block."""
+    from paddle_tpu.ops.kernels.paged_attention import (
+        _row_tile, append_tile_steps)
+    tr, MB = _row_tile(G, S), 20
+    n_tiles = G * S // tr
+    for _ in range(12):
+        B = int(rng.integers(1, 6))
+        lens = rng.integers(0, MB * BS - S, size=B)
+        qlens = rng.integers(0, S + 1, size=B)
+        qlens[rng.integers(0, B)] = rng.choice([0, 1, S])
+        run = 0
+        for L, n in zip(lens, qlens):
+            if n == 0:
+                continue
+            j_last = min((L + n - 1) // BS, MB - 1)
+            for jj in range(j_last + 1):
+                keys = jj * BS + np.arange(BS)
+                for t in range(n_tiles):
+                    r = np.arange(t * tr, min((t + 1) * tr, n * G))
+                    if r.size and (keys[None, :] <= L + r[:, None] // G).any():
+                        run += 1
+        assert append_tile_steps(lens, qlens, G, S, BS, MB) == \
+            (run, B * MB * n_tiles)
 
 
 @pytest.mark.slow

@@ -171,6 +171,8 @@ def test_a_dispatch_span_joins_the_flight_recorder_by_step_id(served):
         assert DISPATCH_KINDS[s.ids["kind"]] == r.kind
         assert s.ids["live_tokens"] == r.tokens_scheduled
         assert s.ids["rows"] >= s.ids["live_tokens"] > 0
+        # a mixed step (the engine is paged) carries its attn_tile_steps
+        assert ("live_tiles" in s.ids) == (r.kind == "mixed")
         # pc_ns lays the recorder's perf_counter stamps on the trace's
         # clock with one subtraction: the step's entry falls inside the
         # pass that holds its dispatch, before the dispatch
@@ -179,6 +181,8 @@ def test_a_dispatch_span_joins_the_flight_recorder_by_step_id(served):
         loop_pass = list(s.ancestors())[-1]
         assert loop_pass.name == "pt:server.pass"
         assert loop_pass.start - 2000 <= t_begin <= s.start + 2000
+    assert sum(s.ids.get("live_tiles", 0) for s in disp.values()) == \
+        stats["attn_tile_steps"] > 0
     # the sync and the emit of a step carry its id too
     for name in ("pt:engine.sync", "pt:engine.emit"):
         assert {s.ids["step_id"] for s in spans if s.name == name} \
@@ -268,11 +272,13 @@ def run_counted(cache, stride):
                     max_batch=2, max_seq_len=64, chunk_size=16,
                     readout_stride=stride, **kw)
     eng._programs()
-    rows = []
+    rows, mixed = [], []
     fused = eng._fused_fn
 
     def fused_spy(*a, **k):
         rows.append(a[6].size)          # ids: [B, chunk]
+        # the device's own lens going into the step, and the grants
+        mixed.append((np.asarray(a[4]), np.asarray(a[7])))
         return fused(*a, **k)
     eng._fused_fn = fused_spy
     for rid, p in enumerate(prompts(3, (20, 9, 33))):
@@ -287,6 +293,7 @@ def run_counted(cache, stride):
             # with any row active
             rows.append(eng.B * max(int(np.asarray(
                 pending.was_active).any(axis=1).sum()), 1))
+    eng.mixed_steps = mixed
     return eng, dict(eng.stats), rows
 
 
@@ -317,6 +324,26 @@ def test_the_attention_grid_holds_at_least_its_live_blocks(counted):
     assert 0 < stats["kv_live_blocks"] <= stats["kv_grid_blocks"]
     # the grid walks every table entry of every slot each iteration
     assert stats["kv_grid_blocks"] % eng._tables.size == 0
+
+
+def test_attn_tile_steps_grow_by_the_kernels_own_count(counted):
+    """Each mixed paged dispatch books what ``append_tile_steps`` says of
+    the lens and grants the step program was really called with (the
+    device's lens, not the host's mirror of them)."""
+    from paddle_tpu.ops.kernels.paged_attention import append_tile_steps
+    cache, _, eng, stats, _ = counted
+    if cache == "dense":
+        assert stats["attn_tile_steps"] == stats["attn_tile_steps_grid"] == 0
+        return
+    cfg = eng.model.config
+    want = np.sum([append_tile_steps(
+        lens, q_lens, cfg.num_attention_heads // cfg.num_key_value_heads,
+        eng.chunk, eng.block_size, eng._tables.shape[1])
+        for lens, q_lens in eng.mixed_steps], axis=0)
+    assert len(eng.mixed_steps) == stats["fused_steps"] > 0
+    assert (stats["attn_tile_steps"], stats["attn_tile_steps_grid"]) == \
+        tuple(want)
+    assert 0 < stats["attn_tile_steps"] <= stats["attn_tile_steps_grid"]
 
 
 def test_first_grants_count_the_requests_prefilled(counted):
