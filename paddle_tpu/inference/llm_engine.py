@@ -45,6 +45,7 @@ import numpy as np
 
 from ..analysis import lock_watchdog as _lockwatch
 from ..core.tensor import Tensor, functional_mode
+from ..models.cache_layout import collect_counts
 from ..models.llama import SlotKVCache, _sample_logits_device
 from ..models.lora import lora_scope
 from ..profiler import span
@@ -101,6 +102,19 @@ def default_engine_stats():
             # against every row tile x every entry of every slot (the
             # kernel module's own count, paged_attention.append_tile_steps)
             "attn_tile_steps": 0, "attn_tile_steps_grid": 0,
+            # device-side counts of an expert layer that holds a share of
+            # the published experts (ops/kernels/moe_dropless.py), summed
+            # over layers and steps, read beside the tokens: live rows x
+            # experts a token; those that landed on a held expert; rows
+            # the grouped product ran, padding included; the fullest held
+            # expert's rows; and held assignments beyond the product's
+            # rows (stays 0: the routing is dropless)
+            "moe_assignments": 0, "moe_assignments_held": 0,
+            "moe_rows_computed": 0, "moe_expert_peak": 0,
+            "moe_assignments_dropped": 0,
+            # slots assigned into zeroed recurrent state (a layout with a
+            # recurrent layer): admissions and preemption replays alike
+            "state_resets": 0,
             # a (seconds, count) pair: takes a slot -> first prefill
             # grant dispatched (accepted -> takes a slot is telemetry's
             # queue_wait_s histogram, the server's)
@@ -324,7 +338,7 @@ class PendingStep:
     __slots__ = ("toks", "was_active", "counts", "spec", "slots",
                  "pool_done", "sched", "step_id", "fenced", "t_dispatch",
                  "embed_done", "pooled", "verify", "offered", "guarded",
-                 "rows")
+                 "rows", "ctr")
 
     def __init__(self, toks, was_active, counts, spec, slots, pool_done,
                  sched=None, fenced=None, embed_done=None, verify=None):
@@ -360,6 +374,9 @@ class PendingStep:
         #: readout books them times the iterations that ran (0: the
         #: dispatch's rows were known and booked when it was dispatched)
         self.rows = 0
+        #: device vector of the model's step counters for this dispatch
+        #: (None: the model declares none)
+        self.ctr = None
         self.pooled = None
         #: fused speculative dispatches: {slot: drafts granted} — the
         #: readout's acceptance accounting (EWMA + spec counters) and
@@ -378,9 +395,16 @@ class PendingStep:
 
 
 class LLMEngine:
-    """Continuous-batching engine over a LlamaForCausalLM (works with
-    bf16/fp32 and WeightOnlyLinear-quantized weights; under a mesh the
-    programs partition by GSPMD like ``generate()``)."""
+    """Continuous-batching engine over a causal LM that names its decoder
+    and its cache layout (``models/cache_layout.py``: ``model.decoder``,
+    ``model.cache_layout()`` one state kind a layer, ``model._logits``);
+    the engine knows no family. The llama family's layers are all paged
+    (or dense) K/V (bf16/fp32 and WeightOnlyLinear-quantized weights;
+    under a mesh the programs partition by GSPMD like ``generate()``);
+    a layout with a paged latent pool or a recurrent state a slot is
+    served by the fused scheduler over the paged allocator, and every
+    option whose code assumes "state is a list of K/V blocks" refuses it
+    at construction (``_refuse_for_layout``)."""
 
     def __init__(self, model, max_batch=4, max_seq_len=None, chunk_size=64,
                  top_k=0, stream_callback=None, horizon=1, speculative_k=1,
@@ -509,6 +533,26 @@ class LLMEngine:
         self._lock_checks = _lockwatch.enabled()
         self._pool_owner = None
         c = model.config
+        #: THE seam: the model's decoder and one state kind a layer
+        self._decoder = model.decoder
+        self._layout = list(model.cache_layout())
+        #: every layer holds K and V: the pools, programs and options of
+        #: the llama family, unchanged
+        self._kv_only = all(k.kind == "paged_kv" for k in self._layout)
+        self._has_recurrent = any(k.kind == "recurrent"
+                                  for k in self._layout)
+        #: device-side counts the model's layers make during a step; they
+        #: leave the step program beside the tokens (booked at emit)
+        self._step_counter_names = tuple(
+            getattr(model, "step_counter_names", ()))
+        if not self._kv_only:
+            self._refuse_for_layout(
+                scheduler=scheduler, cache_impl=cache_impl, mesh=mesh,
+                enable_prefix_cache=enable_prefix_cache,
+                kv_host_swap=kv_host_swap,
+                kv_host_spill_bytes=kv_host_spill_bytes,
+                speculative_k=speculative_k, kv_cache_dtype=kv_cache_dtype,
+                adapter_store=adapter_store, horizon=horizon)
         self.B = int(max_batch)
         # decode horizon: tokens decoded per step() call as one compiled
         # lax.scan — amortizes the per-step host sync K-fold at the cost of
@@ -569,10 +613,13 @@ class LLMEngine:
         self._state = params + buffers
         self._state_vals = read_values(self._state)
 
-        head_dim = c.hidden_size // c.num_attention_heads
-        kvh = c.num_key_value_heads
-        dt = model.llama.embed_tokens.weight.dtype
-        L = c.num_hidden_layers
+        if self._kv_only:
+            kvh, head_dim = (self._layout[0].kv_heads,
+                             self._layout[0].head_dim)
+        else:
+            kvh = head_dim = 0     # no K/V pool: the kinds size their own
+        dt = self._decoder.embed_tokens.weight.dtype
+        L = len(self._layout)
         # a prefill window is always a full `chunk` wide, so it must fit the
         # buffer (the final window slides BACK over already-written
         # positions instead of padding the time axis — see _admit)
@@ -796,6 +843,80 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # device state (built at __init__, REBUILT by reset())
     # ------------------------------------------------------------------
+    def _refuse_for_layout(self, **opt):
+        """A layout with a layer that is not paged K/V (a paged latent
+        pool, a recurrent state a slot) is served by the fused scheduler
+        over the paged allocator, with ``readout_stride``, pipelining and
+        pool oversubscription (a preempted request replays from its first
+        token into zeroed state, as paged KV does). Every option whose
+        code assumes "a slot's state is a list of K/V blocks" raises
+        here, naming its mechanism, instead of serving a wrong token."""
+        kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
+
+        def refuse(option, why):
+            raise ValueError(
+                f"{option} cannot serve a model whose cache layout has "
+                f"{kinds} layers: {why}")
+        if opt["scheduler"] != "fused":
+            refuse("scheduler='legacy'",
+                   "legacy admission prefills a whole prompt through "
+                   "StaticKVCache slot buffers of K and V; a recurrent "
+                   "state or a latent pool advances only in the fused "
+                   "step programs (scheduler='fused')")
+        if opt["cache_impl"] != "paged":
+            refuse(f"cache_impl={opt['cache_impl']!r}",
+                   "the dense slot buffers are [max_batch, capacity, "
+                   "kv_heads, head_dim] K and V arrays; latents live in a "
+                   "paged pool and a recurrent state is not a sequence of "
+                   "positions (cache_impl='paged')")
+        if opt["horizon"] and int(opt["horizon"]) > 1:
+            refuse("horizon > 1",
+                   "the horizon scan belongs to the legacy scheduler; use "
+                   "readout_stride")
+        if opt["enable_prefix_cache"]:
+            refuse("enable_prefix_cache",
+                   "a cached block holds its tokens' K/V, but a recurrent "
+                   "layer's state after a shared prefix is in no block: a "
+                   "hit would skip the rows that build it (prefix hashing "
+                   "assumes state is a list of blocks)")
+        if opt["kv_host_swap"] or opt["kv_host_spill_bytes"]:
+            refuse("kv_host_swap / kv_host_spill_bytes",
+                   "swap and spill copy a slot's list of pool blocks; its "
+                   "recurrent state and convolution tail are not blocks "
+                   "and would be lost (a preempted request replays from "
+                   "its first token instead)")
+        if int(opt["speculative_k"] or 1) > 1:
+            refuse("speculative_k > 1",
+                   "a rejected draft rolls the slot's length back over "
+                   "rows already computed; a recurrent state that has "
+                   "absorbed them cannot be rolled back")
+        if opt["kv_cache_dtype"] is not None:
+            refuse("kv_cache_dtype",
+                   "pool quantization keeps one scale per (block, kv "
+                   "head) of K and V pools; a latent pool and a float32 "
+                   "recurrent state have no such scales")
+        if opt["adapter_store"] is not None:
+            refuse("adapter_store",
+                   "batched LoRA adds its deltas to the llama family's "
+                   "q/k/v/o and gate/up/down projections by name")
+        mesh = opt["mesh"]
+        if mesh is not None and "tp" in tuple(mesh.axis_names) \
+                and int(mesh.shape["tp"]) > 1:
+            refuse("a tensor-parallel mesh",
+                   "kv heads are the shard dimension of K/V pools; a "
+                   "latent pool has one shared head and a recurrent state "
+                   "is held per slot (experts over chips with their "
+                   "exchange are not written)")
+
+    def _refuse_kv_shipping(self, what):
+        if not self._kv_only:
+            raise ValueError(
+                f"{what} ships a request's list of K/V blocks; a cache "
+                f"layout with "
+                f"{sorted({k.kind for k in self._layout} - {'paged_kv'})} "
+                f"layers keeps state that is not in blocks (a recurrent "
+                f"state a slot), so it cannot be exported or imported")
+
     def _make_zeros(self, shape, dtype, spec=None):
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -820,7 +941,16 @@ class LLMEngine:
             # on a real block (the XLA fallback drops such rows with an
             # out-of-range scatter; a kernel block write needs a real
             # destination)
-            if self.kv_quant:
+            if not self._kv_only:
+                # one state pair a layer, built by its kind: a latent
+                # pool on the allocator's blocks (with the same trailing
+                # scratch block), a recurrent state a slot
+                pairs = [kind.alloc(self._make_zeros, self.n_blocks,
+                                    self.block_size, self.B, self._np_dt)
+                         for kind in self._layout]
+                self._k = [a for a, _ in pairs]
+                self._v = [b for _, b in pairs]
+            elif self.kv_quant:
                 # QUANTIZED pools: int8 payload (int4 nibble-packs two
                 # head-dim elements per byte) + one fp32 scale per
                 # (physical block, kv head), bundled as (pool, scale)
@@ -1098,6 +1228,13 @@ class LLMEngine:
         if self._step_fn is not None:
             return
         model = self.model
+        decoder = self._decoder
+        layout, kv_only = self._layout, self._kv_only
+        n_ctr = len(self._step_counter_names)
+        ctr_zero = jnp.zeros((n_ctr,), jnp.int32)
+        #: the scheduler's bound on a mixed step's live rows over all
+        #: slots (decode tokens always land, prefill takes the rest)
+        mixed_rows = max(self.max_step_tokens, self.B)
         state = self._state
         B, cap, chunk = self.B, self.capacity, self.chunk
         top_k = self.top_k
@@ -1135,10 +1272,17 @@ class LLMEngine:
 
         kvq = self.kv_quant
 
-        def paged_caches(kb, vb, tables, lens, q_lens=None):
-            """Per-layer PagedKVCache list of one traced dispatch — THE
-            one place that unpacks the quantized (payload, scale) pool
-            bundles, so no step body can forget the scales."""
+        def paged_caches(kb, vb, tables, lens, q_lens=None, active=None):
+            """Per-layer cache list of one traced dispatch. All-K/V
+            layouts: PagedKVCache — THE one place that unpacks the
+            quantized (payload, scale) pool bundles, so no step body can
+            forget the scales. Any other layout: each layer's kind makes
+            its own (``q_lens`` None is the one-token step: a slot that
+            is not ``active`` has no live row)."""
+            if not kv_only:
+                rows = mixed_rows if q_lens is not None else B
+                return [kind.cache(a, b, tables, lens, q_lens, active, rows)
+                        for kind, a, b in zip(layout, kb, vb)]
             from ..models.llama import PagedKVCache
             if kvq:
                 return [PagedKVCache(k[0], v[0], tables, lens, q_lens,
@@ -1155,6 +1299,10 @@ class LLMEngine:
             have no scales and kvq is then always None)."""
             def val(x):
                 return x._value if isinstance(x, Tensor) else x
+            if not kv_only:
+                pairs = [kind.unpack(cc)
+                         for kind, cc in zip(layout, new_caches)]
+                return [a for a, _ in pairs], [b for _, b in pairs]
             if kvq:
                 return ([(val(cc.k), val(cc.k_scale)) for cc in new_caches],
                         [(val(cc.v), val(cc.v_scale)) for cc in new_caches])
@@ -1209,10 +1357,12 @@ class LLMEngine:
                     caches = [SlotKVCache(k, v, lens)
                               for k, v in zip(k_bufs, v_bufs)]
                 else:
-                    caches = paged_caches(k_bufs, v_bufs, tables, lens)
-                hidden, new_caches = model.llama(
-                    Tensor(nxt[:, None]), kv_caches=caches,
-                    position_offset=Tensor(lens))
+                    caches = paged_caches(k_bufs, v_bufs, tables, lens,
+                                          active=active)
+                with collect_counts() as counted:
+                    hidden, new_caches = decoder(
+                        Tensor(nxt[:, None]), kv_caches=caches,
+                        position_offset=Tensor(lens))
                 new_logits = model._logits(hidden)._value[:, 0] \
                     .astype(jnp.float32)
             # an INACTIVE row's carried logits must survive the remaining
@@ -1222,7 +1372,8 @@ class LLMEngine:
             kb, vb = unpack_kv(new_caches)
             new_lens = jnp.where(active, lens + 1, lens)
             finished = active & (nxt == eos_ids)
-            return nxt, new_logits, kb, vb, new_lens, finished, rng
+            return (nxt, new_logits, kb, vb, new_lens, finished, rng,
+                    sum(counted, ctr_zero) if n_ctr else None)
 
         def step(state_vals, k_bufs, v_bufs, logits, lens, active, rng,
                  temps, top_ps, eos_ids, budgets, rids, tables=None,
@@ -1235,24 +1386,29 @@ class LLMEngine:
             outputs. ``tables`` (paged mode) is a traced input — the host
             allocator mutates it between steps without recompiling."""
             def body(carry, _):
-                kb, vb, logits, lens, act, emitted, rng = carry
-                nxt, logits, kb, vb, lens, finished, rng = one_step(
+                kb, vb, logits, lens, act, emitted, rng, ctr = carry
+                nxt, logits, kb, vb, lens, finished, rng, c1 = one_step(
                     kb, vb, logits, lens, act, rng, state_vals, temps,
                     top_ps, eos_ids, rids, tables, lora)
                 emitted = emitted + act.astype(jnp.int32)
                 act_next = act & ~finished & (lens < cap - 1) & \
                     (emitted < budgets)
-                return (kb, vb, logits, lens, act_next, emitted, rng), \
-                    (nxt, act)
+                if n_ctr:
+                    ctr = ctr + c1
+                return (kb, vb, logits, lens, act_next, emitted, rng,
+                        ctr), (nxt, act)
 
             emitted0 = jnp.zeros_like(lens)
-            (k_bufs, v_bufs, logits, lens, active, _, rng), \
+            ctr0 = jnp.zeros((n_ctr,), jnp.int32) if n_ctr else None
+            (k_bufs, v_bufs, logits, lens, active, _, rng, ctr), \
                 (toks, was_active) = jax.lax.scan(
                     body,
-                    (k_bufs, v_bufs, logits, lens, active, emitted0, rng),
+                    (k_bufs, v_bufs, logits, lens, active, emitted0, rng,
+                     ctr0),
                     None, length=K)
             return (_pin_rep(toks), _pin_rep(was_active), _pin_rep(logits),
-                    _pin_kv(k_bufs), _pin_kv(v_bufs), _pin_rep(lens), rng)
+                    _pin_kv(k_bufs), _pin_kv(v_bufs), _pin_rep(lens), rng,
+                    ctr)
 
         def make_multi_step(Kms):
             """Build the ``readout_stride=Kms`` MULTI-STEP decode
@@ -1276,10 +1432,12 @@ class LLMEngine:
                     return (i < Kms) & jnp.any(act)
 
                 def body(carry):
-                    i, kb, vb, lg, ln, act, emitted, toks, wa = carry
-                    nxt, lg, kb, vb, ln, finished, _ = one_step(
+                    i, kb, vb, lg, ln, act, emitted, toks, wa, ctr = carry
+                    nxt, lg, kb, vb, ln, finished, _, c1 = one_step(
                         kb, vb, lg, ln, act, rng, state_vals, temps,
                         top_ps, eos_ids, rids, tables, lora)
+                    if n_ctr:
+                        ctr = ctr + c1
                     toks = jax.lax.dynamic_update_slice(
                         toks, nxt[None], (i, jnp.int32(0)))
                     wa = jax.lax.dynamic_update_slice(
@@ -1287,19 +1445,21 @@ class LLMEngine:
                     emitted = emitted + act.astype(jnp.int32)
                     act = act & ~finished & (ln < cap - 1) & \
                         (emitted < budgets)
-                    return (i + 1, kb, vb, lg, ln, act, emitted, toks, wa)
+                    return (i + 1, kb, vb, lg, ln, act, emitted, toks, wa,
+                            ctr)
 
                 carry = (jnp.int32(0), list(k_bufs), list(v_bufs), logits,
                          lens, jnp.asarray(active),
                          jnp.zeros_like(lens),
                          jnp.zeros((Kms, B), jnp.int32),
-                         jnp.zeros((Kms, B), bool))
-                (_, k_out, v_out, logits, lens, _, _, toks, wa) = \
+                         jnp.zeros((Kms, B), bool),
+                         jnp.zeros((n_ctr,), jnp.int32) if n_ctr else None)
+                (_, k_out, v_out, logits, lens, _, _, toks, wa, ctr) = \
                     jax.lax.while_loop(cond, body, carry)
                 assert len(k_out) == nL
                 return (_pin_rep(toks), _pin_rep(wa), _pin_rep(logits),
                         _pin_kv(k_out), _pin_kv(v_out), _pin_rep(lens),
-                        rng)
+                        rng, ctr)
             return multi_step
 
         self._multi_step_factory = make_multi_step
@@ -1381,7 +1541,7 @@ class LLMEngine:
                 with functional_mode(), _bind(state, state_vals):
                     caches = [SlotKVCache(k, v, lens)
                               for k, v in zip(kb, vb)]
-                    hidden, new_caches = model.llama(
+                    hidden, new_caches = decoder(
                         Tensor(window), kv_caches=caches,
                         position_offset=Tensor(lens))
                     logits_all = model._logits(hidden)._value \
@@ -1474,7 +1634,7 @@ class LLMEngine:
                         else:
                             caches = paged_caches(kb, vb, tables, ln,
                                                   q_eff)
-                        hidden, new_caches = model.llama(
+                        hidden, new_caches = decoder(
                             Tensor(window), kv_caches=caches,
                             position_offset=Tensor(ln))
                         logits_win = model._logits(hidden)._value \
@@ -1592,9 +1752,10 @@ class LLMEngine:
                 else:
                     caches = paged_caches(k_bufs, v_bufs, tables, lens,
                                           q_eff)
-                hidden, new_caches = model.llama(
-                    Tensor(ids), kv_caches=caches,
-                    position_offset=Tensor(lens))
+                with collect_counts() as counted:
+                    hidden, new_caches = decoder(
+                        Tensor(ids), kv_caches=caches,
+                        position_offset=Tensor(lens))
                 # per-slot LAST VALID row: a prefill chunk's next-token
                 # logits / the decode token's next logits — one gather,
                 # then the lm head over [B, 1, H] only (never the full
@@ -1631,7 +1792,8 @@ class LLMEngine:
                 # step_finish is shared with the scan-based steps (K==1)
                 return (_pin_rep(nxt[None]), _pin_rep(dec[None]),
                         _pin_rep(new_logits), _pin_kv(kb), _pin_kv(vb),
-                        _pin_rep(new_lens), rng, pooled)
+                        _pin_rep(new_lens), rng, pooled,
+                        sum(counted, ctr_zero) if n_ctr else None)
             counts, _, spec_logits = verify_window(
                 logits_win, draft, lens, q_eff, rng, temps, top_ps,
                 rids, dec)
@@ -1669,7 +1831,7 @@ class LLMEngine:
                     lora_scope(lora):
                 caches = [StaticKVCache(k, v)
                           for k, v in zip(k_slot, v_slot)]
-                hidden, new_caches = model.llama(
+                hidden, new_caches = decoder(
                     Tensor(ids), kv_caches=caches,
                     position_offset=Tensor(off))
                 row = jax.lax.dynamic_slice(
@@ -1726,7 +1888,7 @@ class LLMEngine:
                         lora_scope(lora):
                     caches = [StaticKVCache(k, v)
                               for k, v in zip(k_slot, v_slot)]
-                    hidden, new_caches = model.llama(
+                    hidden, new_caches = decoder(
                         Tensor(ids), kv_caches=caches,
                         position_offset=Tensor(off))
                     row = jax.lax.dynamic_slice(
@@ -2059,6 +2221,13 @@ class LLMEngine:
         ``export_kv``: stage the request's committed KV as a staged
         export entry at its finish (disaggregated serving — see
         :meth:`export_kv`)."""
+        if export_kv:
+            self._refuse_kv_shipping("add_request(export_kv=True)")
+        if kind == "embed" and not self._kv_only:
+            raise ValueError(
+                "kind='embed' pools the hidden rows of a K/V decoder's "
+                "prefill; it is not wired for a cache layout with other "
+                "state kinds")
         ids = np.asarray(
             prompt_ids.numpy() if hasattr(prompt_ids, "numpy")
             else prompt_ids, dtype=np.int32).reshape(-1)
@@ -2858,6 +3027,7 @@ class LLMEngine:
         materialization only reads already-gathered host-bound staging
         arrays, never the pool. Returns the plain-numpy staged entry
         (serializable by ``serving.kv_transport``), or None."""
+        self._refuse_kv_shipping("export_kv()")
         if self.cache_impl != "paged":
             return None
         entry = self._export_store.pop(request_id, None)
@@ -2882,6 +3052,7 @@ class LLMEngine:
         Callable from ANY thread (one GIL-atomic dict write). Returns
         True when staged, False on a compatibility reject — the router
         falls back to plain re-prefill."""
+        self._refuse_kv_shipping("import_kv()")
         if self.cache_impl != "paged" or self.scheduler != "fused":
             return False
         if not entry.get("ready") or entry.get("n_blocks", 0) <= 0:
@@ -2921,6 +3092,7 @@ class LLMEngine:
         (an eviction re-registered under the SAME hash is harmless by
         content addressing). Returns entries for the servable prefix
         only, stopping at the first miss."""
+        self._refuse_kv_shipping("export_prefix_blocks()")
         out = []
         if self.cache_impl != "paged" or not self.prefix_cache:
             return out
@@ -2975,6 +3147,7 @@ class LLMEngine:
         request submitted right after the import hits them. Requires an
         armed spill store (``kv_host_spill_bytes > 0``); entries are
         dropped otherwise. Returns the number queued."""
+        self._refuse_kv_shipping("import_prefix_blocks()")
         if self.cache_impl != "paged" or not self.prefix_cache or \
                 not self.kv_host_spill_bytes:
             return 0
@@ -3145,8 +3318,11 @@ class LLMEngine:
         a side formula."""
         if self.cache_impl != "paged":
             return 0
+        # the POOLS: a layout's per-slot recurrent state is not in blocks
+        pools = [(k, v) for kind, k, v in zip(self._layout, self._k, self._v)
+                 if kind.paged]
         return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
-                   for x in jax.tree_util.tree_leaves([self._k, self._v]))
+                   for x in jax.tree_util.tree_leaves(pools))
 
     def kv_bytes_per_block(self):
         """Device bytes ONE pool block costs across all layers (K + V
@@ -3497,6 +3673,11 @@ class LLMEngine:
         # probe hit
         self._lens = self._set_len_fn(self._lens, np.int32(slot_idx),
                                       np.int32(hit))
+        if self._has_recurrent:
+            # a recurrent layer starts a slot whose length is 0 from
+            # zeros in-graph (cache_layout.Recurrent): setting the length
+            # IS the reset, and costs the host nothing more in this phase
+            self.stats["state_resets"] += 1
         if self._tokens is not None:
             # speculative fused engine: seed the device token history
             # with the WHOLE prompt (host-known even for a prefix-cache
@@ -3968,20 +4149,20 @@ class LLMEngine:
         t0 = self._to("dispatch", **self._dispatch_ids(
             "spec" if spec else "decode", rows * k_iter,
             int(active.sum()) * k_iter))
-        counts = None
+        counts = ctr_dev = None
         if use_multi:
             fn = self._multi_fn(stride)
             if self.cache_impl == "paged":
                 with self._kernel_tp_ctx():
                     (toks, was_active, self._logits, self._k, self._v,
-                     self._lens, self._rng_key) = fn(
+                     self._lens, self._rng_key, ctr_dev) = fn(
                         self._state_vals, self._k, self._v, self._logits,
                         self._lens, active, self._rng_key, temps, top_ps,
                         eos_ids, budgets, rids, self._tables.copy(),
                         lora=lora)
             else:
                 (toks, was_active, self._logits, self._k, self._v,
-                 self._lens, self._rng_key) = fn(
+                 self._lens, self._rng_key, ctr_dev) = fn(
                     self._state_vals, self._k, self._v, self._logits,
                     self._lens, active, self._rng_key, temps, top_ps,
                     eos_ids, budgets, rids, lora=lora)
@@ -3989,7 +4170,7 @@ class LLMEngine:
         elif self.cache_impl == "paged":
             with self._kernel_tp_ctx():
                 (toks, was_active, self._logits, self._k, self._v,
-                 self._lens, self._rng_key) = self._step_paged_fn(
+                 self._lens, self._rng_key, ctr_dev) = self._step_paged_fn(
                     self._state_vals, self._k, self._v, self._logits,
                     self._lens, active, self._rng_key, temps, top_ps,
                     eos_ids, budgets, rids, self._tables.copy(),
@@ -4002,7 +4183,7 @@ class LLMEngine:
                 temps, top_ps, eos_ids, budgets, rids, self._tokens)
         else:
             (toks, was_active, self._logits, self._k, self._v, self._lens,
-             self._rng_key) = self._step_fn(
+             self._rng_key, ctr_dev) = self._step_fn(
                 self._state_vals, self._k, self._v, self._logits,
                 self._lens, active, self._rng_key,
                 temps, top_ps, eos_ids, budgets, rids, lora=lora)
@@ -4032,6 +4213,7 @@ class LLMEngine:
                      for b in np.nonzero(active)[0]
                      if self.slots[b] is not None} if spec else None))
         pending.t_dispatch = t0
+        pending.ctr = ctr_dev
         pending.rows = rows if use_multi else 0
         if self.cache_impl == "paged":
             self._book_kv_grid(k_iter)
@@ -4385,8 +4567,9 @@ class LLMEngine:
         spec_args = dict(tokens_buf=self._tokens, spec_ks=spec_ks) \
             if spec else {}
         counts_dev = None
+        # the append kernel's tile count is of K/V pools
         tiles = self._attn_tile_steps(q_lens) \
-            if self.cache_impl == "paged" else None
+            if self.cache_impl == "paged" and self._kv_only else None
         t0 = self._to("dispatch", **self._dispatch_ids(
             "mixed", ids.size, int(q_lens.sum()),
             live_tiles=tiles and tiles[0]))
@@ -4405,7 +4588,7 @@ class LLMEngine:
                 temps, top_ps, rids,
                 lora=lora, is_embed=is_embed, pooled=pooled_arg,
                 **spec_args)
-        offered = None
+        offered = ctr_dev = None
         if spec:
             # spec layout: [1, B, Kw] window tokens + [1, B] counts —
             # the readout flatten shared with the legacy verify scan
@@ -4414,7 +4597,7 @@ class LLMEngine:
              self._tokens, offered) = ret
         else:
             (toks, was_active, self._logits, self._k, self._v,
-             self._lens, self._rng_key, pooled_out) = ret
+             self._lens, self._rng_key, pooled_out, ctr_dev) = ret
         if pooled_out is not None:
             self._pooled = pooled_out
         dt = self._to("schedule") - t0
@@ -4456,13 +4639,15 @@ class LLMEngine:
         self._inflight += 1
         if self.cache_impl == "paged":
             self._book_kv_grid(1)
-            self.stats["attn_tile_steps"] += tiles[0]
-            self.stats["attn_tile_steps_grid"] += tiles[1]
+            if tiles is not None:
+                self.stats["attn_tile_steps"] += tiles[0]
+                self.stats["attn_tile_steps_grid"] += tiles[1]
         pending = PendingStep(toks, was_active, counts_dev, spec,
                               list(self.slots), pool_done, sched=sched,
                               fenced=fenced, embed_done=embed_done,
                               verify=verify)
         pending.t_dispatch = t0
+        pending.ctr = ctr_dev
         pending.pooled = pooled_out
         pending.offered = offered
         rec = self._rec()
@@ -4543,6 +4728,8 @@ class LLMEngine:
         else:
             toks_np = np.asarray(pending.toks)       # [K, B] — THE transfer
             act_np = np.asarray(pending.was_active)  # [K, B]
+        # the model's step counters left the program beside the tokens
+        ctr_np = np.asarray(pending.ctr) if pending.ctr is not None else None
         dt = self._to(None) - t0
         self.stats["steps"] += 1
         if pending.guarded:
@@ -4590,6 +4777,14 @@ class LLMEngine:
         # an early-exit stride's rows, now that the iterations it ran are
         # known (at least the one every dispatch runs)
         self.stats["rows_computed"] += pending.rows * max(n_exec, 1)
+        if ctr_np is not None:
+            booked = dict(zip(self._step_counter_names,
+                              (int(v) for v in ctr_np)))
+            for name, v in booked.items():
+                self.stats[name] = self.stats.get(name, 0) + v
+            if "moe_assignments_held" in booked:
+                # what the host could not know at dispatch
+                ids["held_rows"] = booked["moe_assignments_held"]
         now_pc = t0 = self._to("emit", **ids)
         if toks_np.shape[0] > 1 and pending.t_dispatch is not None \
                 and n_exec > 1:

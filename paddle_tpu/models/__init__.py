@@ -9,3 +9,5 @@ from .gpt import (  # noqa: F401
 from .unet import (  # noqa: F401
     UNetConfig, UNetModel, sd_unet, diffusion_loss, timestep_embedding,
 )
+from .kimi_linear import (  # noqa: F401
+    KimiLinearConfig, KimiLinearForCausalLM)

@@ -649,6 +649,20 @@ class LlamaForCausalLM(Layer):
             self.lm_head = Linear(config.hidden_size, config.vocab_size,
                                   bias_attr=False)
 
+    @property
+    def decoder(self):
+        """The serving engine's seam (``models/cache_layout.py``): the
+        module it calls with per-layer caches."""
+        return self.llama
+
+    def cache_layout(self):
+        """One state kind a layer: K and V of ``(kv_heads, head_dim)``."""
+        from .cache_layout import PagedKV
+        c = self.config
+        return [PagedKV(c.num_key_value_heads,
+                        c.hidden_size // c.num_attention_heads)
+                for _ in range(c.num_hidden_layers)]
+
     def _logits(self, hidden):
         if self.config.tie_word_embeddings:
             return ops.matmul(hidden, self.llama.embed_tokens.weight,

@@ -1,0 +1,75 @@
+"""Dropless routing over the experts a chip holds.
+
+The layer routes every live row over ALL the published experts (sigmoid
+scores, a selection bias, the ``top_k`` largest, renormalised and scaled)
+and computes the part of the result that its OWN experts give: experts
+``[offset, offset + E)`` of the published count. Every assignment that
+lands on a held expert is computed: the assignments are sorted by expert
+and go through one grouped product a projection (``jax.lax.ragged_dot``:
+a native grouped matmul on the TPU), so there is no capacity and nothing
+is dropped; a dead row is not routed. What the absent experts would have
+added is left out, and no code stands in for the chips that hold them
+(``ops/kernels/moe.py`` is the capacity-routed GShard layer with its
+exchange; no model of the benchmark uses it).
+
+``rows`` is the static height of the grouped product: the caller's bound
+on held assignments (live rows x min(top_k, E)). The counts that leave
+with the result say what was asked and what was computed.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+HI = jax.lax.Precision.HIGHEST
+#: what :func:`held_expert_ffn` counts, in order
+COUNTERS = ("moe_assignments", "moe_assignments_held", "moe_rows_computed",
+            "moe_expert_peak", "moe_assignments_dropped")
+
+
+def route(x, w_router, bias, top_k, scale, renormalize=True):
+    """x: [N, h]; w_router: [h, E_all]; bias: [E_all], used to SELECT only.
+    Scores and selection in float32. Returns (idx [N, k] int32, weights
+    [N, k] float32)."""
+    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32),
+                                  w_router.astype(jnp.float32),
+                                  precision=HI))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w * jnp.float32(scale)
+
+
+def held_expert_ffn(x, idx, w, live, w_gate, w_up, w_down, offset, rows):
+    """x: [N, h]; idx, w: [N, k] from :func:`route`; live: [N] bool;
+    w_gate, w_up: [E, h, f]; w_down: [E, f, h], the held experts ``offset
+    .. offset + E - 1``. Returns (y [N, h] float32, counts [5] int32 in
+    :data:`COUNTERS` order)."""
+    n, k = idx.shape
+    e = w_gate.shape[0]
+    rows = int(min(rows, n * k))
+    local = idx - jnp.int32(offset)
+    held = live[:, None] & (local >= 0) & (local < e)
+    key = jnp.where(held, local, e).reshape(-1)           # e sorts last
+    order = jnp.argsort(key, stable=True)[:rows]
+    sizes = jnp.zeros((e,), jnp.int32).at[key].add(1, mode="drop")
+    n_held = jnp.sum(sizes)
+    token = order // k
+    xs = jnp.take(x, token, axis=0)
+    gate = jax.lax.ragged_dot(xs, w_gate, sizes,
+                              preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(xs, w_up, sizes,
+                            preferred_element_type=jnp.float32)
+    act = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = jax.lax.ragged_dot(act, w_down, sizes,
+                             preferred_element_type=jnp.float32)
+    valid = jnp.arange(rows, dtype=jnp.int32) < n_held
+    ws = jnp.where(valid, jnp.take(w.reshape(-1), order), 0.0)
+    y = jnp.zeros((n, x.shape[1]), jnp.float32).at[token].add(
+        jnp.where(valid[:, None], out, 0.0) * ws[:, None])
+    counts = jnp.stack([
+        jnp.sum(live).astype(jnp.int32) * k, n_held,
+        jnp.int32(rows), jnp.max(sizes),
+        jnp.maximum(n_held - rows, 0)]).astype(jnp.int32)
+    return y, counts
