@@ -45,7 +45,7 @@ import numpy as np
 
 from ..analysis import lock_watchdog as _lockwatch
 from ..core.tensor import Tensor, functional_mode
-from ..models.cache_layout import collect_counts
+from ..models.cache_layout import RowMap, collect_counts, packed_rows
 from ..models.llama import SlotKVCache, _sample_logits_device
 from ..models.lora import lora_scope
 from ..profiler import span
@@ -632,6 +632,11 @@ class LLMEngine:
         if self.max_step_tokens < 1:
             raise ValueError(f"max_step_tokens must be >= 1, got "
                              f"{self.max_step_tokens}")
+        #: rows a mixed step computes: the height of its packed row axis
+        #: (derived, models/cache_layout.py; never ``max_batch x chunk``
+        #: unless the budget really grants that many)
+        self.mixed_rows = packed_rows(self.max_step_tokens, self.B,
+                                      self.chunk, self.speculative_k)
         self._mesh = mesh
         #: tensor-parallel serving (the multichip subsystem, serving/
         #: cluster.py): a mesh with a "tp" axis turns the engine's KV
@@ -1233,8 +1238,12 @@ class LLMEngine:
         n_ctr = len(self._step_counter_names)
         ctr_zero = jnp.zeros((n_ctr,), jnp.int32)
         #: the scheduler's bound on a mixed step's live rows over all
-        #: slots (decode tokens always land, prefill takes the rest)
-        mixed_rows = max(self.max_step_tokens, self.B)
+        #: slots (decode tokens always land, prefill takes the rest):
+        #: what an expert layer sizes its grouped product by
+        row_budget = max(self.max_step_tokens, self.B)
+        #: the packed height of a mixed step's row axis: that bound,
+        #: rounded to the chip's row tile (``cache_layout.packed_rows``)
+        T = self.mixed_rows
         state = self._state
         B, cap, chunk = self.B, self.capacity, self.chunk
         top_k = self.top_k
@@ -1272,24 +1281,27 @@ class LLMEngine:
 
         kvq = self.kv_quant
 
-        def paged_caches(kb, vb, tables, lens, q_lens=None, active=None):
+        def paged_caches(kb, vb, tables, lens, q_lens=None, active=None,
+                         rows=None):
             """Per-layer cache list of one traced dispatch. All-K/V
             layouts: PagedKVCache — THE one place that unpacks the
             quantized (payload, scale) pool bundles, so no step body can
             forget the scales. Any other layout: each layer's kind makes
             its own (``q_lens`` None is the one-token step: a slot that
-            is not ``active`` has no live row)."""
+            is not ``active`` has no live row). ``rows``: the mixed
+            step's RowMap, which every cache object then carries."""
             if not kv_only:
-                rows = mixed_rows if q_lens is not None else B
-                return [kind.cache(a, b, tables, lens, q_lens, active, rows)
+                budget = row_budget if q_lens is not None else B
+                return [kind.cache(a, b, tables, lens, q_lens, active,
+                                   budget, rows)
                         for kind, a, b in zip(layout, kb, vb)]
             from ..models.llama import PagedKVCache
             if kvq:
                 return [PagedKVCache(k[0], v[0], tables, lens, q_lens,
                                      k_scale=k[1], v_scale=v[1],
-                                     quant=kvq)
+                                     quant=kvq, rows=rows)
                         for k, v in zip(kb, vb)]
-            return [PagedKVCache(k, v, tables, lens, q_lens)
+            return [PagedKVCache(k, v, tables, lens, q_lens, rows=rows)
                     for k, v in zip(kb, vb)]
 
         def unpack_kv(new_caches):
@@ -1688,13 +1700,22 @@ class LLMEngine:
                        tables=None, lora=None, is_embed=None, pooled=None,
                        tokens_buf=None, spec_ks=None):
             """ONE mixed prefill+decode dispatch (the fused scheduler's
-            step): slot b processes rows [0, q_lens[b]) of ``ids`` —
-            either a prefill chunk (host-provided prompt rows) or one
-            decode token (row 0, sampled IN-GRAPH from the carried
-            logits, so no extra host round-trip vs the plain step).
-            Every slot's rows sit at its own absolute positions
-            (``lens``); padding rows write nothing (drop-scatter) and
-            their outputs are never read. ``tables`` selects the cache
+            step): slot b is granted rows [0, q_lens[b]) of the host's
+            ``ids[B, chunk]`` — either a prefill chunk (host-provided
+            prompt rows) or one decode token (row 0, sampled IN-GRAPH
+            from the carried logits, so no extra host round-trip vs the
+            plain step). The decoder is NOT handed ``[B, chunk]`` rows:
+            once the capacity guard has fixed the effective grants, the
+            granted rows are packed slot-major onto ONE row axis of the
+            static height ``T = packed_rows(max_step_tokens, max_batch,
+            chunk)`` (``cache_layout.RowMap``, made here in the graph),
+            ``ids`` is gathered to ``[1, T]`` and every cache object
+            carries the map; the layers take the per-slot ``[B, chunk]``
+            view only around their attention and recurrent cores. Every
+            row sits at its own absolute position (``lens[slot] + col``);
+            the ``T - sum(q_lens)`` padding rows write nothing and their
+            outputs are never read. A slot's last live packed row feeds
+            the head. ``tables`` selects the cache
             backend at trace time exactly like ``step``; ``lora`` arms
             the per-slot adapter delta exactly like ``one_step``.
 
@@ -1743,47 +1764,49 @@ class LLMEngine:
                 ids = jnp.where(dec[:, None] & wcols, padded_win, ids)
                 tb_new = _write_window(tokens_buf, window, lens)
                 tokens_buf = jnp.where(dec[:, None], tb_new, tokens_buf)
+            # the packed row axis: made HERE, after the guard above may
+            # have taken a slot out in the graph
+            rows = RowMap(q_eff, lens, T, chunk)
             with functional_mode(), _bind(state, state_vals), \
-                    lora_scope(lora):
+                    lora_scope(lora and dict(lora, rows=rows)):
                 if tables is None:
                     from ..models.llama import ChunkKVCache
-                    caches = [ChunkKVCache(k, v, lens, q_eff)
+                    caches = [ChunkKVCache(k, v, lens, q_eff, rows)
                               for k, v in zip(k_bufs, v_bufs)]
                 else:
                     caches = paged_caches(k_bufs, v_bufs, tables, lens,
-                                          q_eff)
+                                          q_eff, rows=rows)
                 with collect_counts() as counted:
                     hidden, new_caches = decoder(
-                        Tensor(ids), kv_caches=caches,
-                        position_offset=Tensor(lens))
-                # per-slot LAST VALID row: a prefill chunk's next-token
+                        Tensor(rows.from_slots(ids)[None]),
+                        kv_caches=caches,
+                        position_offset=Tensor(rows.pos[None]))
+                hidden = hidden._value[0]                       # [T, H]
+                # per-slot LAST LIVE row: a prefill chunk's next-token
                 # logits / the decode token's next logits — one gather,
-                # then the lm head over [B, 1, H] only (never the full
-                # chunk: the head over B*chunk rows would dominate)
-                rows = jnp.take_along_axis(
-                    hidden._value,
-                    jnp.maximum(q_eff - 1, 0)[:, None, None], axis=1)
-                new_logits = model._logits(Tensor(rows))._value[:, 0] \
+                # then the lm head over [B, 1, H] only (never every
+                # row: the head over them would dominate)
+                new_logits = model._logits(Tensor(
+                    hidden[rows.last()][:, None]))._value[:, 0] \
                     .astype(jnp.float32)
                 if spec_ks is not None:
                     # verify slots need PER-ROW logits over the window
-                    # (not just the last valid row): the head runs over
-                    # [B, Kspec, H] — bounded by the window width, never
-                    # the full chunk
-                    logits_win = model._logits(
-                        Tensor(hidden._value[:, :Kspec]))._value \
+                    # (not just the last live row): the head runs over
+                    # [B, Kspec, H] — bounded by the window width
+                    logits_win = model._logits(Tensor(
+                        rows.to_slots(hidden, Kspec)))._value \
                         .astype(jnp.float32)
             if pooled is not None:
                 # masked sum of this dispatch's real prefill rows for
-                # embed slots only, fp32 — one tiny [B,S,H]x[B,S]
+                # embed slots only, fp32 — one tiny [B,T]x[T,H]
                 # contraction riding the mixed step
-                rows_real = jnp.arange(chunk, dtype=jnp.int32)[None, :] \
-                    < q_eff[:, None]
-                emb_mask = (rows_real & is_embed[:, None]
-                            & ~is_decode[:, None]).astype(jnp.float32)
+                emb_mask = ((rows.slot[None, :]
+                             == jnp.arange(B, dtype=jnp.int32)[:, None])
+                            & rows.live[None, :]
+                            & (is_embed & ~is_decode)[:, None])
                 pooled = pooled + jnp.einsum(
-                    "bsh,bs->bh", hidden._value.astype(jnp.float32),
-                    emb_mask)
+                    "bt,th->bh", emb_mask.astype(jnp.float32),
+                    hidden.astype(jnp.float32))
             kb, vb = unpack_kv(new_caches)
             if spec_ks is None:
                 new_logits = jnp.where(active[:, None], new_logits, logits)
@@ -4571,7 +4594,7 @@ class LLMEngine:
         tiles = self._attn_tile_steps(q_lens) \
             if self.cache_impl == "paged" and self._kv_only else None
         t0 = self._to("dispatch", **self._dispatch_ids(
-            "mixed", ids.size, int(q_lens.sum()),
+            "mixed", self.mixed_rows, int(q_lens.sum()),
             live_tiles=tiles and tiles[0]))
         if self.cache_impl == "paged":
             with self._kernel_tp_ctx():
@@ -4602,8 +4625,8 @@ class LLMEngine:
             self._pooled = pooled_out
         dt = self._to("schedule") - t0
         self.stats["fused_steps"] += 1
-        # every row of ``ids`` is computed, granted or padding
-        self.stats["rows_computed"] += ids.size
+        # every row of the packed axis is computed, granted or padding
+        self.stats["rows_computed"] += self.mixed_rows
         # host mirrors of the scheduled growth (dispatch-time, so the
         # next step — possibly dispatched before this one's readout —
         # schedules from the post-step state)

@@ -18,6 +18,24 @@ A kind makes the layer's state ``(a, b)`` (two pytrees of device arrays,
 ``b`` possibly None: the engine carries every layer's pair through its
 programs as it carried K and V pools), the cache object the layer is
 handed for one dispatch, and takes the pair back off the returned cache.
+
+**What the decoder is handed in a mixed step.** A one-token step hands it
+``ids[B, 1]``. A mixed step (the fused scheduler's prefill chunks and
+decode tokens in one dispatch) hands it ``ids[1, T]``: ONE PACKED ROW AXIS
+of the step's granted rows, slot-major, a slot's rows adjacent and in
+position order, ``T`` the scheduler's static bound on them (a few rows of
+padding at the end, not ``max_batch x chunk``). Every cache object of
+that dispatch carries the same :class:`RowMap` as ``rows`` (None in a
+one-token step) beside ``seq_lens`` / ``q_lens``. Embedding, norms,
+residuals, projections, feed-forwards, routers and experts run on the
+``T`` rows and never look at the map, except for a row's position
+(``rows.pos``) and whether it holds a token (``rows.live``). A layer
+takes the per-slot view ``[B, S, ...]`` only where its state is per slot
+-- around the paged append attention, the dense scatter-and-attend, the
+latent pool's write and attention, a recurrent layer's convolution tail
+and chunk scan -- through the two gathers :meth:`RowMap.to_slots` and
+:meth:`RowMap.from_slots`; rows of that view past ``q_lens[b]`` are
+finite garbage nobody reads, as those kernels' contracts always said.
 """
 from __future__ import annotations
 
@@ -25,6 +43,7 @@ import contextlib
 import threading
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
@@ -62,9 +81,10 @@ class PagedLatent:
     def alloc(self, zeros, n_blocks, block_size, batch, dtype):
         return zeros((n_blocks + 1, block_size, self.width), dtype), None
 
-    def cache(self, a, b, tables, lens, q_lens, active, row_budget):
+    def cache(self, a, b, tables, lens, q_lens, active, row_budget,
+              rows=None):
         return LatentPagedCache(a, tables, lens, _q_lens(q_lens, active),
-                                row_budget)
+                                row_budget, rows)
 
     def unpack(self, cache):
         return _val(cache.pool), None
@@ -90,8 +110,10 @@ class Recurrent:
         return {k: zeros((batch,) + s, dt)
                 for k, (s, dt) in self.shapes.items()}, None
 
-    def cache(self, a, b, tables, lens, q_lens, active, row_budget):
-        return RecurrentCache(a, lens, _q_lens(q_lens, active), row_budget)
+    def cache(self, a, b, tables, lens, q_lens, active, row_budget,
+              rows=None):
+        return RecurrentCache(a, lens, _q_lens(q_lens, active), row_budget,
+                              rows)
 
     def unpack(self, cache):
         return {k: _val(v) for k, v in cache.state.items()}, None
@@ -110,25 +132,115 @@ class LatentPagedCache:
     ``block_tables`` [B, max_blocks], ``seq_lens`` [B] entries already
     held, ``q_lens`` [B] live rows of this step's S (0: the slot writes
     and attends nothing). ``row_budget``: the dispatcher's static bound on
-    the step's live rows over all slots (None: every row may be live)."""
-    __slots__ = ("pool", "block_tables", "seq_lens", "q_lens", "row_budget")
+    the step's live rows over all slots (None: every row may be live).
+    ``rows``: the :class:`RowMap` of a mixed step, whose ``x`` is the
+    packed ``[1, T, ...]`` (None: ``x`` is ``[B, S, ...]``)."""
+    __slots__ = ("pool", "block_tables", "seq_lens", "q_lens", "row_budget",
+                 "rows")
 
     def __init__(self, pool, block_tables, seq_lens, q_lens,
-                 row_budget=None):
+                 row_budget=None, rows=None):
         self.pool, self.block_tables = pool, block_tables
         self.seq_lens, self.q_lens = seq_lens, q_lens
-        self.row_budget = row_budget
+        self.row_budget, self.rows = row_budget, rows
 
 
 class RecurrentCache:
     """A recurrent layer's per-slot state for one dispatch: ``state``
     {name: [B, ...]}, ``seq_lens`` [B] tokens already absorbed, ``q_lens``
-    [B] live rows of this step (rows past them are identity updates)."""
-    __slots__ = ("state", "seq_lens", "q_lens", "row_budget")
+    [B] live rows of this step (rows past them are identity updates).
+    ``rows``: as on :class:`LatentPagedCache`."""
+    __slots__ = ("state", "seq_lens", "q_lens", "row_budget", "rows")
 
-    def __init__(self, state, seq_lens, q_lens, row_budget=None):
+    def __init__(self, state, seq_lens, q_lens, row_budget=None, rows=None):
         self.state, self.seq_lens, self.q_lens = state, seq_lens, q_lens
-        self.row_budget = row_budget
+        self.row_budget, self.rows = row_budget, rows
+
+
+# ---------------------------------------------------------------------------
+# the packed row axis of a mixed step
+# ---------------------------------------------------------------------------
+
+#: what the chip wants of a bf16 operand's row count (a packed sublane
+#: tile): the packed height is a multiple of it
+ROW_TILE = 16
+
+
+def packed_rows(max_step_tokens, max_batch, chunk, window=1):
+    """The static height ``T`` of a mixed step's packed row axis: the
+    fused scheduler's bound on the rows it grants one step, rounded up to
+    :data:`ROW_TILE`. Decode tokens always land and the budget is what is
+    left for prefill, the oldest ramping slot's guaranteed token included:
+    ``max(max_step_tokens, max_batch)`` rows. A speculative engine
+    (``window`` = its verify window > 1) grants a decode slot its committed
+    token even when the drafts of the slots before it spent the budget:
+    ``max_batch - 1`` rows more. Never more than the padded step's
+    ``max_batch * chunk``, where the packed step is the padded step."""
+    bound = max(int(max_step_tokens), int(max_batch))
+    if window > 1:
+        bound = int(max_step_tokens) + int(max_batch) - 1
+    bound = min(bound, int(max_batch) * int(chunk))
+    return -(-bound // ROW_TILE) * ROW_TILE
+
+
+class RowMap:
+    """Which slot and which of its rows each packed row of a mixed step
+    is. Made in the graph from the step's effective ``q_lens`` (the
+    capacity guard can take a slot out there, so the host cannot make it).
+    With ``cu = cumsum(q_lens)``: slot ``b`` owns packed rows ``[cu[b] -
+    q_lens[b], cu[b])``, in position order.
+
+    ``slot`` [T] the row's slot, ``col`` [T] its index among the slot's
+    rows, ``pos`` [T] its absolute position ``seq_lens[slot] + col``,
+    ``live`` [T] whether it holds a token (``t < cu[-1]``; the rest are
+    padding, at column and position 0 so that nothing computed on them
+    indexes out of a table), ``start`` [B] a slot's first packed row,
+    ``q_lens`` [B], ``width`` the per-slot view's S."""
+    __slots__ = ("slot", "col", "pos", "live", "start", "q_lens", "width")
+
+    def __init__(self, q_lens, seq_lens, n_rows, width):
+        q = q_lens.astype(jnp.int32)
+        cu = jnp.cumsum(q)
+        t = jnp.arange(int(n_rows), dtype=jnp.int32)
+        self.q_lens, self.width = q, int(width)
+        self.start = cu - q
+        self.slot = jnp.minimum(
+            jnp.sum(t[:, None] >= cu[None, :], axis=1, dtype=jnp.int32),
+            q.shape[0] - 1)
+        self.live = t < cu[-1]
+        self.col = jnp.where(self.live, t - self.start[self.slot], 0)
+        self.pos = jnp.where(
+            self.live, seq_lens.astype(jnp.int32)[self.slot] + self.col, 0)
+
+    def last(self):
+        """[B] a slot's last live packed row (row 0 of the axis for a
+        slot without rows: nobody reads what it gathers)."""
+        return jnp.maximum(self.start + self.q_lens - 1, 0)
+
+    def to_slots(self, x, width=None):
+        """Packed ``x[T, ...]`` -> the per-slot view ``[B, width, ...]``
+        (``width`` = S unless given): row ``i < q_lens[b]`` of slot ``b``
+        lands at ``[b, i]``. One contiguous slice a slot, from its first
+        packed row on, so a row past ``q_lens[b]`` holds whatever follows
+        on the axis (the next slots' rows, the padding, then zeros)."""
+        w = self.width if width is None else int(width)
+        x = jnp.concatenate([x, jnp.zeros((w,) + x.shape[1:], x.dtype)])
+        return jax.vmap(
+            lambda at: jax.lax.dynamic_slice_in_dim(x, at, w))(self.start)
+
+    def from_slots(self, y):
+        """The per-slot view ``y[B, S, ...]`` -> packed ``[T, ...]``: the
+        inverse of :meth:`to_slots` on live rows (a padding row reads
+        some row of the last slot)."""
+        s = y.shape[1]
+        flat = y.reshape((y.shape[0] * s,) + y.shape[2:])
+        return jnp.take(flat, self.slot * s + self.col, axis=0)
+
+
+def packed(cache):
+    """The :class:`RowMap` a cache object of a mixed step carries, or
+    None (a one-token step, a plain forward, a cache class without one)."""
+    return getattr(cache, "rows", None)
 
 
 # ---------------------------------------------------------------------------

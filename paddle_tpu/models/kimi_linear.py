@@ -147,31 +147,42 @@ class KimiDeltaAttention(Layer):
 
     def forward(self, x, cache):
         H, K, eps = self.H, self.K, self.eps
+        rows = CL.packed(cache)
 
         def fn(x, S, tail, lens, q_lens, wq, wk, wv, cq, ck, cv, wfa, wfb,
                dtb, alog, wb, wga, wgb, on, wo):
-            b, s, _ = x.shape
+            # every projection on x's own rows: [B, S] or, in a mixed
+            # step, the packed [1, T]
+            lead = x.shape[:2]
+            qkv = jnp.concatenate([_mm(x, wq), _mm(x, wk), _mm(x, wv)], -1)
+            g = -jnp.exp(alog.astype(F32))[:, None] * jax.nn.softplus(
+                (_mm32(_mm(x, wfa), wfb) + dtb.astype(F32))
+                .reshape(lead + (H, K)))
+            beta = jax.nn.sigmoid(_mm32(x, wb))
+            gate = jax.nn.sigmoid(_mm32(_mm(x, wga), wgb)) \
+                .reshape(lead + (H, K))
+            if rows is not None:
+                # the per-slot view, around the convolution's tail and
+                # the recurrence only
+                qkv, g, beta = (rows.to_slots(a[0]) for a in (qkv, g, beta))
+            b, s = qkv.shape[:2]
             fresh = (lens.astype(jnp.int32) == 0)
             S = jnp.where(fresh[:, None, None, None], 0.0, S)
             tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
             live = _live_rows(q_lens, s)
-            qkv = jnp.concatenate([_mm(x, wq), _mm(x, wk), _mm(x, wv)], -1)
             y, tail = _kda.causal_conv(
                 qkv, tail, jnp.concatenate([cq, ck, cv], -1), q_lens)
             y = jax.nn.silu(y).reshape(b, s, 3, H, K)
             q = _l2(y[:, :, 0]) * jnp.float32(K ** -0.5)
             k, v = _l2(y[:, :, 1]), y[:, :, 2]
-            g = -jnp.exp(alog.astype(F32))[:, None] * jax.nn.softplus(
-                (_mm32(_mm(x, wfa), wfb) + dtb.astype(F32))
-                .reshape(b, s, H, K))
-            beta = jax.nn.sigmoid(_mm32(x, wb))
             g = jnp.where(live[:, :, None, None], g, 0.0)
             beta = jnp.where(live[:, :, None], beta, 0.0)
             run = _kda.kda_recurrent if s == 1 else _kda.kda_chunk
             o, S = run(q, k, v, g, beta, S)
-            gate = jax.nn.sigmoid(_mm32(_mm(x, wga), wgb)).reshape(b, s, H, K)
+            if rows is not None:
+                o = rows.from_slots(o)[None]
             o = (_rms(o, on, eps) * gate).astype(x.dtype)
-            return _mm(o.reshape(b, s, H * K), wo), S, tail
+            return _mm(o.reshape(lead + (H * K,)), wo), S, tail
 
         st = cache.state
         out, S, tail = dispatch(
@@ -183,7 +194,7 @@ class KimiDeltaAttention(Layer):
                  self.g_b_proj.weight, self.o_norm.weight,
                  self.o_proj.weight), {}, name="kimi_kda")
         return out, CL.RecurrentCache({"S": S, "conv": tail}, cache.seq_lens,
-                                      cache.q_lens, cache.row_budget)
+                                      cache.q_lens, cache.row_budget, rows)
 
 
 class KimiLatentAttention(Layer):
@@ -211,24 +222,32 @@ class KimiLatentAttention(Layer):
         H, r, dn, dp, dv, eps = self.H, self.r, self.dn, self.dp, self.dv, \
             self.eps
 
+        rows = CL.packed(cache)
+
         def fn(x, pool, tables, lens, q_lens, wq, wkva, nw, wkvb, wo):
-            b, s, _ = x.shape
-            q = _mm(x, wq).reshape(b, s, H, dn + dp)
+            # projections on x's own rows ([B, S], or a mixed step's
+            # packed [1, T]); the per-slot view around the pool only
+            lead = x.shape[:2]
+            q = _mm(x, wq).reshape(lead + (H, dn + dp))
             kv = _mm(x, wkva)
             entry = jnp.concatenate(
                 [_rms(kv[..., :r], nw, eps).astype(x.dtype), kv[..., r:]], -1)
-            pool = _lat.latent_pool_write(pool, entry, tables, lens, q_lens)
             wkvb = wkvb.reshape(r, H, dn + dv)
             # absorbed: q_nope through the key half into the latent's width
             q_abs = jnp.einsum("bshn,chn->bshc", q[..., :dn], wkvb[..., :dn],
                                preferred_element_type=F32)
-            qc = jnp.concatenate([q_abs, q[..., dn:].astype(F32)], -1) * \
-                jnp.float32((dn + dp) ** -0.5)
+            qc = (jnp.concatenate([q_abs, q[..., dn:].astype(F32)], -1) *
+                  jnp.float32((dn + dp) ** -0.5)).astype(x.dtype)
+            if rows is not None:
+                entry, qc = rows.to_slots(entry[0]), rows.to_slots(qc[0])
+            pool = _lat.latent_pool_write(pool, entry, tables, lens, q_lens)
             o = _lat.latent_attention_append(
-                qc.astype(x.dtype), pool, tables, lens, q_lens, r)
+                qc, pool, tables, lens, q_lens, r)
+            if rows is not None:
+                o = rows.from_slots(o)[None]
             o = jnp.einsum("bshc,chv->bshv", o, wkvb[..., dn:],
                            preferred_element_type=F32).astype(x.dtype)
-            return _mm(o.reshape(b, s, H * dv), wo), pool
+            return _mm(o.reshape(lead + (H * dv,)), wo), pool
 
         out, pool = dispatch(
             fn, (x, cache.pool, cache.block_tables, cache.seq_lens,
@@ -237,7 +256,7 @@ class KimiLatentAttention(Layer):
                  self.o_proj.weight), {}, name="kimi_mla")
         return out, CL.LatentPagedCache(pool, cache.block_tables,
                                         cache.seq_lens, cache.q_lens,
-                                        cache.row_budget)
+                                        cache.row_budget, rows)
 
 
 def _swiglu(x, wg, wu, wd):
@@ -298,12 +317,19 @@ class KimiSparseMoE(Layer):
         k = c.num_experts_per_token
         budget = getattr(cache, "row_budget", None)
         q_lens = getattr(cache, "q_lens", None)
+        rmap = CL.packed(cache)
 
         def fn(x, q_lens, wr, bias, wg, wu, wd, sg, su, sd):
             b, s, h = x.shape
             n = b * s
-            live = jnp.ones((n,), bool) if q_lens is None else \
-                _live_rows(q_lens, s).reshape(n)
+            if rmap is not None:
+                # a mixed step's packed rows: the first sum(q_lens) hold
+                # a token
+                live = rmap.live
+            elif q_lens is None:
+                live = jnp.ones((n,), bool)
+            else:
+                live = _live_rows(q_lens, s).reshape(n)
             xf = x.reshape(n, h)
             idx, w = _moe.route(xf, wr, bias, k, c.routed_scaling_factor,
                                 c.moe_renormalize)

@@ -27,6 +27,7 @@ from ..nn.functional.attention import flash_tp_context
 from ..core.tensor import Tensor, dispatch, functional_mode
 from ..jit.functional_call import stored_sharding
 from .lora import active_lora
+from .cache_layout import packed
 from .. import ops
 
 
@@ -126,10 +127,14 @@ def precompute_rope(head_dim, max_len, theta=10000.0):
 
 def apply_rope(q, k, cos, sin, position_offset=0):
     """q,k: [B, S, H, D]; rotate-half formulation in fp32. position_offset is
-    a scalar (shared offset) or a [B] vector (per-slot positions for the
-    continuous-batching decode step)."""
+    a scalar (shared offset), a [B] vector (per-slot positions for the
+    continuous-batching decode step) or a [B, S] array of every row's own
+    position (the mixed step's packed row axis)."""
     s = q.shape[1]
-    if getattr(position_offset, "ndim", 0) == 1:
+    if getattr(position_offset, "ndim", 0) == 2:
+        cos_t = jnp.take(cos, position_offset, axis=0)[:, :, None, :]
+        sin_t = jnp.take(sin, position_offset, axis=0)[:, :, None, :]
+    elif getattr(position_offset, "ndim", 0) == 1:
         pos = position_offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
         cos_t = jnp.take(cos, pos, axis=0)[:, :, None, :]   # [B, S, 1, D]
         sin_t = jnp.take(sin, pos, axis=0)[:, :, None, :]
@@ -197,18 +202,22 @@ class PagedKVCache:
     the pools are int8/int4 QUANTIZED storage (int4 nibble-packed on the
     head dim) with per-(physical block, kv head) fp32 scale arrays
     [num_blocks, Hkv] riding alongside — the attention op dequantizes on
-    read and returns updated scales with the pools."""
+    read and returns updated scales with the pools.
+
+    ``rows`` (a :class:`~paddle_tpu.models.cache_layout.RowMap`): the
+    layer's ``x`` is the mixed step's packed ``[1, T, ...]`` and not
+    ``[B, S, ...]``; the attention takes the per-slot view through it."""
 
     __slots__ = ("k", "v", "block_tables", "seq_lens", "q_lens",
-                 "k_scale", "v_scale", "quant")
+                 "k_scale", "v_scale", "quant", "rows")
 
     def __init__(self, k, v, block_tables, seq_lens, q_lens=None,
-                 k_scale=None, v_scale=None, quant=None):
+                 k_scale=None, v_scale=None, quant=None, rows=None):
         self.k, self.v = k, v
         self.block_tables, self.seq_lens = block_tables, seq_lens
         self.q_lens = q_lens
         self.k_scale, self.v_scale = k_scale, v_scale
-        self.quant = quant
+        self.quant, self.rows = quant, rows
 
 
 class ChunkKVCache:
@@ -219,12 +228,14 @@ class ChunkKVCache:
     slot b writes position lens[b]+i when i < q_lens[b] (padding and
     past-capacity rows DROP — no dynamic-slice clamping that could slide
     back over live history) and attends causally to positions
-    <= lens[b]+i. The engine advances ``lens`` by q_lens itself."""
+    <= lens[b]+i. The engine advances ``lens`` by q_lens itself.
+    ``rows``: as on :class:`PagedKVCache`."""
 
-    __slots__ = ("k", "v", "lens", "q_lens")
+    __slots__ = ("k", "v", "lens", "q_lens", "rows")
 
-    def __init__(self, k, v, lens, q_lens):
+    def __init__(self, k, v, lens, q_lens, rows=None):
         self.k, self.v, self.lens, self.q_lens = k, v, lens, q_lens
+        self.rows = rows
 
 
 def _window_causal_mask(s, T):
@@ -335,6 +346,9 @@ class LlamaAttention(Layer):
     def forward(self, x, rope_cache, attn_mask=None, kv_cache=None, position_offset=0):
         b, s = x.shape[0], x.shape[1]
         lora = active_lora()
+        #: a mixed step's packed row axis: x is [1, T, ...], the cache
+        #: branches below want the per-slot view [B, S, ...]
+        rows = packed(kv_cache)
         if self.fused:
             if lora is not None:
                 raise ValueError(
@@ -363,6 +377,9 @@ class LlamaAttention(Layer):
             v = ops.reshape(vf, [b, s, self.num_kv_heads, self.head_dim])
 
         def o_proj(t):
+            if rows is not None:
+                t = dispatch(lambda y: rows.from_slots(y)[None], (t,), {},
+                             name="rows_from_slots")
             out = self.o_proj(t)
             if lora is not None:
                 out = lora.apply("o_proj", self.layer_idx, t, out)
@@ -379,6 +396,13 @@ class LlamaAttention(Layer):
             q, k = dispatch(
                 lambda qq, kk: apply_rope(qq, kk, cos, sin, position_offset),
                 (q, k), {}, name="rope")
+        if rows is not None:
+            # what is above ran on the granted rows; o_proj takes the
+            # attention's output back to them
+            q, k, v = dispatch(
+                lambda *ts: tuple(rows.to_slots(t[0]) for t in ts),
+                (q, k, v), {}, name="rows_to_slots")
+            b, s = q.shape[0], q.shape[1]
         if isinstance(kv_cache, PagedKVCache):
             # paged decode step (one new token/sequence) through the
             # block_multihead_attention op — the framework's own paged-KV

@@ -54,34 +54,43 @@ class _LoraApply:
     """The armed context: the traced stacks + per-batch-row device slots
     of ONE dispatch, applying the gathered delta on demand."""
 
-    __slots__ = ("A", "B", "alpha", "slots")
+    __slots__ = ("A", "B", "alpha", "slots", "rows")
 
     def __init__(self, pack):
         self.A = pack["A"]
         self.B = pack["B"]
         self.alpha = pack["alpha"]
         self.slots = pack["slots"]
+        #: a mixed step's RowMap (models/cache_layout.py): the
+        #: projections then run on the packed rows [1, T, d]
+        self.rows = pack.get("rows")
 
     def apply(self, target, layer_idx, x, base):
         """``base + (x @ A[s, l]) @ B[s, l] * alpha[s]`` with ``s`` the
         per-row device slot — fp32 accumulation, cast back to the base
         dtype. ``x``/``base`` are framework Tensors [B, S, d_in/d_out];
-        slot 0 gathers the all-zeros base row (delta exactly 0)."""
+        slot 0 gathers the all-zeros base row (delta exactly 0). On a
+        mixed step's packed rows the delta is computed on the per-slot
+        view (one adapter a slot) and gathered back."""
         import jax.numpy as jnp
         from ..core.tensor import dispatch
 
         A, Bm = self.A.get(target), self.B.get(target)
         if A is None or Bm is None:
             return base
-        alpha, slots = self.alpha, self.slots
+        alpha, slots, rows = self.alpha, self.slots, self.rows
         li = int(layer_idx)
 
         def f(xv, bv):
             Ag = A[slots, li]                   # [B, d_in, r]
             Bg = Bm[slots, li]                  # [B, r, d_out]
             al = alpha[slots]                   # [B]
+            if rows is not None:
+                xv = rows.to_slots(xv[0])
             h = jnp.einsum("bsd,bdr->bsr", xv.astype(jnp.float32), Ag)
             d = jnp.einsum("bsr,bro->bso", h, Bg) * al[:, None, None]
+            if rows is not None:
+                d = rows.from_slots(d)[None]
             return bv + d.astype(bv.dtype)
 
         return dispatch(f, (x, base), {}, name=f"lora_{target}")

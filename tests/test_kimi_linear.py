@@ -328,14 +328,18 @@ def _serve(model, arrivals, **over):
     return done, eng
 
 
-@pytest.mark.parametrize("case", ["staggered", "preempted"])
+@pytest.mark.parametrize("case", ["staggered", "preempted", "two_ramping"])
 def test_engine_serves_what_the_reference_would(case):
     """Chunked prefill then ``multi_step`` decode, compared on the gaps of
     the served tokens' logits as ``served_gaps`` compares. ``staggered``:
     arrivals spread over steps, a slot that idles while others decode, a
     slot reused by later requests. ``preempted``: a pool too small for the
     batch, so a request is preempted and replays from its first token into
-    zeroed state."""
+    zeroed state. ``two_ramping``: a budget of two chunks, so two
+    documents prefill in ONE mixed step beside a third's decode token:
+    the packed row axis holds two slots' chunks back to back, and the
+    convolution tails, the recurrences and the latent pool each take
+    their own slot's rows out of it."""
     seed = 17
     model, _ = build(TOY, seed)
     rng = np.random.default_rng(6)
@@ -348,6 +352,14 @@ def test_engine_serves_what_the_reference_would(case):
         done, eng = _serve(model, arrivals)
         assert eng.stats["preemptions"] == 0
         assert eng.stats["state_resets"] == 5
+    elif case == "two_ramping":
+        arrivals = {0: [(doc(21), 20)], 2: [(doc(70), 9), (doc(61), 8)]}
+        done, eng = _serve(model, arrivals, max_step_tokens=64)
+        assert eng.mixed_rows == 64 < 3 * 32
+        # some mixed step carried two prefill grants
+        assert eng.stats["prefill_chunks"] > eng.stats["fused_steps"]
+        assert eng.stats["rows_computed"] >= 64 * eng.stats["fused_steps"]
+        assert eng.stats["state_resets"] == 3
     else:
         arrivals = {0: [(doc(90), 30), (doc(80), 30), (doc(85), 30)]}
         done, eng = _serve(model, arrivals, kv_pool_blocks=16)
