@@ -44,7 +44,7 @@ import time
 
 import numpy as np
 
-#: the flagship widths (bench.py's BENCH_MODEL=llama / llama_serve shape)
+#: the flagship widths: Llama-2-7B's hidden size and heads, 3 layers
 FLAGSHIP = dict(vocab_size=32000, hidden_size=4096, intermediate_size=11264,
                 num_hidden_layers=3, num_attention_heads=32,
                 num_key_value_heads=32)
@@ -494,7 +494,7 @@ def multichip(cfg_kw=FLAGSHIP, n=4, batch=4, seq=2048, n_requests=8,
 def one_chip():
     from paddle_tpu.models import LlamaConfig
 
-    # B=6 x S=2048: the BENCH_MODEL=llama shape
+    # B=6 x S=2048: the shape PR 22 brought up on the chip
     train_cfg = LlamaConfig(max_position_embeddings=2048, use_recompute=True,
                             **FLAGSHIP)
     with lowered_programs() as read:

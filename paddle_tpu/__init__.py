@@ -21,7 +21,7 @@ def _configure_compile_cache():
     wins when set (JAX reads it itself — nothing is set in code); otherwise
     the cache lives at ``<checkout>/.jax_cache``. The path is part of the
     cache key's environment, so it is FIXED: never a tempdir, pid or time.
-    THE one place a cache directory is chosen — bench.py children, the
+    THE one place a cache directory is chosen — benchmark/run.py, the
     examples, __graft_entry__.py and chip_smoke.py all get it by importing
     this package. A config update does not initialise a backend."""
     import os

@@ -41,7 +41,7 @@ Runtime sanitizers (the dynamic halves):
   ``jax.transfer_guard("disallow")`` across the fused all-decode
   stride's dispatch→readout window and counts the documented readout
   as ``stats["guarded_syncs"]`` — the one-sync-per-stride contract as
-  an assertion instead of a bench number.
+  an assertion.
 * lock-order watchdog — ``PADDLE_TPU_LOCK_CHECKS=1`` wraps the
   documented serving locks, records actual acquisition edges, raises
   on cycles online, and :func:`lock_watchdog.assert_consistent` checks
@@ -54,20 +54,4 @@ from . import lock_watchdog
 
 __all__ = ["Finding", "Report", "JSON_SCHEMA_VERSION", "default_checks",
            "iter_py_files", "load_baseline", "run_analysis",
-           "static_lock_graph", "lock_watchdog", "count_findings"]
-
-
-def count_findings(paths, baseline_path=None):
-    """Convenience for bench/CI headers: ``(active, baselined,
-    suppressed)`` finding counts for ``paths``. ``active`` is what
-    would fail the run; ``baselined`` is the grandfathered debt still
-    to burn down."""
-    baseline = None
-    if baseline_path is not None:
-        try:
-            baseline = load_baseline(baseline_path)
-        except (OSError, ValueError):
-            baseline = None
-    report = run_analysis(paths, baseline=baseline)
-    s = report.summary()
-    return s["new"], s["baselined"], s["suppressed"]
+           "static_lock_graph", "lock_watchdog"]

@@ -18,8 +18,8 @@ StepRecord split and the preemption A/B read.
 
 A KV copy issued anywhere else has none of those guarantees: it can
 race a pipelined writer (silently on CPU, corrupt KV on TPU), and its
-bytes vanish from the swap accounting — the bench's "re-prefill tokens
-avoided" number quietly lies. This check makes that a lint error:
+bytes vanish from the swap accounting — ``kv_swap_saved_tokens``
+quietly lies. This check makes that a lint error:
 
 * any ``np.asarray`` / ``np.array`` / ``jax.device_get`` /
   ``jax.device_put`` / ``.copy_to_host_async()`` call whose argument

@@ -85,7 +85,6 @@ def default_engine_stats():
             # explain_tail preempt classifier's exclusive signal
             "kv_ship_out_blocks": 0, "kv_ship_in_blocks": 0,
             "kv_ship_out_bytes": 0, "kv_ship_in_bytes": 0,
-            "swap_out_time_s": 0.0, "swap_in_time_s": 0.0,
             "decode_time_s": 0.0, "admit_time_s": 0.0,
             "schedule_time_s": 0.0,
             "dispatch_time_s": 0.0, "host_sync_time_s": 0.0,
@@ -479,9 +478,8 @@ class LLMEngine:
         kv-heads under a TP mesh exactly like the pools. ``None`` (the
         default) is bit-identical to the bf16 engine. Output tokens
         DRIFT from bf16 (that is the deal: ~2x/4x capacity for a
-        quantization error of ~0.4%/~7% per KV read); the serve bench's
-        ``llama_serve_kv_quant`` A/B and tests/test_kv_quant.py track
-        greedy drift explicitly.
+        quantization error of ~0.4%/~7% per KV read); tests/test_kv_quant.py
+        tracks greedy drift explicitly.
 
         ``kv_host_swap`` (paged + fused only — the HOST KV TIER's
         preemption half): when pool pressure preempts a slot, its
@@ -2741,7 +2739,6 @@ class LLMEngine:
         blocks = self._slot_blocks[b][:nb]
         if len(blocks) < nb:
             return
-        t0 = time.perf_counter()
         k_host, v_host = self._kv_gather_fn(self._k, self._v,
                                             self._pad_block_idx(blocks))
         for leaf in jax.tree_util.tree_leaves([k_host, v_host]):
@@ -2759,7 +2756,6 @@ class LLMEngine:
         self._swap_pending.append(entry)
         self.stats["kv_swap_out_blocks"] += nb
         self.stats["kv_swap_out_bytes"] += entry["nbytes"]
-        self.stats["swap_out_time_s"] += time.perf_counter() - t0
 
     def _drain_swap_writes(self):
         """Materialize every pending device→host tier copy into plain
@@ -2769,7 +2765,6 @@ class LLMEngine:
         that needs an entry sooner."""
         if not self._swap_pending:
             return
-        t0 = time.perf_counter()
         for entry in self._swap_pending:
             nb = entry["n_blocks"]
             entry["k"] = jax.tree_util.tree_map(
@@ -2778,7 +2773,6 @@ class LLMEngine:
                 lambda x: np.asarray(x)[:nb], entry["v"])
             entry["ready"] = True
         self._swap_pending.clear()
-        self.stats["swap_out_time_s"] += time.perf_counter() - t0
 
     def _try_swap_restores(self):
         """The swap-in half, run at the top of every MIXED step (the
@@ -2885,7 +2879,6 @@ class LLMEngine:
                     self.kv_bytes_per_block()
             self.stats["kv_swap_saved_tokens"] += max(stitch - pos, 0)
             restore_s = time.perf_counter() - t0
-            self.stats["swap_in_time_s"] += restore_s
             if shipped:
                 # the migration's STITCH phase wall (alloc + H2D scatter
                 # + lens jump), keyed by rid for the router's migration
@@ -2917,7 +2910,6 @@ class LLMEngine:
         per = self.kv_bytes_per_block()
         if h is None or h in self._spill or per > self.kv_host_spill_bytes:
             return
-        t0 = time.perf_counter()
         k_host, v_host = self._kv_gather_fn(self._k, self._v,
                                             self._pad_block_idx([phys]))
         for leaf in jax.tree_util.tree_leaves([k_host, v_host]):
@@ -2943,7 +2935,6 @@ class LLMEngine:
         # swap-off preemption step "preempt_swap" whenever an unrelated
         # eviction landed on it
         self.stats["kv_spill_blocks"] += 1
-        self.stats["swap_out_time_s"] += time.perf_counter() - t0
 
     def _promote_spilled(self, h):
         """Promote a spilled block back into the device pool: claim a
@@ -2955,7 +2946,6 @@ class LLMEngine:
         entry = self._spill.get(h)
         if entry is None or not self._n_allocatable():
             return None
-        t0 = time.perf_counter()
         self._drain_swap_writes()
         del self._spill[h]
         self._spill_bytes -= entry["nbytes"]
@@ -2979,7 +2969,6 @@ class LLMEngine:
         # matching note in _spill_block (swap-byte deltas stay the
         # preemption classifier's exclusive signal)
         self.stats["kv_promote_blocks"] += 1
-        self.stats["swap_in_time_s"] += time.perf_counter() - t0
         return phys
 
     def swap_resident_rids(self):
@@ -3016,7 +3005,6 @@ class LLMEngine:
         blocks = self._slot_blocks[b][:nb]
         if len(blocks) < nb:
             return
-        t0 = time.perf_counter()
         k_host, v_host = self._kv_gather_fn(self._k, self._v,
                                             self._pad_block_idx(blocks))
         for leaf in jax.tree_util.tree_leaves([k_host, v_host]):
@@ -3041,7 +3029,6 @@ class LLMEngine:
             self._export_store.popitem(last=False)
         self.stats["kv_ship_out_blocks"] += nb
         self.stats["kv_ship_out_bytes"] += entry["nbytes"]
-        self.stats["swap_out_time_s"] += time.perf_counter() - t0
 
     def export_kv(self, request_id):
         """Pop + materialize the staged export entry for ``request_id``
@@ -3349,8 +3336,8 @@ class LLMEngine:
 
     def kv_bytes_per_block(self):
         """Device bytes ONE pool block costs across all layers (K + V
-        payload plus its per-head scales) — what the serve bench's
-        equal-byte pool sizing divides a HBM budget by."""
+        payload plus its per-head scales) — what an equal-byte pool
+        sizing divides a HBM budget by."""
         if self.cache_impl != "paged":
             return 0
         return self.kv_pool_nbytes() // (self.n_blocks + 1)
@@ -3761,7 +3748,7 @@ class LLMEngine:
                     # the preempt ladder forever (preempt newest →
                     # re-admit → re-grab → preempt), burning prefill
                     # FLOPs without either finishing (the 2-slot ×
-                    # 4-block-prompt × 4-block-pool thrash PR 12's bench
+                    # 4-block-prompt × 4-block-pool thrash PR 12
                     # surfaced). Deferring costs nothing: the resident
                     # ramp can always finish alone, and its retirement
                     # re-opens admission.
@@ -3876,7 +3863,7 @@ class LLMEngine:
         multi-step dispatch: until the guard closes (step_finish, or the
         next chained step_begin under pipelining), ANY implicit device
         transfer on the stepping thread raises — the PR-8 headline claim
-        as a runtime assertion instead of a bench number. Explicit
+        as a runtime assertion. Explicit
         transfers (jax.device_put / device_get) stay allowed, which is
         exactly the allowlist semantics the documented readout needs."""
         if not self._transfer_checks or \
@@ -4164,7 +4151,7 @@ class LLMEngine:
 
         # the decode clock starts HERE: pool-allocator scans and host array
         # construction above must not masquerade as device decode time in
-        # throughput() or the serve bench's wall split. All arms DISPATCH
+        # the stats' wall split. All arms DISPATCH
         # only — no host read; JAX async dispatch returns futures and the
         # transfer blocks in step_finish().
         k_iter = stride if use_multi else self.horizon
@@ -4975,10 +4962,6 @@ class LLMEngine:
         while self.has_unfinished():
             self.step()
         return [self.finished_outputs.pop(r) for r in rids]
-
-    def throughput(self):
-        dt = self.stats["decode_time_s"]
-        return self.stats["tokens_generated"] / dt if dt > 0 else 0.0
 
     def reset_stats(self):
         for key in self.stats:

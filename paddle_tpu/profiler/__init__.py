@@ -34,7 +34,6 @@ from .slo import (  # noqa: F401
 __all__ = [
     "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent", "span",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-    "summarize_device_trace",
     "SummaryView", "benchmark", "merge_profile",
     "ServingTelemetry", "LatencyHistogram", "LABELED_GAUGE_FAMILIES",
     "FlightRecorder", "StepRecord", "TAIL_CAUSES",
@@ -305,49 +304,6 @@ def load_profiler_result(filename):
     """Load an exported chrome-trace JSON back as a list of events."""
     with open(filename) as f:
         return json.load(f).get("traceEvents", [])
-
-
-def summarize_device_trace(events):
-    """Aggregate the DEVICE tracks of an XLA chrome trace into
-    ``({instr_name: {"count", "total_us"}}, module_total_us)`` — THE
-    dedupe-aware trace parser:
-
-    a device lane carries THREE overlapping span families — ``jit_*``
-    module spans (the true device step time), the per-instruction op
-    spans nested inside them, and the "Steps" track's bare-number step
-    markers, which cover the same wall time as the module spans. A tool
-    that naively sums every device span therefore double-counts step
-    time once via the step markers and again via the modules (and
-    triple-counts it via the ops). Here each family is routed exactly
-    once: ``jit_*`` spans sum into ``module_total_us``, per-op spans
-    aggregate by name, and bare-number step markers count toward
-    NEITHER.
-
-    ``events``: a ``traceEvents`` list (e.g. from
-    :func:`load_profiler_result`). Device lanes are recognized by their
-    ``process_name`` metadata containing ``device:TPU``."""
-    device_pids = set()
-    for e in events:
-        if (e.get("ph") == "M" and e.get("name") == "process_name"
-                and "device:TPU" in str(e.get("args", {}).get("name", ""))):
-            device_pids.add(e["pid"])
-    agg = {}
-    module_total = 0.0
-    for e in events:
-        if e.get("ph") != "X" or e.get("pid") not in device_pids:
-            continue
-        name = e["name"]
-        dur = float(e.get("dur", 0.0))
-        if name.startswith("jit_"):
-            module_total += dur
-            continue
-        if name.isdigit():
-            # "Steps" track marker: overlaps the module spans it brackets
-            continue
-        entry = agg.setdefault(name, {"count": 0, "total_us": 0.0})
-        entry["count"] += 1
-        entry["total_us"] += dur
-    return agg, module_total
 
 
 def merge_profile(rank_dirs_or_files, output_path, align_start=True):

@@ -801,7 +801,7 @@ class FlightRecorder:
 
     def snapshot(self, tail=None):
         """JSON-ready summary: retained step counts + cause histogram of
-        the current 0.99 tail (cheap enough to ride in bench output).
+        the current 0.99 tail (cheap enough to ride in a result line).
         Pass a precomputed ``explain_tail`` result as ``tail`` to avoid
         re-walking the timelines."""
         recs = self.records()

@@ -230,8 +230,8 @@ class SLOEngine:
         self._lock = threading.Lock()
 
     def add(self, slo):
-        """Append an objective at runtime (benches calibrate a target
-        from a warmup phase, then arm the SLO)."""
+        """Append an objective at runtime (a caller calibrates a target
+        from a warmup phase, then arms the SLO)."""
         if not isinstance(slo, SLO):
             raise TypeError(f"expected SLO, got {type(slo).__name__}")
         with self._lock:

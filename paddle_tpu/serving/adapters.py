@@ -339,7 +339,7 @@ def apply_merged(model, store, adapter_id):
 
 def random_lora_weights(config, rank, seed=0, scale=0.02, targets=None):
     """Small random (A, B) factors for every (or the given) target —
-    the test/bench/example adapter generator. ``scale`` keeps the delta
+    the test and example adapter generator. ``scale`` keeps the delta
     small enough that greedy decoding stays numerically stable while
     still changing the stream."""
     rng = np.random.default_rng(seed)

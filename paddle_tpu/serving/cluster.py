@@ -329,7 +329,7 @@ class ReplicaRouter:
 
     ``policy``: ``"affinity"`` (the default — affinity score on top of
     least-loaded), ``"least_loaded"`` (ignore affinity), or ``"random"``
-    (the bench's control arm). ``submit(..., replica=i)`` pins a request
+    (the control arm of a comparison). ``submit(..., replica=i)`` pins a request
     explicitly (ops / tests). The router owns replica lifecycle when
     started through it: :meth:`start` starts un-started replicas plus the
     failover monitor, :meth:`stop` drains and stops everything.

@@ -265,10 +265,6 @@ class BertEmbedEngine:
         self.stats["emit_time_s"] += time.perf_counter() - t0
         return done
 
-    def throughput(self):
-        dt = self.stats["decode_time_s"]
-        return self.stats["prefill_tokens"] / dt if dt > 0 else 0.0
-
     def reset_stats(self):
         for key in self.stats:
             self.stats[key] = 0.0 if key.endswith("_s") else 0

@@ -3,8 +3,8 @@
 Reference analog: the reference's real server is AnalysisPredictor driven
 by PaddleNLP's serving stack (SURVEY §1 layer 6c) — request queue in
 front, predictor loop behind, per-request streaming out. This module is
-that shape on the TPU-native engine, built around the one property the
-synchronous ``bench.py`` loop never exploited: **JAX async dispatch**.
+that shape on the TPU-native engine, built around the one property a
+synchronous ``engine.step()`` loop never exploits: **JAX async dispatch**.
 
 The engine thread runs a PIPELINED loop::
 
@@ -250,7 +250,7 @@ class AsyncLLMServer:
                 black_box = BlackBox(out_dir=black_box)
         self.black_box = black_box or None
         #: mint a TraceContext per submitted request (False exists for
-        #: the bench's on/off overhead A/B; caller-supplied contexts
+        #: an on/off overhead A/B; caller-supplied contexts
         #: are honored either way)
         self.trace_context = bool(trace_context)
         #: alert instances whose RAISE already triggered a bundle —

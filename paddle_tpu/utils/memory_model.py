@@ -1,14 +1,14 @@
 """Memory-residency model for train steps: what the backward saves.
 
-The fit proofs (tests/test_7b_scale.py) and the on-chip cross-validation
-(bench.py BENCH_MODEL=memcheck) decompose per-device residency into
+The fit proofs (tests/test_7b_scale.py) decompose per-device residency into
 
 1. state — exact, from the compiled program's ``argument_size_in_bytes``;
 2. backward residuals — trace-level, from jax's ``saved_residuals`` (the
    only backend-independent view that SEES remat; the CPU backend's
    ``temp_size_in_bytes`` is remat-blind, measured in round 3);
 3. in-segment transients — the remainder against the TPU compiler's
-   ``peak_memory_in_bytes`` (cross-validated on the real chip).
+   ``peak_memory_in_bytes`` (not measured on the chip under the installed
+   toolchain).
 
 ``saved_residuals`` is a PRIVATE jax API (jax._src.ad_checkpoint) — this
 module is the single import site, with a loud failure naming the
@@ -25,7 +25,7 @@ def saved_residuals_compat(f, *args):
     """jax's saved_residuals, isolated behind one loud-failure import.
 
     Raises RuntimeError (not ImportError) with a clear message when the
-    private API moves, so callers (tests skip; bench reports) can react
+    private API moves, so callers (the tests skip) can react
     instead of dying on an opaque AttributeError."""
     try:
         from jax._src.ad_checkpoint import saved_residuals

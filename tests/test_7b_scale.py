@@ -1,7 +1,7 @@
 """North-star scale proof: the REAL Llama-2-7B compiles and fits v5e HBM.
 
-VERDICT r2 #1: nothing had ever compiled the actual 32-layer model — the
-bench proxies with 3 layers. Without a pod, the scale proof is AOT: build the
+Nothing else compiles the actual 32-layer model. Without a pod, the scale
+proof is AOT: build the
 full 7B ABSTRACTLY (LazyGuard — zero host memory), assign the hybrid
 placements, compile the complete fused train step (fwd+bwd+AdamW, remat) on
 the virtual 8-device mesh, and read the per-device budget out of the
@@ -20,14 +20,11 @@ The budget decomposes into two honestly-measurable parts:
    used for the fit claim: measured here (and with a pure-jax repro), CPU
    buffer assignment reports identical temps with and without
    ``jax.checkpoint``, so it cannot see the remat structure that governs TPU
-   residency. In-segment transients on the TPU path are MEASURED, not
-   assumed (round 4, bench.py BENCH_MODEL=memcheck on the real chip): at
-   the single-chip bench config (879M, B=6, S=2048, ff=11264 unsharded)
-   the TPU compiler's peak exceeds state+residuals by 1.068 GB (9.25% of
-   peak — the residual model accounts for the rest of the compiler's temp
-   bytes exactly). Transients scale with the largest live activation block
-   (B, S, ff/mp); at the TP=8 proof config (B=4, ff=11008/8) that block is
-   ~12x smaller → ~90 MB, inside the 0.88 GB headroom left after 1.+2.
+   residency. In-segment transients on the TPU path (the TPU compiler's peak
+   beyond state+residuals) are not measured on the chip under the installed
+   toolchain. They scale with the largest live activation block
+   (B, S, ff/mp), which is small at the TP=8 proof config (B=4,
+   ff=11008/8) against the 0.88 GB headroom left after 1.+2.
 
 Reference analog: test/auto_parallel/hybrid_strategy/semi_auto_llama.py:1
 (the hybrid-parallel llama train config this mirrors), with the memory proof
@@ -247,7 +244,7 @@ def test_lazyguard_abstract_then_materialize():
 
 
 def test_7b_tp8_accumulation_compiles_and_fits():
-    """The flagship bench config at full scale: TP=8, ZeRO-1 state sharding,
+    """The flagship config at full scale: TP=8, ZeRO-1 state sharding,
     bf16 moments, gradient accumulation. aot_compile returns the
     (microstep, update) program pair; BOTH must fit — the microstep carries
     the persistent fp32 accumulators (which inherit the param's TP sharding:

@@ -343,35 +343,3 @@ def test_spec_k1_is_plain_fused(tiny_model, greedy_ref):
     out = [o.token_ids for o in eng.generate(prompts, max_new_tokens=8)]
     assert out == greedy_ref(prompts, 8)
     assert eng.stats["spec_proposed_tokens"] == 0
-
-
-@pytest.mark.slow
-def test_bench_spec_smoke_b8():
-    """CPU dry-run of the batched (B=8) fused-scheduler spec bench arm:
-    the A/B completes, reports a speedup ratio + per-arm acceptance
-    rate, and the arms are token-parity. Gated slow (CI hygiene
-    satellite): 4 serve passes through a real model dominate CPU
-    wall."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    env = {"BENCH_BATCH": "8", "BENCH_REQUESTS": "8",
-           "BENCH_NEW_TOKENS": "8", "BENCH_LAYERS": "1",
-           "BENCH_HIDDEN": "128", "BENCH_SPEC_K": "4",
-           "BENCH_READOUT_STRIDE": "2"}
-    old = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    try:
-        import bench
-        out = bench._bench_other("llama_serve_spec")
-        assert out["metric"] == "llama_serve_spec_tokens_per_sec"
-        assert out["token_parity"] is True
-        assert out["speculation_speedup"] > 0
-        assert out["spec_on"]["acceptance_rate"] is not None
-        assert out["spec_off"]["acceptance_rate"] is None
-    finally:
-        for k, v in old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v

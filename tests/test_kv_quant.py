@@ -17,8 +17,8 @@ Three layers of coverage:
   supervised reset} — token-EXACT where quantization commutes with the
   feature (same quantized bytes either way), drift-BOUNDED where it
   cannot (speculative rollback re-rounds block scales; documented in
-  docs/architecture.md), plus recorder/telemetry plumbing and the bench
-  A/B smoke. ``kv_cache_dtype=None`` stays bit-identical to the
+  docs/architecture.md), plus recorder/telemetry plumbing.
+  ``kv_cache_dtype=None`` stays bit-identical to the
   pre-quantization engine (same traced programs — regression-tested
   against a plain bf16-pool engine).
 """
@@ -414,8 +414,7 @@ class TestEngineDrift:
         """int8 KV quantization must not derail greedy output early: the
         stream matches the bf16 engine for at least the first 8 tokens
         on the tiny model (measured: all 12 match — the bar leaves
-        rounding-luck margin, and the bench's drift metric tracks the
-        production-shape number)."""
+        rounding-luck margin)."""
         for ref, got in zip(bf16_toks, int8_toks):
             assert _match_prefix(ref, got) >= 8
 
@@ -436,8 +435,7 @@ class TestEngineDrift:
     def test_int4_generates_and_packs(self, tiny_model, prompts):
         """int4 serving runs end to end with nibble-packed pools (half
         the payload bytes of int8); output quality is workload-dependent
-        at 4 bits, so only structure is asserted here — the bench A/B
-        reports its drift."""
+        at 4 bits, so only structure is asserted here."""
         eng = LLMEngine(tiny_model, **_kw(kv_cache_dtype="int4"))
         outs = _toks(eng, prompts)
         assert all(len(t) == 10 for t in outs)
@@ -566,7 +564,7 @@ class TestComposition:
 
 
 # ---------------------------------------------------------------------------
-# observability plumbing + bench smoke
+# observability plumbing
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
@@ -605,40 +603,3 @@ def test_kv_pool_effective_blocks_gauge(tiny_model, prompts):
     server.stop()
     eff = snap["gauges"]["kv_pool_effective_blocks"]
     assert eff >= 1.9 * eng.n_blocks
-
-
-@pytest.mark.slow
-def test_bench_smoke_kv_quant(monkeypatch, tmp_path):
-    """CPU dry-run of the llama_serve_kv_quant bench line: equal-byte
-    pool sizing gives the quantized arms more blocks, the drift metric
-    rides every arm, and the artifact lands. `slow` per the wall-budget
-    note above (three serve arms = three compiled engines); the tier-1
-    core keeps kernel parity + capacity + drift."""
-    import bench
-
-    # moderate oversubscription: prompts of ~2 blocks in a 6-of-8-block
-    # bf16 pool. (A pool barely larger than ONE prompt can ramp-thrash
-    # the fused scheduler — a pre-existing corner, not a quantization
-    # one; the bench arm's wall deadline turns it into a loud failure.)
-    for k, v in {"BENCH_BATCH": "2", "BENCH_REQUESTS": "3",
-                 "BENCH_NEW_TOKENS": "4", "BENCH_LAYERS": "1",
-                 "BENCH_HIDDEN": "64", "BENCH_FF": "128",
-                 "BENCH_CHUNK": "16", "BENCH_BLOCK": "8",
-                 "BENCH_PROMPT": "16", "BENCH_POOL_FRAC": "0.75",
-                 "BENCH_ARTIFACT_DIR": str(tmp_path)}.items():
-        monkeypatch.setenv(k, v)
-    out = bench._bench_other("llama_serve_kv_quant")
-    assert out["metric"] == "llama_serve_kv_quant_tokens_per_sec"
-    assert out["value"] > 0
-    # equal-byte sizing caps at the full (never-preempts) demand
-    full = out["full_blocks"]
-    bf16_blocks = out["bf16"]["pool_blocks"]
-    assert out["int8"]["pool_blocks"] >= min(full, int(1.9 * bf16_blocks))
-    assert out["int4"]["pool_blocks"] >= min(full, int(3.5 * bf16_blocks))
-    assert out["int8"]["pool_bytes"] <= out["bf16"]["pool_bytes"]
-    assert out["int4"]["pool_bytes"] <= out["bf16"]["pool_bytes"]
-    for arm in ("int8", "int4"):
-        d = out[arm]["drift_vs_bf16"]
-        assert 0 <= d["min_match_prefix"] <= 4
-        assert "first_divergence_step" in d
-    assert (tmp_path / "llama_serve_kv_quant.json").exists()

@@ -22,7 +22,7 @@ The acceptance bars from the ISSUE:
   spill → promote path serves them instead of recomputing.
 
 Engine-heavy cases ride the ``slow`` lane per the tier-1 wall-budget
-policy (int4, the chaos kill, the TP-mesh export, the bench smoke).
+policy (int4, the chaos kill, the TP-mesh export).
 """
 import numpy as np
 import pytest
@@ -455,7 +455,7 @@ def test_pull_on_miss_fetches_peer_prefix(tiny_model, prompt,
 
 
 # ---------------------------------------------------------------------------
-# chaos / TP / bench (engine-heavy: slow lane)
+# chaos / TP (engine-heavy: slow lane)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
@@ -520,27 +520,3 @@ def test_tp_mesh_export_import_and_spill(tiny_model, prompt, tp_mesh):
     assert tpe.stats["kv_promote_blocks"] >= 1
     tpe._check_pool_invariants()
     dst._check_pool_invariants()
-
-
-@pytest.mark.slow
-def test_bench_smoke_disagg(monkeypatch, tmp_path):
-    """CPU dry-run of the llama_serve_disagg bench line: token parity
-    across arms, migrated requests pay zero re-prefill, and the ship
-    traffic rides the output."""
-    import bench
-
-    for k, v in {"BENCH_BATCH": "2", "BENCH_REQUESTS": "6",
-                 "BENCH_NEW_TOKENS": "12", "BENCH_LAYERS": "1",
-                 "BENCH_HIDDEN": "64", "BENCH_FF": "128",
-                 "BENCH_CHUNK": "16", "BENCH_BLOCK": "8",
-                 "BENCH_PROMPT": "24",
-                 "BENCH_ARTIFACT_DIR": str(tmp_path)}.items():
-        monkeypatch.setenv(k, v)
-    out = bench._bench_other("llama_serve_disagg")
-    assert out["metric"] == "llama_serve_disagg_decode_p99_ms"
-    assert out["value"] > 0
-    assert out["token_parity"] is True
-    assert out["disagg"]["kv_shipped"] >= 1
-    assert out["disagg"]["ship_bytes"] > 0
-    assert out["disagg"]["decode_reprefill_tokens"] == 0
-    assert out["mixed"]["tokens_per_sec"] > 0

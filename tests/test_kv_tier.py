@@ -20,9 +20,8 @@ The acceptance bars from the ISSUE:
   swap-resident requests on hung-replica failover.
 
 Engine-heavy cases ride the ``slow`` lane per the tier-1 wall-budget
-policy (int4 round-trip, restart chaos, hung-replica failover, the
-bench smoke); the tier-1 core keeps the swap/spill/livelock
-correctness bars with engines shared as hard as the seeding allows.
+policy (int4 round-trip, restart chaos, hung-replica failover); the
+tier-1 core keeps the swap/spill/livelock correctness bars with engines shared as hard as the seeding allows.
 """
 import time
 
@@ -186,7 +185,7 @@ def test_swap_resident_window_and_entry_cleanup(tiny_model, prompts):
 
 
 # ---------------------------------------------------------------------------
-# ramp-livelock regression (ROADMAP item 1 / PR-12 bench finding)
+# ramp-livelock regression (PR 12's finding)
 # ---------------------------------------------------------------------------
 
 def test_ramp_livelock_shape_completes(tiny_model):
@@ -408,32 +407,3 @@ def test_router_counts_swap_resident_failover(tiny_model, prompts):
         assert router.stats["swap_resident_failover"] >= 1
     finally:
         router.stop(timeout=120)
-
-
-@pytest.mark.slow
-def test_bench_smoke_kv_tier(monkeypatch, tmp_path):
-    """CPU dry-run of the llama_serve_kv_tier bench line: equal
-    device-pool bytes both arms, token parity, and the re-prefill
-    reduction metric rides the output."""
-    import bench
-
-    # prompts of ~3 blocks + 2 blocks of decode growth over a pool that
-    # holds both residents' prompts but NOT their growth: decode-phase
-    # preemption is guaranteed (the tier's conversion target), while
-    # the admission-defer guarantee keeps the ramps themselves clean
-    for k, v in {"BENCH_BATCH": "2", "BENCH_REQUESTS": "4",
-                 "BENCH_NEW_TOKENS": "16", "BENCH_LAYERS": "1",
-                 "BENCH_HIDDEN": "64", "BENCH_FF": "128",
-                 "BENCH_CHUNK": "16", "BENCH_BLOCK": "8",
-                 "BENCH_PROMPT": "24", "BENCH_POOL_FRAC": "0.5",
-                 "BENCH_ARTIFACT_DIR": str(tmp_path)}.items():
-        monkeypatch.setenv(k, v)
-    out = bench._bench_other("llama_serve_kv_tier")
-    assert out["metric"] == "llama_serve_kv_tier_tokens_per_sec"
-    assert out["value"] > 0
-    assert out["tier_on"]["pool_blocks"] == out["tier_off"]["pool_blocks"]
-    assert out["token_parity"] is True
-    assert out["tier_on"]["preemptions"] >= 1   # pressure was real
-    assert out["reprefill_tokens_off"] > 0
-    assert out["reprefill_tokens_on"] <= out["reprefill_tokens_off"]
-    assert 0.0 <= out["tier_on"]["swap_stall_share"] <= 1.0

@@ -132,14 +132,14 @@ class TestEngineLifecycle:
         with pytest.raises(ValueError):
             eng.add_request(rng.integers(1, 96, size=(20,)), 4)
 
-    def test_throughput_stats(self, tiny_model):
+    def test_token_count_stats(self, tiny_model):
         rng = np.random.default_rng(8)
         eng = LLMEngine(tiny_model, max_batch=2, max_seq_len=32,
                         chunk_size=8)
         eng.generate([rng.integers(1, 96, size=(4,)).astype(np.int32)],
                      max_new_tokens=4)
         assert eng.stats["tokens_generated"] == 4
-        assert eng.throughput() > 0
+        assert eng.stats["prefill_tokens"] == 4
 
 
 def test_engine_with_quantized_weights(tiny_model):

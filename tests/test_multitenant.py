@@ -607,7 +607,7 @@ def test_explain_tail_adapter_swap_cause():
 
 
 # ---------------------------------------------------------------------------
-# heavies: multi-tenant soak + 8-adapter bench smoke (slow)
+# heavies: multi-tenant soak (slow)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
@@ -656,33 +656,3 @@ def test_multitenant_soak_churn(store, prompts):
                 (ref,) = ref_eng.generate([p], max_new_tokens=4)
                 assert out.token_ids == ref.token_ids, (wave, aid)
     assert eng.stats["adapter_swaps"] > 4
-
-
-@pytest.mark.slow
-def test_bench_lora_and_embed_smoke(monkeypatch):
-    """The 8-adapter bench rung + the mixed embed rung run end-to-end on
-    a CPU-sized config and emit driver-format dicts with parity."""
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..",
-                              "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    env = {"BENCH_HIDDEN": "64", "BENCH_FF": "128", "BENCH_LAYERS": "2",
-           "BENCH_BATCH": "2", "BENCH_NEW_TOKENS": "6",
-           "BENCH_REQUESTS": "4", "BENCH_CHUNK": "16", "BENCH_BLOCK": "8",
-           "BENCH_PROMPT": "10", "BENCH_EMBED": "2",
-           "BENCH_EMBED_LEN": "12", "BENCH_ADAPTERS": "8",
-           "BENCH_ADAPTER_SLOTS": "4", "BENCH_RANK": "4",
-           "BENCH_PARITY_ADAPTERS": "1"}
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
-    out = bench._bench_other("llama_serve_lora")
-    assert out["metric"] == "llama_serve_lora_tokens_per_sec"
-    assert out["token_parity_vs_merged"] is True
-    assert out["adapter_mix"]["adapter_swaps"] > 0
-    out = bench._bench_other("llama_serve_embed")
-    assert out["metric"] == "llama_serve_embed_mixed_tokens_per_sec"
-    assert out["token_parity"] is True
-    assert out["embeds_per_sec"] > 0

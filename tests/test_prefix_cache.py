@@ -8,8 +8,8 @@ shared/unshared workloads on both schedulers, live cross-slot sharing +
 refcounts, COW tails, LRU eviction under pool pressure, preemption
 interplay, the pool-invariant audit under churn (admit/cancel/preempt/
 finish, dense and paged), allocation-order determinism, request-id reuse
-with a hit in flight, recorder/telemetry integration, and the bench A/B
-smoke. The conftest sets PADDLE_TPU_POOL_CHECKS=1, so every engine here
+with a hit in flight, and recorder/telemetry integration. The conftest
+sets PADDLE_TPU_POOL_CHECKS=1, so every engine here
 audits free + cached + live-refcounted == n_blocks after each alloc/free.
 
 CPU-wall discipline: program compilation dominates, so the model is ONE
@@ -465,29 +465,3 @@ class TestObservability:
         (expl,) = rec.explain_tail(0.5)
         assert expl["cause"] == "interfering_prefill"
         assert expl["cold_miss"] is True
-
-
-@pytest.mark.slow
-def test_bench_smoke_prefix_cache(monkeypatch, tmp_path):
-    """CPU dry-run of the llama_serve_prefix_cache bench line (satellite:
-    the A/B rides the non-slow path so schema regressions surface in
-    tier-1): hit-rate > 0 on the shared arm, token parity across arms,
-    and the zero-reuse overhead guard fields present."""
-    import bench
-
-    for k, v in {"BENCH_BATCH": "2", "BENCH_REQUESTS": "3",
-                 "BENCH_NEW_TOKENS": "3", "BENCH_LAYERS": "1",
-                 "BENCH_HIDDEN": "64", "BENCH_FF": "128",
-                 "BENCH_CHUNK": "16", "BENCH_BLOCK": "8",
-                 "BENCH_HORIZON": "2", "BENCH_SYS_PROMPT": "24",
-                 "BENCH_TAIL": "8",
-                 "BENCH_ARTIFACT_DIR": str(tmp_path)}.items():
-        monkeypatch.setenv(k, v)
-    out = bench._bench_other("llama_serve_prefix_cache")
-    assert out["metric"] == "llama_serve_prefix_cache_tokens_per_sec"
-    assert out["value"] > 0
-    assert out["token_parity"] is True
-    assert out["cache_on"]["hit_rate"] > 0
-    assert out["cache_off"]["hit_rate"] == 0.0
-    assert "zero_reuse_overhead_pct" in out
-    assert (tmp_path / "llama_serve_prefix_cache.json").exists()

@@ -171,57 +171,3 @@ def test_merge_profile_from_dir(tmp_path):
     out = merge_profile([str(d)], str(tmp_path / "m.json"))
     merged = json.load(open(out))["traceEvents"]
     assert len([e for e in merged if e.get("ph") == "X"]) == 2
-
-
-def test_device_trace_parser_dedupes_step_markers():
-    """Regression for the round-5 double-count: the device lane of
-    an XLA trace carries OVERLAPPING span families — 'jit_*' module
-    spans (true device step time), bare-number "Steps"-track markers
-    covering the same wall time, and the per-op spans nested inside.
-    Naively summing every device span double-counts step time; the
-    shared parser must route each family exactly once (modules -> the
-    total, ops -> the per-op table, step markers -> NEITHER)."""
-    from paddle_tpu.profiler import summarize_device_trace
-
-    events = [
-        {"ph": "M", "pid": 3, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": 9, "name": "process_name",
-         "args": {"name": "python host"}},
-        # two module spans (the true step time: 100 + 80 us)
-        {"ph": "X", "pid": 3, "tid": 0, "name": "jit_step(1)",
-         "ts": 0, "dur": 100.0},
-        {"ph": "X", "pid": 3, "tid": 0, "name": "jit_step(1)",
-         "ts": 200, "dur": 80.0},
-        # "Steps" track: bare-number markers OVERLAPPING the modules —
-        # counting these on top of the modules is the double-count
-        {"ph": "X", "pid": 3, "tid": 7, "name": "4", "ts": 0,
-         "dur": 100.0},
-        {"ph": "X", "pid": 3, "tid": 7, "name": "5", "ts": 200,
-         "dur": 80.0},
-        # per-op spans nested inside the modules
-        {"ph": "X", "pid": 3, "tid": 0, "name": "fusion.3", "ts": 10,
-         "dur": 60.0},
-        {"ph": "X", "pid": 3, "tid": 0, "name": "fusion.3", "ts": 210,
-         "dur": 40.0},
-        {"ph": "X", "pid": 3, "tid": 0, "name": "copy.1", "ts": 80,
-         "dur": 5.0},
-        # host-lane event: not a device span at all
-        {"ph": "X", "pid": 9, "tid": 0, "name": "jit_step(1)", "ts": 0,
-         "dur": 999.0},
-    ]
-    agg, module_total = summarize_device_trace(events)
-    assert module_total == 180.0          # modules only, host lane ignored
-    assert set(agg) == {"fusion.3", "copy.1"}   # no bare-number markers
-    assert agg["fusion.3"] == {"count": 2, "total_us": 100.0}
-    assert agg["copy.1"] == {"count": 1, "total_us": 5.0}
-    # the naive sum (what the double-count bug produced) is visibly
-    # bigger than the deduped step total
-    naive = sum(e["dur"] for e in events
-                if e.get("ph") == "X" and e["pid"] == 3)
-    assert naive > module_total + sum(v["total_us"] for v in agg.values())
-    # the roofline profiler consumes THIS parser (one shared copy)
-    import inspect
-    from paddle_tpu.utils import roofline
-    assert "summarize_device_trace" in inspect.getsource(
-        roofline.profile_device_events)

@@ -297,7 +297,7 @@ def test_a_steps_buffers_are_freed_before_its_results_are_routed(dense_eng):
 def test_telemetry_snapshot_schema_and_attribution(dense_eng):
     """The snapshot carries every named stage, the latency histograms,
     and an attribution that explains (nearly) all of a busy serve
-    window — the observability contract bench.py's serve line reports."""
+    window."""
     eng = _fresh(dense_eng)
     prompts = _prompts(7, (7, 12, 5, 9, 6, 10))
     server = AsyncLLMServer(eng, max_queue_size=16)
@@ -333,10 +333,8 @@ def test_telemetry_snapshot_schema_and_attribution(dense_eng):
     assert snap["latency"]["admission_stall"]["count"] >= 1
     att = snap["attribution"]
     assert 0.0 < att["attributed_share"] <= 1.0
-    # a busy window must be explained by the named stages — the round-5
-    # acceptance bar from the serving_telemetry docstring (the r05 serve
-    # bench attributed only 24%; every piece of the loop body now lands
-    # in a stage, so >= 0.9 must hold deterministically)
+    # a busy window must be explained by the named stages: every piece of
+    # the loop body lands in a stage, so >= 0.9 must hold deterministically
     assert att["attributed_share"] >= 0.9, att
     assert snap["counters"]["requests_finished"] == len(prompts)
     text = server.telemetry.prometheus_text()

@@ -1,7 +1,6 @@
 """SLO sensor layer — burn-rate math, live pathology detectors
 (synthetic fire + quiescent), gauge staleness, the server's
-slo_report, per-tenant latency histograms, fleet aggregation, and the
-llama_serve_slo bench smoke.
+slo_report, per-tenant latency histograms and fleet aggregation.
 
 The math/detector halves are PURE HOST (synthetic StepRecords, no jax
 dispatch). The serve-backed tests reuse one tiny module-scoped model
@@ -601,53 +600,3 @@ def test_router_fleet_slo_report(tiny_model):
     assert "router_outstanding" in names
     assert "router_replica_outstanding" in names
     assert "fleet" in rep["text"]
-
-
-# tier-1 wall budget (PR 19): the bench smoke joins the other bench
-# smokes on the slow lane (~9s back) — the SLO machinery it drives
-# (per-tenant histograms, burn fire/clear, report schema) is covered by
-# the pure-host and tiny-serve tests above
-@pytest.mark.slow
-def test_bench_smoke_llama_serve_slo(monkeypatch, tmp_path):
-    """CPU dry-run of the llama_serve_slo bench line: report schema,
-    per-tenant p99 measured per tenant (victim != adversary), the burn
-    alert FIRES under the flood and CLEARS after, and the artifact
-    lands."""
-    import json
-
-    import bench
-
-    for k, v in {"BENCH_BATCH": "2", "BENCH_LAYERS": "1",
-                 "BENCH_HIDDEN": "64", "BENCH_FF": "128",
-                 "BENCH_CHUNK": "16", "BENCH_BLOCK": "8",
-                 "BENCH_VICTIM_PROMPT": "8",
-                 "BENCH_VICTIM_NEW_TOKENS": "3",
-                 "BENCH_FLOOD_PROMPT": "48",
-                 "BENCH_FLOOD_NEW_TOKENS": "12", "BENCH_FLOOD": "6",
-                 "BENCH_WARM": "3", "BENCH_VICTIM_INTERVAL_S": "0.02",
-                 "BENCH_SLO_WINDOW_S": "2.0",
-                 "BENCH_SLO_FAST_WINDOW_S": "0.5",
-                 "BENCH_SLO_BURN": "2.0",
-                 "BENCH_ARTIFACT_DIR": str(tmp_path)}.items():
-        monkeypatch.setenv(k, v)
-    out = bench._bench_other("llama_serve_slo")
-    assert out["metric"] == "llama_serve_slo_victim_ttft_p99_ms"
-    for key in ("victim_ttft_p99_ms", "adversary_ttft_p99_ms",
-                "target_ms", "burn_alert_fired", "burn_alert_cleared",
-                "peak_burn_rate_fast", "pathologies_active"):
-        assert key in out, key
-    assert out["burn_alert_fired"] is True
-    assert out["burn_alert_cleared"] is True
-    assert out["victim_ttft_p99_ms"] > out["target_ms"]
-    art = json.load(open(tmp_path / "slo_report.json"))
-    for key in ("slo", "report", "burn_alerts", "trajectory", "config"):
-        assert key in art, key
-    assert art["slo"]["metric"] == "ttft_p99" and art["slo"]["tenant"] == 0
-    assert any(p["burning"] for p in art["trajectory"])
-    assert art["trajectory"][-1]["burning"] is False
-    (r,) = art["report"]["slos"]
-    assert r["slo"] == "victim_ttft"
-    # flood-server victim requests only (calibration ran on its own
-    # server whose telemetry is separate)
-    assert art["report"]["tenant_latency"]["0"]["ttft"]["count"] \
-        == out["victim_requests"] >= 1
