@@ -23,6 +23,19 @@ Design (mirrors ops/kernels/flash_attention.py idiom, adapted to paging):
 - online softmax across the block loop: fp32 (m, l, acc) VMEM scratch
   carried over the innermost grid dimension, initialized at block 0,
   finalized (acc / l) into the output at the last block step.
+- what is f32 and what is not (``_mxu_dtype``, one rule for both
+  kernels): every matmul accumulates in f32, and the scores, the softmax
+  scale, the mask, the running max/normalizer and the finalize are f32.
+  The matmuls' OPERANDS are converted to f32 only when they have to be:
+  operands stored in the same 16-bit float (bf16 q against a bf16 pool,
+  the chunk's K/V against the block it merges into) go to the MXU as
+  stored — every bf16 x bf16 product is exact in f32 — and the scale
+  then sits on the f32 scores, ``(q . k) * scale``: 1/sqrt(D) is not a
+  power of two, so ``q * scale`` has no exact 16-bit form. An f32 q,
+  mismatched dtypes and quantized pools (dequantized to f32 in VMEM) take
+  the f32 form with the scale on q; Mosaic's default precision rounds
+  that form's operands to bf16 for one MXU pass, so the 16-bit form is
+  the exact one and costs the same.
 - GQA zero-copy: q arrives [B, Hkv, G, D] (G = q-heads per kv head); each
   (batch, kv_head) window attends its whole q-head group against one
   stream of that kv head's blocks.
@@ -69,7 +82,8 @@ with one fp32 scale per (physical block, kv head) riding in
 ``k_scale``/``v_scale`` [num_blocks, Hkv] arrays. Both kernels
 dequantize each block IN VMEM during the online-softmax walk
 (``int * scale`` right after the block DMA — HBM traffic shrinks by
-2x/4x, the f32 attention math is unchanged), and the fused write
+2x/4x, and the attention math takes the f32 form of ``_mxu_dtype``:
+this file never casts a dequantized block to 16 bits), and the fused write
 re-quantizes IN VMEM too: the written block is merged in f32, its new
 per-head absmax scale computed in-kernel, and the int payload + scale
 store back through aliased outputs — no bf16 block ever round-trips to
@@ -378,6 +392,46 @@ def _scale_spec(nb, hkv):
     return pl.BlockSpec((nb, hkv), lambda b, h, j, *refs: (Z, Z))
 
 
+def _mxu_dtype(lhs, rhs, quant):
+    """THE operand rule of this file's matmuls: the dtype both operands of
+    a product go to the MXU in. Operands that are stored in the same
+    16-bit float (bf16 ``q`` against a bf16 pool, the chunk's K/V against
+    the block it merges into) go as they are stored: every bf16 x bf16
+    product is exact in f32 and the MXU accumulates in f32, so nothing is
+    rounded on the way. Anything else is converted to f32 first (the f32
+    form): an f32 ``q``, mismatched dtypes, and a quantized pool, whose
+    block is dequantized to f32 in VMEM. What the f32 form keeps of its
+    32 bits is the compiler's to say: at default precision Mosaic rounds
+    f32 operands to bf16 and runs ONE pass (read on a v5e, PERF.md
+    section 6, PR 31: the two forms take the same time, and the f32 form
+    of a bf16 ``q * scale`` gave ``bf16(q * scale) . k``, 2^-9 off).
+    Read at trace time from what the code can observe; no option sets it.
+    The kernels and the tests call the same rule."""
+    lhs, rhs = jnp.dtype(lhs), jnp.dtype(rhs)
+    if not quant and lhs == rhs and lhs.itemsize == 2 \
+            and jnp.issubdtype(lhs, jnp.floating):
+        return lhs
+    return jnp.dtype(jnp.float32)
+
+
+def _scores(q, k, scale, dt):
+    """f32 scaled scores ``[n, bs]`` of query rows ``q`` [n, D] against a
+    block ``k`` [bs, D] that its caller already holds in ``dt`` =
+    :func:`_mxu_dtype` of the two. In the f32 form the scale goes on
+    ``q``, as it always did. In the 16-bit form it goes on the f32
+    scores: ``1/sqrt(D)`` is not a power of two at most head sizes, so
+    ``q * scale`` has no exact 16-bit form (and the f32 form's is rounded
+    to one on its way into the MXU), where ``(q . k) * scale`` is the
+    exact product to f32 rounding."""
+    nt = (((1,), (1,)), ((), ()))
+    if dt == jnp.float32:
+        return jax.lax.dot_general(
+            q.astype(jnp.float32) * np.float32(scale), k, nt,
+            preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(
+        q, k, nt, preferred_element_type=jnp.float32) * np.float32(scale)
+
+
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
                    bs, mb, nb, write_new, quant=None, d_head=None):
     if quant:
@@ -421,7 +475,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
     v_blk = v_ref[0, 0]
     if quant:
         # in-VMEM dequant right after the (2x/4x smaller) block DMA: the
-        # attention math below is the plain f32 path
+        # attention math below takes the f32 form (``_mxu_dtype``)
         phys_r = jnp.maximum(phys, Z)
         k_blk = kv_unpack(k_blk, quant, d_head) * _scale_read(ks_ref,
                                                               phys_r, h)
@@ -482,10 +536,8 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
 
     @pl.when(live)
     def _attend():
-        q = q_ref[0, 0].astype(jnp.float32) * np.float32(scale)    # [G, D]
-        s = jax.lax.dot_general(q, k_blk.astype(jnp.float32),
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [G, bs]
+        dt = _mxu_dtype(q_ref.dtype, k_blk.dtype, quant)
+        s = _scores(q_ref[0, 0], k_blk.astype(dt), scale, dt)     # [G, bs]
         pos = jj * bs_i + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
         s = jnp.where(pos <= L, s, NEG_INF)          # include new token at L
         m_prev = m_ref[...]
@@ -521,7 +573,10 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
     write into the kernel — returns (out, k_pool, v_pool) with the pools
     updated in place (aliased). Without them the caller must have already
     scattered the new token into the pools; returns out only.
-    Out: [B, Hq, D] in q.dtype (fp32 accumulation inside).
+    Out: [B, Hq, D] in q.dtype. Inside, accumulation, scores, scale and
+    softmax are f32; ``QK^T`` takes q and the block as stored when both
+    are the same 16-bit float (:func:`_mxu_dtype`), with the scale on
+    the f32 scores, and converts both to f32 (scale on q) otherwise.
 
     ``quant="int8"|"int4"`` + ``k_scale``/``v_scale`` [num_blocks, Hkv]
     fp32: the pools are QUANTIZED storage (int4 nibble-packed on D, so
@@ -625,15 +680,30 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
 # append attention: q_len = chunk (the fused prefill+decode mixed step)
 # ---------------------------------------------------------------------------
 
-#: most query rows in one row tile. A (tile, block, head) update costs
-#: ~0.35 us before its rows cost anything on a v5e (the K block is the
-#: MXU's stationary operand and an f32 product loads it several times), so
-#: tiles want to be tall; past 256 rows the tile's f32 scores and
-#: accumulator spill and a full chunk runs slower than untiled (PERF.md
-#: section 6, PR 26: 128 / 256 / 512 rows = 8.1 / 5.4 / 7.1 ms a call)
+#: most query rows in one row tile. An update (one tile against one block
+#: for one kv head) is a chain of two small matmuls with a softmax between
+#: them, whose latency a v5e pays whatever the rows, so tiles want to be
+#: tall. One chain at a time, 256 ran a full chunk fastest (PERF.md
+#: section 6, PR 26: 128 / 256 / 512 rows = 8.1 / 5.4 / 7.1 ms a call of
+#: eight chunks). With the heads interleaved (``_HEADS_INTERLEAVED``) it
+#: is 4.57 / 4.05 / 3.28 (PR 31), and 512 is left to the change that
+#: restates ``append_tile_steps``' counters, which count these tiles
 _ROW_TILE_MAX = 256
 #: rows a tile runs when its live rows end inside them (see _row_subtile)
 _ROW_SUBTILE = 32
+#: kv heads whose updates of one row tile share a basic block. The heads'
+#: chains (QK^T -> max -> exp -> P.V) are independent: one after the other
+#: with constant head indices and no loop between them (a ``fori_loop``
+#: unrolled whole), the scheduler overlaps their latencies and spreads
+#: their matmuls over the core's four MXUs. A loop over the heads ran
+#: them one at a time: eight decode rows 1.13 -> 0.51 ms a call, eight
+#: chunks 5.38 -> 4.05 at the same tile (PERF.md section 6, PR 31; 4 heads
+#: a block: 0.66). A step that serves more heads than this runs them in
+#: groups of it. Unrolled by the loop and not in Python, so that the body
+#: is traced once: the kernel's lowering is Python time in every
+#: process's set-up, compile cache or not (1.9 s against 0.3 in
+#: ``doc_batch``'s).
+_HEADS_INTERLEAVED = 8
 #: VMEM the append call plans its per-step buffers into (a v5e core has
 #: 128 MiB; the compiler's own default limit for a kernel is 16 MiB and
 #: the call raises it to what it planned, see ``_append_vmem_bytes``)
@@ -861,7 +931,7 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         # outputs. Only window blocks pay it.
         row = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 0)
         ci = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 1)
-        sel_f = (((jj * bs_i + row - L) == ci) & (ci < QL)).astype(f32)
+        sel = ((jj * bs_i + row - L) == ci) & (ci < QL)
         # block row r takes a chunk row iff its chunk index lands in
         # [0, q_lens) — index math, not a bool reduction over ``sel``
         # (Mosaic has no i1 reduce)
@@ -869,9 +939,12 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         has_new = (idx >= Z) & (idx < QL)
 
         def merged(blk, new_ref, h):
+            # 0 and 1 are exact in every float, and each output is one
+            # stored value times 1: the operand rule changes no bit here
+            dt = _mxu_dtype(new_ref.dtype, blk.dtype, quant)
             m = jax.lax.dot_general(
-                sel_f, new_ref[0, h].astype(f32), (((1,), (0,)), ((), ())),
-                preferred_element_type=f32)
+                sel.astype(dt), new_ref[0, h].astype(dt),
+                (((1,), (0,)), ((), ())), preferred_element_type=f32)
             return jnp.where(has_new, m.astype(blk.dtype), blk)
 
         def head(h):
@@ -913,25 +986,31 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         this step's block, read from ``kr``/``vr``. ``masked``: the block
         reaches into the chunk, so the causal mask applies; a block of
         the pooled history is visible to every live row."""
-        def head(h):
-            k_blk, v_blk = kr[0, h], vr[0, h]
-            if quant:
-                k_blk = dequant(k_blk, kso_ref, h)
-                v_blk = dequant(v_blk, vso_ref, h)
-            k_f = k_blk.astype(f32)
+        def update(r0, n):
+            """One row tile's ``n`` rows against the block, for this
+            step's ``hb`` kv heads: ``hu`` = ``_HEADS_INTERLEAVED`` of
+            them (or the largest divisor of ``hb`` under it) in one basic
+            block, by a loop that is unrolled whole (its body is traced
+            once, its head indices are constants), the groups by a loop
+            that is not."""
+            rows = pl.ds(r0, n)
+            if masked:
+                # row r (chunk index r // g) sees kv position p iff
+                # (p - lens) * g <= r — no vector division
+                rel = jj * bs_i - L + jax.lax.broadcasted_iota(
+                    jnp.int32, (n, bs), 1)
+                r = r0 + jax.lax.broadcasted_iota(jnp.int32, (n, bs), 0)
+                seen = rel * np.int32(g) <= r
 
-            def update(r0, n):
-                rows = pl.ds(r0, n)
-                q = q_ref[0, h, rows, :].astype(f32) * np.float32(scale)
-                s = jax.lax.dot_general(q, k_f, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=f32)
+            def head(_, h):
+                k_blk, v_blk = kr[0, h], vr[0, h]
+                if quant:
+                    k_blk = dequant(k_blk, kso_ref, h)
+                    v_blk = dequant(v_blk, vso_ref, h)
+                dt = _mxu_dtype(q_ref.dtype, k_blk.dtype, quant)
+                s = _scores(q_ref[0, h, rows, :], k_blk.astype(dt), scale, dt)
                 if masked:
-                    # row r (chunk index r // g) sees kv position p iff
-                    # (p - lens) * g <= r — no vector division
-                    rel = jj * bs_i - L + jax.lax.broadcasted_iota(
-                        jnp.int32, (n, bs), 1)
-                    r = r0 + jax.lax.broadcasted_iota(jnp.int32, (n, bs), 0)
-                    s = jnp.where(rel * np.int32(g) <= r, s, NEG_INF)
+                    s = jnp.where(seen, s, NEG_INF)
                 m_prev = m_ref[h, rows, :]
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
                 p = jnp.exp(s - m_new)
@@ -943,19 +1022,29 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
                         p.astype(v_blk.dtype), v_blk,
                         (((1,), (0,)), ((), ())), preferred_element_type=f32)
                 m_ref[h, rows, :] = m_new
+                return h + np.int32(1)
 
-            def tile(r0):
-                if ts == tr:
-                    return update(r0, tr)
-                # a tile whose live rows end inside its first ``ts`` (a
-                # decode row's, a verify window's, a chunk's tail) runs
-                # those alone; its other rows keep their initial state
-                # and finalize to zeros
-                short = QL * np.int32(g) - r0 <= np.int32(ts)
-                pl.when(short)(lambda: update(r0, ts))
-                pl.when(jnp.logical_not(short))(lambda: update(r0, tr))
-            tiles(t_lo, t_end, tile)
-        heads(head)
+            hu = max(u for u in range(1, _HEADS_INTERLEAVED + 1)
+                     if hb % u == 0)
+
+            def group(_, h0):
+                return jax.lax.fori_loop(0, hu, head, h0, unroll=True)
+            if hu == hb:
+                group(0, Z)
+            else:
+                jax.lax.fori_loop(0, hb // hu, group, Z)
+
+        def tile(r0):
+            if ts == tr:
+                return update(r0, tr)
+            # a tile whose live rows end inside its first ``ts`` (a
+            # decode row's, a verify window's, a chunk's tail) runs
+            # those alone; its other rows keep their initial state
+            # and finalize to zeros
+            short = QL * np.int32(g) - r0 <= np.int32(ts)
+            pl.when(short)(lambda: update(r0, ts))
+            pl.when(jnp.logical_not(short))(lambda: update(r0, tr))
+        tiles(t_lo, t_end, tile)
 
     # a window block is read where the merge just stored it (the aliased
     # out buffers: merged, and for quantized pools round-tripped)
@@ -1018,11 +1107,23 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
     :func:`_row_subtile` rows computes those alone: a decode row (q_lens
     1) costs one such short tile a block, a full chunk every tile. Rows
     of tiles never run come back as zeros. One grid step serves every kv head of a table entry
-    (:func:`_heads_per_step`: as many as fit VMEM), its heads in one DMA;
+    (:func:`_heads_per_step`: as many as fit VMEM), its heads in one DMA,
+    and runs a row tile's update for ``_HEADS_INTERLEAVED`` of them in one
+    basic block, so that their independent chains overlap;
     entries past the window's last block re-map to it (no copy) and do no
     vector work; the merge of the chunk into a block runs for window
     blocks only; an idle slot (q_lens 0) carries its boundary block
     through unchanged and walks nothing.
+
+    **What is f32**: every matmul's accumulation, the scores, the scale,
+    the mask, ``m``/``l``/``acc`` and the finalize. **What is not**: the
+    operands of ``QK^T`` and of the merge's one-hot selection when q, the
+    pools and (cast on the way in) the chunk's K/V are stored in the same
+    16-bit float — they go to the MXU as stored, exact
+    (:func:`_mxu_dtype`), and the scale multiplies the f32 scores, since
+    ``q * scale`` has no exact 16-bit form. ``P`` is cast to V's dtype
+    for ``P.V`` as it always was. An f32 q, mismatched dtypes or
+    ``quant`` take the f32 form (operands converted, scale on q).
 
     Returns (out [B, S, Hq, D] in q.dtype, k_pool, v_pool).
 

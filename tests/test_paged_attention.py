@@ -254,6 +254,19 @@ def test_append_parity_block_boundaries_and_gqa(group, rng):
     _assert_append_parity(q, kc, vc, tables, lens, qlens, kn, vn)
 
 
+def test_append_more_heads_than_one_interleaved_group(rng):
+    """12 kv heads a grid step: a row tile's update runs them in two
+    groups of 6 (``_HEADS_INTERLEAVED`` = 8 does not divide 12), the
+    groups by a loop, the heads of a group by a loop unrolled whole."""
+    from paddle_tpu.ops.kernels import paged_attention as pa
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, [16, 17, 7, 3], [8, 1, 5, 0], Hq=12, Hkv=12)
+    G, S, D, BS = 1, 8, 32, 8
+    assert pa._heads_per_step(12, G, S, D, BS, D, 4, 4, 4) == 12
+    assert pa._HEADS_INTERLEAVED == 8
+    _assert_append_parity(q, kc, vc, tables, lens, qlens, kn, vn)
+
+
 def test_append_first_chunk_from_empty(rng):
     """lens == 0 (first prefill chunk of a fresh slot) including a full
     chunk that exactly fills a block."""
@@ -387,6 +400,173 @@ def test_append_tile_steps_is_the_brute_force_count(G, S, BS, rng):
                         run += 1
         assert append_tile_steps(lens, qlens, G, S, BS, MB) == \
             (run, B * MB * n_tiles)
+
+
+# ---------------------------------------------------------------------------
+# the operand rule: stored 16-bit operands go to the MXU as they are stored
+# ---------------------------------------------------------------------------
+
+def _bf16_ulp(x):
+    """Spacing of bfloat16 (8 significant bits) at the magnitude of x."""
+    x = np.maximum(np.abs(np.asarray(x, np.float32)), np.float32(2.0 ** -126))
+    return np.exp2(np.floor(np.log2(x)) - 7).astype(np.float32)
+
+
+@pytest.fixture()
+def f32_form(monkeypatch):
+    """Call it to pin ``_mxu_dtype`` to float32: the kernels then run
+    the form they had before the rule (every operand converted, the scale
+    on ``q``) on whatever they are fed. ``_append_call`` is jitted and
+    does not see a patched global: its cache is dropped at each change."""
+    from paddle_tpu.ops.kernels import paged_attention as pa
+
+    def pin():
+        monkeypatch.setattr(pa, "_mxu_dtype",
+                            lambda *_: jnp.dtype(jnp.float32))
+        pa._append_call.clear_cache()
+    yield pin
+    monkeypatch.undo()
+    pa._append_call.clear_cache()
+
+
+# name -> (kernel, lens, q_lens); S = 16 positions x 4 q heads a kv head =
+# 64 rows = one row tile, the 32-row short path under it
+_STORED_16BIT = {
+    "append_chunk_over_history": ("append", [40, 19], [16, 9]),
+    "append_chunk_from_empty": ("append", [0, 0], [16, 5]),
+    "append_decode_rows": ("append", [33, 7, 64], [1, 1, 1]),
+    "append_verify_window_of_4": ("append", [33, 64, 18], [4, 4, 3]),
+    "append_idle_slot": ("append", [12, 25], [0, 7]),
+    "decode_kernel": ("decode", [16, 17, 7, 3], None),
+}
+
+
+@pytest.mark.parametrize("name", list(_STORED_16BIT))
+def test_bf16_operands_give_what_the_f32_product_gave(name, rng, f32_form):
+    """bf16 ``q``, pools and new K/V through the 16-bit form (``QK^T`` and
+    the merge on the arrays as stored, the scale on the f32 scores)
+    against (1) the f32 form of the same kernel on the same bf16 arrays,
+    which is what the kernel computed before the rule: equal to f32
+    rounding, so the bf16 outputs differ by at most one of their own ulps,
+    and only where that rounding crosses a bf16 boundary; and (2) the same
+    values fed as f32 arrays: there ``P`` meets ``V`` unrounded where the
+    bf16 pools' ``P`` is rounded to bf16 as it always was (at most 2^-9 of
+    the largest |V|), and the output is not rounded, so the two agree
+    within one bf16 ulp at the scale of the values attended. The pools
+    come back bit-equal in all three."""
+    import ml_dtypes
+    kernel, lens, qlens = _STORED_16BIT[name]
+    if kernel == "append":
+        q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+            rng, lens, qlens, Hq=8, Hkv=2, S=16)
+        live = [(b, slice(0, int(n))) for b, n in enumerate(qlens) if n]
+    else:
+        q, kc, vc, tables, lens, kn, vn = _case(rng, lens, Hq=8, Hkv=2)
+        live = [(b, slice(None)) for b in range(len(lens))]
+
+    def run(dt):
+        """The kernel on the case's values rounded to bf16, held as dt."""
+        q_, kc_, vc_, kn_, vn_ = (
+            jnp.asarray(x.astype(ml_dtypes.bfloat16).astype(dt))
+            for x in (q, kc, vc, kn, vn))
+        if kernel == "append":
+            return paged_attention_append(
+                q_, kc_, vc_, jnp.asarray(tables), jnp.asarray(lens),
+                jnp.asarray(qlens), kn_, vn_)
+        return paged_attention_decode(
+            q_, kc_, vc_, jnp.asarray(tables), jnp.asarray(lens),
+            new_k=kn_, new_v=vn_)
+
+    got = [np.asarray(x, np.float32) for x in run(ml_dtypes.bfloat16)]
+    fed_f32 = [np.asarray(x) for x in run(np.float32)]
+    f32_form()
+    before = [np.asarray(x, np.float32) for x in run(ml_dtypes.bfloat16)]
+
+    v_scale = _bf16_ulp(max(np.abs(fed_f32[2]).max(), np.abs(vn).max()))
+    for b, rows in live:
+        o, o_before, o_f32 = (x[0][b, rows] for x in (got, before, fed_f32))
+        assert np.all(np.abs(o - o_before) <= _bf16_ulp(o_before))
+        assert np.mean(o != o_before) < 0.01
+        assert np.all(np.abs(o - o_f32) <= v_scale)
+    for other in (before, fed_f32):
+        np.testing.assert_array_equal(got[1], other[1])
+        np.testing.assert_array_equal(got[2], other[2])
+
+
+def _kernel_dot_operands(fn, *args):
+    """``(lhs dtype, rhs dtype)`` of every ``dot_general`` in the program
+    ``fn`` traces to, the Pallas kernel's body included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                found.append(tuple(v.aval.dtype for v in eqn.invars))
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (list, tuple)) \
+                        else (param,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+_BF, _F16, _F32 = jnp.bfloat16, jnp.float16, jnp.float32
+
+
+@pytest.mark.parametrize("lhs,rhs,quant,want", [
+    (_BF, _BF, None, _BF),         # the serving cells: stored bf16
+    (_F16, _F16, None, _F16),
+    (_F32, _BF, None, _F32),       # f32 q (the CPU parity tests)
+    (_BF, _F32, None, _F32),
+    (_BF, _F16, None, _F32),       # mismatched 16-bit floats
+    (_F32, _F32, None, _F32),
+    (_BF, _BF, "int8", _F32),      # a dequantized block is f32
+    (_BF, jnp.int8, "int8", _F32),
+    (_BF, jnp.int8, "int4", _F32),
+    (jnp.int16, jnp.int16, None, _F32),
+])
+def test_mxu_operand_rule(lhs, rhs, quant, want):
+    from paddle_tpu.ops.kernels.paged_attention import _mxu_dtype
+    assert _mxu_dtype(lhs, rhs, quant) == jnp.dtype(want)
+
+
+@pytest.mark.parametrize("q_dt,pool_dt,quant,want", [
+    # QK^T, the merge and P.V of the window and history walks, all on
+    # the stored arrays
+    (_BF, _BF, None, {(_BF, _BF)}),
+    # f32 q: the f32 product as before; the chunk's K/V is cast to the
+    # pool's dtype on its way in, as before, so the merge's operands are
+    # stored bf16, and P was always cast to V's dtype
+    (_F32, _BF, None, {(_F32, _F32), (_BF, _BF)}),
+    (_F32, _F32, None, {(_F32, _F32)}),
+    # a quantized pool: nothing dequantized reaches the MXU in 16 bits
+    (_BF, jnp.int8, "int8", {(_F32, _F32)}),
+])
+def test_kernels_take_the_form_the_rule_names(q_dt, pool_dt, quant, want):
+    """The form a program takes, read from the traced kernels: the dtypes
+    the append and the decode kernel hand their matmuls."""
+    B, S, Hq, Hkv, D, NB, BS, MB = 2, 16, 8, 2, 32, 6, 8, 4
+    pool = jnp.zeros((NB, Hkv, BS, D), pool_dt)
+    scales = dict(k_scale=jnp.ones((NB, Hkv), _F32),
+                  v_scale=jnp.ones((NB, Hkv), _F32),
+                  quant=quant) if quant else {}
+    tables = jnp.zeros((B, MB), jnp.int32)
+    lens = jnp.zeros((B,), jnp.int32)
+    want = {tuple(jnp.dtype(d) for d in pair) for pair in want}
+    dots = _kernel_dot_operands(
+        lambda q, k, v, nk, nv: paged_attention_append(
+            q, k, v, tables, lens, lens + 1, nk, nv, **scales),
+        jnp.zeros((B, S, Hq, D), q_dt), pool, pool,
+        jnp.zeros((B, S, Hkv, D), q_dt), jnp.zeros((B, S, Hkv, D), q_dt))
+    assert set(dots) == want and len(dots) >= 6
+    dots = _kernel_dot_operands(
+        lambda q, k, v, nk, nv: paged_attention_decode(
+            q, k, v, tables, lens, new_k=nk, new_v=nv, **scales),
+        jnp.zeros((B, Hq, D), q_dt), pool, pool,
+        jnp.zeros((B, Hkv, D), q_dt), jnp.zeros((B, Hkv, D), q_dt))
+    assert set(dots) == want and len(dots) == 2     # QK^T and P.V
 
 
 @pytest.mark.slow
