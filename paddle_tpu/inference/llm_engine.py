@@ -848,18 +848,24 @@ class LLMEngine:
     # ------------------------------------------------------------------
     def _refuse_for_layout(self, **opt):
         """A layout with a layer that is not paged K/V (a paged latent
-        pool, a recurrent state a slot) is served by the fused scheduler
-        over the paged allocator, with ``readout_stride``, pipelining and
-        pool oversubscription (a preempted request replays from its first
-        token into zeroed state, as paged KV does). Every option whose
-        code assumes "a slot's state is a list of K/V blocks" raises
-        here, naming its mechanism, instead of serving a wrong token."""
+        pool, a recurrent state a slot; a layout of latent pools alone
+        too) is served by the fused scheduler over the paged allocator,
+        with ``readout_stride``, pipelining and pool oversubscription (a
+        preempted request replays from its first token, as paged KV
+        does). Every option whose code assumes "a slot's state is a list
+        of K/V blocks" raises here, naming its mechanism, instead of
+        serving a wrong token. Where the reason differs, the first is a
+        recurrent layer's (its state is in no block) and the second a
+        latent-only layout's (its state IS a list of blocks, of ONE pool
+        a layer, which that option's code does not read yet)."""
         kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
+        recurrent = self._has_recurrent
 
-        def refuse(option, why):
+        def refuse(option, why, latent_only=None):
             raise ValueError(
                 f"{option} cannot serve a model whose cache layout has "
-                f"{kinds} layers: {why}")
+                f"{kinds} layers: "
+                f"{why if recurrent or latent_only is None else latent_only}")
         if opt["scheduler"] != "fused":
             refuse("scheduler='legacy'",
                    "legacy admission prefills a whole prompt through "
@@ -881,18 +887,30 @@ class LLMEngine:
                    "a cached block holds its tokens' K/V, but a recurrent "
                    "layer's state after a shared prefix is in no block: a "
                    "hit would skip the rows that build it (prefix hashing "
-                   "assumes state is a list of blocks)")
+                   "assumes state is a list of blocks)",
+                   "the content store adopts, copies and spills a block "
+                   "as a (K, V) pair of pools a layer; a latent layer has "
+                   "one pool and no V, and that path is not written for "
+                   "it (ROADMAP Queue 2)")
         if opt["kv_host_swap"] or opt["kv_host_spill_bytes"]:
             refuse("kv_host_swap / kv_host_spill_bytes",
                    "swap and spill copy a slot's list of pool blocks; its "
                    "recurrent state and convolution tail are not blocks "
                    "and would be lost (a preempted request replays from "
-                   "its first token instead)")
+                   "its first token instead)",
+                   "swap and spill gather a slot's blocks out of a (K, V) "
+                   "pair of pools a layer; a latent layer has one pool "
+                   "and no V, and that path is not written for it (a "
+                   "preempted request replays from its first token "
+                   "instead; ROADMAP Queue 2)")
         if int(opt["speculative_k"] or 1) > 1:
             refuse("speculative_k > 1",
                    "a rejected draft rolls the slot's length back over "
                    "rows already computed; a recurrent state that has "
-                   "absorbed them cannot be rolled back")
+                   "absorbed them cannot be rolled back",
+                   "the verify grants are wired through PagedKVCache "
+                   "alone; a latent pool's rejected rows could be rolled "
+                   "back by its block table, but that path is not written")
         if opt["kv_cache_dtype"] is not None:
             refuse("kv_cache_dtype",
                    "pool quantization keeps one scale per (block, kv "
@@ -913,12 +931,12 @@ class LLMEngine:
 
     def _refuse_kv_shipping(self, what):
         if not self._kv_only:
+            kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
             raise ValueError(
                 f"{what} ships a request's list of K/V blocks; a cache "
-                f"layout with "
-                f"{sorted({k.kind for k in self._layout} - {'paged_kv'})} "
-                f"layers keeps state that is not in blocks (a recurrent "
-                f"state a slot), so it cannot be exported or imported")
+                f"layout with {kinds} layers keeps state that is not in "
+                f"blocks of K and V (a recurrent state a slot, one latent "
+                f"pool a layer), so it cannot be exported or imported")
 
     def _make_zeros(self, shape, dtype, spec=None):
         if self._mesh is not None:

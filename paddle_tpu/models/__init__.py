@@ -11,3 +11,5 @@ from .unet import (  # noqa: F401
 )
 from .kimi_linear import (  # noqa: F401
     KimiLinearConfig, KimiLinearForCausalLM)
+from .deepseek_v2 import (  # noqa: F401
+    DeepseekV2Config, DeepseekV2ForCausalLM)

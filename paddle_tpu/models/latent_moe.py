@@ -1,0 +1,346 @@
+"""The layers that the latent-attention, sparse-expert decoder families
+share (``models/kimi_linear.py``, ``models/deepseek_v2.py``): multi-head
+latent attention served in its absorbed form over a paged latent pool, a
+SwiGLU feed-forward, the held share of a layer's routed experts with its
+router and shared expert, the pre-norm block around them, and the decoder
+and causal LM that run such blocks on per-layer state
+(``models/cache_layout.py``). What a family does differently is a
+constructor argument resolved in Python, so each family traces only the
+operations of its own equations; nothing here asks which family it serves.
+
+Latent attention, H heads: ``q_h = [q_nope_h; q_pe_h]`` from ``W_q x``, or
+through a compressed query ``W_qb RMSNorm(W_qa x)`` (``q_rank``); ``[c;
+k_pe] = W_kva x``, ``c <- RMSNorm(c)``; with ``rotary``, ``q_pe_h`` and
+the one shared ``k_pe`` are rotated by the row's position in float32; a
+token's cache entry is ``(c, k_pe)``, ROTATED, so the pool is all the
+attention ever reads and the kernel knows no positions; ``[k_nope_h; v_h]
+= W_kvb,h c``; causal softmax of ``q_h . [k_nope_h; k_pe] * scale``.
+Absorbed: ``q_nope`` goes through ``W_kvb``'s key half into the latent's
+width, the attention runs against the pool with the latent itself as
+values (``ops/kernels/latent_attention.py``), ``W_kvb``'s value half
+after. A row's position is ``RowMap.pos`` in a mixed step's packed form
+and ``seq_lens + i`` in the per-slot forms (the one-token step is S = 1).
+
+Experts: ``ops/kernels/moe_dropless.py``; a layer holds experts
+``[offset, offset + held)`` of ``published`` and routes over all of them.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
+from ..nn.initializer import Constant, Normal
+from ..core.tensor import Tensor, dispatch
+from ..ops.kernels import latent_attention as _lat
+from ..ops.kernels import moe_dropless as _moe
+from . import cache_layout as CL
+
+F32 = jnp.float32
+
+
+def mm(x, w):
+    """bf16 (or whatever the weights are) in, float32 accumulate, cast
+    back: the MXU's native product."""
+    return jnp.matmul(x, w, preferred_element_type=F32).astype(x.dtype)
+
+
+def mm32(x, w):
+    return jnp.matmul(x, w, preferred_element_type=F32)
+
+
+def rms(x, w, eps):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * \
+        w.astype(F32)
+
+
+def live_rows(q_lens, s):
+    return jnp.arange(s, dtype=jnp.int32)[None, :] < \
+        q_lens.astype(jnp.int32)[:, None]
+
+
+def _linear(i, o):
+    return Linear(i, o, bias_attr=False)
+
+
+class LatentAttention(Layer):
+    """``q_rank``: the compressed query's width (None: one projection).
+    ``rotary(x, pos)``: rotates the last axis of float32 ``x`` by the
+    positions ``pos`` of its leading axes (None: nothing is rotated).
+    ``softmax_scale``: None is ``(nope + pe)^-1/2``."""
+
+    def __init__(self, hidden, heads, kv_rank, nope, pe, v_dim, eps,
+                 q_rank=None, rotary=None, softmax_scale=None):
+        super().__init__()
+        self.H, self.r, self.dn, self.dp, self.dv = heads, kv_rank, nope, \
+            pe, v_dim
+        self.eps, self.rotary = eps, rotary
+        self.scale = float(softmax_scale if softmax_scale is not None
+                           else (nope + pe) ** -0.5)
+        if q_rank is None:
+            self.q_proj = _linear(hidden, heads * (nope + pe))
+        else:
+            self.q_a_proj = _linear(hidden, q_rank)
+            self.q_a_layernorm = RMSNorm(q_rank, eps)
+            self.q_b_proj = _linear(q_rank, heads * (nope + pe))
+        self.kv_a_proj = _linear(hidden, kv_rank + pe)
+        self.kv_a_layernorm = RMSNorm(kv_rank, eps)
+        self.kv_b_proj = _linear(kv_rank, heads * (nope + v_dim))
+        self.o_proj = _linear(heads * v_dim, hidden)
+
+    @property
+    def width(self):
+        """Values a token costs in the pool: the latent and the shared
+        key part."""
+        return self.r + self.dp
+
+    def _query_leaves(self):
+        if hasattr(self, "q_proj"):
+            return (self.q_proj.weight,)
+        return (self.q_a_proj.weight, self.q_a_layernorm.weight,
+                self.q_b_proj.weight)
+
+    def forward(self, x, cache):
+        H, r, dn, dp, dv, eps = self.H, self.r, self.dn, self.dp, self.dv, \
+            self.eps
+        rotary, scale = self.rotary, self.scale
+        rows = CL.packed(cache)
+
+        def fn(x, pool, tables, lens, q_lens, wq, wkva, nw, wkvb, wo):
+            # projections on x's own rows ([B, S], or a mixed step's
+            # packed [1, T]); the per-slot view around the pool only
+            lead = x.shape[:2]
+            if len(wq) == 1:
+                q = mm(x, wq[0])
+            else:
+                q = mm(rms(mm(x, wq[0]), wq[1], eps).astype(x.dtype),
+                        wq[2])
+            q = q.reshape(lead + (H, dn + dp))
+            kv = mm(x, wkva)
+            c = rms(kv[..., :r], nw, eps).astype(x.dtype)
+            k_pe = kv[..., r:]
+            if rotary is not None:
+                pos = rows.pos[None] if rows is not None else (
+                    lens.astype(jnp.int32)[:, None]
+                    + jnp.arange(lead[1], dtype=jnp.int32)[None, :])
+                k_pe = rotary(k_pe, pos).astype(x.dtype)
+            entry = jnp.concatenate([c, k_pe], -1)
+            wkvb = wkvb.reshape(r, H, dn + dv)
+            # absorbed: q_nope through the key half into the latent's width
+            q_abs = jnp.einsum("bshn,chn->bshc", q[..., :dn], wkvb[..., :dn],
+                               preferred_element_type=F32)
+            q_pe = q[..., dn:].astype(F32)
+            if rotary is not None:
+                q_pe = rotary(q_pe, pos)
+            qc = (jnp.concatenate([q_abs, q_pe], -1) *
+                  jnp.float32(scale)).astype(x.dtype)
+            if rows is not None:
+                entry, qc = rows.to_slots(entry[0]), rows.to_slots(qc[0])
+            pool = _lat.latent_pool_write(pool, entry, tables, lens, q_lens)
+            o = _lat.latent_attention_append(
+                qc, pool, tables, lens, q_lens, r)
+            if rows is not None:
+                o = rows.from_slots(o)[None]
+            o = jnp.einsum("bshc,chv->bshv", o, wkvb[..., dn:],
+                           preferred_element_type=F32).astype(x.dtype)
+            return mm(o.reshape(lead + (H * dv,)), wo), pool
+
+        out, pool = dispatch(
+            fn, (x, cache.pool, cache.block_tables, cache.seq_lens,
+                 cache.q_lens, self._query_leaves(), self.kv_a_proj.weight,
+                 self.kv_a_layernorm.weight, self.kv_b_proj.weight,
+                 self.o_proj.weight), {}, name="latent_attention")
+        return out, CL.LatentPagedCache(pool, cache.block_tables,
+                                        cache.seq_lens, cache.q_lens,
+                                        cache.row_budget, rows)
+
+
+def swiglu(x, wg, wu, wd):
+    h = (jax.nn.silu(mm32(x, wg)) * mm32(x, wu)).astype(x.dtype)
+    return mm(h, wd)
+
+
+class SwiGLU(Layer):
+    def __init__(self, hidden, width):
+        super().__init__()
+        self.gate_proj = _linear(hidden, width)
+        self.up_proj = _linear(hidden, width)
+        self.down_proj = _linear(width, hidden)
+
+    def forward(self, x, cache=None):
+        return dispatch(swiglu, (x, self.gate_proj.weight,
+                                  self.up_proj.weight,
+                                  self.down_proj.weight), {}, name="swiglu")
+
+
+class Experts(Layer):
+    """The held experts' weights, stacked: one leaf a projection."""
+
+    def __init__(self, held, hidden, width):
+        super().__init__()
+        init = Normal(0.0, 0.02)
+        self.gate_proj = self.create_parameter((held, hidden, width),
+                                               default_initializer=init)
+        self.up_proj = self.create_parameter((held, hidden, width),
+                                             default_initializer=init)
+        self.down_proj = self.create_parameter((held, width, hidden),
+                                               default_initializer=init)
+
+
+class Router(Layer):
+    def __init__(self, hidden, published, selection_bias):
+        super().__init__()
+        self.weight = self.create_parameter(
+            (hidden, published), default_initializer=Normal(0.0, 0.02))
+        if selection_bias:
+            self.e_score_correction_bias = self.create_parameter(
+                (published,), default_initializer=Constant(0.0))
+
+
+class SparseMoE(Layer):
+    """``held`` experts from ``offset`` of the ``published`` the router
+    scores, ``top_k`` a row, beside one shared expert of ``shared_width``
+    that every row takes. ``scoring``, ``selection_bias``, ``n_group`` /
+    ``topk_group`` and ``renormalize``: :func:`moe_dropless.route`."""
+
+    def __init__(self, hidden, width, held, published, offset, top_k,
+                 scale, shared_width, scoring="sigmoid", selection_bias=True,
+                 n_group=1, topk_group=1, renormalize=True):
+        super().__init__()
+        self.held, self.offset, self.top_k, self.scale = held, offset, \
+            top_k, scale
+        self.routing = dict(renormalize=renormalize, scoring=scoring,
+                            n_group=n_group, topk_group=topk_group)
+        self.gate = Router(hidden, published, selection_bias)
+        self.experts = Experts(held, hidden, width)
+        self.shared_experts = SwiGLU(hidden, shared_width)
+
+    def forward(self, x, cache=None):
+        k, held, offset, scale, routing = self.top_k, self.held, \
+            self.offset, self.scale, self.routing
+        budget = getattr(cache, "row_budget", None)
+        q_lens = getattr(cache, "q_lens", None)
+        rmap = CL.packed(cache)
+
+        def fn(x, q_lens, wr, bias, wg, wu, wd, sg, su, sd):
+            b, s, h = x.shape
+            n = b * s
+            if rmap is not None:
+                # a mixed step's packed rows: the first sum(q_lens) hold
+                # a token
+                live = rmap.live
+            elif q_lens is None:
+                live = jnp.ones((n,), bool)
+            else:
+                live = live_rows(q_lens, s).reshape(n)
+            xf = x.reshape(n, h)
+            idx, w = _moe.route(xf, wr, bias, k, scale, **routing)
+            rows = (budget or n) * min(k, held)
+            y, counts = _moe.held_expert_ffn(
+                xf, idx, w, live, wg, wu, wd, offset, rows)
+            out = swiglu(xf, sg, su, sd).astype(F32) + y
+            return out.astype(x.dtype).reshape(b, s, h), counts
+
+        sh = self.shared_experts
+        out, counts = dispatch(
+            fn, (x, q_lens, self.gate.weight,
+                 getattr(self.gate, "e_score_correction_bias", None),
+                 self.experts.gate_proj, self.experts.up_proj,
+                 self.experts.down_proj, sh.gate_proj.weight,
+                 sh.up_proj.weight, sh.down_proj.weight), {},
+            name="sparse_moe")
+        CL.count(counts._value if isinstance(counts, Tensor) else counts)
+        return out
+
+
+class DecoderBlock(Layer):
+    """Pre-norm: ``x + attn(norm(x))``, then ``x + mlp(norm(x))``. The
+    attention returns its layer's new cache object."""
+
+    def __init__(self, self_attn, mlp, hidden, eps):
+        super().__init__()
+        self.self_attn, self.mlp = self_attn, mlp
+        self.input_layernorm = RMSNorm(hidden, eps)
+        self.post_attention_layernorm = RMSNorm(hidden, eps)
+
+    def forward(self, x, cache):
+        a, new_cache = self.self_attn(self.input_layernorm(x), cache)
+        x = x + a
+        x = x + self.mlp(self.post_attention_layernorm(x), cache)
+        return x, new_cache
+
+
+class StateDecoder(Layer):
+    """Embedding, the blocks, the final norm, on one cache object a layer
+    (``cache_layout``). The positions ride on the caches (``seq_lens``,
+    ``RowMap.pos``), so ``position_offset`` is not read."""
+
+    def __init__(self, config, blocks):
+        super().__init__()
+        self.config = config
+        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size)
+        self.layers = LayerList(blocks)
+        self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
+
+    def forward(self, input_ids, attn_mask=None, kv_caches=None,
+                position_offset=0):
+        if kv_caches is None:
+            raise ValueError(
+                f"{type(self).__name__} runs on per-layer state: call the "
+                f"causal LM (it builds a one-call state) or pass kv_caches")
+        x = self.embed_tokens(input_ids)
+        new_caches = []
+        for layer, cache in zip(self.layers, kv_caches):
+            x, c = layer(x, cache)
+            new_caches.append(c)
+        return self.norm(x), new_caches
+
+
+class StateCausalLM(Layer):
+    """A :class:`StateDecoder` (``self.model``) under an untied head, as :class:`paddle_tpu.inference.LLMEngine` serves it:
+    ``decoder``, ``cache_layout()`` (the family's), ``_logits``. A plain
+    ``model(ids)`` builds a one-call state. Serving only: the backward of
+    latent attention is not written (ROADMAP Queue 2)."""
+    #: device-side counts of a step (``cache_layout.count``), booked into
+    #: ``engine.stats`` under these names
+    step_counter_names = _moe.COUNTERS
+
+    def __init__(self, config, decoder):
+        super().__init__()
+        self.config = config
+        self.model = decoder
+        self.lm_head = _linear(config.hidden_size, config.vocab_size)
+
+    @property
+    def decoder(self):
+        return self.model
+
+    def _logits(self, hidden):
+        return self.lm_head(hidden)
+
+    def fresh_caches(self, batch, seq, block_size=64):
+        """Per-layer state for ONE call over ``seq`` new positions from
+        position 0 (the plain forward's; the engine builds its own)."""
+        mb = -(-seq // block_size)
+        tables = jnp.arange(batch * mb, dtype=jnp.int32).reshape(batch, mb)
+        lens = jnp.zeros((batch,), jnp.int32)
+        q_lens = jnp.full((batch,), seq, jnp.int32)
+        dt = self.model.embed_tokens.weight.dtype
+        zeros = lambda shape, dtype: jnp.zeros(shape, dtype)  # noqa: E731
+        out = []
+        for kind in self.cache_layout():
+            a, b = kind.alloc(zeros, batch * mb, block_size, batch, dt)
+            out.append(kind.cache(a, b, tables, lens, q_lens, None, None))
+        return out
+
+    def forward(self, input_ids, labels=None, attn_mask=None):
+        if labels is not None:
+            raise NotImplementedError(
+                f"training {type(self).__name__} needs the backward of "
+                f"its attention layers, which is not written (ROADMAP "
+                f"Queue 2)")
+        b, s = input_ids.shape[0], input_ids.shape[1]
+        hidden, _ = self.model(input_ids,
+                               kv_caches=self.fresh_caches(b, s))
+        return self._logits(hidden)

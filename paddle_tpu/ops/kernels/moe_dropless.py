@@ -1,8 +1,10 @@
 """Dropless routing over the experts a chip holds.
 
-The layer routes every live row over ALL the published experts (sigmoid
-scores, a selection bias, the ``top_k`` largest, renormalised and scaled)
-and computes the part of the result that its OWN experts give: experts
+The layer routes every live row over ALL the published experts
+(:func:`route`: sigmoid scores with a selection bias and renormalised
+weights, or softmax scores as they are; optionally limited to the best
+groups of experts; the ``top_k`` largest, scaled) and computes the part of
+the result that its OWN experts give: experts
 ``[offset, offset + E)`` of the published count. Every assignment that
 lands on a held expert is computed: the assignments are sorted by expert
 and go through one grouped product a projection (``jax.lax.ragged_dot``:
@@ -13,8 +15,11 @@ added is left out, and no code stands in for the chips that hold them
 exchange; no model of the benchmark uses it).
 
 ``rows`` is the static height of the grouped product: the caller's bound
-on held assignments (live rows x min(top_k, E)). The counts that leave
-with the result say what was asked and what was computed.
+on held assignments (live rows x min(top_k, E)), rounded up here to a
+multiple of 8: XLA:TPU takes its grouped-matmul kernel only for such a
+height, and else multiplies every row by every expert (read in the
+compiled HLO; ``benchmark/tests/test_aot_deepseek_v2.py`` holds it). The
+counts that leave with the result say what was asked and what was computed.
 """
 from __future__ import annotations
 
@@ -24,17 +29,33 @@ import jax.numpy as jnp
 HI = jax.lax.Precision.HIGHEST
 #: what :func:`held_expert_ffn` counts, in order
 COUNTERS = ("moe_assignments", "moe_assignments_held", "moe_rows_computed",
-            "moe_expert_peak", "moe_assignments_dropped")
+            "moe_expert_peak", "moe_assignments_dropped", "moe_rows_held")
 
 
-def route(x, w_router, bias, top_k, scale, renormalize=True):
-    """x: [N, h]; w_router: [h, E_all]; bias: [E_all], used to SELECT only.
-    Scores and selection in float32. Returns (idx [N, k] int32, weights
+def route(x, w_router, bias, top_k, scale, renormalize=True,
+          scoring="sigmoid", n_group=1, topk_group=1):
+    """x: [N, h]; w_router: [h, E_all]; bias: [E_all], used to SELECT only,
+    or None. Scores (``scoring``: "sigmoid", or "softmax" over the
+    experts) and selection in float32. With ``n_group`` > 1 the experts
+    are ``n_group`` runs of consecutive ids; a group scores what its best
+    expert scores, the ``topk_group`` best groups keep their experts and
+    the others' selection scores are 0 before the ``top_k`` are taken.
+    The weights are the selected experts' scores, divided by their sum if
+    ``renormalize``, times ``scale``. Returns (idx [N, k] int32, weights
     [N, k] float32)."""
-    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32),
-                                  w_router.astype(jnp.float32),
-                                  precision=HI))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    logits = jnp.matmul(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=HI)
+    s = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    sel = s if bias is None else s + bias.astype(jnp.float32)
+    if n_group > 1:
+        n, e = sel.shape
+        best = jnp.max(sel.reshape(n, n_group, e // n_group), axis=-1)
+        _, keep = jax.lax.top_k(best, topk_group)
+        kept = jnp.any(keep[:, :, None] == jnp.arange(
+            n_group, dtype=keep.dtype), axis=1)
+        sel = jnp.where(jnp.repeat(kept, e // n_group, axis=1), sel, 0.0)
+    _, idx = jax.lax.top_k(sel, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -44,11 +65,13 @@ def route(x, w_router, bias, top_k, scale, renormalize=True):
 def held_expert_ffn(x, idx, w, live, w_gate, w_up, w_down, offset, rows):
     """x: [N, h]; idx, w: [N, k] from :func:`route`; live: [N] bool;
     w_gate, w_up: [E, h, f]; w_down: [E, f, h], the held experts ``offset
-    .. offset + E - 1``. Returns (y [N, h] float32, counts [5] int32 in
-    :data:`COUNTERS` order)."""
+    .. offset + E - 1``. Returns (y [N, h] float32, counts int32 in
+    :data:`COUNTERS` order; ``moe_rows_held`` is the live rows with at
+    least one assignment here: the rows an exchange would bring this
+    chip)."""
     n, k = idx.shape
     e = w_gate.shape[0]
-    rows = int(min(rows, n * k))
+    rows = int(min(-(-rows // 8) * 8, n * k))
     local = idx - jnp.int32(offset)
     held = live[:, None] & (local >= 0) & (local < e)
     key = jnp.where(held, local, e).reshape(-1)           # e sorts last
@@ -71,5 +94,6 @@ def held_expert_ffn(x, idx, w, live, w_gate, w_up, w_down, offset, rows):
     counts = jnp.stack([
         jnp.sum(live).astype(jnp.int32) * k, n_held,
         jnp.int32(rows), jnp.max(sizes),
-        jnp.maximum(n_held - rows, 0)]).astype(jnp.int32)
+        jnp.maximum(n_held - rows, 0),
+        jnp.sum(jnp.any(held, axis=1))]).astype(jnp.int32)
     return y, counts
