@@ -1206,13 +1206,19 @@ class LLMEngine:
         (one grid step for all of its kv heads); an entry is live when it
         holds a token once the dispatch has landed (host lens mirror, so
         call this after the mirrors grew). Layers multiply both counts
-        alike, and so do heads in the decode kernel."""
-        bs = self.block_size
-        live = sum(-(-s.sched_len() // bs)
+        alike, and so do heads in the decode kernel. A latent pool's
+        kernel walks its table in wide entries (``entries_per_step`` of
+        them a grid step, asked of the kernel module): both counts are in
+        its grid steps, one live when it holds a live entry."""
+        bs, n = self.block_size, 1
+        if any(k.kind == "paged_latent" for k in self._layout):
+            from ..ops.kernels.latent_attention import entries_per_step
+            n = entries_per_step(self._tables.shape[1], bs)
+        live = sum(-(-s.sched_len() // (bs * n))
                    for s in self.slots if s is not None)
-        self.stats["kv_grid_blocks"] += iterations * self._tables.size
-        self.stats["kv_live_blocks"] += iterations * min(
-            live, self._tables.size)
+        grid = self._tables.size // n
+        self.stats["kv_grid_blocks"] += iterations * grid
+        self.stats["kv_live_blocks"] += iterations * min(live, grid)
 
     def _program(self, name, fn):
         """``fn`` (a jitted program) with its builds booked. A build is

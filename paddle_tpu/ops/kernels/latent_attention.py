@@ -21,9 +21,11 @@ q_lens)`` scalar-prefetched, a slot's rows POSITION-major so the live ones
 are a prefix, only the row tiles that a table entry's block is not wholly
 masked for are computed (the same :func:`_tile_span` rule), a decode row
 runs one short tile a block, an idle slot walks nothing. A grid step is
-one table entry of one slot for ``hq`` of the heads: the block is read
-once for all of them. On a CPU the dense fallback gathers the slot's
-context (tests only; :func:`latent_attention_enabled`).
+one WIDE entry of one slot's table for ``hq`` of the heads: ``n``
+consecutive table entries (:func:`entries_per_step`) whose blocks make one
+key tile of ``n * block_size`` latents, read once for all of those heads
+and attended in one update a row tile. On a CPU the dense fallback gathers
+the slot's context (tests only; :func:`latent_attention_enabled`).
 """
 from __future__ import annotations
 
@@ -39,10 +41,15 @@ from . import paged_attention as _pa
 from .paged_attention import (NEG_INF, Z, _apd_blk, _apd_walk, _div_i32,
                               _tile_span)
 
-#: rows of one query row tile at most; VMEM a grid step may hold
+#: rows of one query row tile at most; latents of one key tile at most;
+#: VMEM a grid step may hold
 _ROW_TILE_MAX = 512
 _ROW_SUBTILE = 32
+_KEY_TILE_MAX = 256
 _VMEM_BUDGET = 40 << 20
+#: what a ``-1`` table entry inside a wide entry adds to its columns'
+#: positions: past every row's, and times a head group still an int32
+_DEAD_ENTRY = np.int32(1 << 20)
 
 
 def _interpret():
@@ -108,20 +115,35 @@ def _row_tile(hq, s):
     return rows
 
 
-def _vmem_bytes(hq, s, d, dv, bs, isz):
-    """VMEM of one grid step with ``hq`` heads: the q and out tiles and the
-    pool block (double-buffered), the f32 accumulator and the running max
-    and norm (one lane wide, padded to 128)."""
+def entries_per_step(mb, bs):
+    """Table entries one grid step holds (``n``): the most, halving from
+    a key tile of ``_KEY_TILE_MAX`` latents, that divide a table of ``mb``
+    entries; 1 is a grid step a table entry. The kernel's wrapper and the
+    engine's booking of the walk's grid steps both ask this."""
+    n = max(_KEY_TILE_MAX // bs, 1)
+    while mb % n:
+        n //= 2
+    return n
+
+
+def _vmem_bytes(hq, s, d, dv, kt, isz):
+    """VMEM of one grid step with ``hq`` heads against a key tile of
+    ``kt`` latents: the q and out tiles and the tile's blocks
+    (double-buffered), the tile itself, a row tile's f32 scores, the f32
+    accumulator and the running max and norm (one lane wide, padded to
+    128)."""
     rows = hq * s
-    return (2 * rows * d * isz + 2 * rows * dv * isz + 2 * bs * d * isz
-            + rows * (dv + 2 * 128) * 4)
+    return (2 * rows * d * isz + 2 * rows * dv * isz + 3 * kt * d * isz
+            + _row_tile(hq, s) * kt * 4 + rows * (dv + 2 * 128) * 4)
 
 
 def heads_per_step(h, s, d, dv, bs, isz=2):
     """Query heads one grid step serves: the most (a divisor of ``h``)
-    whose buffers fit ``_VMEM_BUDGET``."""
+    whose buffers fit ``_VMEM_BUDGET`` beside the widest key tile a table
+    of ``bs``-latent blocks may get."""
+    kt = max(_KEY_TILE_MAX, bs)
     for hq in range(h, 1, -1):
-        if h % hq == 0 and _vmem_bytes(hq, s, d, dv, bs, isz) <= _VMEM_BUDGET:
+        if h % hq == 0 and _vmem_bytes(hq, s, d, dv, kt, isz) <= _VMEM_BUDGET:
             return hq
     return 1
 
@@ -130,28 +152,37 @@ def _q_index_map(b, h, j, tables_ref, lens_ref, qlens_ref):
     return (b, h, Z, Z)
 
 
-def _pool_index_map(bs, mb):
+def _pool_index_map(bs, mb, n, i):
     def im(b, h, j, tables_ref, lens_ref, qlens_ref):
-        # the append kernel's walk: entries past the window's last block
-        # re-map to it (no copy); an idle slot stays on one block
-        jj = _apd_walk(lens_ref, qlens_ref, b, j, bs, mb)
+        # block ``i`` of wide entry ``j``, on the append kernel's walk:
+        # entries past the window's last block re-map to it (no copy); an
+        # idle slot stays on one block
+        jj = _apd_walk(lens_ref, qlens_ref, b, j * np.int32(n) + np.int32(i),
+                       bs, mb)
         return (jnp.maximum(tables_ref[b, jj], Z), Z, Z)
     return im
 
 
-def _kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, o_ref, m_ref,
-            l_ref, acc_ref, *, bs, mb, s_chunk, g, tr, ts, dv):
+def _kernel(tables_ref, lens_ref, qlens_ref, q_ref, *rest, bs, mb, n,
+            s_chunk, g, tr, ts, dv):
+    k_refs, (o_ref, m_ref, l_ref, acc_ref) = rest[:n], rest[n:]
     f32 = jnp.float32
     b = pl.program_id(0)
     j = pl.program_id(2)
-    bs_i, tr_i = np.int32(bs), np.int32(tr)
+    kt = n * bs                               # latents of the key tile
+    kt_i, tr_i = np.int32(kt), np.int32(tr)
     L = lens_ref[b]
     QL = jnp.minimum(qlens_ref[b], np.int32(s_chunk))
     j_last = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
-    jj = _apd_walk(lens_ref, qlens_ref, b, j, bs, mb)
-    phys = tables_ref[b, jj]
-    live = (j <= j_last) & (phys >= Z) & (QL > Z)
-    t_lo, t_end = _tile_span(L, QL, jj, g, bs, tr, jnp, _div_i32)
+    # the wide entry's ``n`` table entries; positions come from the
+    # UNCLAMPED index, so one past the window's last block (its operand
+    # re-read that block) lies past every row and the causal rule masks it
+    ents = [j * np.int32(n) + np.int32(i) for i in range(n)]
+    held = [tables_ref[b, _apd_walk(lens_ref, qlens_ref, b, e, bs, mb)] >= Z
+            for e in ents]
+    live = (ents[0] <= j_last) & functools.reduce(jnp.logical_or, held) \
+        & (QL > Z)
+    t_lo, t_end = _tile_span(L, QL, j, g, kt, tr, jnp, _div_i32)
 
     def tiles(lo, hi, fn):
         def body(t, c):
@@ -169,20 +200,28 @@ def _kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, o_ref, m_ref,
         tiles(Z, t_end, tile)
 
     def attend(masked):
-        k_blk = k_ref[0]                      # [bs, D]
-        v_blk = k_blk[:, :dv]
+        k_tile = k_refs[0][0] if n == 1 else jnp.concatenate(
+            [r[0] for r in k_refs], axis=0)   # [kt, D]
+        v_tile = k_tile[:, :dv]
+        if masked:
+            # a column's position less ``lens``; a ``-1`` entry's columns
+            # are sent past every row
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1)
+            rel = j * kt_i - L + col
+            for i in range(n):
+                dead = jnp.where(held[i], Z, _DEAD_ENTRY)
+                rel = jnp.where((col >= i * bs) & (col < (i + 1) * bs),
+                                rel + dead, rel)
 
-        def update(r0, n):
-            rows = pl.ds(r0, n)
-            s = jax.lax.dot_general(q_ref[0, 0, rows, :], k_blk,
+        def update(r0, nr):
+            rows = pl.ds(r0, nr)
+            s = jax.lax.dot_general(q_ref[0, 0, rows, :], k_tile,
                                     (((1,), (1,)), ((), ())),
                                     preferred_element_type=f32)
             if masked:
                 # row r (chunk index r // g) sees position p iff
                 # (p - lens) * g <= r
-                rel = jj * bs_i - L + jax.lax.broadcasted_iota(
-                    jnp.int32, (n, bs), 1)
-                r = r0 + jax.lax.broadcasted_iota(jnp.int32, (n, bs), 0)
+                r = r0 + jax.lax.broadcasted_iota(jnp.int32, (nr, kt), 0)
                 s = jnp.where(rel * np.int32(g) <= r, s, NEG_INF)
             m_prev = m_ref[rows, :]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -191,7 +230,7 @@ def _kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, o_ref, m_ref,
             l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
                 p, axis=1, keepdims=True)
             acc_ref[rows, :] = acc_ref[rows, :] * alpha + \
-                jax.lax.dot_general(p.astype(v_blk.dtype), v_blk,
+                jax.lax.dot_general(p.astype(v_tile.dtype), v_tile,
                                     (((1,), (0,)), ((), ())),
                                     preferred_element_type=f32)
             m_ref[rows, :] = m_new
@@ -204,19 +243,20 @@ def _kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, o_ref, m_ref,
             pl.when(jnp.logical_not(short))(lambda: update(r0, tr))
         tiles(t_lo, t_end, tile)
 
-    # a block that ends at or before ``lens`` is history: every live row
-    # sees all of it
-    in_chunk = jj * bs_i + np.int32(bs - 1) >= L
+    # a wide entry whose last latent lies before ``lens`` is history: every
+    # live row sees all of it, unless one of its table entries is ``-1``
+    needs_mask = (j * kt_i + np.int32(kt - 1) >= L) | \
+        jnp.logical_not(functools.reduce(jnp.logical_and, held))
 
-    @pl.when(live & in_chunk)
+    @pl.when(live & needs_mask)
     def _attend_window():
         attend(True)
 
-    @pl.when(live & jnp.logical_not(in_chunk))
+    @pl.when(live & jnp.logical_not(needs_mask))
     def _attend_history():
         attend(False)
 
-    @pl.when(j == np.int32(mb - 1))
+    @pl.when(j == np.int32(mb // n - 1))
     def _finalize():
         def live_tile(r0):
             rows = pl.ds(r0, tr)
@@ -254,6 +294,7 @@ def _append_call(q, pool, block_tables, seq_lens, q_lens, *, dv, interpret):
     assert D == Dk, (q.shape, pool.shape)
     MB = block_tables.shape[1]
     hq = heads_per_step(H, S, D, dv, BS, q.dtype.itemsize)
+    n = entries_per_step(MB, BS)
     HG = H // hq
     tr = _row_tile(hq, S)
     ts = _ROW_SUBTILE if tr % _ROW_SUBTILE == 0 else tr
@@ -262,15 +303,16 @@ def _append_call(q, pool, block_tables, seq_lens, q_lens, *, dv, interpret):
     q4 = jnp.transpose(q.reshape(B, S, HG, hq, D),
                        (0, 2, 1, 3, 4)).reshape(B, HG, S * hq, D)
     q4 = q4.astype(pool.dtype)
-    kernel = functools.partial(_kernel, bs=BS, mb=MB, s_chunk=S, g=hq,
+    kernel = functools.partial(_kernel, bs=BS, mb=MB, n=n, s_chunk=S, g=hq,
                                tr=tr, ts=ts, dv=dv)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, HG, MB),
-            in_specs=[pl.BlockSpec((1, 1, S * hq, D), _q_index_map),
-                      pl.BlockSpec((1, BS, D), _pool_index_map(BS, MB))],
+            grid=(B, HG, MB // n),
+            in_specs=[pl.BlockSpec((1, 1, S * hq, D), _q_index_map)] + [
+                pl.BlockSpec((1, BS, D), _pool_index_map(BS, MB, n, i))
+                for i in range(n)],
             out_specs=pl.BlockSpec((1, 1, S * hq, dv), _q_index_map),
             scratch_shapes=[
                 pltpu.VMEM((S * hq, 1), jnp.float32),
@@ -283,11 +325,11 @@ def _append_call(q, pool, block_tables, seq_lens, q_lens, *, dv, interpret):
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=max(
                 32 << 20,
-                _vmem_bytes(hq, S, D, dv, BS, q.dtype.itemsize)
+                _vmem_bytes(hq, S, D, dv, n * BS, q.dtype.itemsize)
                 + (16 << 20))),
         name="latent_attention_append",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q_lens.astype(jnp.int32), q4, pool)
+      q_lens.astype(jnp.int32), q4, *([pool] * n))
     out = out.reshape(B, HG, S, hq, dv)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, H, dv)

@@ -188,30 +188,124 @@ def _pool_case(rng, b, s, h, d, bs, mb, lens, qlens):
         jnp.asarray(qlens, jnp.int32)
 
 
-@pytest.mark.parametrize("s,lens,qlens", [
-    (32, [37, 0, 90, 5], [32, 0, 1, 20]),     # chunk, idle, decode, ramp
-    (1, [37, 64, 90, 0], [1, 1, 0, 0]),       # the one-token step
-])
-def test_latent_kernel_equals_the_gathered_form(s, lens, qlens):
+#: (block, table entries, chunk, lens, q_lens, dtype) of the kernel's
+#: cases. A table of 8 / 12 / 6 / 7 entries of 16 latents walks in wide
+#: entries of 8 / 4 / 2 / 1 (``entries_per_step``); 8 of 64 in 4. With
+#: ``n`` 4 of 16 a wide entry is 64 latents: [0, 64), [64, 128), [128, 192)
+KERNEL_CASES = {
+    "chunk_idle_decode_ramp": (16, 8, 32, [37, 0, 90, 5], [32, 0, 1, 20],
+                               "float32"),
+    "one_token": (16, 8, 1, [37, 64, 90, 0], [1, 1, 0, 0], "float32"),
+    # n 4: a context that ends inside a wide entry, a window that
+    # straddles two, an idle slot, a context that ends on an entry's edge
+    "wide4_inside_straddle_idle_edge": (16, 12, 32, [70, 50, 0, 96],
+                                        [32, 32, 0, 32], "float32"),
+    # n 4: decode rows at the first and the last latent of a wide entry,
+    # a ramp that starts on an edge, a chunk that fills the table
+    "wide4_decode_rows_on_edges": (16, 12, 32, [64, 127, 128, 160],
+                                   [1, 1, 9, 32], "float32"),
+    "wide4_one_token": (16, 12, 1, [63, 64, 128, 191], [1, 1, 1, 0],
+                        "float32"),
+    "wide2": (16, 6, 32, [37, 0, 63, 5], [32, 0, 1, 20], "float32"),
+    "wide2_one_token": (16, 6, 1, [31, 32, 95, 0], [1, 1, 1, 0], "float32"),
+    "wide1": (16, 7, 32, [37, 0, 63, 5], [32, 0, 1, 20], "float32"),
+    "wide1_one_token": (16, 7, 1, [15, 16, 111, 0], [1, 1, 1, 0],
+                        "float32"),
+    "blocks_of_64": (64, 8, 32, [250, 0, 256, 480], [32, 0, 1, 32],
+                     "float32"),
+    "blocks_of_64_one_token": (64, 8, 1, [255, 256, 511, 7], [1, 1, 1, 0],
+                               "float32"),
+    "bf16": (16, 12, 32, [70, 50, 0, 96], [32, 32, 0, 32], "bfloat16"),
+    "bf16_one_token": (16, 12, 1, [63, 64, 128, 191], [1, 1, 1, 0],
+                       "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_latent_kernel_equals_the_gathered_form(case):
     """The Pallas kernel (interpret mode here) against attention over the
-    slot's gathered context, on the rows that are live."""
+    slot's gathered context, on the rows that are live. Every table holds
+    ``-1`` past its slot's length (``_pool_case``)."""
+    bs, mb, s, lens, qlens, dtype = KERNEL_CASES[case]
     rng = np.random.default_rng(2)
-    q, pool, tables, lens, qlens = _pool_case(rng, 4, s, 4, 48, 16, 8,
+    q, pool, tables, lens, qlens = _pool_case(rng, 4, s, 4, 48, bs, mb,
                                               lens, qlens)
     new = jnp.asarray(rng.normal(size=(4, s, 48)), jnp.float32)
+    q, pool, new = (x.astype(dtype) for x in (q, pool, new))
     pool = latent_attention.latent_pool_write(pool, new, tables, lens, qlens)
     ctx = np.asarray(pool)[np.maximum(np.asarray(tables), 0)] \
         .reshape(4, -1, 48)
     for b in range(4):
         lo, n = int(lens[b]), int(qlens[b])
         np.testing.assert_array_equal(ctx[b, lo:lo + n], new[b, :n])
+        assert (np.asarray(tables)[b, -(-(lo + n) // bs):] == -1).all()
     want = latent_attention.latent_attention_dense(q, pool, tables, lens,
                                                    qlens, 32)
     got = latent_attention._append_call(q, pool, tables, lens, qlens, dv=32,
                                         interpret=True)
+    assert got.dtype == q.dtype
     live = np.arange(s)[None, :] < np.asarray(qlens)[:, None]
-    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
-                               atol=2e-5)
+    # bf16: the kernel rounds the weights ``p`` and the output once each
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
+        atol=2e-5 if dtype == "float32" else 2e-2)
+
+
+@pytest.mark.parametrize("mb", [12, 6, 7])
+def test_a_dead_table_entry_inside_a_context_masks_its_latents(mb):
+    """A ``-1`` entry below a slot's length (the scheduler never grants
+    one; a wiped table row could hold one): its latents are seen by no
+    row, in a wide entry of 4, 2 or 1, in the history and in the window."""
+    rng = np.random.default_rng(5)
+    lens, qlens = [70, 40, 17, 0], [20, 1, 32, 0]
+    q, pool, tables, lens, qlens = _pool_case(rng, 4, 32, 4, 48, 16, mb,
+                                              lens, qlens)
+    tables = np.array(tables)
+    tables[0, 1], tables[1, 2], tables[2, 1] = -1, -1, -1
+    want = np.zeros((4, 32, 4, 32))
+    ctx = np.asarray(pool, np.float64)[np.maximum(tables, 0)] \
+        .reshape(4, -1, 48)
+    seen = np.repeat(tables >= 0, 16, axis=1)
+    for b in range(3):
+        for i in range(int(qlens[b])):
+            ok = seen[b] & (np.arange(mb * 16) <= int(lens[b]) + i)
+            sc = np.asarray(q, np.float64)[b, i] @ ctx[b].T
+            p = np.exp(sc - sc[:, ok].max(axis=1, keepdims=True)) * ok
+            want[b, i] = (p / p.sum(axis=1, keepdims=True)) @ ctx[b, :, :32]
+    got = latent_attention._append_call(q, pool, jnp.asarray(tables), lens,
+                                        qlens, dv=32, interpret=True)
+    live = np.arange(32)[None, :] < np.asarray(qlens)[:, None]
+    np.testing.assert_allclose(np.asarray(got)[live], want[live], atol=2e-5)
+
+
+@pytest.mark.parametrize("mb,bs,n", [
+    (136, 64, 4), (260, 64, 4),     # the two cells' tables
+    (8, 64, 4), (6, 64, 2), (7, 64, 1), (130, 64, 2), (8, 16, 8),
+    (12, 16, 4), (16, 256, 1), (16, 512, 1),
+])
+def test_the_walks_entries_a_grid_step_come_from_the_tables_shape(mb, bs, n):
+    assert latent_attention.entries_per_step(mb, bs) == n
+    assert mb % n == 0
+    assert n * bs <= max(latent_attention._KEY_TILE_MAX, bs)
+
+
+@pytest.mark.parametrize("shape,hq", [
+    ((128, 512, 576, 512, 64), 8),      # dsv2_rag_answers' chunk step
+    ((32, 256, 576, 512, 64), 16),      # kimi_long_docs' chunk step
+    ((128, 1, 576, 512, 64), 128), ((32, 1, 576, 512, 64), 32),
+])
+def test_heads_a_grid_step_fit_vmem_with_the_key_tile_counted(shape, hq):
+    la = latent_attention
+    assert la.heads_per_step(*shape) == hq
+    h, s, d, dv, bs = shape
+    kt = la._KEY_TILE_MAX
+    with_tile = la._vmem_bytes(hq, s, d, dv, kt, 2)
+    assert with_tile <= la._VMEM_BUDGET
+    # the tile's two buffers, the tile and a row tile's f32 scores
+    assert with_tile - la._vmem_bytes(hq, s, d, dv, 0, 2) == \
+        3 * kt * d * 2 + la._row_tile(hq, s) * kt * 4
+    if hq < h:
+        assert la._vmem_bytes(2 * hq, s, d, dv, kt, 2) > la._VMEM_BUDGET
 
 
 def test_absorbed_attention_equals_the_per_head_reference():
@@ -381,6 +475,76 @@ def test_engine_serves_what_the_reference_would(case):
     assert s["moe_assignments_held"] <= s["moe_rows_computed"]
     assert s["moe_assignments_dropped"] == 0
     assert s["moe_expert_peak"] > 0 and s["kv_grid_blocks"] > 0
+
+
+def _booked(monkeypatch):
+    """Every ``_book_kv_grid`` call of the engines made from here on:
+    (iterations, the slots' lengths as booked, growth of ``kv_grid_blocks``,
+    growth of ``kv_live_blocks``)."""
+    calls, real = [], LLMEngine._book_kv_grid
+
+    def spy(self, iterations):
+        was = self.stats["kv_grid_blocks"], self.stats["kv_live_blocks"]
+        real(self, iterations)
+        calls.append((iterations,
+                      [0 if s is None else s.sched_len() for s in self.slots],
+                      self.stats["kv_grid_blocks"] - was[0],
+                      self.stats["kv_live_blocks"] - was[1]))
+    monkeypatch.setattr(LLMEngine, "_book_kv_grid", spy)
+    return calls
+
+
+@pytest.mark.parametrize("n", [4, 2, 1])
+def test_the_walks_grid_is_booked_in_the_latent_kernels_grid_steps(
+        monkeypatch, n):
+    """A latent layout books ``kv_grid_blocks`` / ``kv_live_blocks`` in the
+    grid steps of its kernel's walk, ``entries_per_step`` table entries
+    each, asked of the kernel module at each booking (4 for this table of
+    12; 2 and 1 are that function patched: the booking follows it)."""
+    assert latent_attention.entries_per_step(192 // 16, 16) == 4
+    if n != 4:
+        monkeypatch.setattr(latent_attention, "entries_per_step",
+                            lambda mb, bs: n)
+    calls = _booked(monkeypatch)
+    model, _ = build(TOY, 17)
+    rng = np.random.default_rng(9)
+    done, eng = _serve(model, {
+        0: [(rng.integers(1, 256, size=70).astype(np.int32), 9)],
+        2: [(rng.integers(1, 256, size=45).astype(np.int32), 12)]})
+    assert eng._tables.shape == (3, 12) and len(calls) > 5
+    assert any(it > 1 for it, *_ in calls) and \
+        any(it == 1 for it, *_ in calls)
+    for it, lens, grid, live in calls:
+        assert grid == it * 3 * (12 // n)
+        assert live == it * sum(-(-(-(-x // 16)) // n) for x in lens)
+    s = eng.stats
+    assert s["kv_grid_blocks"] == sum(c[2] for c in calls)
+    assert 0 < s["kv_live_blocks"] == sum(c[3] for c in calls)
+
+
+def test_a_kv_layout_books_its_walk_in_table_entries_as_before(monkeypatch):
+    """Paged K/V pools go through ``paged_attention_append``, whose grid
+    is a step a table entry: the latent kernel's rule is not asked."""
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    def boom(mb, bs):
+        raise AssertionError("a K/V layout asked the latent kernel")
+    monkeypatch.setattr(latent_attention, "entries_per_step", boom)
+    calls = _booked(monkeypatch)
+    paddle.seed(7)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=256))
+    model.eval()
+    rng = np.random.default_rng(9)
+    done, eng = _serve(model, {
+        0: [(rng.integers(1, 256, size=70).astype(np.int32), 9)],
+        2: [(rng.integers(1, 256, size=45).astype(np.int32), 12)]})
+    assert eng._tables.shape == (3, 12) and len(calls) > 5
+    for it, lens, grid, live in calls:
+        assert grid == it * 3 * 12
+        assert live == it * sum(-(-x // 16) for x in lens)
 
 
 def test_the_latent_pool_and_the_state_are_sized_by_the_layout():
