@@ -101,6 +101,17 @@ def default_engine_stats():
             # against every row tile x every entry of every slot (the
             # kernel module's own count, paged_attention.append_tile_steps)
             "attn_tile_steps": 0, "attn_tile_steps_grid": 0,
+            # per paged dispatch: pool blocks that belong to a request
+            # (neither free, nor cached, nor waiting out a write fence)
+            # and the pool's blocks, so their ratio is the share of the
+            # pool in use a step; and, booked at readout for the
+            # all-decode dispatches (the iterations that ran through the
+            # one-token decode attention): those iterations, their live
+            # rows (a slot an iteration), and the tokens the live slots
+            # held in them, the new one included
+            "pool_blocks_used": 0, "pool_blocks_total": 0,
+            "decode_ctx_tokens": 0, "decode_rows": 0,
+            "decode_iterations": 0,
             # device-side counts of an expert layer that holds a share of
             # the published experts (ops/kernels/moe_dropless.py), summed
             # over layers and steps, read beside the tokens: live rows x
@@ -337,7 +348,7 @@ class PendingStep:
     __slots__ = ("toks", "was_active", "counts", "spec", "slots",
                  "pool_done", "sched", "step_id", "fenced", "t_dispatch",
                  "embed_done", "pooled", "verify", "offered", "guarded",
-                 "rows", "ctr")
+                 "rows", "ctr", "ctx0")
 
     def __init__(self, toks, was_active, counts, spec, slots, pool_done,
                  sched=None, fenced=None, embed_done=None, verify=None):
@@ -376,6 +387,10 @@ class PendingStep:
         #: device vector of the model's step counters for this dispatch
         #: (None: the model declares none)
         self.ctr = None
+        #: a paged all-decode dispatch: [B] tokens each active slot held
+        #: when it was dispatched (0: not active), from which the readout
+        #: books ``decode_ctx_tokens`` for the iterations that ran
+        self.ctx0 = None
         self.pooled = None
         #: fused speculative dispatches: {slot: drafts granted} — the
         #: readout's acceptance accounting (EWMA + spec counters) and
@@ -539,6 +554,15 @@ class LLMEngine:
         self._kv_only = all(k.kind == "paged_kv" for k in self._layout)
         self._has_recurrent = any(k.kind == "recurrent"
                                   for k in self._layout)
+        #: every layer's state is K and V pools that the paged kernels
+        #: read, once a token or once a loop step
+        self._kv_pools = all(k.kind in ("paged_kv", "paged_kv_looped")
+                             for k in self._layout)
+        #: runs of a weight layer a token, each with K/V of its own under
+        #: the slot's ONE block id (a looped layout; else 1): what a
+        #: block, a token and a grid walk cost multiplies by it
+        self._loop_steps = max(getattr(k, "loop_steps", 1)
+                               for k in self._layout)
         #: device-side counts the model's layers make during a step; they
         #: leave the step program beside the tokens (booked at emit)
         self._step_counter_names = tuple(
@@ -847,25 +871,29 @@ class LLMEngine:
     # device state (built at __init__, REBUILT by reset())
     # ------------------------------------------------------------------
     def _refuse_for_layout(self, **opt):
-        """A layout with a layer that is not paged K/V (a paged latent
-        pool, a recurrent state a slot; a layout of latent pools alone
-        too) is served by the fused scheduler over the paged allocator,
-        with ``readout_stride``, pipelining and pool oversubscription (a
-        preempted request replays from its first token, as paged KV
-        does). Every option whose code assumes "a slot's state is a list
+        """A layout with a layer that is not plain paged K/V (a paged
+        latent pool, a recurrent state a slot, K/V kept once a loop step;
+        a layout of latent pools alone too) is served by the fused
+        scheduler over the paged allocator, with ``readout_stride``,
+        pipelining and pool oversubscription (a preempted request replays
+        from its first token, as paged KV does). Every option whose code assumes "a slot's state is a list
         of K/V blocks" raises here, naming its mechanism, instead of
         serving a wrong token. Where the reason differs, the first is a
         recurrent layer's (its state is in no block) and the second a
         latent-only layout's (its state IS a list of blocks, of ONE pool
-        a layer, which that option's code does not read yet)."""
+        a layer, which that option's code does not read yet). A looped
+        layout has ONE reason for them all (:meth:`_looped_reason`)."""
         kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
         recurrent = self._has_recurrent
 
         def refuse(option, why, latent_only=None):
+            if self._loop_steps > 1:
+                why = self._looped_reason()
+            elif not recurrent and latent_only is not None:
+                why = latent_only
             raise ValueError(
                 f"{option} cannot serve a model whose cache layout has "
-                f"{kinds} layers: "
-                f"{why if recurrent or latent_only is None else latent_only}")
+                f"{kinds} layers: {why}")
         if opt["scheduler"] != "fused":
             refuse("scheduler='legacy'",
                    "legacy admission prefills a whole prompt through "
@@ -929,9 +957,23 @@ class LLMEngine:
                    "is held per slot (experts over chips with their "
                    "exchange are not written)")
 
+    def _looped_reason(self):
+        """Why an option written for "one pool block a block id" cannot
+        serve a looped layout, whichever option it is."""
+        return (f"a looped layout keeps {self._loop_steps} runs of K/V "
+                f"under one block id (the loop step is part of a pool "
+                f"block's address, and the steps run as a loop inside the "
+                f"fused paged step programs); this option's code moves, "
+                f"copies, scales or shards ONE pool block a block id, and "
+                f"its form over all the runs is not written")
+
     def _refuse_kv_shipping(self, what):
         if not self._kv_only:
             kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
+            if self._loop_steps > 1:
+                raise ValueError(f"{what} cannot serve a model whose cache "
+                                 f"layout has {kinds} layers: "
+                                 f"{self._looped_reason()}")
             raise ValueError(
                 f"{what} ships a request's list of K/V blocks; a cache "
                 f"layout with {kinds} layers keeps state that is not in "
@@ -1209,7 +1251,9 @@ class LLMEngine:
         alike, and so do heads in the decode kernel. A latent pool's
         kernel walks its table in wide entries (``entries_per_step`` of
         them a grid step, asked of the kernel module): both counts are in
-        its grid steps, one live when it holds a live entry."""
+        its grid steps, one live when it holds a live entry. A looped
+        layout walks the table once a loop step: both counts times R.
+        Beside them, the pool's blocks in use at this dispatch."""
         bs, n = self.block_size, 1
         if any(k.kind == "paged_latent" for k in self._layout):
             from ..ops.kernels.latent_attention import entries_per_step
@@ -1217,8 +1261,12 @@ class LLMEngine:
         live = sum(-(-s.sched_len() // (bs * n))
                    for s in self.slots if s is not None)
         grid = self._tables.size // n
+        iterations *= self._loop_steps
         self.stats["kv_grid_blocks"] += iterations * grid
         self.stats["kv_live_blocks"] += iterations * min(live, grid)
+        self.stats["pool_blocks_used"] += self.n_blocks - len(
+            self._free_blocks) - len(self._lru) - len(self._quarantine)
+        self.stats["pool_blocks_total"] += self.n_blocks
 
     def _program(self, name, fn):
         """``fn`` (a jitted program) with its builds booked. A build is
@@ -2272,7 +2320,8 @@ class LLMEngine:
             raise ValueError(
                 "kind='embed' pools the hidden rows of a K/V decoder's "
                 "prefill; it is not wired for a cache layout with other "
-                "state kinds")
+                "state kinds (a latent pool, a recurrent state, a looped "
+                "layout)")
         ids = np.asarray(
             prompt_ids.numpy() if hasattr(prompt_ids, "numpy")
             else prompt_ids, dtype=np.int32).reshape(-1)
@@ -4226,6 +4275,10 @@ class LLMEngine:
         if not use_multi:
             # the scan runs its whole horizon whatever deactivates
             self.stats["rows_computed"] += rows * k_iter
+        ctx0 = None
+        if self.cache_impl == "paged" and not spec:
+            ctx0 = np.array([s.sched_len() if s is not None and active[b]
+                             else 0 for b, s in enumerate(self.slots)])
         sched = {}
         if self.scheduler == "fused":
             # host lens mirror for the paged pipeline: a surviving slot
@@ -4248,6 +4301,7 @@ class LLMEngine:
                      if self.slots[b] is not None} if spec else None))
         pending.t_dispatch = t0
         pending.ctr = ctr_dev
+        pending.ctx0 = ctx0
         pending.rows = rows if use_multi else 0
         if self.cache_impl == "paged":
             self._book_kv_grid(k_iter)
@@ -4601,9 +4655,10 @@ class LLMEngine:
         spec_args = dict(tokens_buf=self._tokens, spec_ks=spec_ks) \
             if spec else {}
         counts_dev = None
-        # the append kernel's tile count is of K/V pools
+        # the append kernel's tile count is of K/V pools (a looped
+        # layout's too: loop steps multiply both counts alike)
         tiles = self._attn_tile_steps(q_lens) \
-            if self.cache_impl == "paged" and self._kv_only else None
+            if self.cache_impl == "paged" and self._kv_pools else None
         t0 = self._to("dispatch", **self._dispatch_ids(
             "mixed", self.mixed_rows, int(q_lens.sum()),
             live_tiles=tiles and tiles[0]))
@@ -4811,6 +4866,14 @@ class LLMEngine:
         # an early-exit stride's rows, now that the iterations it ran are
         # known (at least the one every dispatch runs)
         self.stats["rows_computed"] += pending.rows * max(n_exec, 1)
+        if pending.ctx0 is not None:
+            # iteration k attends a live slot's tokens at dispatch, the k
+            # it decoded since, and the new one
+            k = np.arange(act_np.shape[0])[:, None]
+            self.stats["decode_ctx_tokens"] += int(
+                ((pending.ctx0[None, :] + k + 1) * act_np).sum())
+            self.stats["decode_rows"] += int(act_np.sum())
+            self.stats["decode_iterations"] += n_exec
         if ctr_np is not None:
             booked = dict(zip(self._step_counter_names,
                               (int(v) for v in ctr_np)))

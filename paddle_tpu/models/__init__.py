@@ -13,3 +13,4 @@ from .kimi_linear import (  # noqa: F401
     KimiLinearConfig, KimiLinearForCausalLM)
 from .deepseek_v2 import (  # noqa: F401
     DeepseekV2Config, DeepseekV2ForCausalLM)
+from .ouro import OuroConfig, OuroForCausalLM  # noqa: F401

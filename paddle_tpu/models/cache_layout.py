@@ -66,6 +66,58 @@ class PagedKV:
         return 2 * self.kv_heads * self.head_dim * itemsize
 
 
+class LoopedPagedKV:
+    """K and V of ``(kv_heads, head_dim)``, ``loop_steps`` times: a WEIGHT
+    layer that runs R times a token (a looped stack) and keeps keys and
+    values of its own for each run. One K and one V pool a weight layer of
+    ``[R x (n_blocks + 1), kv_heads, block, head_dim]`` on the engine's ONE
+    block table a slot: the loop step is part of a block's address. Block
+    ``p`` of the allocator is pool block ``t x (n_blocks + 1) + p`` at loop
+    step ``t`` (:meth:`at_step`), so one block id holds R steps' worth of
+    content and the allocator, the tables, preemption and replay know
+    nothing of R. An unallocated entry (-1) stays -1 at every step: it
+    reads block 0 masked and its write lands in the pool's last block,
+    which is step R - 1's scratch block. The cache object is the llama
+    family's :class:`~paddle_tpu.models.llama.PagedKVCache` (so are the
+    kernels); in a one-token step its ``q_lens`` says which slots hold a
+    live row."""
+    kind = "paged_kv_looped"
+    paged = True
+
+    def __init__(self, kv_heads, head_dim, loop_steps):
+        self.kv_heads, self.head_dim = int(kv_heads), int(head_dim)
+        self.loop_steps = int(loop_steps)
+
+    def bytes_per_token(self, itemsize):
+        return 2 * self.kv_heads * self.head_dim * itemsize * self.loop_steps
+
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype):
+        shape = (self.loop_steps * (n_blocks + 1), self.kv_heads,
+                 block_size, self.head_dim)
+        return zeros(shape, dtype), zeros(shape, dtype)
+
+    def cache(self, a, b, tables, lens, q_lens, active, row_budget,
+              rows=None):
+        from .llama import PagedKVCache
+        return PagedKVCache(a, b, tables, lens, _q_lens(q_lens, active),
+                            rows=rows)
+
+    def unpack(self, cache):
+        return _val(cache.k), _val(cache.v)
+
+    def at_step(self, cache, t, k=None, v=None):
+        """``cache`` as loop step ``t`` (traced or not) sees it: the same
+        pools (or ``k`` / ``v``, the pools as the steps before left
+        them), the table moved to the step's run of blocks."""
+        from .llama import PagedKVCache
+        k = cache.k if k is None else k
+        stride = _val(k).shape[0] // self.loop_steps
+        tables = _val(cache.block_tables).astype(jnp.int32)
+        tables = jnp.where(tables < 0, -1, tables + t * stride)
+        return PagedKVCache(k, cache.v if v is None else v, tables,
+                            cache.seq_lens, cache.q_lens, rows=cache.rows)
+
+
 class PagedLatent:
     """One paged pool ``[n_blocks + 1, block, width]`` of latent entries on
     the engine's block tables (:class:`LatentPagedCache`)."""

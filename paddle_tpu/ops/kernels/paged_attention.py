@@ -39,6 +39,13 @@ Design (mirrors ops/kernels/flash_attention.py idiom, adapted to paging):
 - GQA zero-copy: q arrives [B, Hkv, G, D] (G = q-heads per kv head); each
   (batch, kv_head) window attends its whole q-head group against one
   stream of that kv head's blocks.
+- a group of one (``G == 1``): a (batch, kv_head) window would hold a
+  single query row and a grid step would be all fixed cost, so the call
+  takes every kv head of a table entry in one step, grid
+  ``(batch, 1, max_blocks)``, the heads' blocks stacked as the keys of one
+  matmul pair and a row's scores against the other heads' keys masked
+  (``_decode_heads_per_step``, ``_decode_kernel_heads``). Grouped and
+  quantized calls keep the one-head step.
 - optional fused new-token write: the decode step's fresh K/V (one token
   per sequence) is merged into the last live block IN VMEM — attention
   sees the new token without a prior XLA scatter round-trip through HBM —
@@ -556,6 +563,109 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest, scale,
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+#: VMEM a decode call at a group of one plans its pool blocks into, in and
+#: out, double-buffered (``_decode_heads_per_step``)
+_DECODE_VMEM_BUDGET = 12 << 20
+
+
+def _decode_heads_per_step(hkv, g, bs, dk, pool_isz, quant):
+    """KV heads one grid step of the decode call serves. With a group of
+    one (every query head has its own kv head) a (slot, head, entry) step
+    moves one ``[bs, D]`` block of K and of V for a single query row, and
+    the step's fixed cost is all of its time (0.39 us a step, 3 % of the
+    kernel's roofline at 16 heads; PERF.md section 6, PR 34): such a call
+    takes every head of a table entry in one step, the largest divisor of
+    ``hkv`` whose double-buffered blocks in and out fit
+    ``_DECODE_VMEM_BUDGET`` (they lie together in the pool: one DMA).
+    A grouped or quantized call is left at one head a step, the program
+    ``doc_batch`` runs, and so is a block that is not whole native tiles
+    (8 rows of 4 bytes, 16 of 2), whose heads do not stack for free.
+    Static shapes only: no option sets it."""
+    if g != 1 or quant or bs % (32 // pool_isz):
+        return 1
+    for hb in range(hkv, 1, -1):
+        if hkv % hb == 0 and \
+                2 * 2 * 2 * hb * bs * dk * pool_isz <= _DECODE_VMEM_BUDGET:
+            return hb
+    return 1
+
+
+def _decode_kernel_heads(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
+                         scale, bs, mb, write_new):
+    """``_decode_kernel`` for ``hb`` kv heads a grid step at a group of one
+    (16-bit or f32 pools, not quantized): the heads of a table entry are
+    attended in ONE pair of matmuls, their blocks stacked as
+    ``[hb * bs, D]`` keys, the heads' query rows against all of them, and
+    every score of a row against another head's keys masked like a dead
+    position, so that its ``p`` is exactly 0 and the sum over the stacked
+    values adds exact zeros to the head's own: a head reads what the
+    one-head kernel gives it, bit for bit on a v5e. The MXU does ``hb``
+    times the products the heads need, which it has room for (a decode
+    row fills 1 of its 128 rows), and is loaded ``hb`` times less often:
+    16 heads in one pair read 93 us a call where one head a step read
+    657, and pairs of 8 / 4 / 2 / 1 heads 95 / 115 / 113 / 162 (PERF.md
+    section 6, PR 34)."""
+    if write_new:
+        nk_ref, nv_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+    b = pl.program_id(0)
+    j = pl.program_id(2)
+    bs_i = np.int32(bs)
+    L = lens_ref[b]
+    j_last = _last_live(lens_ref, b, bs, mb)
+    jj = jnp.minimum(j, j_last)
+    phys = tables_ref[b, jj]
+    live = (j <= j_last) & (phys >= Z)
+    hb, _, d = k_ref.shape[1:]
+
+    @pl.when(j == Z)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    k_blk = k_ref[0]                                      # [hb, bs, D]
+    v_blk = v_ref[0]
+    if write_new:
+        slot = L - j_last * bs_i
+        row = jax.lax.broadcasted_iota(jnp.int32, k_blk.shape, 1)
+        sel = (row == slot) & (j == j_last)
+        k_blk = jnp.where(sel, nk_ref[0].astype(k_blk.dtype), k_blk)
+        v_blk = jnp.where(sel, nv_ref[0].astype(v_blk.dtype), v_blk)
+
+        @pl.when(j == j_last)
+        def _store_block():
+            ko_ref[0] = k_blk
+            vo_ref[0] = v_blk
+
+    @pl.when(live)
+    def _attend():
+        k2 = k_blk.reshape(hb * bs, d)
+        v2 = v_blk.reshape(hb * bs, d)
+        dt = _mxu_dtype(q_ref.dtype, k2.dtype, None)
+        s = _scores(q_ref[0, 0], k2.astype(dt), scale, dt)     # [hb, hb*bs]
+        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        within = col - head * bs_i           # the key's place in ITS block
+        seen = (within >= Z) & (within < bs_i) & (jj * bs_i + within <= L)
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v2.dtype), v2, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == np.int32(mb - 1))
+    def _finalize():
+        l = jnp.maximum(l_ref[...], np.float32(1e-30))
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
 def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
                            scale=None, new_k=None, new_v=None,
                            k_scale=None, v_scale=None, quant=None):
@@ -600,6 +710,11 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     write_new = new_k is not None
     assert (new_v is not None) == write_new
+
+    hb = _decode_heads_per_step(Hkv, G, BS, Dk, k_pool.dtype.itemsize, quant)
+    if hb > 1:
+        return _decode_heads_call(q, k_pool, v_pool, block_tables, seq_lens,
+                                  scale, new_k, new_v, hb)
 
     q4 = q.reshape(B, Hkv, G, D)
     tables = block_tables.astype(jnp.int32)
@@ -674,6 +789,62 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
             return out, outs[1], outs[2], outs[3], outs[4]
         return out, outs[1], outs[2]
     return out
+
+
+def _decode_heads_call(q, k_pool, v_pool, block_tables, seq_lens, scale,
+                       new_k, new_v, hb):
+    """The decode call at a group of one with ``hb`` kv heads a grid step
+    (``_decode_kernel_heads``): grid ``(B, Hkv / hb, MB)``, the index maps
+    of the one-head call with a block of ``hb`` heads where it has one."""
+    B, H, D = q.shape
+    NB, _, BS, Dk = k_pool.shape
+    MB = block_tables.shape[1]
+    tables = block_tables.astype(jnp.int32)
+    lens = seq_lens.astype(jnp.int32)
+    write_new = new_k is not None
+
+    q_spec = pl.BlockSpec((1, 1, hb, D), _q_index_map)
+    kv_spec = pl.BlockSpec((1, hb, BS, Dk), _kv_index_map(BS, MB))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((B, H // hb, hb, D), q.dtype)]
+    inputs = [tables, lens, q.reshape(B, H // hb, hb, D), k_pool, v_pool]
+    io_aliases = {}
+    if write_new:
+        new_spec = pl.BlockSpec((1, hb, 1, D), _new_kv_index_map)
+        pool_spec = pl.BlockSpec((1, hb, BS, Dk),
+                                 _pool_out_index_map(BS, MB, NB))
+        in_specs += [new_spec, new_spec]
+        inputs += [new_k.reshape(B, H, 1, D).astype(k_pool.dtype),
+                   new_v.reshape(B, H, 1, D).astype(k_pool.dtype)]
+        out_specs += [pool_spec, pool_spec]
+        out_shape += [jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                      jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)]
+        io_aliases = {3: 1, 4: 2}
+    outs = pl.pallas_call(
+        functools.partial(_decode_kernel_heads, scale=scale, bs=BS, mb=MB,
+                          write_new=write_new),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H // hb, MB),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((hb, 1), jnp.float32),
+                pltpu.VMEM((hb, 1), jnp.float32),
+                pltpu.VMEM((hb, D), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
+        input_output_aliases=io_aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_DECODE_VMEM_BUDGET + (8 << 20)),
+        name="paged_attention_decode",
+        interpret=_interpret(),
+    )(*inputs)
+    out = outs[0].reshape(B, H, D)
+    return (out, outs[1], outs[2]) if write_new else out
 
 
 # ---------------------------------------------------------------------------

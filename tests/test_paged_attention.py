@@ -149,6 +149,76 @@ def test_invalid_write_target_routes_to_scratch_block(rng):
                                rtol=2e-5, atol=2e-5)
 
 
+# ---- every kv head of a table entry in one grid step (group 1) ------------
+
+def _decode(q, kc, vc, tables, lens, knew, vnew, fused=True):
+    a = [jnp.asarray(x) for x in (q, kc, vc, tables, lens)]
+    if fused:
+        return paged_attention_decode(*a, new_k=jnp.asarray(knew),
+                                      new_v=jnp.asarray(vnew))
+    return paged_attention_decode(*a)
+
+
+@pytest.mark.parametrize("hb", [4, 2])
+def test_heads_a_step_decode_is_the_one_head_decode(hb, rng, monkeypatch):
+    """``hb`` kv heads a grid step at a group of one: outputs and pools of
+    the one-head-a-step call (block boundaries, -1 tail entries, a slot
+    with no block at all, the fused write and the read-only form), the
+    other heads' keys of the stacked matmul masked to exact zeros."""
+    from paddle_tpu.ops.kernels import paged_attention as PA
+    case = _case(rng, [16, 17, 7, 3, 30], Hq=4, Hkv=4, BS=8,
+                 spare_block=True)
+    case[3][3, :] = -1
+    want = {}
+    monkeypatch.setattr(PA, "_decode_heads_per_step", lambda *a: 1)
+    for fused in (True, False):
+        want[fused] = _decode(*case, fused=fused)
+    monkeypatch.setattr(PA, "_decode_heads_per_step", lambda *a: hb)
+    out, kc2, vc2 = _decode(*case)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want[True][0]),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(kc2), np.asarray(want[True][1]))
+    np.testing.assert_array_equal(np.asarray(vc2), np.asarray(want[True][2]))
+    np.testing.assert_allclose(np.asarray(_decode(*case, fused=False)),
+                               np.asarray(want[False]), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,hb", [
+    ((16, 1, 64, 128, 2, None), 16),        # ouro_worked_answers: all heads
+    ((8, 4, 64, 128, 2, None), 1),          # doc_batch: grouped, as it was
+    ((16, 1, 64, 128, 1, "int8"), 1),       # a quantized pool, as it was
+    ((32, 1, 128, 256, 2, None), 16),       # what fits the VMEM budget
+    ((16, 1, 8, 128, 2, None), 1),          # a block of half a bf16 tile
+    ((1, 1, 64, 128, 2, None), 1)])
+def test_decode_heads_per_step_reads_only_static_shapes(shape, hb):
+    from paddle_tpu.ops.kernels.paged_attention import _decode_heads_per_step
+    assert _decode_heads_per_step(*shape) == hb
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the digest is of jax 0.9.0's jaxpr")
+def test_a_grouped_decode_call_traces_to_what_the_parent_traced():
+    """The one-head-a-step call (what ``doc_batch``'s scans run at a group
+    of 4) is the program it was before a group of 1 got a kernel of its
+    own: the digest of its jaxpr, kernel body and index maps included,
+    read from the parent of PR 34 under this suite's settings."""
+    import hashlib
+    S, BF = jax.ShapeDtypeStruct, jnp.bfloat16
+    b, hq, hkv, d, nb, bs, mb = 4, 8, 2, 32, 9, 8, 4
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, t, n, nk, nv: paged_attention_decode(
+            q, k, v, t, n, new_k=nk, new_v=nv))(
+        S((b, hq, d), BF), S((nb, hkv, bs, d), BF), S((nb, hkv, bs, d), BF),
+        S((b, mb), jnp.int32), S((b,), jnp.int32), S((b, hkv, d), BF),
+        S((b, hkv, d), BF)))
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16],
+            len(text.splitlines())) == GROUPED_DECODE
+
+
+#: see the test above
+GROUPED_DECODE = ("1236e86128893468", 217)
+
+
 @pytest.mark.slow
 def test_large_shape_parity(rng):
     """Production-ish decode shape (B=8, 32 q heads / 8 kv heads, D=128,
