@@ -117,11 +117,14 @@ def default_engine_stats():
             # over layers and steps, read beside the tokens: live rows x
             # experts a token; those that landed on a held expert; rows
             # the grouped product ran, padding included; the fullest held
-            # expert's rows; and held assignments beyond the product's
-            # rows (stays 0: the routing is dropless)
+            # expert's rows; held assignments beyond the product's rows
+            # (stays 0: the routing is dropless); and the held experts
+            # that got a row, of the experts held (the share of the held
+            # weights the grouped product has to read)
             "moe_assignments": 0, "moe_assignments_held": 0,
             "moe_rows_computed": 0, "moe_expert_peak": 0,
             "moe_assignments_dropped": 0,
+            "moe_experts_nonempty": 0, "moe_experts_held": 0,
             # slots assigned into zeroed recurrent state (a layout with a
             # recurrent layer): admissions and preemption replays alike
             "state_resets": 0,
@@ -4882,6 +4885,9 @@ class LLMEngine:
             if "moe_assignments_held" in booked:
                 # what the host could not know at dispatch
                 ids["held_rows"] = booked["moe_assignments_held"]
+            if "moe_experts_nonempty" in booked:
+                ids["experts_read"] = booked["moe_experts_nonempty"]
+                ids["experts_held"] = booked["moe_experts_held"]
         now_pc = t0 = self._to("emit", **ids)
         if toks_np.shape[0] > 1 and pending.t_dispatch is not None \
                 and n_exec > 1:

@@ -7,29 +7,38 @@ groups of experts; the ``top_k`` largest, scaled) and computes the part of
 the result that its OWN experts give: experts
 ``[offset, offset + E)`` of the published count. Every assignment that
 lands on a held expert is computed: the assignments are sorted by expert
-and go through one grouped product a projection (``jax.lax.ragged_dot``:
-a native grouped matmul on the TPU), so there is no capacity and nothing
-is dropped; a dead row is not routed. What the absent experts would have
-added is left out, and no code stands in for the chips that hold them
-(``ops/kernels/moe.py`` is the capacity-routed GShard layer with its
-exchange; no model of the benchmark uses it).
+and go through one grouped product (``grouped_expert_matmul.py``: a Pallas
+kernel that reads each non-empty expert's weights once a projection, gate
+and up in one call and down in another; ``jax.lax.ragged_dot`` for widths
+that do not fill the lanes, a toy model's), so there is no capacity and
+nothing is dropped; a dead row is not routed. What the absent experts
+would have added is left out, and no code stands in for the chips that
+hold them (``ops/kernels/moe.py`` is the capacity-routed GShard layer
+with its exchange; no model of the benchmark uses it).
 
 ``rows`` is the static height of the grouped product: the caller's bound
-on held assignments (live rows x min(top_k, E)), rounded up here to a
-multiple of 8: XLA:TPU takes its grouped-matmul kernel only for such a
-height, and else multiplies every row by every expert (read in the
-compiled HLO; ``benchmark/tests/test_aot_deepseek_v2.py`` holds it). The
-counts that leave with the result say what was asked and what was computed.
+on held assignments (live rows x min(top_k, E)), rounded up here to the
+kernel's row tile (``grouped_expert_matmul.row_tile``: 16 to 64 rows by
+the shapes, so that the last row tile is whole wherever the step's own
+height allows it; 8 on the ``ragged_dot`` side, where XLA:TPU takes its
+grouped-matmul kernel only for such a height and else multiplies every
+row by every expert: read in the compiled HLO). The kernel runs the row
+tiles that hold a held row and no other, so the rounding costs nothing
+there. The counts that leave with the result say what was asked and what
+was computed.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from . import grouped_expert_matmul as _gmm
+
 HI = jax.lax.Precision.HIGHEST
 #: what :func:`held_expert_ffn` counts, in order
 COUNTERS = ("moe_assignments", "moe_assignments_held", "moe_rows_computed",
-            "moe_expert_peak", "moe_assignments_dropped", "moe_rows_held")
+            "moe_expert_peak", "moe_assignments_dropped", "moe_rows_held",
+            "moe_experts_nonempty", "moe_experts_held")
 
 
 def route(x, w_router, bias, top_k, scale, renormalize=True,
@@ -68,10 +77,12 @@ def held_expert_ffn(x, idx, w, live, w_gate, w_up, w_down, offset, rows):
     .. offset + E - 1``. Returns (y [N, h] float32, counts int32 in
     :data:`COUNTERS` order; ``moe_rows_held`` is the live rows with at
     least one assignment here: the rows an exchange would bring this
-    chip)."""
+    chip; ``moe_experts_nonempty`` of the ``moe_experts_held`` got a row:
+    the share of the held weights the product has to read)."""
     n, k = idx.shape
-    e = w_gate.shape[0]
-    rows = int(min(-(-rows // 8) * 8, n * k))
+    e, h, f = w_gate.shape
+    tm = _gmm.row_tile(rows, h, f, e, x.dtype)
+    rows = int(min(-(-rows // tm) * tm, n * k))
     local = idx - jnp.int32(offset)
     held = live[:, None] & (local >= 0) & (local < e)
     key = jnp.where(held, local, e).reshape(-1)           # e sorts last
@@ -80,13 +91,7 @@ def held_expert_ffn(x, idx, w, live, w_gate, w_up, w_down, offset, rows):
     n_held = jnp.sum(sizes)
     token = order // k
     xs = jnp.take(x, token, axis=0)
-    gate = jax.lax.ragged_dot(xs, w_gate, sizes,
-                              preferred_element_type=jnp.float32)
-    up = jax.lax.ragged_dot(xs, w_up, sizes,
-                            preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gate) * up).astype(x.dtype)
-    out = jax.lax.ragged_dot(act, w_down, sizes,
-                             preferred_element_type=jnp.float32)
+    out = _gmm.grouped_expert_ffn(xs, w_gate, w_up, w_down, sizes, tm)
     valid = jnp.arange(rows, dtype=jnp.int32) < n_held
     ws = jnp.where(valid, jnp.take(w.reshape(-1), order), 0.0)
     y = jnp.zeros((n, x.shape[1]), jnp.float32).at[token].add(
@@ -95,5 +100,6 @@ def held_expert_ffn(x, idx, w, live, w_gate, w_up, w_down, offset, rows):
         jnp.sum(live).astype(jnp.int32) * k, n_held,
         jnp.int32(rows), jnp.max(sizes),
         jnp.maximum(n_held - rows, 0),
-        jnp.sum(jnp.any(held, axis=1))]).astype(jnp.int32)
+        jnp.sum(jnp.any(held, axis=1)),
+        jnp.sum(sizes > 0), jnp.int32(e)]).astype(jnp.int32)
     return y, counts

@@ -392,7 +392,10 @@ def test_dead_rows_are_not_routed_and_nothing_is_dropped():
     assert counts == dict(moe_assignments=14, moe_assignments_held=14,
                           moe_rows_computed=16, moe_assignments_dropped=0,
                           moe_expert_peak=counts["moe_expert_peak"],
-                          moe_rows_held=7)
+                          moe_rows_held=7,
+                          moe_experts_nonempty=counts["moe_experts_nonempty"],
+                          moe_experts_held=8)
+    assert 0 < counts["moe_experts_nonempty"] <= 8
     assert not np.asarray(y)[7:].any()
     want = R.routed_part(x[:7], idx[:7], w[:7], wg, wu, wd, 0, "f32")
     np.testing.assert_allclose(y[:7], want, atol=2e-5)
@@ -408,6 +411,13 @@ def _serve(model, arrivals, **over):
     """Drive the engine a step at a time; ``arrivals``: {step: [(prompt,
     max_new)]}. Returns ({rid: (prompt, tokens)}, engine)."""
     eng = LLMEngine(model, **dict(ENGINE, **over))
+    eng.emitted, to = [], eng._to        # what rides on pt:engine.emit
+
+    def recording(phase, **ids):
+        if phase == "emit":
+            eng.emitted.append(ids)
+        return to(phase, **ids)
+    eng._to = recording
     prompts, done, step = {}, {}, 0
     while step < 400:
         for prompt, n in arrivals.get(step, ()):
@@ -475,6 +485,14 @@ def test_engine_serves_what_the_reference_would(case):
     assert s["moe_assignments_held"] <= s["moe_rows_computed"]
     assert s["moe_assignments_dropped"] == 0
     assert s["moe_expert_peak"] > 0 and s["kv_grid_blocks"] > 0
+    # 7 expert layers of 4 held experts a step or scan iteration; what a
+    # step's emit span carries adds up to the counters
+    assert s["moe_experts_held"] % (4 * 7) == 0
+    assert 0 < s["moe_experts_nonempty"] <= s["moe_experts_held"]
+    for key, name in (("held_rows", "moe_assignments_held"),
+                      ("experts_read", "moe_experts_nonempty"),
+                      ("experts_held", "moe_experts_held")):
+        assert sum(ids.get(key, 0) for ids in eng.emitted) == s[name]
 
 
 def _booked(monkeypatch):
