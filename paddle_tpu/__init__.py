@@ -23,8 +23,17 @@ def _configure_compile_cache():
     cache key's environment, so it is FIXED: never a tempdir, pid or time.
     THE one place a cache directory is chosen — benchmark/run.py, the
     examples, __graft_entry__.py and chip_smoke.py all get it by importing
-    this package. A config update does not initialise a backend."""
+    this package. A config update does not initialise a backend.
+
+    The cache's key takes the operations' metadata in. jax leaves it out by
+    default, and an executable carries the metadata of the tree that
+    COMPILED it: one that a tree without device scopes (``profiler.scope``)
+    cached would be loaded by this one, and a profile would then name
+    nothing (read on the v5e: a second process whose scope had another
+    name showed the first one's). The price is a cold compile after an
+    edit that moves a traced line."""
     import os
+    _jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

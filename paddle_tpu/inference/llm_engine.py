@@ -48,7 +48,7 @@ from ..core.tensor import Tensor, functional_mode
 from ..models.cache_layout import RowMap, collect_counts, packed_rows
 from ..models.llama import SlotKVCache, _sample_logits_device
 from ..models.lora import lora_scope
-from ..profiler import span
+from ..profiler import scope, span
 
 __all__ = ["LLMEngine", "GenerationRequest", "RequestOutput", "PendingStep",
            "PoolCapacityError", "default_engine_stats"]
@@ -1215,18 +1215,27 @@ class LLMEngine:
             self._phase = (phase, now, ann)
         return now
 
-    def _dispatch_ids(self, kind, rows, live_tokens, live_tiles=None):
+    def _dispatch_ids(self, kind, rows, live_tokens, granted,
+                      prefill_rows=0, live_tiles=None):
         """What rides on a ``pt:engine.dispatch`` span. ``step_id`` is the
         StepRecord's where a recorder is attached, else this dispatch's
         index among the engine's own, so flight-recorder timelines and
-        the profile join by id. ``live_tiles``: a mixed paged step's
+        the profile join by id. The step's shape: ``prefill_rows`` and
+        ``decode_rows`` of its ``live_tokens``, and ``ctx_tokens``, the
+        tokens the ``granted`` slots (a mask) hold before it (the host's
+        lens mirror, so call this BEFORE the mirrors grow): what a
+        kernel-alone run needs to stand at the traced steps' own
+        ``(seq_lens, q_lens)``. ``live_tiles``: a mixed paged step's
         ``attn_tile_steps``."""
         rec = self._rec()
         ids = dict(
             step_id=(rec.next_step_id() if rec is not None
                      else self.stats["steps"] + self._inflight),
             kind=DISPATCH_KINDS.index(kind), rows=int(rows),
-            live_tokens=int(live_tokens))
+            live_tokens=int(live_tokens), prefill_rows=int(prefill_rows),
+            decode_rows=int(live_tokens) - int(prefill_rows),
+            ctx_tokens=sum(s.sched_len() for s, g in zip(self.slots, granted)
+                           if g and s is not None))
         if live_tiles is not None:
             ids["live_tiles"] = int(live_tiles)
         return ids
@@ -1415,14 +1424,16 @@ class LLMEngine:
             same per-position keys instead of advancing a shared stream —
             leaves ``key`` untouched across steps, so resumption is
             token-exact in sampled mode too (docs/architecture.md)."""
-            greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            keys = jax.vmap(lambda r, p: jax.random.fold_in(
-                jax.random.fold_in(key, r), p))(rids, lens)
-            sampled = jax.vmap(
-                lambda k, row, t, tp: _sample_logits_device(
-                    row, k, jnp.maximum(t, 1e-6), top_k, tp, False, True)
-            )(keys, logits, temps, top_ps)
-            return jnp.where(temps <= 0.0, greedy_tok, sampled)
+            with scope("pt.sample"):
+                greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                keys = jax.vmap(lambda r, p: jax.random.fold_in(
+                    jax.random.fold_in(key, r), p))(rids, lens)
+                sampled = jax.vmap(
+                    lambda k, row, t, tp: _sample_logits_device(
+                        row, k, jnp.maximum(t, 1e-6), top_k, tp, False,
+                        True)
+                )(keys, logits, temps, top_ps)
+                return jnp.where(temps <= 0.0, greedy_tok, sampled)
 
         def one_step(k_bufs, v_bufs, logits, lens, active, rng, state_vals,
                      temps, top_ps, eos_ids, rids, tables, lora=None):
@@ -1453,12 +1464,13 @@ class LLMEngine:
             # an INACTIVE row's carried logits must survive the remaining
             # scan iterations — a slot deactivated non-terminally (pool
             # budget clamp) samples from them next step
-            new_logits = jnp.where(active[:, None], new_logits, logits)
             kb, vb = unpack_kv(new_caches)
-            new_lens = jnp.where(active, lens + 1, lens)
-            finished = active & (nxt == eos_ids)
-            return (nxt, new_logits, kb, vb, new_lens, finished, rng,
-                    sum(counted, ctr_zero) if n_ctr else None)
+            with scope("pt.readout"):
+                new_logits = jnp.where(active[:, None], new_logits, logits)
+                new_lens = jnp.where(active, lens + 1, lens)
+                finished = active & (nxt == eos_ids)
+                return (nxt, new_logits, kb, vb, new_lens, finished, rng,
+                        sum(counted, ctr_zero) if n_ctr else None)
 
         def step(state_vals, k_bufs, v_bufs, logits, lens, active, rng,
                  temps, top_ps, eos_ids, budgets, rids, tables=None,
@@ -1475,11 +1487,12 @@ class LLMEngine:
                 nxt, logits, kb, vb, lens, finished, rng, c1 = one_step(
                     kb, vb, logits, lens, act, rng, state_vals, temps,
                     top_ps, eos_ids, rids, tables, lora)
-                emitted = emitted + act.astype(jnp.int32)
-                act_next = act & ~finished & (lens < cap - 1) & \
-                    (emitted < budgets)
-                if n_ctr:
-                    ctr = ctr + c1
+                with scope("pt.readout"):
+                    emitted = emitted + act.astype(jnp.int32)
+                    act_next = act & ~finished & (lens < cap - 1) & \
+                        (emitted < budgets)
+                    if n_ctr:
+                        ctr = ctr + c1
                 return (kb, vb, logits, lens, act_next, emitted, rng,
                         ctr), (nxt, act)
 
@@ -1521,15 +1534,16 @@ class LLMEngine:
                     nxt, lg, kb, vb, ln, finished, _, c1 = one_step(
                         kb, vb, lg, ln, act, rng, state_vals, temps,
                         top_ps, eos_ids, rids, tables, lora)
-                    if n_ctr:
-                        ctr = ctr + c1
-                    toks = jax.lax.dynamic_update_slice(
-                        toks, nxt[None], (i, jnp.int32(0)))
-                    wa = jax.lax.dynamic_update_slice(
-                        wa, act[None], (i, jnp.int32(0)))
-                    emitted = emitted + act.astype(jnp.int32)
-                    act = act & ~finished & (ln < cap - 1) & \
-                        (emitted < budgets)
+                    with scope("pt.readout"):
+                        if n_ctr:
+                            ctr = ctr + c1
+                        toks = jax.lax.dynamic_update_slice(
+                            toks, nxt[None], (i, jnp.int32(0)))
+                        wa = jax.lax.dynamic_update_slice(
+                            wa, act[None], (i, jnp.int32(0)))
+                        emitted = emitted + act.astype(jnp.int32)
+                        act = act & ~finished & (ln < cap - 1) & \
+                            (emitted < budgets)
                     return (i + 1, kb, vb, lg, ln, act, emitted, toks, wa,
                             ctr)
 
@@ -1811,35 +1825,36 @@ class LLMEngine:
             None on non-speculative engines, so the spec-free program —
             and ``speculative_k=1`` serving — is bit-identical."""
             nxt = sample_next(logits, rng, temps, top_ps, rids, lens)
-            # capacity guard for pipelined over-dispatch: a window that
-            # would cross the buffer end deactivates in-graph
-            active = active & (lens + q_lens <= cap)
-            dec = active & is_decode
-            if spec_ks is None:
-                nxt = jnp.where(dec, nxt, 0)
-                q_eff = jnp.where(active, q_lens, 0)
-                row0 = jnp.arange(chunk, dtype=jnp.int32)[None, :] == 0
-                ids = jnp.where(dec[:, None] & row0, nxt[:, None], ids)
-            else:
-                # verify windows must fit the token-history write below;
-                # a clamped-out verify slot goes fully inactive (its
-                # rows must not scatter) — in practice the readout's
-                # capacity margin retires slots before this fires
-                dec = dec & (lens + Kspec <= cap)
-                active = active & (~is_decode | dec)
-                nxt = jnp.where(dec, nxt, 0)
-                q_eff = jnp.where(active, q_lens, 0)
-                draft = _lookup_draft(tokens_buf, lens, Kspec - 1, ngram)
-                window = jnp.concatenate([nxt[:, None], draft], axis=1)
-                wcols = jnp.arange(chunk, dtype=jnp.int32)[None, :] < Kspec
-                padded_win = jnp.zeros_like(ids) \
-                    .at[:, :Kspec].set(window)
-                ids = jnp.where(dec[:, None] & wcols, padded_win, ids)
-                tb_new = _write_window(tokens_buf, window, lens)
-                tokens_buf = jnp.where(dec[:, None], tb_new, tokens_buf)
-            # the packed row axis: made HERE, after the guard above may
-            # have taken a slot out in the graph
-            rows = RowMap(q_eff, lens, T, chunk)
+            with scope("pt.pack"):
+                # capacity guard for pipelined over-dispatch: a window that
+                # would cross the buffer end deactivates in-graph
+                active = active & (lens + q_lens <= cap)
+                dec = active & is_decode
+                if spec_ks is None:
+                    nxt = jnp.where(dec, nxt, 0)
+                    q_eff = jnp.where(active, q_lens, 0)
+                    row0 = jnp.arange(chunk, dtype=jnp.int32)[None, :] == 0
+                    ids = jnp.where(dec[:, None] & row0, nxt[:, None], ids)
+                else:
+                    # verify windows must fit the token-history write below;
+                    # a clamped-out verify slot goes fully inactive (its
+                    # rows must not scatter) — in practice the readout's
+                    # capacity margin retires slots before this fires
+                    dec = dec & (lens + Kspec <= cap)
+                    active = active & (~is_decode | dec)
+                    nxt = jnp.where(dec, nxt, 0)
+                    q_eff = jnp.where(active, q_lens, 0)
+                    draft = _lookup_draft(tokens_buf, lens, Kspec - 1, ngram)
+                    window = jnp.concatenate([nxt[:, None], draft], axis=1)
+                    wcols = jnp.arange(chunk, dtype=jnp.int32)[None, :] < Kspec
+                    padded_win = jnp.zeros_like(ids) \
+                        .at[:, :Kspec].set(window)
+                    ids = jnp.where(dec[:, None] & wcols, padded_win, ids)
+                    tb_new = _write_window(tokens_buf, window, lens)
+                    tokens_buf = jnp.where(dec[:, None], tb_new, tokens_buf)
+                # the packed row axis: made HERE, after the guard above may
+                # have taken a slot out in the graph
+                rows = RowMap(q_eff, lens, T, chunk)
             with functional_mode(), _bind(state, state_vals), \
                     lora_scope(lora and dict(lora, rows=rows)):
                 if tables is None:
@@ -1849,18 +1864,21 @@ class LLMEngine:
                 else:
                     caches = paged_caches(k_bufs, v_bufs, tables, lens,
                                           q_eff, rows=rows)
+                with scope("pt.pack"):
+                    packed_ids = Tensor(rows.from_slots(ids)[None])
+                    packed_pos = Tensor(rows.pos[None])
                 with collect_counts() as counted:
                     hidden, new_caches = decoder(
-                        Tensor(rows.from_slots(ids)[None]),
-                        kv_caches=caches,
-                        position_offset=Tensor(rows.pos[None]))
+                        packed_ids, kv_caches=caches,
+                        position_offset=packed_pos)
                 hidden = hidden._value[0]                       # [T, H]
                 # per-slot LAST LIVE row: a prefill chunk's next-token
                 # logits / the decode token's next logits — one gather,
                 # then the lm head over [B, 1, H] only (never every
                 # row: the head over them would dominate)
-                new_logits = model._logits(Tensor(
-                    hidden[rows.last()][:, None]))._value[:, 0] \
+                with scope("pt.pack"):
+                    last_rows = Tensor(hidden[rows.last()][:, None])
+                new_logits = model._logits(last_rows)._value[:, 0] \
                     .astype(jnp.float32)
                 if spec_ks is not None:
                     # verify slots need PER-ROW logits over the window
@@ -1882,14 +1900,17 @@ class LLMEngine:
                     hidden.astype(jnp.float32))
             kb, vb = unpack_kv(new_caches)
             if spec_ks is None:
-                new_logits = jnp.where(active[:, None], new_logits, logits)
-                new_lens = lens + q_eff
-                # [1, B] token/activity rows: the readout walk in
-                # step_finish is shared with the scan-based steps (K==1)
-                return (_pin_rep(nxt[None]), _pin_rep(dec[None]),
-                        _pin_rep(new_logits), _pin_kv(kb), _pin_kv(vb),
-                        _pin_rep(new_lens), rng, pooled,
-                        sum(counted, ctr_zero) if n_ctr else None)
+                with scope("pt.readout"):
+                    new_logits = jnp.where(active[:, None], new_logits,
+                                           logits)
+                    new_lens = lens + q_eff
+                    # [1, B] token/activity rows: the readout walk in
+                    # step_finish is shared with the scan-based steps
+                    # (K==1)
+                    return (_pin_rep(nxt[None]), _pin_rep(dec[None]),
+                            _pin_rep(new_logits), _pin_kv(kb), _pin_kv(vb),
+                            _pin_rep(new_lens), rng, pooled,
+                            sum(counted, ctr_zero) if n_ctr else None)
             counts, _, spec_logits = verify_window(
                 logits_win, draft, lens, q_eff, rng, temps, top_ps,
                 rids, dec)
@@ -4234,7 +4255,7 @@ class LLMEngine:
         rows = self.B * (self.speculative_k if spec else 1)
         t0 = self._to("dispatch", **self._dispatch_ids(
             "spec" if spec else "decode", rows * k_iter,
-            int(active.sum()) * k_iter))
+            int(active.sum()) * k_iter, active))
         counts = ctr_dev = None
         if use_multi:
             fn = self._multi_fn(stride)
@@ -4426,7 +4447,8 @@ class LLMEngine:
                 self._fence_blocks(int(b), lo, hi, fenced)
 
         t0 = self._to("dispatch", **self._dispatch_ids(
-            "spec", self.B * Kw * stride, int(spec_qs.sum()) * stride))
+            "spec", self.B * Kw * stride, int(spec_qs.sum()) * stride,
+            active))
         fn = self._multi_spec_fn(stride)
         if paged:
             with self._kernel_tp_ctx():
@@ -4663,7 +4685,8 @@ class LLMEngine:
         tiles = self._attn_tile_steps(q_lens) \
             if self.cache_impl == "paged" and self._kv_pools else None
         t0 = self._to("dispatch", **self._dispatch_ids(
-            "mixed", self.mixed_rows, int(q_lens.sum()),
+            "mixed", self.mixed_rows, int(q_lens.sum()), q_lens > 0,
+            prefill_rows=int(q_lens[~is_dec].sum()),
             live_tiles=tiles and tiles[0]))
         if self.cache_impl == "paged":
             with self._kernel_tp_ctx():

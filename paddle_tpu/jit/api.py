@@ -23,7 +23,7 @@ from ..core.tensor import Tensor, functional_mode, no_grad
 from ..core import random as _random
 from ..nn.layer_base import Layer
 from ..optimizer.optimizer import stored_placements
-from ..profiler import span
+from ..profiler import scope, span
 from .functional_call import collect_state, bind_state, read_values
 
 
@@ -552,9 +552,10 @@ class TrainStep:
 
             (loss_val, new_bufs), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(param_vals)
-            new_pv, new_slots = opt.apply_updates(
-                param_vals, grads, slot_vals, lr, step_i, decay_flags,
-                fused_ctx=fused_ctx)
+            with scope("pt.optimizer"):
+                new_pv, new_slots = opt.apply_updates(
+                    param_vals, grads, slot_vals, lr, step_i, decay_flags,
+                    fused_ctx=fused_ctx)
             return loss_val, new_pv, new_slots, new_bufs
 
         donate = (0, 1, 2) if self.donate else ()
@@ -675,9 +676,10 @@ class TrainStep:
                         size *= s
                     a = jnp.reshape(a[:size], shp)
                 grads.append(a / K)
-            return opt.apply_updates(param_vals, grads, slot_vals, lr,
-                                     step_i, decay_flags,
-                                     fused_ctx=fused_ctx)
+            with scope("pt.optimizer"):
+                return opt.apply_updates(param_vals, grads, slot_vals, lr,
+                                         step_i, decay_flags,
+                                         fused_ctx=fused_ctx)
 
         donate = (0, 1, 2) if self.donate else (2,)
         return jax.jit(update_fn, donate_argnums=donate)

@@ -45,6 +45,7 @@ from ..nn import Layer, Linear, RMSNorm
 from ..nn.initializer import Constant, Normal
 from ..core.tensor import dispatch
 from ..ops.kernels import kda as _kda
+from ..profiler import scope
 from . import cache_layout as CL
 from .latent_moe import (F32, DecoderBlock, LatentAttention, SparseMoE,
                          StateCausalLM, StateDecoder, SwiGLU, live_rows, mm,
@@ -132,35 +133,46 @@ class KimiDeltaAttention(Layer):
             # every projection on x's own rows: [B, S] or, in a mixed
             # step, the packed [1, T]
             lead = x.shape[:2]
-            qkv = jnp.concatenate([mm(x, wq), mm(x, wk), mm(x, wv)], -1)
-            g = -jnp.exp(alog.astype(F32))[:, None] * jax.nn.softplus(
-                (mm32(mm(x, wfa), wfb) + dtb.astype(F32))
-                .reshape(lead + (H, K)))
-            beta = jax.nn.sigmoid(mm32(x, wb))
-            gate = jax.nn.sigmoid(mm32(mm(x, wga), wgb)) \
-                .reshape(lead + (H, K))
+            with scope("qkv_proj"):
+                qkv = jnp.concatenate([mm(x, wq), mm(x, wk), mm(x, wv)], -1)
+            with scope("pt.gate"):
+                g = -jnp.exp(alog.astype(F32))[:, None] * jax.nn.softplus(
+                    (mm32(mm(x, wfa), wfb) + dtb.astype(F32))
+                    .reshape(lead + (H, K)))
+                beta = jax.nn.sigmoid(mm32(x, wb))
+                gate = jax.nn.sigmoid(mm32(mm(x, wga), wgb)) \
+                    .reshape(lead + (H, K))
+            with scope("pt.view"):
+                if rows is not None:
+                    # the per-slot view, around the convolution's tail
+                    # and the recurrence only
+                    qkv, g, beta = (rows.to_slots(a[0])
+                                    for a in (qkv, g, beta))
+                b, s = qkv.shape[:2]
+                fresh = (lens.astype(jnp.int32) == 0)
+                S = jnp.where(fresh[:, None, None, None], 0.0, S)
+                tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail),
+                                 tail)
+                live = live_rows(q_lens, s)
+            with scope("pt.conv"):
+                y, tail = _kda.causal_conv(
+                    qkv, tail, jnp.concatenate([cq, ck, cv], -1), q_lens)
+                y = jax.nn.silu(y).reshape(b, s, 3, H, K)
+                q = _l2(y[:, :, 0]) * jnp.float32(K ** -0.5)
+                k, v = _l2(y[:, :, 1]), y[:, :, 2]
+            with scope("pt.gate"):
+                g = jnp.where(live[:, :, None, None], g, 0.0)
+                beta = jnp.where(live[:, :, None], beta, 0.0)
+            with scope("pt.core"):
+                run = _kda.kda_recurrent if s == 1 else _kda.kda_chunk
+                o, S = run(q, k, v, g, beta, S)
             if rows is not None:
-                # the per-slot view, around the convolution's tail and
-                # the recurrence only
-                qkv, g, beta = (rows.to_slots(a[0]) for a in (qkv, g, beta))
-            b, s = qkv.shape[:2]
-            fresh = (lens.astype(jnp.int32) == 0)
-            S = jnp.where(fresh[:, None, None, None], 0.0, S)
-            tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
-            live = live_rows(q_lens, s)
-            y, tail = _kda.causal_conv(
-                qkv, tail, jnp.concatenate([cq, ck, cv], -1), q_lens)
-            y = jax.nn.silu(y).reshape(b, s, 3, H, K)
-            q = _l2(y[:, :, 0]) * jnp.float32(K ** -0.5)
-            k, v = _l2(y[:, :, 1]), y[:, :, 2]
-            g = jnp.where(live[:, :, None, None], g, 0.0)
-            beta = jnp.where(live[:, :, None], beta, 0.0)
-            run = _kda.kda_recurrent if s == 1 else _kda.kda_chunk
-            o, S = run(q, k, v, g, beta, S)
-            if rows is not None:
-                o = rows.from_slots(o)[None]
-            o = (rms(o, on, eps) * gate).astype(x.dtype)
-            return mm(o.reshape(lead + (H * K,)), wo), S, tail
+                with scope("pt.view"):
+                    o = rows.from_slots(o)[None]
+            with scope("pt.gate"):
+                o = (rms(o, on, eps) * gate).astype(x.dtype)
+            with scope("o_proj"):
+                return mm(o.reshape(lead + (H * K,)), wo), S, tail
 
         st = cache.state
         out, S, tail = dispatch(

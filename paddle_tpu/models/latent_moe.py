@@ -34,6 +34,7 @@ from ..nn.initializer import Constant, Normal
 from ..core.tensor import Tensor, dispatch
 from ..ops.kernels import latent_attention as _lat
 from ..ops.kernels import moe_dropless as _moe
+from ..profiler import scope
 from . import cache_layout as CL
 
 F32 = jnp.float32
@@ -111,40 +112,55 @@ class LatentAttention(Layer):
             # projections on x's own rows ([B, S], or a mixed step's
             # packed [1, T]); the per-slot view around the pool only
             lead = x.shape[:2]
-            if len(wq) == 1:
-                q = mm(x, wq[0])
-            else:
-                q = mm(rms(mm(x, wq[0]), wq[1], eps).astype(x.dtype),
-                        wq[2])
-            q = q.reshape(lead + (H, dn + dp))
-            kv = mm(x, wkva)
-            c = rms(kv[..., :r], nw, eps).astype(x.dtype)
-            k_pe = kv[..., r:]
+            with scope("q_proj"):
+                if len(wq) == 1:
+                    q = mm(x, wq[0])
+                else:
+                    q = mm(rms(mm(x, wq[0]), wq[1], eps).astype(x.dtype),
+                            wq[2])
+                q = q.reshape(lead + (H, dn + dp))
+            with scope("kv_a_proj"):
+                kv = mm(x, wkva)
+                c = rms(kv[..., :r], nw, eps).astype(x.dtype)
+                k_pe = kv[..., r:]
             if rotary is not None:
-                pos = rows.pos[None] if rows is not None else (
-                    lens.astype(jnp.int32)[:, None]
-                    + jnp.arange(lead[1], dtype=jnp.int32)[None, :])
-                k_pe = rotary(k_pe, pos).astype(x.dtype)
-            entry = jnp.concatenate([c, k_pe], -1)
+                with scope("pt.rope"):
+                    pos = rows.pos[None] if rows is not None else (
+                        lens.astype(jnp.int32)[:, None]
+                        + jnp.arange(lead[1], dtype=jnp.int32)[None, :])
+                    k_pe = rotary(k_pe, pos).astype(x.dtype)
+            with scope("pt.view"):
+                entry = jnp.concatenate([c, k_pe], -1)
             wkvb = wkvb.reshape(r, H, dn + dv)
-            # absorbed: q_nope through the key half into the latent's width
-            q_abs = jnp.einsum("bshn,chn->bshc", q[..., :dn], wkvb[..., :dn],
-                               preferred_element_type=F32)
+            with scope("kv_b_proj"):
+                # absorbed: q_nope through the key half into the latent's
+                # width
+                q_abs = jnp.einsum("bshn,chn->bshc", q[..., :dn],
+                                   wkvb[..., :dn],
+                                   preferred_element_type=F32)
             q_pe = q[..., dn:].astype(F32)
             if rotary is not None:
-                q_pe = rotary(q_pe, pos)
-            qc = (jnp.concatenate([q_abs, q_pe], -1) *
-                  jnp.float32(scale)).astype(x.dtype)
+                with scope("pt.rope"):
+                    q_pe = rotary(q_pe, pos)
+            with scope("pt.view"):
+                qc = (jnp.concatenate([q_abs, q_pe], -1) *
+                      jnp.float32(scale)).astype(x.dtype)
+                if rows is not None:
+                    entry, qc = rows.to_slots(entry[0]), \
+                        rows.to_slots(qc[0])
+                pool = _lat.latent_pool_write(pool, entry, tables, lens,
+                                              q_lens)
+            with scope("pt.core"):
+                o = _lat.latent_attention_append(
+                    qc, pool, tables, lens, q_lens, r)
             if rows is not None:
-                entry, qc = rows.to_slots(entry[0]), rows.to_slots(qc[0])
-            pool = _lat.latent_pool_write(pool, entry, tables, lens, q_lens)
-            o = _lat.latent_attention_append(
-                qc, pool, tables, lens, q_lens, r)
-            if rows is not None:
-                o = rows.from_slots(o)[None]
-            o = jnp.einsum("bshc,chv->bshv", o, wkvb[..., dn:],
-                           preferred_element_type=F32).astype(x.dtype)
-            return mm(o.reshape(lead + (H * dv,)), wo), pool
+                with scope("pt.view"):
+                    o = rows.from_slots(o)[None]
+            with scope("kv_b_proj"):
+                o = jnp.einsum("bshc,chv->bshv", o, wkvb[..., dn:],
+                               preferred_element_type=F32).astype(x.dtype)
+            with scope("o_proj"):
+                return mm(o.reshape(lead + (H * dv,)), wo), pool
 
         out, pool = dispatch(
             fn, (x, cache.pool, cache.block_tables, cache.seq_lens,
@@ -235,12 +251,16 @@ class SparseMoE(Layer):
             else:
                 live = live_rows(q_lens, s).reshape(n)
             xf = x.reshape(n, h)
-            idx, w = _moe.route(xf, wr, bias, k, scale, **routing)
+            with scope("pt.route"):
+                idx, w = _moe.route(xf, wr, bias, k, scale, **routing)
             rows = (budget or n) * min(k, held)
             y, counts = _moe.held_expert_ffn(
                 xf, idx, w, live, wg, wu, wd, offset, rows)
-            out = swiglu(xf, sg, su, sd).astype(F32) + y
-            return out.astype(x.dtype).reshape(b, s, h), counts
+            with scope("pt.shared"):
+                shared = swiglu(xf, sg, su, sd).astype(F32)
+            with scope("pt.combine"):
+                out = shared + y
+                return out.astype(x.dtype).reshape(b, s, h), counts
 
         sh = self.shared_experts
         out, counts = dispatch(

@@ -28,6 +28,7 @@ from ..core.tensor import Tensor, dispatch, functional_mode
 from ..jit.functional_call import stored_sharding
 from .lora import active_lora
 from .cache_layout import packed
+from ..profiler import scope
 from .. import ops
 
 
@@ -378,30 +379,34 @@ class LlamaAttention(Layer):
 
         def o_proj(t):
             if rows is not None:
-                t = dispatch(lambda y: rows.from_slots(y)[None], (t,), {},
-                             name="rows_from_slots")
+                with scope("pt.view"):
+                    t = dispatch(lambda y: rows.from_slots(y)[None], (t,),
+                                 {}, name="rows_from_slots")
             out = self.o_proj(t)
             if lora is not None:
                 out = lora.apply("o_proj", self.layer_idx, t, out)
             return out
         cos, sin = rope_cache
-        if isinstance(position_offset, Tensor):
-            # traced offset (static-shape decode): the offset is a dispatch
-            # ARGUMENT, so every step shares one compiled entry
-            q, k = dispatch(
-                lambda qq, kk, off: apply_rope(qq, kk, cos, sin,
-                                               off.astype(jnp.int32)),
-                (q, k, position_offset), {}, name="rope_offset")
-        else:
-            q, k = dispatch(
-                lambda qq, kk: apply_rope(qq, kk, cos, sin, position_offset),
-                (q, k), {}, name="rope")
+        with scope("pt.rope"):
+            if isinstance(position_offset, Tensor):
+                # traced offset (static-shape decode): the offset is a
+                # dispatch ARGUMENT, so every step shares one compiled entry
+                q, k = dispatch(
+                    lambda qq, kk, off: apply_rope(qq, kk, cos, sin,
+                                                   off.astype(jnp.int32)),
+                    (q, k, position_offset), {}, name="rope_offset")
+            else:
+                q, k = dispatch(
+                    lambda qq, kk: apply_rope(qq, kk, cos, sin,
+                                              position_offset),
+                    (q, k), {}, name="rope")
         if rows is not None:
             # what is above ran on the granted rows; o_proj takes the
             # attention's output back to them
-            q, k, v = dispatch(
-                lambda *ts: tuple(rows.to_slots(t[0]) for t in ts),
-                (q, k, v), {}, name="rows_to_slots")
+            with scope("pt.view"):
+                q, k, v = dispatch(
+                    lambda *ts: tuple(rows.to_slots(t[0]) for t in ts),
+                    (q, k, v), {}, name="rows_to_slots")
             b, s = q.shape[0], q.shape[1]
         if isinstance(kv_cache, PagedKVCache):
             # paged decode step (one new token/sequence) through the
@@ -422,13 +427,16 @@ class LlamaAttention(Layer):
                     raise ValueError(
                         "PagedKVCache with seq len > 1 is the fused "
                         "append step and needs per-slot q_lens")
-                qkv = ops.concat([ops.reshape(q, [b, s, H * D]),
-                                  ops.reshape(k, [b, s, Hkv * D]),
-                                  ops.reshape(v, [b, s, Hkv * D])], axis=-1)
-                outs = IF.block_multihead_attention(
-                    qkv, kv_cache.k, kv_cache.v, None, kv_cache.seq_lens,
-                    kv_cache.q_lens, block_tables=kv_cache.block_tables,
-                    **qargs)
+                with scope("pt.view"):
+                    qkv = ops.concat([ops.reshape(q, [b, s, H * D]),
+                                      ops.reshape(k, [b, s, Hkv * D]),
+                                      ops.reshape(v, [b, s, Hkv * D])],
+                                     axis=-1)
+                with scope("pt.core"):
+                    outs = IF.block_multihead_attention(
+                        qkv, kv_cache.k, kv_cache.v, None,
+                        kv_cache.seq_lens, kv_cache.q_lens,
+                        block_tables=kv_cache.block_tables, **qargs)
                 out, kc, vc = outs[:3]
                 ks, vs = outs[3:] if kvq else (None, None)
                 out = o_proj(ops.reshape(out, [b, s, H * D]))
@@ -436,12 +444,14 @@ class LlamaAttention(Layer):
                     kc, vc, kv_cache.block_tables,
                     kv_cache.seq_lens + kv_cache.q_lens, kv_cache.q_lens,
                     k_scale=ks, v_scale=vs, quant=kvq)
-            qkv = ops.concat([ops.reshape(q, [b, H * D]),
-                              ops.reshape(k, [b, Hkv * D]),
-                              ops.reshape(v, [b, Hkv * D])], axis=-1)
-            outs = IF.block_multihead_attention(
-                qkv, kv_cache.k, kv_cache.v, None, kv_cache.seq_lens, None,
-                block_tables=kv_cache.block_tables, **qargs)
+            with scope("pt.view"):
+                qkv = ops.concat([ops.reshape(q, [b, H * D]),
+                                  ops.reshape(k, [b, Hkv * D]),
+                                  ops.reshape(v, [b, Hkv * D])], axis=-1)
+            with scope("pt.core"):
+                outs = IF.block_multihead_attention(
+                    qkv, kv_cache.k, kv_cache.v, None, kv_cache.seq_lens,
+                    None, block_tables=kv_cache.block_tables, **qargs)
             out, kc, vc = outs[:3]
             ks, vs = outs[3:] if kvq else (None, None)
             out = o_proj(ops.reshape(out, [b, 1, H * D]))
@@ -471,16 +481,18 @@ class LlamaAttention(Layer):
                 return (jax.vmap(upd)(kb, kk, pos),
                         jax.vmap(upd)(vb, vv, pos))
 
-            k_buf, v_buf = dispatch(
-                chunk_write,
-                (kv_cache.k, kv_cache.v, k, v, kv_cache.lens,
-                 kv_cache.q_lens), {}, name="chunk_kv_update")
-            T = k_buf.shape[1]
-            mask = dispatch(_window_causal_mask(s, T), (kv_cache.lens,),
-                            {}, name="chunk_decode_mask")
-            out = F.scaled_dot_product_attention(
-                q, k_buf, v_buf, attn_mask=mask, is_causal=False,
-                training=self.training)
+            with scope("pt.view"):
+                k_buf, v_buf = dispatch(
+                    chunk_write,
+                    (kv_cache.k, kv_cache.v, k, v, kv_cache.lens,
+                     kv_cache.q_lens), {}, name="chunk_kv_update")
+                T = k_buf.shape[1]
+                mask = dispatch(_window_causal_mask(s, T), (kv_cache.lens,),
+                                {}, name="chunk_decode_mask")
+            with scope("pt.core"):
+                out = F.scaled_dot_product_attention(
+                    q, k_buf, v_buf, attn_mask=mask, is_causal=False,
+                    training=self.training)
             out = ops.reshape(out, [b, s, self.num_heads * self.head_dim])
             return o_proj(out), ChunkKVCache(
                 k_buf, v_buf, kv_cache.lens, kv_cache.q_lens)
@@ -496,15 +508,17 @@ class LlamaAttention(Layer):
                                     buf, new.astype(buf.dtype), o, 0))
                 return upd1(kb, kk, lens), upd1(vb, vv, lens)
 
-            k_buf, v_buf = dispatch(
-                slot_step, (kv_cache.k, kv_cache.v, k, v, kv_cache.lens), {},
-                name="slot_kv_update")
-            T = k_buf.shape[1]
-            mask = dispatch(_window_causal_mask(s, T), (kv_cache.lens,),
-                            {}, name="slot_decode_mask")
-            out = F.scaled_dot_product_attention(
-                q, k_buf, v_buf, attn_mask=mask, is_causal=False,
-                training=self.training)
+            with scope("pt.view"):
+                k_buf, v_buf = dispatch(
+                    slot_step, (kv_cache.k, kv_cache.v, k, v, kv_cache.lens),
+                    {}, name="slot_kv_update")
+                T = k_buf.shape[1]
+                mask = dispatch(_window_causal_mask(s, T), (kv_cache.lens,),
+                                {}, name="slot_decode_mask")
+            with scope("pt.core"):
+                out = F.scaled_dot_product_attention(
+                    q, k_buf, v_buf, attn_mask=mask, is_causal=False,
+                    training=self.training)
             out = ops.reshape(out, [b, s, self.num_heads * self.head_dim])
             return o_proj(out), SlotKVCache(k_buf, v_buf, kv_cache.lens)
         if isinstance(kv_cache, StaticKVCache):
@@ -512,10 +526,11 @@ class LlamaAttention(Layer):
                 return jax.lax.dynamic_update_slice_in_dim(
                     buf, new.astype(buf.dtype), off.astype(jnp.int32), 1)
 
-            k_buf = dispatch(upd, (kv_cache.k, k, position_offset), {},
-                             name="kv_update")
-            v_buf = dispatch(upd, (kv_cache.v, v, position_offset), {},
-                             name="kv_update")
+            with scope("pt.view"):
+                k_buf = dispatch(upd, (kv_cache.k, k, position_offset), {},
+                                 name="kv_update")
+                v_buf = dispatch(upd, (kv_cache.v, v, position_offset), {},
+                                 name="kv_update")
             T = k_buf.shape[1]
 
             def make_mask(off):
@@ -528,18 +543,20 @@ class LlamaAttention(Layer):
                     <= rows[None, None, :, None]
                 return jnp.where(valid, jnp.float32(0), jnp.float32(-1e30))
 
-            mask = dispatch(make_mask, (position_offset,), {},
-                            name="kv_decode_mask")
-            out = F.scaled_dot_product_attention(
-                q, k_buf, v_buf, attn_mask=mask, is_causal=False,
-                training=self.training)
+            with scope("pt.view"):
+                mask = dispatch(make_mask, (position_offset,), {},
+                                name="kv_decode_mask")
+            with scope("pt.core"):
+                out = F.scaled_dot_product_attention(
+                    q, k_buf, v_buf, attn_mask=mask, is_causal=False,
+                    training=self.training)
             out = ops.reshape(out, [b, s, self.num_heads * self.head_dim])
             return o_proj(out), StaticKVCache(k_buf, v_buf)
         if kv_cache is not None:
             k = ops.concat([kv_cache[0], k], axis=1)
             v = ops.concat([kv_cache[1], v], axis=1)
             kv_cache = (k, v)
-        with flash_tp_context(self._heads_tp()):
+        with flash_tp_context(self._heads_tp()), scope("pt.core"):
             out = F.scaled_dot_product_attention(
                 q, k, v, attn_mask=attn_mask, is_causal=(attn_mask is None),
                 training=self.training)
@@ -689,8 +706,9 @@ class LlamaForCausalLM(Layer):
 
     def _logits(self, hidden):
         if self.config.tie_word_embeddings:
-            return ops.matmul(hidden, self.llama.embed_tokens.weight,
-                              transpose_y=True)
+            with scope("lm_head"):
+                return ops.matmul(hidden, self.llama.embed_tokens.weight,
+                                  transpose_y=True)
         return self.lm_head(hidden)
 
     def forward(self, input_ids, labels=None, attn_mask=None):
@@ -698,11 +716,12 @@ class LlamaForCausalLM(Layer):
         logits = self._logits(hidden)
         if labels is None:
             return logits
-        loss = F.cross_entropy(
-            # no fp32 pre-cast: cross_entropy's fused path accumulates
-            # the lse in fp32 internally without copying the logits
-            ops.reshape(logits, [-1, self.config.vocab_size]),
-            ops.reshape(labels, [-1]), ignore_index=-100)
+        with scope("pt.loss"):
+            loss = F.cross_entropy(
+                # no fp32 pre-cast: cross_entropy's fused path accumulates
+                # the lse in fp32 internally without copying the logits
+                ops.reshape(logits, [-1, self.config.vocab_size]),
+                ops.reshape(labels, [-1]), ignore_index=-100)
         return loss, logits
 
     def _gen_programs(self, B, prompt_len, limit, total, temperature, top_k,

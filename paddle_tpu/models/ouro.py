@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
 from ..core.tensor import Tensor, functional_mode
+from ..profiler import scope
 from . import cache_layout as CL
 from .cache_layout import _val
 from .llama import LlamaAttention, LlamaMLP, PagedKVCache, precompute_rope
@@ -139,15 +140,18 @@ class OuroDecoder(Layer):
                     if cached:
                         ks[i], vs[i] = _val(new.k), _val(new.v)
                 x = self.norm(x)
-                gate = jax.nn.sigmoid(
-                    self.early_exit_gate(x)._value[..., 0]
-                    .astype(jnp.float32))
+                with scope("pt.exit"):
+                    gate = jax.nn.sigmoid(
+                        self.early_exit_gate(x)._value[..., 0]
+                        .astype(jnp.float32))
             return (x._value, (tuple(ks), tuple(vs))), gate
 
         (x, (ks, vs)), gates = jax.lax.scan(
             step, (x, pools), jnp.arange(steps, dtype=jnp.int32))
         first = kv_caches[0] if cached else None
-        CL.count(_exit_counts(gates, _live_rows(first, gates.shape[1:])))
+        with scope("pt.exit"):
+            CL.count(_exit_counts(gates, _live_rows(first,
+                                                    gates.shape[1:])))
         if not cached:
             return Tensor(x)
         return Tensor(x), [

@@ -54,7 +54,7 @@ class LayerList(Layer):
     def __setitem__(self, idx, layer):
         if idx < 0:
             idx += len(self)
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -71,7 +71,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for l in layers:

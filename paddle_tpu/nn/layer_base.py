@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..core import dtype as dtypes
 from ..core.tensor import Tensor
+from ..profiler import scope
 
 _GLOBAL_WEIGHT_INIT = None
 _GLOBAL_BIAS_INIT = None
@@ -126,6 +127,10 @@ class Layer:
         d(self, "_forward_post_hooks", OrderedDict())
         d(self, "_hook_id", 0)
         d(self, "_name_scope", name_scope or type(self).__name__.lower())
+        #: the token this layer's operations carry in a device profile
+        #: (``profiler.scope``): the name its FIRST parent registered it
+        #: under; None (a root, an unregistered layer) reads as the class
+        d(self, "_scope_name", None)
 
     # -- attribute routing ---------------------------------------------------
     def __setattr__(self, name, value):
@@ -139,7 +144,7 @@ class Layer:
                 return
             if isinstance(value, Layer):
                 self.__dict__.pop(name, None)
-                self._sub_layers[name] = value
+                self.add_sublayer(name, value)
                 return
         object.__setattr__(self, name, value)
 
@@ -195,6 +200,9 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None and \
+                sublayer.__dict__.get("_scope_name") is None:
+            object.__setattr__(sublayer, "_scope_name", str(name))
         return sublayer
 
     def add_parameter(self, name, parameter):
@@ -373,16 +381,19 @@ class Layer:
         raise NotImplementedError
 
     def __call__(self, *args, **kwargs):
-        for hook in self._forward_pre_hooks.values():
-            res = hook(self, args)
-            if res is not None:
-                args = res if isinstance(res, tuple) else (res,)
-        out = self.forward(*args, **kwargs)
-        for hook in self._forward_post_hooks.values():
-            res = hook(self, args, out)
-            if res is not None:
-                out = res
-        return out
+        # the model tree rides on every operation lowered below as one
+        # token a layer (forward and backward): trace-time metadata only
+        with scope(self.__dict__.get("_scope_name") or type(self).__name__):
+            for hook in self._forward_pre_hooks.values():
+                res = hook(self, args)
+                if res is not None:
+                    args = res if isinstance(res, tuple) else (res,)
+            out = self.forward(*args, **kwargs)
+            for hook in self._forward_post_hooks.values():
+                res = hook(self, args, out)
+                if res is not None:
+                    out = res
+            return out
 
     def clear_gradients(self):
         for p in self.parameters():

@@ -229,8 +229,8 @@ def quantize_linears_for_inference(layer, weight_dtype="int8",
         for name, sub in list(l._sub_layers.items()):
             qual = f"{prefix}{name}"
             if isinstance(sub, _common.Linear) and not skip(qual, sub):
-                l._sub_layers[name] = WeightOnlyLinear(
-                    sub, weight_dtype=weight_dtype)
+                l.add_sublayer(name, WeightOnlyLinear(
+                    sub, weight_dtype=weight_dtype))
                 n[0] += 1
             elif isinstance(sub, Layer):
                 visit(sub, qual + ".")

@@ -32,6 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...profiler import scope
 from . import grouped_expert_matmul as _gmm
 
 HI = jax.lax.Precision.HIGHEST
@@ -83,19 +84,22 @@ def held_expert_ffn(x, idx, w, live, w_gate, w_up, w_down, offset, rows):
     e, h, f = w_gate.shape
     tm = _gmm.row_tile(rows, h, f, e, x.dtype)
     rows = int(min(-(-rows // tm) * tm, n * k))
-    local = idx - jnp.int32(offset)
-    held = live[:, None] & (local >= 0) & (local < e)
-    key = jnp.where(held, local, e).reshape(-1)           # e sorts last
-    order = jnp.argsort(key, stable=True)[:rows]
-    sizes = jnp.zeros((e,), jnp.int32).at[key].add(1, mode="drop")
-    n_held = jnp.sum(sizes)
-    token = order // k
-    xs = jnp.take(x, token, axis=0)
-    out = _gmm.grouped_expert_ffn(xs, w_gate, w_up, w_down, sizes, tm)
-    valid = jnp.arange(rows, dtype=jnp.int32) < n_held
-    ws = jnp.where(valid, jnp.take(w.reshape(-1), order), 0.0)
-    y = jnp.zeros((n, x.shape[1]), jnp.float32).at[token].add(
-        jnp.where(valid[:, None], out, 0.0) * ws[:, None])
+    with scope("pt.dispatch"):
+        local = idx - jnp.int32(offset)
+        held = live[:, None] & (local >= 0) & (local < e)
+        key = jnp.where(held, local, e).reshape(-1)       # e sorts last
+        order = jnp.argsort(key, stable=True)[:rows]
+        sizes = jnp.zeros((e,), jnp.int32).at[key].add(1, mode="drop")
+        n_held = jnp.sum(sizes)
+        token = order // k
+        xs = jnp.take(x, token, axis=0)
+    with scope("pt.experts"):
+        out = _gmm.grouped_expert_ffn(xs, w_gate, w_up, w_down, sizes, tm)
+    with scope("pt.combine"):
+        valid = jnp.arange(rows, dtype=jnp.int32) < n_held
+        ws = jnp.where(valid, jnp.take(w.reshape(-1), order), 0.0)
+        y = jnp.zeros((n, x.shape[1]), jnp.float32).at[token].add(
+            jnp.where(valid[:, None], out, 0.0) * ws[:, None])
     counts = jnp.stack([
         jnp.sum(live).astype(jnp.int32) * k, n_held,
         jnp.int32(rows), jnp.max(sizes),

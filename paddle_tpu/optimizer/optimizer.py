@@ -23,6 +23,7 @@ from ..core.tensor import Tensor, no_grad
 from ..nn.layer_base import Parameter
 from .clip import ClipGradBase, ClipGradByGlobalNorm
 from .lr import LRScheduler
+from ..profiler import scope
 
 
 def _is_low_precision(dtype):
@@ -114,7 +115,8 @@ class Optimizer:
         pipeline step, ``step()``), overridden per param by the ZeRO
         wrapper's own plans."""
         if self._grad_clip is not None:
-            grads = self._grad_clip.apply(vals, grads)
+            with scope("pt.clip"):
+                grads = self._grad_clip.apply(vals, grads)
         fused = getattr(self, "_apply_fused", None)
         fused_takes_pid = self.__dict__.get("_fused_takes_param_id")
         if fused is not None and fused_takes_pid is None:

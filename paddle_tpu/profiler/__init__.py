@@ -16,7 +16,7 @@ import threading
 import time
 from enum import Enum
 
-from ._span import span  # noqa: F401
+from ._span import scope, span  # noqa: F401
 from .timer import benchmark  # noqa: F401
 from .serving_telemetry import (  # noqa: F401
     LABELED_GAUGE_FAMILIES, LatencyHistogram, ServingTelemetry)
@@ -32,7 +32,7 @@ from .slo import (  # noqa: F401
     SLO, SLOEngine, default_detectors, evaluate_slo, format_slo_report)
 
 __all__ = [
-    "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent", "span",
+    "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent", "span", "scope",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
     "SummaryView", "benchmark", "merge_profile",
     "ServingTelemetry", "LatencyHistogram", "LABELED_GAUGE_FAMILIES",

@@ -526,7 +526,7 @@ def _swap_sublayers(model, swap_fn):
     for name, child in list(model._sub_layers.items()):
         replaced = swap_fn(name, child)
         if replaced is not None:
-            model._sub_layers[name] = replaced
+            model.add_sublayer(name, replaced)
         else:
             _swap_sublayers(child, swap_fn)
     return model

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from ..core import dtype as dtypes
+from ..profiler import scope
 
 __all__ = [
     "Program", "program_guard", "default_main_program", "default_startup_program",
@@ -86,7 +87,7 @@ def program_guard(main_program, startup_program=None):
 
 @contextlib.contextmanager
 def name_scope(prefix=None):
-    with jax.named_scope(prefix or "scope"):
+    with scope(prefix or "scope"):
         yield
 
 

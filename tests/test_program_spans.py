@@ -183,6 +183,25 @@ def test_a_dispatch_span_joins_the_flight_recorder_by_step_id(served):
         assert loop_pass.start - 2000 <= t_begin <= s.start + 2000
     assert sum(s.ids.get("live_tiles", 0) for s in disp.values()) == \
         stats["attn_tile_steps"] > 0
+    # the step's shape: its prefill and decode rows are its grants', and
+    # its context is what its granted requests held before it (no prefix
+    # cache, nothing preempted: every token a request holds was granted)
+    held = {}
+    for sid in sorted(disp):
+        s, r = disp[sid], recs[sid]
+        by_kind = {"prefill": 0, "decode": 0}
+        for _, _, kind, n in r.grants:
+            by_kind[kind] += n
+        assert (s.ids["prefill_rows"], s.ids["decode_rows"]) == \
+            (by_kind["prefill"], by_kind["decode"])
+        assert s.ids["ctx_tokens"] == sum(held.get(rid, 0)
+                                          for _, rid, _, _ in r.grants)
+        for _, rid, _, n in r.grants:
+            held[rid] = held.get(rid, 0) + n
+    assert sum(s.ids["prefill_rows"] for s in disp.values()) == \
+        stats["prefill_tokens"] > 0
+    assert sum(s.ids["decode_rows"] for s in disp.values()) >= \
+        stats["tokens_generated"] > 0
     # the sync and the emit of a step carry its id too
     for name in ("pt:engine.sync", "pt:engine.emit"):
         assert {s.ids["step_id"] for s in spans if s.name == name} \
