@@ -305,18 +305,24 @@ _TLS = threading.local()
 @contextlib.contextmanager
 def collect_counts():
     """Collects what the layers :func:`count` while the body runs (one
-    traced model call). Yields a list of int32 vectors; sum them."""
+    traced model call). Yields a list of int32 vectors, of one length
+    once the body has run; sum them."""
     prev = getattr(_TLS, "sink", None)
     _TLS.sink = sink = []
     try:
         yield sink
     finally:
         _TLS.sink = prev
+        # a layer counts a run of the names: every vector to the widest
+        width = max((v.shape[0] for v in sink), default=0)
+        sink[:] = [v if v.shape[0] == width
+                   else jnp.pad(v, (0, width - v.shape[0])) for v in sink]
 
 
-def count(vec):
+def count(vec, at=0):
     """Add one layer's counts (an int32 vector in the order of the model's
-    ``step_counter_names``) to the collecting dispatch, if there is one."""
+    ``step_counter_names``, from name ``at`` on; the names past its end
+    count 0) to the collecting dispatch, if there is one."""
     sink = getattr(_TLS, "sink", None)
     if sink is not None:
-        sink.append(vec)
+        sink.append(jnp.pad(vec, (at, 0)) if at else vec)

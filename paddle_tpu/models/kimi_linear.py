@@ -18,8 +18,9 @@ implementation):
 KDA, H heads of K = V: ``q~, k~, v~ = W x``; ``q, k, v = SiLU(conv4(.))``;
 ``q <- q/|q| K^-1/2``, ``k <- k/|k|``; ``g = -exp(A_log) softplus(W_fb
 W_fa x + dt_bias)`` a channel, ``beta = sigmoid(W_b x)`` a head; the
-recurrence of ``ops/kernels/kda.py``; ``y = W_o [RMSNorm_head(o) *
-sigmoid(W_gb W_ga x)]``.
+recurrence of ``ops/kernels/kda.py`` (a step of several rows a slot: the
+Pallas kernel ``ops/kernels/kda_chunk_walk.py`` over the live chunks);
+``y = W_o [RMSNorm_head(o) * sigmoid(W_gb W_ga x)]``.
 
 MLA (``models/latent_moe.py``'s layer, which the DeepSeek-V2 family
 shares, with one query projection, no rotation and the plain scale): ``q =
@@ -45,6 +46,7 @@ from ..nn import Layer, Linear, RMSNorm
 from ..nn.initializer import Constant, Normal
 from ..core.tensor import dispatch
 from ..ops.kernels import kda as _kda
+from ..ops.kernels import kda_chunk_walk as _walk
 from ..profiler import scope
 from . import cache_layout as CL
 from .latent_moe import (F32, DecoderBlock, LatentAttention, SparseMoE,
@@ -149,33 +151,44 @@ class KimiDeltaAttention(Layer):
                     qkv, g, beta = (rows.to_slots(a[0])
                                     for a in (qkv, g, beta))
                 b, s = qkv.shape[:2]
+                # the kernel walks a step's live chunks, and resets a
+                # fresh slot and masks the dead rows itself
+                walk = s > 1 and _walk.serves(K, K)
                 fresh = (lens.astype(jnp.int32) == 0)
-                S = jnp.where(fresh[:, None, None, None], 0.0, S)
+                if not walk:
+                    S = jnp.where(fresh[:, None, None, None], 0.0, S)
                 tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail),
                                  tail)
-                live = live_rows(q_lens, s)
+                live = None if walk else live_rows(q_lens, s)
             with scope("pt.conv"):
                 y, tail = _kda.causal_conv(
                     qkv, tail, jnp.concatenate([cq, ck, cv], -1), q_lens)
                 y = jax.nn.silu(y).reshape(b, s, 3, H, K)
                 q = _l2(y[:, :, 0]) * jnp.float32(K ** -0.5)
                 k, v = _l2(y[:, :, 1]), y[:, :, 2]
-            with scope("pt.gate"):
-                g = jnp.where(live[:, :, None, None], g, 0.0)
-                beta = jnp.where(live[:, :, None], beta, 0.0)
+            if not walk:
+                with scope("pt.gate"):
+                    g = jnp.where(live[:, :, None, None], g, 0.0)
+                    beta = jnp.where(live[:, :, None], beta, 0.0)
             with scope("pt.core"):
-                run = _kda.kda_recurrent if s == 1 else _kda.kda_chunk
-                o, S = run(q, k, v, g, beta, S)
+                if walk:
+                    o, S = _walk.kda_chunk_walk(q, k, v, g, beta, S, q_lens,
+                                                lens)
+                    counts = _walk.grid_counts(q_lens, s)
+                else:
+                    run = _kda.kda_recurrent if s == 1 else _kda.kda_chunk
+                    o, S = run(q, k, v, g, beta, S)
+                    counts = jnp.zeros((len(_walk.COUNTERS),), jnp.int32)
             if rows is not None:
                 with scope("pt.view"):
                     o = rows.from_slots(o)[None]
             with scope("pt.gate"):
                 o = (rms(o, on, eps) * gate).astype(x.dtype)
             with scope("o_proj"):
-                return mm(o.reshape(lead + (H * K,)), wo), S, tail
+                return mm(o.reshape(lead + (H * K,)), wo), S, tail, counts
 
         st = cache.state
-        out, S, tail = dispatch(
+        out, S, tail, counts = dispatch(
             fn, (x, st["S"], st["conv"], cache.seq_lens, cache.q_lens,
                  self.q_proj.weight, self.k_proj.weight, self.v_proj.weight,
                  self.q_conv, self.k_conv, self.v_conv,
@@ -183,6 +196,7 @@ class KimiDeltaAttention(Layer):
                  self.A_log, self.b_proj.weight, self.g_a_proj.weight,
                  self.g_b_proj.weight, self.o_norm.weight,
                  self.o_proj.weight), {}, name="kimi_kda")
+        CL.count(counts._value, at=len(StateCausalLM.step_counter_names))
         return out, CL.RecurrentCache({"S": S, "conv": tail}, cache.seq_lens,
                                       cache.q_lens, cache.row_budget, rows)
 
@@ -207,6 +221,9 @@ class KimiDecoderLayer(DecoderBlock):
 
 
 class KimiLinearForCausalLM(StateCausalLM):
+    #: the experts' counts, then the KDA kernel's grid
+    step_counter_names = StateCausalLM.step_counter_names + _walk.COUNTERS
+
     def __init__(self, config: KimiLinearConfig):
         super().__init__(config, StateDecoder(config, [
             KimiDecoderLayer(config, i)

@@ -1,8 +1,12 @@
 """Kimi Delta Attention's recurrence: a gated delta rule with a decay per
 key channel, in its one-token and its chunked form, and the short causal
-convolution in front of it. Plain ``jax.numpy`` on raw arrays (XLA on the
-chip and on the CPU alike); float32 state, ``highest`` matmul precision
-inside the recurrence.
+convolution in front of it. Plain ``jax.numpy`` on raw arrays; float32
+state, ``highest`` matmul precision inside the recurrence. On the chip a
+step of more than one row a slot runs the recurrence in the Pallas kernel
+beside this file (``kda_chunk_walk.py``: 64-row chunks, only a step's live
+ones); :func:`kda_recurrent` serves the one-row steps, and
+:func:`kda_chunk` is the form the kernel is held to by the tests and what
+a head width off the lanes (a toy model's) still takes.
 
 Per head, with state ``S`` [K, V], log-decay ``g_t`` [K] (<= 0), write
 strength ``beta_t`` and unit-norm ``k_t``::
@@ -27,8 +31,8 @@ chunk, with ``G_t`` the running sum of ``g`` from the chunk's start::
 Every exponent is <= 0 (``G`` only falls), so nothing overflows however
 strong the decay: ``A`` and ``B`` are formed elementwise over the channel
 axis and never as a product of ``exp(G)`` and ``exp(-G)``. That is why the
-chunk is short (16 rows: a [C, C, K] tensor a head); a longer chunk with
-secondary chunking is the fused kernel's business (ROADMAP Queue 1).
+chunk is short (16 rows: a [C, C, K] tensor a head); the longer chunk with
+secondary chunking is the kernel's (``kda_chunk_walk.py``).
 """
 from __future__ import annotations
 
