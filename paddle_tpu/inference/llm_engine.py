@@ -128,6 +128,16 @@ def default_engine_stats():
             # slots assigned into zeroed recurrent state (a layout with a
             # recurrent layer): admissions and preemption replays alike
             "state_resets": 0,
+            # device-side counts of a power-retention layer
+            # (ops/kernels/power_retention.py), summed over layers and
+            # steps: slot states the core read and wrote (every slot in a
+            # one-token step; in a mixed step every slot once for the
+            # one-row slots' pass and each slot with a chunk once more);
+            # those of them with a live row;
+            # live rows through the chunk form and through the one-token
+            # form
+            "ret_state_walked": 0, "ret_state_live": 0,
+            "ret_rows_chunk": 0, "ret_rows_step": 0,
             # a (seconds, count) pair: takes a slot -> first prefill
             # grant dispatched (accepted -> takes a slot is telemetry's
             # queue_wait_s histogram, the server's)
@@ -421,7 +431,9 @@ class LLMEngine:
     a layout with a paged latent pool or a recurrent state a slot is
     served by the fused scheduler over the paged allocator, and every
     option whose code assumes "state is a list of K/V blocks" refuses it
-    at construction (``_refuse_for_layout``)."""
+    at construction (``_refuse_for_layout``). A layout with NO paged
+    layer (every layer a recurrent state a slot) goes the same way with
+    nothing to page: see ``_has_paged``."""
 
     def __init__(self, model, max_batch=4, max_seq_len=None, chunk_size=64,
                  top_k=0, stream_callback=None, horizon=1, speculative_k=1,
@@ -557,6 +569,20 @@ class LLMEngine:
         self._kv_only = all(k.kind == "paged_kv" for k in self._layout)
         self._has_recurrent = any(k.kind == "recurrent"
                                   for k in self._layout)
+        #: some layer keeps its state in pool blocks. False (every layer
+        #: a fixed-size recurrent state a slot): NO pool is allocated and
+        #: admission is bounded by slots and ``max_seq_len`` alone. The
+        #: host allocator and the block tables are still built, as an
+        #: empty formality: ``max_batch x ceil(capacity / block_size)``
+        #: block ids that back no device memory, so every slot can always
+        #: cover its whole capacity, the pool never runs dry, nothing is
+        #: ever preempted for room, and the scheduler, the step programs'
+        #: ``tables`` argument and reset() stay the code every other
+        #: layout runs. ``kv_pool_blocks`` is refused (there is no pool
+        #: to size), ``kv_pool_nbytes()`` / ``kv_bytes_per_block()`` are
+        #: 0, the capacity need not be a multiple of the chunk, and the
+        #: pool's and the attention grid's counters book nothing.
+        self._has_paged = any(k.paged for k in self._layout)
         #: every layer's state is K and V pools that the paged kernels
         #: read, once a token or once a loop step
         self._kv_pools = all(k.kind in ("paged_kv", "paged_kv_looped")
@@ -577,7 +603,8 @@ class LLMEngine:
                 kv_host_swap=kv_host_swap,
                 kv_host_spill_bytes=kv_host_spill_bytes,
                 speculative_k=speculative_k, kv_cache_dtype=kv_cache_dtype,
-                adapter_store=adapter_store, horizon=horizon)
+                adapter_store=adapter_store, horizon=horizon,
+                kv_pool_blocks=kv_pool_blocks)
         self.B = int(max_batch)
         # decode horizon: tokens decoded per step() call as one compiled
         # lax.scan — amortizes the per-step host sync K-fold at the cost of
@@ -773,11 +800,11 @@ class LLMEngine:
             if self.chunk % self.block_size:
                 raise ValueError(f"chunk_size {self.chunk} must be a "
                                  f"multiple of block_size {self.block_size}")
-            if self.capacity % self.chunk:
+            if self.capacity % self.chunk and self._has_paged:
                 raise ValueError(f"capacity {self.capacity} must be a "
                                  f"multiple of chunk_size {self.chunk} "
                                  f"under paged KV")
-            self._max_blocks = self.capacity // self.block_size
+            self._max_blocks = -(-self.capacity // self.block_size)
             full = self.B * self._max_blocks
             self.n_blocks = int(kv_pool_blocks or full)
             #: pool-invariant debug audit (satellite): on under
@@ -876,22 +903,31 @@ class LLMEngine:
     def _refuse_for_layout(self, **opt):
         """A layout with a layer that is not plain paged K/V (a paged
         latent pool, a recurrent state a slot, K/V kept once a loop step;
-        a layout of latent pools alone too) is served by the fused
-        scheduler over the paged allocator, with ``readout_stride``,
-        pipelining and pool oversubscription (a preempted request replays
-        from its first token, as paged KV does). Every option whose code assumes "a slot's state is a list
+        a layout of latent pools alone or of recurrent states alone too)
+        is served by the fused scheduler over the paged allocator, with
+        ``readout_stride``, pipelining and pool oversubscription (a
+        preempted request replays from its first token, as paged KV
+        does). Every option whose code assumes "a slot's state is a list
         of K/V blocks" raises here, naming its mechanism, instead of
         serving a wrong token. Where the reason differs, the first is a
-        recurrent layer's (its state is in no block) and the second a
-        latent-only layout's (its state IS a list of blocks, of ONE pool
-        a layer, which that option's code does not read yet). A looped
-        layout has ONE reason for them all (:meth:`_looped_reason`)."""
+        recurrent layer's beside a pool (its state is in no block), the
+        second a latent-only layout's (its state IS a list of blocks, of
+        ONE pool a layer, which that option's code does not read yet)
+        and the third a recurrent-ONLY layout's (no layer is paged: there
+        is no block, no pool and no K/V at all). A looped layout has ONE
+        reason for them all (:meth:`_looped_reason`)."""
         kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
         recurrent = self._has_recurrent
+        #: what a recurrent-only layout's reasons start from
+        no_pool = ("a recurrent-only layout (no layer is paged: every "
+                   "layer keeps one fixed-size state a slot, and the "
+                   "engine allocates no pool)")
 
-        def refuse(option, why, latent_only=None):
+        def refuse(option, why, latent_only=None, recurrent_only=None):
             if self._loop_steps > 1:
                 why = self._looped_reason()
+            elif not self._has_paged and recurrent_only is not None:
+                why = f"{no_pool} {recurrent_only}"
             elif not recurrent and latent_only is not None:
                 why = latent_only
             raise ValueError(
@@ -902,17 +938,31 @@ class LLMEngine:
                    "legacy admission prefills a whole prompt through "
                    "StaticKVCache slot buffers of K and V; a recurrent "
                    "state or a latent pool advances only in the fused "
-                   "step programs (scheduler='fused')")
+                   "step programs (scheduler='fused')",
+                   recurrent_only="has no K and V for legacy admission's "
+                   "StaticKVCache slot buffers to hold; its state "
+                   "advances only in the fused step programs "
+                   "(scheduler='fused')")
         if opt["cache_impl"] != "paged":
             refuse(f"cache_impl={opt['cache_impl']!r}",
                    "the dense slot buffers are [max_batch, capacity, "
                    "kv_heads, head_dim] K and V arrays; latents live in a "
                    "paged pool and a recurrent state is not a sequence of "
-                   "positions (cache_impl='paged')")
+                   "positions (cache_impl='paged')",
+                   recurrent_only="has no K and V to put in the dense "
+                   "[max_batch, capacity, kv_heads, head_dim] slot "
+                   "buffers: a recurrent state is not a sequence of "
+                   "positions (cache_impl='paged' names the fused step "
+                   "programs' state seam; it allocates nothing here)")
         if opt["horizon"] and int(opt["horizon"]) > 1:
             refuse("horizon > 1",
                    "the horizon scan belongs to the legacy scheduler; use "
                    "readout_stride")
+        if opt["kv_pool_blocks"] is not None and not self._has_paged:
+            refuse("kv_pool_blocks", "",
+                   recurrent_only="has no pool to size: admission is "
+                   "bounded by max_batch slots and max_seq_len alone, and "
+                   "a slot's state costs the same whatever its length")
         if opt["enable_prefix_cache"]:
             refuse("enable_prefix_cache",
                    "a cached block holds its tokens' K/V, but a recurrent "
@@ -922,7 +972,11 @@ class LLMEngine:
                    "the content store adopts, copies and spills a block "
                    "as a (K, V) pair of pools a layer; a latent layer has "
                    "one pool and no V, and that path is not written for "
-                   "it (ROADMAP Queue 2)")
+                   "it (ROADMAP Queue 2)",
+                   recurrent_only="has no blocks for the content store to "
+                   "hash, share or evict: the state after a shared prefix "
+                   "is one array a (slot, layer), and a hit would need it "
+                   "snapshotted at the prefix's end, which is not written")
         if opt["kv_host_swap"] or opt["kv_host_spill_bytes"]:
             refuse("kv_host_swap / kv_host_spill_bytes",
                    "swap and spill copy a slot's list of pool blocks; its "
@@ -933,7 +987,12 @@ class LLMEngine:
                    "pair of pools a layer; a latent layer has one pool "
                    "and no V, and that path is not written for it (a "
                    "preempted request replays from its first token "
-                   "instead; ROADMAP Queue 2)")
+                   "instead; ROADMAP Queue 2)",
+                   recurrent_only="has no pool blocks to swap out or "
+                   "spill: a slot's whole state is its recurrent state, "
+                   "whose copy to the host is not written, and with no "
+                   "pool to run dry nothing is preempted for room (a "
+                   "preempted request would replay from its first token)")
         if int(opt["speculative_k"] or 1) > 1:
             refuse("speculative_k > 1",
                    "a rejected draft rolls the slot's length back over "
@@ -941,16 +1000,26 @@ class LLMEngine:
                    "absorbed them cannot be rolled back",
                    "the verify grants are wired through PagedKVCache "
                    "alone; a latent pool's rejected rows could be rolled "
-                   "back by its block table, but that path is not written")
+                   "back by its block table, but that path is not written",
+                   recurrent_only="cannot roll a rejected draft back: the "
+                   "state has absorbed the draft's rows, and there is no "
+                   "block table whose length could forget them")
         if opt["kv_cache_dtype"] is not None:
             refuse("kv_cache_dtype",
                    "pool quantization keeps one scale per (block, kv "
                    "head) of K and V pools; a latent pool and a float32 "
-                   "recurrent state have no such scales")
+                   "recurrent state have no such scales",
+                   recurrent_only="has no K/V pool to quantize: a float32 "
+                   "recurrent state has no (block, kv head) scales")
         if opt["adapter_store"] is not None:
             refuse("adapter_store",
                    "batched LoRA adds its deltas to the llama family's "
-                   "q/k/v/o and gate/up/down projections by name")
+                   "q/k/v/o and gate/up/down projections by name",
+                   recurrent_only="is not the llama family's attention: "
+                   "batched LoRA adds its deltas inside that family's "
+                   "q/k/v/o and gate/up/down forwards by name, and a "
+                   "recurrent layer's projections do not read the "
+                   "adapter scope")
         mesh = opt["mesh"]
         if mesh is not None and "tp" in tuple(mesh.axis_names) \
                 and int(mesh.shape["tp"]) > 1:
@@ -958,7 +1027,10 @@ class LLMEngine:
                    "kv heads are the shard dimension of K/V pools; a "
                    "latent pool has one shared head and a recurrent state "
                    "is held per slot (experts over chips with their "
-                   "exchange are not written)")
+                   "exchange are not written)",
+                   recurrent_only="has no K/V pools, whose kv heads are "
+                   "what the mesh shards: a recurrent state is held whole "
+                   "a slot, and its form sharded by head is not written")
 
     def _looped_reason(self):
         """Why an option written for "one pool block a block id" cannot
@@ -977,6 +1049,13 @@ class LLMEngine:
                 raise ValueError(f"{what} cannot serve a model whose cache "
                                  f"layout has {kinds} layers: "
                                  f"{self._looped_reason()}")
+            if not self._has_paged:
+                raise ValueError(
+                    f"{what} ships a request's list of K/V blocks; a "
+                    f"recurrent-only layout ({kinds} layers, no layer "
+                    f"paged) has no blocks at all: a request's state is "
+                    f"one fixed-size array a (slot, layer), whose export "
+                    f"and import are not written")
             raise ValueError(
                 f"{what} ships a request's list of K/V blocks; a cache "
                 f"layout with {kinds} layers keeps state that is not in "
@@ -1266,6 +1345,8 @@ class LLMEngine:
         its grid steps, one live when it holds a live entry. A looped
         layout walks the table once a loop step: both counts times R.
         Beside them, the pool's blocks in use at this dispatch."""
+        if not self._has_paged:
+            return      # no pool, no attention grid: nothing to book
         bs, n = self.block_size, 1
         if any(k.kind == "paged_latent" for k in self._layout):
             from ..ops.kernels.latent_attention import entries_per_step
@@ -3446,7 +3527,7 @@ class LLMEngine:
         scale overhead). The ``kv_pool_effective_blocks`` Prometheus
         gauge samples this: capacity dashboards read one number that is
         comparable across pool dtypes."""
-        if self.cache_impl != "paged":
+        if self.cache_impl != "paged" or not self._has_paged:
             return 0
         if not self.kv_quant:
             return self.n_blocks
@@ -4911,6 +4992,12 @@ class LLMEngine:
             if "moe_experts_nonempty" in booked:
                 ids["experts_read"] = booked["moe_experts_nonempty"]
                 ids["experts_held"] = booked["moe_experts_held"]
+            if "ret_state_live" in booked:
+                # summed over the layers: (slot, layer) states with a
+                # live row, and the live rows through either form
+                ids["live_states"] = booked["ret_state_live"]
+                ids["ret_rows"] = booked["ret_rows_chunk"] + \
+                    booked["ret_rows_step"]
         now_pc = t0 = self._to("emit", **ids)
         if toks_np.shape[0] > 1 and pending.t_dispatch is not None \
                 and n_exec > 1:

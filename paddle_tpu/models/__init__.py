@@ -14,3 +14,4 @@ from .kimi_linear import (  # noqa: F401
 from .deepseek_v2 import (  # noqa: F401
     DeepseekV2Config, DeepseekV2ForCausalLM)
 from .ouro import OuroConfig, OuroForCausalLM  # noqa: F401
+from .brumby import BrumbyConfig, BrumbyForCausalLM  # noqa: F401
