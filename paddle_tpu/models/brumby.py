@@ -13,7 +13,12 @@ and no K/V at all.
       log g = logsigmoid(w_g . u)  in float32, one gate a key/value head
       o = power retention of degree 2 (``ops/kernels/power_retention.py``:
           the recurrent form, state S [Hk, D, d] and z [Hk, D] float32 a
-          slot, D = d (d + 1) / 2)
+          slot, D = d (d + 1) / 2), by ONE implementation in every step
+          program and the plain forward: the Pallas kernel
+          ``ops/kernels/power_retention_walk.py``, which reads and writes
+          a live slot's state once a step (one-token form for a slot with
+          one row, chunk form for one with more, nothing for one with
+          none), interpreted on a CPU
       out = W_o concat_a o^a
 
 ``config.json`` carries Qwen3-14B's keys and none of the retention layer;
@@ -37,6 +42,7 @@ import jax.numpy as jnp
 from ..nn import Layer, Linear, RMSNorm
 from ..core.tensor import Tensor, dispatch
 from ..ops.kernels import power_retention as _ret
+from ..ops.kernels import power_retention_walk as _walk
 from ..profiler import scope
 from . import cache_layout as CL
 from .latent_moe import (F32, DecoderBlock, StateCausalLM, StateDecoder,
@@ -112,9 +118,9 @@ class PowerRetention(Layer):
             if rows is None and lead[1] == 1:
                 with scope("pt.core"):
                     live = q_lens > 0
-                    o, S, z = _ret.retention_step(
+                    o, S, z = _walk.retention_step(
                         q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], S, z, live,
-                        lens == 0, ret_eps)
+                        lens, ret_eps)
                     o, counts = o[:, None], _ret.step_counts(live)
             else:
                 with scope("pt.view"):
@@ -126,7 +132,7 @@ class PowerRetention(Layer):
                     flat = [a.reshape((n,) + a.shape[2:])
                             for a in (q, k, v, log_g)]
                 with scope("pt.core"):
-                    o, S, z = _ret.retention_walk(
+                    o, S, z = _walk.retention_walk(
                         *flat, S, z, start, q_lens, lens, ret_eps)
                     o, counts = o.reshape(lead + o.shape[1:]), \
                         _ret.walk_counts(q_lens)

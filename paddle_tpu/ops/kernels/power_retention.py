@@ -2,7 +2,7 @@
 Attention"; the mixer of Brumby-14B-Base): causal attention whose weight is
 a POWER of the score, ``(q.k / sqrt d)^p``, under a scalar gate a head, and
 which therefore has an exact recurrent form with a fixed-size state. Degree
-``p = 2`` only. Plain ``jax.numpy`` on raw arrays, float32 state.
+``p = 2`` only. Float32 state.
 
 Per key/value head (``G`` query heads read one key/value head's state),
 with ``log g_t <= 0`` and ``G_t = sum_{r<=t} log g_r``::
@@ -15,29 +15,28 @@ with ``log g_t <= 0`` and ``G_t = sum_{r<=t} log g_r``::
 
 with ``x' = x / d^(1/4)`` and :func:`phi` such that ``phi(a) . phi(b) ==
 (a . b)^2`` exactly: the symmetric degree-2 monomials ``a_i a_j``, the
-off-diagonal ones times sqrt 2, ``D = d (d + 1) / 2`` of them. The three
-forms here are the same function:
+off-diagonal ones times sqrt 2, ``D = d (d + 1) / 2`` of them.
 
-- :func:`retention_attention`: the attention form (what the tests hold the
-  others to; the served path never runs it over a whole context);
+**The served path runs none of the forms below**: ``models/brumby.py``
+calls ``power_retention_walk`` (a Pallas kernel, the same function on the
+same state, which reads and writes a live slot's state once a step). The
+three forms here are plain XLA, the same function, and what the tests hold
+the kernel to (``tests/test_power_retention_kernel.py``):
+
+- :func:`retention_attention`: the attention form (what holds the others
+  and the kernel; never run over a whole served context);
 - :func:`retention_step`: one row a slot against its state, all slots at
-  once (the engine's one-token step and ``multi_step`` scan);
-- :func:`retention_walk`: a step of several rows a slot (a mixed step's
-  prefill chunks and decode rows, a plain forward). The rows lie on ONE
-  flat axis, slot ``b``'s ``q_lens[b]`` rows adjacent from ``start[b]``
-  (a mixed step's packed row axis as it is; no per-slot ``[B, S]`` view is
-  ever made). The slots with ONE live row (a mixed step's decode rows)
-  take the one-token form in one pass over every slot's state, the other
-  slots the identity (in a serving batch most slots decode; a loop over
-  those slots alone read no faster on the chip, 7.12 against 6.93 ms a
-  layer at fifteen of sixteen, and is not written). Then a
-  loop over the slots with MORE rows, each a loop over its live
-  sub-chunks of :data:`SUB` rows in the chunk form: inside a sub-chunk
-  the attention form against its own keys, plus ``phi(Q)`` against the
-  state that entered it, decayed by the gates up to and including the
-  row; the state advanced once a sub-chunk. Both loops have trip counts
-  made in the graph from ``q_lens``, so a slot's dead sub-chunks and a
-  slot without a chunk cost nothing there.
+  once, in one pass over every slot's state;
+- :func:`retention_walk`: a step of several rows a slot. The rows lie on
+  ONE flat axis, slot ``b``'s ``q_lens[b]`` rows adjacent from
+  ``start[b]`` (a mixed step's packed row axis as it is; no per-slot
+  ``[B, S]`` view is ever made). The slots with ONE live row take the
+  one-token form in one pass over every slot's state, the other slots the
+  identity. Then a loop over the slots with MORE rows, each a loop over
+  its live sub-chunks of :data:`SUB` rows in the chunk form: inside a
+  sub-chunk the attention form against its own keys, plus ``phi(Q)``
+  against the state that entered it, decayed by the gates up to and
+  including the row; the state advanced once a sub-chunk.
 
 **The order of the monomials** is by diagonals of the ``d x d`` product:
 ``phi(a) = [a * roll(a, -r) for r = 0 .. d/2]``, the first block the
@@ -61,9 +60,10 @@ SUB = 64
 STATE_PRECISION = jax.lax.Precision.HIGHEST
 #: device-side counts a retention layer makes a step, in the order of the
 #: vector :func:`step_counts` / :func:`walk_counts` return (``engine.stats``
-#: names): slot states the core read and wrote, those of them with a live
-#: row, live rows through the chunk form, live rows through the one-token
-#: form
+#: names): slot states the served path's kernel fetched (each read once and
+#: written once), those of them with a live row (all of them: a regression
+#: to walking dead slots shows as a gap), live rows through the chunk form,
+#: live rows through the one-token form
 COUNTERS = ("ret_state_walked", "ret_state_live", "ret_rows_chunk",
             "ret_rows_step")
 
@@ -269,19 +269,20 @@ def retention_walk(q, k, v, log_g, S, z, start, q_lens, lens, eps=1e-6,
 
 
 def step_counts(live):
-    """:data:`COUNTERS` of one :func:`retention_step` over ``live`` [B]:
-    it reads and writes every slot's state."""
+    """:data:`COUNTERS` of one one-token step over ``live`` [B] as the
+    served path's kernel walks it: the live slots' states, each once."""
     n = jnp.sum(live, dtype=jnp.int32)
-    return jnp.stack([jnp.int32(live.shape[0]), n, jnp.int32(0), n])
+    return jnp.stack([n, n, jnp.int32(0), n])
 
 
 def walk_counts(q_lens):
-    """:data:`COUNTERS` of one :func:`retention_walk`: the one-row slots'
-    pass reads and writes every slot's state, and each slot with more
-    rows its own again."""
+    """:data:`COUNTERS` of one step of ``q_lens[b]`` rows a slot as the
+    served path's kernel walks it (``power_retention_walk``): it fetches
+    the state of every slot with a live row once, whatever the slot's
+    form, and no other."""
     q_lens = q_lens.astype(jnp.int32)
     one = jnp.sum(q_lens == 1, dtype=jnp.int32)
     many = jnp.sum(q_lens > 1, dtype=jnp.int32)
-    return jnp.stack([q_lens.shape[0] + many, one + many,
+    return jnp.stack([one + many, one + many,
                       jnp.sum(jnp.where(q_lens > 1, q_lens, 0),
                               dtype=jnp.int32), one])
