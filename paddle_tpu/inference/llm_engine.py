@@ -583,10 +583,14 @@ class LLMEngine:
         #: 0, the capacity need not be a multiple of the chunk, and the
         #: pool's and the attention grid's counters book nothing.
         self._has_paged = any(k.paged for k in self._layout)
-        #: every layer's state is K and V pools that the paged kernels
-        #: read, once a token or once a loop step
-        self._kv_pools = all(k.kind in ("paged_kv", "paged_kv_looped")
-                             for k in self._layout)
+        #: the kind of the layers whose state is K and V pools that the
+        #: paged kernels read, once a token or once a loop step (None: no
+        #: layer's is). The pools' head counts and the append kernel's
+        #: tile counts are of these layers, however many others (a
+        #: recurrent state a slot) stand beside them
+        self._kv_kind = next(
+            (k for k in self._layout
+             if k.kind in ("paged_kv", "paged_kv_looped")), None)
         #: runs of a weight layer a token, each with K/V of its own under
         #: the slot's ONE block id (a looped layout; else 1): what a
         #: block, a token and a grid walk cost multiplies by it
@@ -666,10 +670,9 @@ class LLMEngine:
         self._state_vals = read_values(self._state)
 
         if self._kv_only:
-            kvh, head_dim = (self._layout[0].kv_heads,
-                             self._layout[0].head_dim)
+            kvh, head_dim = self._kv_kind.kv_heads, self._kv_kind.head_dim
         else:
-            kvh = head_dim = 0     # no K/V pool: the kinds size their own
+            kvh = head_dim = 0     # the kinds size their own state
         dt = self._decoder.embed_tokens.weight.dtype
         L = len(self._layout)
         # a prefill window is always a full `chunk` wide, so it must fit the
@@ -910,7 +913,8 @@ class LLMEngine:
         does). Every option whose code assumes "a slot's state is a list
         of K/V blocks" raises here, naming its mechanism, instead of
         serving a wrong token. Where the reason differs, the first is a
-        recurrent layer's beside a pool (its state is in no block), the
+        recurrent layer's beside a pool, of latents or of plain K and V
+        alike (its state is in no block), the
         second a latent-only layout's (its state IS a list of blocks, of
         ONE pool a layer, which that option's code does not read yet)
         and the third a recurrent-ONLY layout's (no layer is paged: there
@@ -1321,14 +1325,19 @@ class LLMEngine:
 
     def _attn_tile_steps(self, q_lens):
         """``(run, grid)`` row-tile steps of the append kernel for a
-        mixed paged step granting ``q_lens``, per kv head and layer (they
-        multiply both alike): the kernel module's own count over the
-        host's lens mirror, so call this BEFORE the mirrors grow."""
+        mixed paged step granting ``q_lens``, per kv head and K/V layer
+        (they multiply both alike; a layout's other layers run no such
+        kernel): the kernel module's own count over the host's lens
+        mirror, so call this BEFORE the mirrors grow. The group (query
+        heads a kv head) is the K/V kind's where it states its query
+        heads, else the model config's."""
         from ..ops.kernels.paged_attention import append_tile_steps
-        c = self.model.config
+        kind = self._kv_kind
+        q_heads = getattr(kind, "q_heads", None) or \
+            self.model.config.num_attention_heads
         lens = [0 if s is None else s.sched_len() for s in self.slots]
         return append_tile_steps(
-            lens, q_lens, c.num_attention_heads // c.num_key_value_heads,
+            lens, q_lens, q_heads // kind.kv_heads,
             self.chunk, self.block_size, self._tables.shape[1])
 
     def _book_kv_grid(self, iterations):
@@ -1338,25 +1347,30 @@ class LLMEngine:
         kernel's too: it skips the work of a dead entry, not the entry
         (one grid step for all of its kv heads); an entry is live when it
         holds a token once the dispatch has landed (host lens mirror, so
-        call this after the mirrors grew). Layers multiply both counts
-        alike, and so do heads in the decode kernel. A latent pool's
-        kernel walks its table in wide entries (``entries_per_step`` of
-        them a grid step, asked of the kernel module): both counts are in
-        its grid steps, one live when it holds a live entry. A looped
-        layout walks the table once a loop step: both counts times R.
-        Beside them, the pool's blocks in use at this dispatch."""
+        call this after the mirrors grew). The counts are of ONE paged
+        layer of each paged kind the layout has: the layers of a kind
+        multiply both counts alike, and so do heads in the decode kernel,
+        and a recurrent layer beside them walks no table and adds
+        nothing. A latent pool's kernel walks its table in wide entries
+        (``entries_per_step`` of them a grid step, asked of the kernel
+        module): both counts are in its grid steps, one live when it
+        holds a live entry. A looped layout walks the table once a loop
+        step: both counts times R. Beside them, the pool's blocks in use
+        at this dispatch."""
         if not self._has_paged:
             return      # no pool, no attention grid: nothing to book
-        bs, n = self.block_size, 1
-        if any(k.kind == "paged_latent" for k in self._layout):
-            from ..ops.kernels.latent_attention import entries_per_step
-            n = entries_per_step(self._tables.shape[1], bs)
-        live = sum(-(-s.sched_len() // (bs * n))
-                   for s in self.slots if s is not None)
-        grid = self._tables.size // n
+        bs = self.block_size
         iterations *= self._loop_steps
-        self.stats["kv_grid_blocks"] += iterations * grid
-        self.stats["kv_live_blocks"] += iterations * min(live, grid)
+        for kind in sorted({k.kind for k in self._layout if k.paged}):
+            n = 1       # K/V pools: a grid step a table entry
+            if kind == "paged_latent":
+                from ..ops.kernels.latent_attention import entries_per_step
+                n = entries_per_step(self._tables.shape[1], bs)
+            live = sum(-(-s.sched_len() // (bs * n))
+                       for s in self.slots if s is not None)
+            grid = self._tables.size // n
+            self.stats["kv_grid_blocks"] += iterations * grid
+            self.stats["kv_live_blocks"] += iterations * min(live, grid)
         self.stats["pool_blocks_used"] += self.n_blocks - len(
             self._free_blocks) - len(self._lru) - len(self._quarantine)
         self.stats["pool_blocks_total"] += self.n_blocks
@@ -4761,10 +4775,12 @@ class LLMEngine:
         spec_args = dict(tokens_buf=self._tokens, spec_ks=spec_ks) \
             if spec else {}
         counts_dev = None
-        # the append kernel's tile count is of K/V pools (a looped
-        # layout's too: loop steps multiply both counts alike)
+        # the append kernel's tile count is of the layers that hold K/V
+        # pools, few or all (a looped layout's too: loop steps multiply
+        # both counts alike)
         tiles = self._attn_tile_steps(q_lens) \
-            if self.cache_impl == "paged" and self._kv_pools else None
+            if self.cache_impl == "paged" and self._kv_kind is not None \
+            else None
         t0 = self._to("dispatch", **self._dispatch_ids(
             "mixed", self.mixed_rows, int(q_lens.sum()), q_lens > 0,
             prefill_rows=int(q_lens[~is_dec].sum()),

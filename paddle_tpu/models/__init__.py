@@ -15,3 +15,5 @@ from .deepseek_v2 import (  # noqa: F401
     DeepseekV2Config, DeepseekV2ForCausalLM)
 from .ouro import OuroConfig, OuroForCausalLM  # noqa: F401
 from .brumby import BrumbyConfig, BrumbyForCausalLM  # noqa: F401
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config, SolarOpen2ForCausalLM)

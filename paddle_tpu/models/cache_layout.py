@@ -19,6 +19,21 @@ A kind makes the layer's state ``(a, b)`` (two pytrees of device arrays,
 programs as it carried K and V pools), the cache object the layer is
 handed for one dispatch, and takes the pair back off the returned cache.
 
+**The layouts the engine serves** (a layout is the list of kinds, one a
+layer; ``LLMEngine._refuse_for_layout`` names what each cannot take):
+
+1. every layer :class:`PagedKV` (the llama family; the one layout with
+   the legacy scheduler, dense buffers, quantized and sharded pools,
+   prefix caching, swap, speculation, LoRA);
+2. :class:`PagedLatent` in every layer (DeepSeek-V2);
+3. :class:`Recurrent` layers beside paged ones -- latent pools
+   (Kimi-Linear) or K/V pools (a gated GQA layer among delta-rule layers):
+   one block table a slot serves the paged layers, the recurrent layers
+   hold ``[max_batch, ...]`` states, and a replayed slot's state is
+   zeroed in the graph;
+4. :class:`LoopedPagedKV` in every layer (a looped stack);
+5. :class:`Recurrent` in every layer (no pool at all).
+
 **What the decoder is handed in a mixed step.** A one-token step hands it
 ``ids[B, 1]``. A mixed step (the fused scheduler's prefill chunks and
 decode tokens in one dispatch) hands it ``ids[1, T]``: ONE PACKED ROW AXIS
@@ -55,18 +70,41 @@ def _val(x):
 
 class PagedKV:
     """Paged K and V pools ``[n_blocks + 1, kv_heads, block, head_dim]``
-    (:class:`paddle_tpu.models.llama.PagedKVCache`)."""
+    on the engine's block tables
+    (:class:`paddle_tpu.models.llama.PagedKVCache`): THE general K/V kind,
+    with the three methods every kind has, so a layer of a mixed layout
+    holds K and V pools beside layers that hold a recurrent state or a
+    latent pool. (A layout of this kind alone is also served by the
+    engine's older all-K/V branch, which builds the same pools itself and
+    alone carries their quantized, sharded, swapped and shared forms.)
+    ``q_heads``: the query heads that read the ``kv_heads`` (None: the
+    model's config says, ``num_attention_heads``); the append kernel's
+    row tile follows the group, and so do the engine's tile counts."""
     kind = "paged_kv"
     paged = True
 
-    def __init__(self, kv_heads, head_dim):
+    def __init__(self, kv_heads, head_dim, q_heads=None):
         self.kv_heads, self.head_dim = int(kv_heads), int(head_dim)
+        self.q_heads = None if q_heads is None else int(q_heads)
 
     def bytes_per_token(self, itemsize):
         return 2 * self.kv_heads * self.head_dim * itemsize
 
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype):
+        shape = (n_blocks + 1, self.kv_heads, block_size, self.head_dim)
+        return zeros(shape, dtype), zeros(shape, dtype)
 
-class LoopedPagedKV:
+    def cache(self, a, b, tables, lens, q_lens, active, row_budget,
+              rows=None):
+        from .llama import PagedKVCache
+        return PagedKVCache(a, b, tables, lens, _q_lens(q_lens, active),
+                            rows=rows, row_budget=row_budget)
+
+    def unpack(self, cache):
+        return _val(cache.k), _val(cache.v)
+
+
+class LoopedPagedKV(PagedKV):
     """K and V of ``(kv_heads, head_dim)``, ``loop_steps`` times: a WEIGHT
     layer that runs R times a token (a looped stack) and keeps keys and
     values of its own for each run. One K and one V pool a weight layer of
@@ -80,30 +118,23 @@ class LoopedPagedKV:
     which is step R - 1's scratch block. The cache object is the llama
     family's :class:`~paddle_tpu.models.llama.PagedKVCache` (so are the
     kernels); in a one-token step its ``q_lens`` says which slots hold a
-    live row."""
+    live row. :class:`PagedKV` is the general kind and this one is it
+    with what only a loop needs: R runs of blocks in ``alloc`` and in a
+    token's cost, :meth:`at_step`, and a ``kind`` of its own, by which
+    the engine refuses options for it with one reason."""
     kind = "paged_kv_looped"
-    paged = True
 
     def __init__(self, kv_heads, head_dim, loop_steps):
-        self.kv_heads, self.head_dim = int(kv_heads), int(head_dim)
+        super().__init__(kv_heads, head_dim)
         self.loop_steps = int(loop_steps)
 
     def bytes_per_token(self, itemsize):
-        return 2 * self.kv_heads * self.head_dim * itemsize * self.loop_steps
+        return super().bytes_per_token(itemsize) * self.loop_steps
 
     def alloc(self, zeros, n_blocks, block_size, batch, dtype):
         shape = (self.loop_steps * (n_blocks + 1), self.kv_heads,
                  block_size, self.head_dim)
         return zeros(shape, dtype), zeros(shape, dtype)
-
-    def cache(self, a, b, tables, lens, q_lens, active, row_budget,
-              rows=None):
-        from .llama import PagedKVCache
-        return PagedKVCache(a, b, tables, lens, _q_lens(q_lens, active),
-                            rows=rows)
-
-    def unpack(self, cache):
-        return _val(cache.k), _val(cache.v)
 
     def at_step(self, cache, t, k=None, v=None):
         """``cache`` as loop step ``t`` (traced or not) sees it: the same
@@ -115,7 +146,8 @@ class LoopedPagedKV:
         tables = _val(cache.block_tables).astype(jnp.int32)
         tables = jnp.where(tables < 0, -1, tables + t * stride)
         return PagedKVCache(k, cache.v if v is None else v, tables,
-                            cache.seq_lens, cache.q_lens, rows=cache.rows)
+                            cache.seq_lens, cache.q_lens, rows=cache.rows,
+                            row_budget=cache.row_budget)
 
 
 class PagedLatent:

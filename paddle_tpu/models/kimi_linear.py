@@ -97,8 +97,16 @@ def _l2(x):
 
 
 class KimiDeltaAttention(Layer):
-    def __init__(self, c: KimiLinearConfig):
+    """``c``: any config with ``hidden_size``, ``linear_num_heads``,
+    ``linear_head_dim``, ``short_conv_kernel_size``, ``gate_low_rank`` and
+    ``rms_norm_eps``. ``beta_scale``: the write strength is ``beta_scale *
+    sigmoid(W_b x)``; 2 lets ``I - beta k k^T`` take an eigenvalue in (-1,
+    1) along ``k`` (a family that allows negative eigenvalues), 1 is this
+    family's and traces nothing more."""
+
+    def __init__(self, c: KimiLinearConfig, beta_scale=1.0):
         super().__init__()
+        self.beta_scale = float(beta_scale)
         h, H, K, r = c.hidden_size, c.linear_num_heads, c.linear_head_dim, \
             c.gate_low_rank
         self.H, self.K, self.eps = H, K, c.rms_norm_eps
@@ -128,6 +136,7 @@ class KimiDeltaAttention(Layer):
 
     def forward(self, x, cache):
         H, K, eps = self.H, self.K, self.eps
+        beta_scale = self.beta_scale
         rows = CL.packed(cache)
 
         def fn(x, S, tail, lens, q_lens, wq, wk, wv, cq, ck, cv, wfa, wfb,
@@ -142,15 +151,16 @@ class KimiDeltaAttention(Layer):
                     (mm32(mm(x, wfa), wfb) + dtb.astype(F32))
                     .reshape(lead + (H, K)))
                 beta = jax.nn.sigmoid(mm32(x, wb))
+                if beta_scale != 1.0:
+                    beta = beta * jnp.float32(beta_scale)
                 gate = jax.nn.sigmoid(mm32(mm(x, wga), wgb)) \
                     .reshape(lead + (H, K))
             with scope("pt.view"):
-                if rows is not None:
-                    # the per-slot view, around the convolution's tail
-                    # and the recurrence only
-                    qkv, g, beta = (rows.to_slots(a[0])
-                                    for a in (qkv, g, beta))
-                b, s = qkv.shape[:2]
+                # the per-slot view [B, S, ...], around the recurrence
+                # only: a mixed step's convolution runs on its packed
+                # rows, and q, k, v, g, beta go to the slots after it
+                b, s = (S.shape[0], rows.width) if rows is not None \
+                    else qkv.shape[:2]
                 # the kernel walks a step's live chunks, and resets a
                 # fresh slot and masks the dead rows itself
                 walk = s > 1 and _walk.serves(K, K)
@@ -161,11 +171,22 @@ class KimiDeltaAttention(Layer):
                                  tail)
                 live = None if walk else live_rows(q_lens, s)
             with scope("pt.conv"):
-                y, tail = _kda.causal_conv(
-                    qkv, tail, jnp.concatenate([cq, ck, cv], -1), q_lens)
-                y = jax.nn.silu(y).reshape(b, s, 3, H, K)
-                q = _l2(y[:, :, 0]) * jnp.float32(K ** -0.5)
-                k, v = _l2(y[:, :, 1]), y[:, :, 2]
+                cw = jnp.concatenate([cq, ck, cv], -1)
+                if rows is not None:
+                    y, tail = _kda.causal_conv_packed(qkv[0], tail, cw,
+                                                      rows)
+                    y = jax.nn.silu(y).reshape(-1, 3, H, K)
+                    q = _l2(y[:, 0]) * jnp.float32(K ** -0.5)
+                    k, v = _l2(y[:, 1]), y[:, 2]
+                else:
+                    y, tail = _kda.causal_conv(qkv, tail, cw, q_lens)
+                    y = jax.nn.silu(y).reshape(b, s, 3, H, K)
+                    q = _l2(y[:, :, 0]) * jnp.float32(K ** -0.5)
+                    k, v = _l2(y[:, :, 1]), y[:, :, 2]
+            if rows is not None:
+                with scope("pt.view"):
+                    q, k, v, g, beta = (rows.to_slots(a) for a in
+                                        (q, k, v, g[0], beta[0]))
             if not walk:
                 with scope("pt.gate"):
                     g = jnp.where(live[:, :, None, None], g, 0.0)

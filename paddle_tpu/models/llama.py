@@ -207,18 +207,25 @@ class PagedKVCache:
 
     ``rows`` (a :class:`~paddle_tpu.models.cache_layout.RowMap`): the
     layer's ``x`` is the mixed step's packed ``[1, T, ...]`` and not
-    ``[B, S, ...]``; the attention takes the per-slot view through it."""
+    ``[B, S, ...]``; the attention takes the per-slot view through it.
+
+    ``row_budget``: the dispatcher's static bound on the step's live rows
+    over all slots, as the other kinds' cache objects carry it (None:
+    every row may be live); an expert layer beside the attention sizes
+    its grouped product by it."""
 
     __slots__ = ("k", "v", "block_tables", "seq_lens", "q_lens",
-                 "k_scale", "v_scale", "quant", "rows")
+                 "k_scale", "v_scale", "quant", "rows", "row_budget")
 
     def __init__(self, k, v, block_tables, seq_lens, q_lens=None,
-                 k_scale=None, v_scale=None, quant=None, rows=None):
+                 k_scale=None, v_scale=None, quant=None, rows=None,
+                 row_budget=None):
         self.k, self.v = k, v
         self.block_tables, self.seq_lens = block_tables, seq_lens
         self.q_lens = q_lens
         self.k_scale, self.v_scale = k_scale, v_scale
         self.quant, self.rows = quant, rows
+        self.row_budget = row_budget
 
 
 class ChunkKVCache:

@@ -877,8 +877,14 @@ _ROW_SUBTILE = 32
 _HEADS_INTERLEAVED = 8
 #: VMEM the append call plans its per-step buffers into (a v5e core has
 #: 128 MiB; the compiler's own default limit for a kernel is 16 MiB and
-#: the call raises it to what it planned, see ``_append_vmem_bytes``)
-_APPEND_VMEM_BUDGET = 40 << 20
+#: the call raises it to what it planned plus 16 MiB, see
+#: ``_append_vmem_bytes``). It was 40 MiB while no cell's heads needed
+#: more; a group of 8 over a 512-row chunk (4,096 rows a kv head, 10.6 MiB
+#: a head) then ran 2 kv heads a step, two chains interleaved where the
+#: tile is built for eight, and took 36.5 ms a call where all 8 heads a
+#: step (85 MiB planned) take 18.3, bit for bit the same output (read on
+#: the v5e at 16 x 400 blocks, PERF.md section 6, PR 41)
+_APPEND_VMEM_BUDGET = 92 << 20
 
 
 def _row_tile(g, s):

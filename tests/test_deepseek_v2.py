@@ -448,8 +448,12 @@ def _digest(fn, *args):
 #: it (``functional_mode``) under this suite's settings (matmul precision
 #: "highest" is part of the text): (digest, equations). The counts the
 #: expert layer books are not among the outputs (that PR added one).
+#: ``packed`` was read again in PR 41, which moved a mixed step's KDA
+#: convolution from the per-slot view onto the packed rows
+#: (``kda.causal_conv_packed``; before it: "3f818f51a25067db", 2145); the
+#: two forms without a packed axis trace what they traced.
 KIMI_PROGRAMS = {"plain": ("ec39cef70d58fe2f", 1867),
-                 "packed": ("3f818f51a25067db", 2145),
+                 "packed": ("af32209e2d763e83", 2643),
                  "one_token": ("75fe0e068be5bd85", 1474)}
 
 
