@@ -46,11 +46,15 @@ residuals, projections, feed-forwards, routers and experts run on the
 ``T`` rows and never look at the map, except for a row's position
 (``rows.pos``) and whether it holds a token (``rows.live``). A layer
 takes the per-slot view ``[B, S, ...]`` only where its state is per slot
--- around the paged append attention, the dense scatter-and-attend, the
-latent pool's write and attention, a recurrent layer's convolution tail
-and chunk scan -- through the two gathers :meth:`RowMap.to_slots` and
-:meth:`RowMap.from_slots`; rows of that view past ``q_lens[b]`` are
-finite garbage nobody reads, as those kernels' contracts always said.
+-- around the paged append attention, the dense scatter-and-attend, a
+recurrent layer's convolution tail and chunk scan -- through the two
+gathers :meth:`RowMap.to_slots` and :meth:`RowMap.from_slots`; rows of
+that view past ``q_lens[b]`` are finite garbage nobody reads, as those
+kernels' contracts always said. A core that reads ``(start, q_lens, seq_lens)``
+itself takes the packed rows as they are and builds no view: power
+retention's walk, and the latent pool's write and attention
+(``ops/kernels/latent_attention.py``), whose rows without a token come
+back zero.
 """
 from __future__ import annotations
 
@@ -218,7 +222,9 @@ class LatentPagedCache:
     and attends nothing). ``row_budget``: the dispatcher's static bound on
     the step's live rows over all slots (None: every row may be live).
     ``rows``: the :class:`RowMap` of a mixed step, whose ``x`` is the
-    packed ``[1, T, ...]`` (None: ``x`` is ``[B, S, ...]``)."""
+    packed ``[1, T, ...]`` (None: ``x`` is ``[B, S, ...]``); the pool's
+    write and the attention kernel are handed it with the packed rows,
+    and read ``start``, ``slot``, ``pos`` and ``live`` off it."""
     __slots__ = ("pool", "block_tables", "seq_lens", "q_lens", "row_budget",
                  "rows")
 

@@ -19,7 +19,9 @@ Absorbed: ``q_nope`` goes through ``W_kvb``'s key half into the latent's
 width, the attention runs against the pool with the latent itself as
 values (``ops/kernels/latent_attention.py``), ``W_kvb``'s value half
 after. A row's position is ``RowMap.pos`` in a mixed step's packed form
-and ``seq_lens + i`` in the per-slot forms (the one-token step is S = 1).
+and ``seq_lens + i`` in the per-slot forms (the one-token step is S = 1);
+the pool's write and the kernel take the packed rows as they are, so a
+mixed step's latent layer never builds the per-slot view.
 
 Experts: ``ops/kernels/moe_dropless.py``; a layer holds experts
 ``[offset, offset + held)`` of ``published`` and routes over all of them.
@@ -109,8 +111,8 @@ class LatentAttention(Layer):
         rows = CL.packed(cache)
 
         def fn(x, pool, tables, lens, q_lens, wq, wkva, nw, wkvb, wo):
-            # projections on x's own rows ([B, S], or a mixed step's
-            # packed [1, T]); the per-slot view around the pool only
+            # everything on x's own rows: [B, S], or a mixed step's
+            # packed [1, T], which no part of the layer leaves
             lead = x.shape[:2]
             with scope("q_proj"):
                 if len(wq) == 1:
@@ -146,16 +148,16 @@ class LatentAttention(Layer):
                 qc = (jnp.concatenate([q_abs, q_pe], -1) *
                       jnp.float32(scale)).astype(x.dtype)
                 if rows is not None:
-                    entry, qc = rows.to_slots(entry[0]), \
-                        rows.to_slots(qc[0])
+                    # the packed rows as they are: the pool's write and
+                    # the kernel read the row map themselves
+                    entry, qc = entry[0], qc[0]
                 pool = _lat.latent_pool_write(pool, entry, tables, lens,
-                                              q_lens)
+                                              q_lens, rows)
             with scope("pt.core"):
                 o = _lat.latent_attention_append(
-                    qc, pool, tables, lens, q_lens, r)
+                    qc, pool, tables, lens, q_lens, r, rows)
             if rows is not None:
-                with scope("pt.view"):
-                    o = rows.from_slots(o)[None]
+                o = o[None]
             with scope("kv_b_proj"):
                 o = jnp.einsum("bshc,chv->bshv", o, wkvb[..., dn:],
                                preferred_element_type=F32).astype(x.dtype)

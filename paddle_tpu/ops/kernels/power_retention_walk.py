@@ -490,13 +490,14 @@ def retention_step(q, k, v, log_g, S, z, live, lens, eps=1e-6):
                           live.astype(jnp.int32), lens, eps)
 
 
-def _aligned(start, ql, n, nal):
-    """Every slot's rows moved to a multiple of :data:`STRIP` (a dynamic
-    row index inside the kernel has to be one): (the slots' new first rows
+def _aligned(start, ql, n, nal, step=STRIP):
+    """Every slot's rows moved to a multiple of ``step`` (a dynamic row
+    index inside the kernel has to be one of the sublane tile; the latent
+    append kernel asks for its own): (the slots' new first rows
     [B]; for each new row the old row it holds [nal] (any row where it
     holds none); for each old row its new row [n]; the old rows a slot
     owns [n] bool)."""
-    room = -(-ql // STRIP) * STRIP
+    room = -(-ql // step) * step
     new = (jnp.cumsum(room) - room).astype(jnp.int32)
 
     def owner(rows, first):

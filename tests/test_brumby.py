@@ -532,3 +532,42 @@ def test_the_step_programs_compile_for_the_v5e_with_the_state_in_place(
         arguments, temporaries = brumby_aot.held_in_place(compiled, eng)
         assert arguments > 16 * 8 * brumby_aot.STATE_BYTES + 8.39e9
         assert temporaries < 1e9
+
+
+@pytest.mark.parametrize("cell,b,t,s,h,mb,hq", [
+    ("dsv2_rag_answers", 8, 528, 512, 128, 136, 16),
+    ("kimi_long_docs", 16, 272, 256, 32, 260, 32),
+])
+def test_the_packed_latent_append_compiles_for_the_v5e(one_chip, cell, b, t,
+                                                       s, h, mb, hq):
+    """The latent append on a mixed step's packed rows (``[T, H, 576]``
+    with ``(start, q_lens, seq_lens)`` prefetched) at the two latent
+    cells' shapes, compiled by the TPU compiler installed here for a
+    described v5e (nothing runs; this file has the tier-1 lane that lowers
+    for the chip from a CPU host, and a second file could not describe the
+    topology beside it): Mosaic takes a head group's resident block with a
+    dynamic sublane offset at the latent width, with the slots' rows as
+    they come (no gather: ``hq`` is a multiple of a sublane tile)."""
+    from benchmark.tests import brumby_aot
+    from paddle_tpu.ops.kernels import latent_attention as la
+    d, dv, bs = 576, 512, 64
+    assert la.heads_per_step(h, b, t, s, d, dv, bs) == hq
+    assert la.slot_step(hq) == 1
+
+    def fn(q, pool, tables, lens, q_lens, start):
+        return la._append_rows(q, pool, tables, lens, q_lens, start,
+                               width=s, dv=dv, every=None, interpret=False)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    i32 = jnp.int32
+    args = (shape((t, h, d), jnp.bfloat16),
+            shape((b * mb + 1, bs, d), jnp.bfloat16), shape((b, mb), i32),
+            shape((b,), i32), shape((b,), i32), shape((b,), i32))
+    # this suite asks every product for float32 passes; the kernel's are
+    # one-pass bfloat16 products, as the served program traces them
+    with jax.default_matmul_precision("default"):
+        text = brumby_aot.compile_for_the_chip(
+            {cell: jax.jit(fn)}, {cell: args}, cell).as_text()
+    assert "tpu_custom_call" in text and "latent_attention_append" in text
+    assert " gather(" not in text

@@ -264,6 +264,51 @@ def test_one_token_and_packed_rows_sit_at_their_positions():
 
 # ---- (iv) group-limited routing -------------------------------------------
 
+@pytest.mark.parametrize("q_lens", [(20, 32, 0), (1, 0, 32), (1, 1, 1)])
+def test_the_layers_packed_rows_through_the_kernel_equal_the_gathered_form(
+        q_lens, monkeypatch):
+    """``LatentAttention.forward`` on a mixed step's packed rows with the
+    Pallas kernel routed in (interpreted here) against the same call on
+    the CPU's gathered form: the layer hands the kernel the row map's
+    ``start`` and ``width`` and takes the packed output as it comes, so
+    the live rows agree, the pools are equal bit for bit, and the packed
+    axis' padding leaves the layer as ``o_proj`` of zeros."""
+    from paddle_tpu.ops.kernels import latent_attention, paged_attention
+    cfg = dict(TOY, num_hidden_layers=1)
+    model, _ = build(cfg, 5)
+    layer = model.model.layers[0].self_attn
+    rng = np.random.default_rng(4)
+    tables = jnp.asarray([[3, 1, 4, 0], [2, 5, 7, 6], [8, 9, 10, 11]],
+                         jnp.int32)
+    lens = jnp.asarray([70, 9, 40], jnp.int32)
+    q_lens = jnp.asarray(q_lens, jnp.int32)
+    pool0 = jnp.asarray(rng.normal(size=(13, 32, 40)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(1, 64, 64)), jnp.float32)
+    rows = CL.RowMap(q_lens, lens, 64, 32)
+
+    def run():
+        with paddle.no_grad():
+            out, cache = layer(paddle.to_tensor(x), CL.LatentPagedCache(
+                pool0, tables, lens, q_lens, rows=rows))
+        return np.asarray(out._value[0]), np.asarray(cache.pool._value)
+    want, want_pool = run()
+    monkeypatch.setattr(paged_attention, "paged_attention_enabled",
+                        lambda: True)
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: True)
+    assert latent_attention.latent_attention_enabled()
+    widths, kernel = [], latent_attention._append_rows
+    monkeypatch.setattr(
+        latent_attention, "_append_rows",
+        lambda *a, **kw: (widths.append(kw["width"]), kernel(*a, **kw))[1])
+    got, got_pool = run()
+    assert widths == [32]
+    live = np.asarray(rows.live)
+    np.testing.assert_array_equal(got_pool, want_pool)
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    np.testing.assert_array_equal(got[~live], want[~live])
+    assert (got[~live] == 0).all()      # no bias: o_proj of zeros
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_routing_keeps_the_best_groups_and_does_not_renormalise(seed):
     rng = np.random.default_rng(seed)
@@ -451,9 +496,11 @@ def _digest(fn, *args):
 #: ``packed`` was read again in PR 41, which moved a mixed step's KDA
 #: convolution from the per-slot view onto the packed rows
 #: (``kda.causal_conv_packed``; before it: "3f818f51a25067db", 2145); the
-#: two forms without a packed axis trace what they traced.
+#: two forms without a packed axis trace what they traced; and in PR 42,
+#: which handed the latent pool's write and attention the packed rows as
+#: they are (before it: "af32209e2d763e83", 2643), the same two again.
 KIMI_PROGRAMS = {"plain": ("ec39cef70d58fe2f", 1867),
-                 "packed": ("af32209e2d763e83", 2643),
+                 "packed": ("61594b51d6cc749b", 2638),
                  "one_token": ("75fe0e068be5bd85", 1474)}
 
 

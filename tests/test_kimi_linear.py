@@ -8,6 +8,7 @@ over the latent pool, the share test of the expert layer, the engine
 slot reuse, preemption and replay) compared as ``served_gaps`` compares,
 every option a recurrent layout refuses, and the latent pool's bytes."""
 import json
+import math
 import os
 
 import jax
@@ -262,20 +263,154 @@ def test_a_dead_table_entry_inside_a_context_masks_its_latents(mb):
                                               lens, qlens)
     tables = np.array(tables)
     tables[0, 1], tables[1, 2], tables[2, 1] = -1, -1, -1
-    want = np.zeros((4, 32, 4, 32))
-    ctx = np.asarray(pool, np.float64)[np.maximum(tables, 0)] \
-        .reshape(4, -1, 48)
-    seen = np.repeat(tables >= 0, 16, axis=1)
-    for b in range(3):
-        for i in range(int(qlens[b])):
-            ok = seen[b] & (np.arange(mb * 16) <= int(lens[b]) + i)
-            sc = np.asarray(q, np.float64)[b, i] @ ctx[b].T
-            p = np.exp(sc - sc[:, ok].max(axis=1, keepdims=True)) * ok
-            want[b, i] = (p / p.sum(axis=1, keepdims=True)) @ ctx[b, :, :32]
+    want = _seen_by_each_row(q, pool, tables, lens, qlens, 32)
     got = latent_attention._append_call(q, pool, jnp.asarray(tables), lens,
                                         qlens, dv=32, interpret=True)
     live = np.arange(32)[None, :] < np.asarray(qlens)[:, None]
     np.testing.assert_allclose(np.asarray(got)[live], want[live], atol=2e-5)
+
+
+def _seen_by_each_row(q, pool, tables, lens, qlens, dv):
+    """[B, S, H, dv] in float64: row i of slot b over the latents of its
+    table's held entries at positions ``<= lens[b] + i`` (a ``-1`` entry's
+    are seen by no row); rows at or past ``qlens`` are zero."""
+    q, tables = np.asarray(q, np.float64), np.asarray(tables)
+    b, s, h, d = q.shape
+    bs = pool.shape[1]
+    ctx = np.asarray(pool, np.float64)[np.maximum(tables, 0)] \
+        .reshape(b, -1, d)
+    seen = np.repeat(tables >= 0, bs, axis=1)
+    want = np.zeros((b, s, h, dv))
+    for i in range(b):
+        for r in range(int(qlens[i])):
+            ok = seen[i] & (np.arange(ctx.shape[1]) <= int(lens[i]) + r)
+            sc = q[i, r] @ ctx[i].T
+            p = np.exp(sc - sc[:, ok].max(axis=1, keepdims=True)) * ok
+            want[i, r] = (p / p.sum(axis=1, keepdims=True)) @ ctx[i, :, :dv]
+    return want
+
+
+#: (block, table entries, the per-slot width, rows of the packed axis,
+#: lens, q_lens, dtype, head groups, (slot, entry) set to -1) of a mixed
+#: step's packed rows through the kernel. 4 heads a group move a slot's
+#: first row to a multiple of 4, 2 heads to one of 8.
+PACKED_CASES = {
+    # a decode row, a whole chunk, an idle slot and a chunk's tail in one
+    # step: the slots start at rows 0, 1, 33, 33 of the axis, and 27 rows
+    # of padding follow
+    "row_chunk_idle_tail": (16, 12, 32, 80, [70, 50, 0, 96], [1, 32, 0, 20],
+                            "float32", 1, ()),
+    "bf16": (16, 12, 32, 80, [70, 50, 0, 96], [1, 32, 0, 20], "bfloat16", 1,
+             ()),
+    # the first slot idle and the axis full to its last row
+    "idle_first_no_padding": (16, 12, 32, 48, [0, 31, 64, 100],
+                              [0, 7, 32, 9], "float32", 1, ()),
+    # a window that ends on the table's last latent, and a decode row there
+    "the_tables_last_block": (16, 12, 32, 64, [175, 191, 0, 160],
+                              [17, 1, 0, 32], "float32", 1, ()),
+    # -1 entries below a slot's length, inside wide entries of 4: in the
+    # history and in the window
+    "dead_entries_in_wide_entries": (16, 12, 32, 64, [70, 40, 17, 0],
+                                     [20, 1, 32, 0], "float32", 1,
+                                     ((0, 1), (1, 2), (2, 1))),
+    "decode_rows_only": (16, 12, 32, 16, [63, 64, 128, 191], [1, 1, 0, 1],
+                         "float32", 1, ()),
+    # a one-row view (every slot a row at most) on 8 packed rows
+    "width_of_one": (16, 12, 1, 8, [63, 64, 128, 0], [1, 0, 1, 1],
+                     "float32", 1, ()),
+    "wide2": (16, 6, 32, 64, [37, 0, 63, 5], [32, 0, 1, 20], "float32", 1,
+              ()),
+    "wide1": (16, 7, 32, 64, [37, 0, 63, 5], [32, 0, 1, 20], "float32", 1,
+              ()),
+    "blocks_of_64": (64, 8, 32, 80, [250, 0, 256, 480], [32, 0, 1, 32],
+                     "float32", 1, ()),
+    # two heads a grid step: a head group's block is walked by every slot
+    # before the next group's, and a slot starts on a multiple of 8 rows
+    "two_head_groups": (16, 12, 32, 80, [70, 50, 0, 96], [1, 32, 0, 20],
+                        "float32", 2, ()),
+    "two_head_groups_bf16": (16, 12, 32, 80, [70, 50, 0, 96],
+                             [1, 32, 0, 20], "bfloat16", 2, ()),
+}
+
+
+@pytest.fixture()
+def fresh_append_programs():
+    """The kernel's plan reads a module constant a case may patch: no
+    program traced under one value serves a call under another."""
+    def clear():
+        latent_attention._append_rows.clear_cache()
+        latent_attention._append_call.clear_cache()
+    clear()
+    yield
+    clear()
+
+
+@pytest.mark.parametrize("case", PACKED_CASES)
+def test_the_packed_rows_go_through_the_kernel_as_they_are(
+        case, monkeypatch, fresh_append_programs):
+    """A mixed step's rows on ONE axis (``cache_layout.RowMap``): the
+    pool's write lands where the per-slot form's does, bit for bit; the
+    kernel (interpret mode here) gives each live row what the gathered
+    form gives it and every other row zero; and the per-slot entries
+    (``_append_call``, as ``benchmark/tests/test_aot_*.py`` call it) are
+    the same program on another row axis, bit for bit."""
+    la = latent_attention
+    bs, mb, s, t, lens, qlens, dtype, groups, dead = PACKED_CASES[case]
+    b, h, d, dv = 4, 4, 48, 32
+    rng = np.random.default_rng(11)
+    _, pool, tables, lens, qlens = _pool_case(rng, b, s, h, d, bs, mb, lens,
+                                              qlens)
+    tables = np.array(tables)
+    for slot, entry in dead:
+        tables[slot, entry] = -1
+    tables = jnp.asarray(tables)
+    rows = CL.RowMap(qlens, lens, t, s)
+    q = (jnp.asarray(rng.normal(size=(t, h, d)), jnp.float32) * 0.3) \
+        .astype(dtype)
+    new = jnp.asarray(rng.normal(size=(t, d)), jnp.float32).astype(dtype)
+    pool = pool.astype(dtype)
+    if groups > 1:
+        hq = h // groups
+        monkeypatch.setattr(la, "_VMEM_BUDGET", la._vmem_bytes(
+            hq, la.held_rows(hq, b, b * s, s), s, d, dv,
+            max(la._KEY_TILE_MAX, bs), q.dtype.itemsize))
+        assert la.heads_per_step(h, b, t, s, d, dv, bs,
+                                 q.dtype.itemsize) == hq
+    live = np.asarray(rows.live)
+    assert live.sum() == int(qlens.sum()) and (
+        case == "idle_first_no_padding") == bool(live.all())
+    if case == "row_chunk_idle_tail":
+        assert np.asarray(rows.start).tolist() == [0, 1, 33, 33]
+
+    written = la.latent_pool_write(pool, new, tables, lens, qlens, rows)
+    per_slot = la.latent_pool_write(pool, rows.to_slots(new), tables, lens,
+                                    qlens)
+    np.testing.assert_array_equal(np.asarray(written, np.float32),
+                                  np.asarray(per_slot, np.float32))
+    assert not np.array_equal(np.asarray(written, np.float32),
+                              np.asarray(pool, np.float32))
+
+    got = la._append_rows(q, written, tables, lens, qlens, rows.start,
+                          width=s, dv=dv, every=None, interpret=True)
+    assert got.shape == (t, h, dv) and got.dtype == q.dtype
+    got = np.asarray(got, np.float32)
+    assert (got[~live] == 0).all()
+    want = np.asarray(rows.from_slots(_seen_by_each_row(
+        rows.to_slots(q), written, tables, lens, qlens, dv)))
+    np.testing.assert_allclose(got[live], want[live],
+                               atol=2e-5 if dtype == "float32" else 2e-2)
+    if not dead:
+        dense = la.latent_attention_dense(rows.to_slots(q), written, tables,
+                                          lens, qlens, dv)
+        np.testing.assert_allclose(
+            got[live], np.asarray(rows.from_slots(dense), np.float32)[live],
+            atol=2e-5 if dtype == "float32" else 2e-2)
+    slots = la._append_call(rows.to_slots(q), written, tables, lens, qlens,
+                            dv=dv, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(rows.from_slots(slots), np.float32)[live], got[live])
+    inside = np.arange(s)[None, :] < np.asarray(qlens)[:, None]
+    assert (np.asarray(slots, np.float32)[~inside] == 0).all()
 
 
 @pytest.mark.parametrize("mb,bs,n", [
@@ -290,22 +425,35 @@ def test_the_walks_entries_a_grid_step_come_from_the_tables_shape(mb, bs, n):
 
 
 @pytest.mark.parametrize("shape,hq", [
-    ((128, 512, 576, 512, 64), 8),      # dsv2_rag_answers' chunk step
-    ((32, 256, 576, 512, 64), 16),      # kimi_long_docs' chunk step
-    ((128, 1, 576, 512, 64), 128), ((32, 1, 576, 512, 64), 32),
+    # (heads, slots, rows of the axis, rows a slot at most, D, dv, block)
+    ((128, 8, 528, 512, 576, 512, 64), 16),  # dsv2_rag_answers' mixed step
+    ((32, 16, 272, 256, 576, 512, 64), 32),  # kimi_long_docs' mixed step
+    ((128, 8, 8, 1, 576, 512, 64), 128), ((32, 16, 16, 1, 576, 512, 64), 32),
+    # the per-slot chunk form at the cells' sizes (the ahead-of-time
+    # compiles of ``benchmark/tests``): every slot's 512 rows are held
+    ((128, 8, 4096, 512, 576, 512, 64), 2),
 ])
 def test_heads_a_grid_step_fit_vmem_with_the_key_tile_counted(shape, hq):
     la = latent_attention
     assert la.heads_per_step(*shape) == hq
-    h, s, d, dv, bs = shape
+    h, b, t, s, d, dv, bs = shape
     kt = la._KEY_TILE_MAX
-    with_tile = la._vmem_bytes(hq, s, d, dv, kt, 2)
+    held = la.held_rows(hq, b, t, s)
+    # a head group's block: every row of the axis, room for each slot to
+    # start on a sublane tile (none where ``hq`` is a multiple of one)
+    # and one row tile past the end
+    step = la.slot_step(hq)
+    assert step == 16 // math.gcd(hq, 16) and held % hq == 0
+    assert t * hq + la._row_tile(hq, s) <= held <= \
+        (t + b * (step - 1) + step) * hq + la._row_tile(hq, s) + hq
+    with_tile = la._vmem_bytes(hq, held, s, d, dv, kt, 2)
     assert with_tile <= la._VMEM_BUDGET
     # the tile's two buffers, the tile and a row tile's f32 scores
-    assert with_tile - la._vmem_bytes(hq, s, d, dv, 0, 2) == \
+    assert with_tile - la._vmem_bytes(hq, held, s, d, dv, 0, 2) == \
         3 * kt * d * 2 + la._row_tile(hq, s) * kt * 4
     if hq < h:
-        assert la._vmem_bytes(2 * hq, s, d, dv, kt, 2) > la._VMEM_BUDGET
+        assert la._vmem_bytes(2 * hq, la.held_rows(2 * hq, b, t, s), s, d,
+                              dv, kt, 2) > la._VMEM_BUDGET
 
 
 def test_absorbed_attention_equals_the_per_head_reference():
