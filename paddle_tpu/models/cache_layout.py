@@ -45,16 +45,16 @@ one-token step) beside ``seq_lens`` / ``q_lens``. Embedding, norms,
 residuals, projections, feed-forwards, routers and experts run on the
 ``T`` rows and never look at the map, except for a row's position
 (``rows.pos``) and whether it holds a token (``rows.live``). A layer
-takes the per-slot view ``[B, S, ...]`` only where its state is per slot
--- around the paged append attention, the dense scatter-and-attend, a
-recurrent layer's convolution tail and chunk scan -- through the two
-gathers :meth:`RowMap.to_slots` and :meth:`RowMap.from_slots`; rows of
-that view past ``q_lens[b]`` are finite garbage nobody reads, as those
-kernels' contracts always said. A core that reads ``(start, q_lens, seq_lens)``
-itself takes the packed rows as they are and builds no view: power
-retention's walk, and the latent pool's write and attention
-(``ops/kernels/latent_attention.py``), whose rows without a token come
-back zero.
+takes the per-slot view ``[B, S, ...]`` only where its core is written
+per slot, through the two gathers :meth:`RowMap.to_slots` and
+:meth:`RowMap.from_slots`; rows of that view past ``q_lens[b]`` are
+finite garbage nobody reads, as those kernels' contracts always said.
+A core that reads ``(start, q_lens, seq_lens)`` itself takes the packed
+rows as they are and builds no view: power retention's walk, the latent
+pool's write and attention (``ops/kernels/latent_attention.py``), and
+KDA's convolution and chunk walk (``kda.causal_conv_packed``,
+``ops/kernels/kda_chunk_walk.py``), whose rows without a token come back
+zero.
 """
 from __future__ import annotations
 
@@ -285,7 +285,18 @@ class RowMap:
     ``live`` [T] whether it holds a token (``t < cu[-1]``; the rest are
     padding, at column and position 0 so that nothing computed on them
     indexes out of a table), ``start`` [B] a slot's first packed row,
-    ``q_lens`` [B], ``width`` the per-slot view's S."""
+    ``q_lens`` [B], ``width`` the per-slot view's S.
+
+    **Who still calls** :meth:`to_slots` / :meth:`from_slots`: the paged
+    K/V append's layers (``models/llama.py``'s attention, Solar's
+    ``GatedAttention``: ``ops/kernels/paged_attention.py`` takes ``[B, S,
+    H, D]``), ``models/lora.py``'s per-slot adapter gather, the engine's
+    read-out and id packing, and the fallbacks that run a per-slot XLA
+    form on packed rows (``latent_attention_append`` on a CPU,
+    ``KimiDeltaAttention`` at a head width off the lanes,
+    ``kda_chunk_walk`` for more packed rows than VMEM holds). The kernels
+    of power retention, latent attention and KDA read ``start`` and
+    ``q_lens`` and call neither."""
     __slots__ = ("slot", "col", "pos", "live", "start", "q_lens", "width")
 
     def __init__(self, q_lens, seq_lens, n_rows, width):

@@ -156,13 +156,11 @@ class KimiDeltaAttention(Layer):
                 gate = jax.nn.sigmoid(mm32(mm(x, wga), wgb)) \
                     .reshape(lead + (H, K))
             with scope("pt.view"):
-                # the per-slot view [B, S, ...], around the recurrence
-                # only: a mixed step's convolution runs on its packed
-                # rows, and q, k, v, g, beta go to the slots after it
                 b, s = (S.shape[0], rows.width) if rows is not None \
                     else qkv.shape[:2]
-                # the kernel walks a step's live chunks, and resets a
-                # fresh slot and masks the dead rows itself
+                # the kernel walks a step's live chunks on the rows as
+                # they lie, packed or per slot, and resets a fresh slot
+                # and masks the dead rows itself
                 walk = s > 1 and _walk.serves(K, K)
                 fresh = (lens.astype(jnp.int32) == 0)
                 if not walk:
@@ -184,25 +182,35 @@ class KimiDeltaAttention(Layer):
                     q = _l2(y[:, :, 0]) * jnp.float32(K ** -0.5)
                     k, v = _l2(y[:, :, 1]), y[:, :, 2]
             if rows is not None:
-                with scope("pt.view"):
-                    q, k, v, g, beta = (rows.to_slots(a) for a in
-                                        (q, k, v, g[0], beta[0]))
-            if not walk:
+                g, beta = g[0], beta[0]
+            if walk:
+                # a mixed step's packed rows go to the kernel as they are
+                # and come back packed: no per-slot view of anything
+                with scope("pt.core"):
+                    o, S = _walk.kda_chunk_walk(q, k, v, g, beta, S, q_lens,
+                                                lens, rows)
+                    counts = _walk.grid_counts(
+                        q_lens, q.shape[0] if rows is not None else b * s, s)
+            else:
+                # the XLA forms (a toy width's chunks, the one-row
+                # recurrence) are per slot: the view [B, S, ...] around
+                # them, of q, k, v, g, beta and of what comes back
+                if rows is not None:
+                    with scope("pt.view"):
+                        q, k, v, g, beta = (rows.to_slots(a) for a in
+                                            (q, k, v, g, beta))
                 with scope("pt.gate"):
                     g = jnp.where(live[:, :, None, None], g, 0.0)
                     beta = jnp.where(live[:, :, None], beta, 0.0)
-            with scope("pt.core"):
-                if walk:
-                    o, S = _walk.kda_chunk_walk(q, k, v, g, beta, S, q_lens,
-                                                lens)
-                    counts = _walk.grid_counts(q_lens, s)
-                else:
+                with scope("pt.core"):
                     run = _kda.kda_recurrent if s == 1 else _kda.kda_chunk
                     o, S = run(q, k, v, g, beta, S)
                     counts = jnp.zeros((len(_walk.COUNTERS),), jnp.int32)
+                if rows is not None:
+                    with scope("pt.view"):
+                        o = rows.from_slots(o)
             if rows is not None:
-                with scope("pt.view"):
-                    o = rows.from_slots(o)[None]
+                o = o[None]
             with scope("pt.gate"):
                 o = (rms(o, on, eps) * gate).astype(x.dtype)
             with scope("o_proj"):

@@ -4,7 +4,8 @@ convolution in front of it. Plain ``jax.numpy`` on raw arrays; float32
 state, ``highest`` matmul precision inside the recurrence. On the chip a
 step of more than one row a slot runs the recurrence in the Pallas kernel
 beside this file (``kda_chunk_walk.py``: 64-row chunks, only a step's live
-ones); :func:`kda_recurrent` serves the one-row steps, and
+ones, read off a mixed step's packed rows where they lie);
+:func:`kda_recurrent` serves the one-row steps, and
 :func:`kda_chunk` is the form the kernel is held to by the tests and what
 a head width off the lanes (a toy model's) still takes.
 

@@ -1,23 +1,52 @@
 """Chunked KDA (``ops/kernels/kda.py``: the recurrence and its chunked
-form) as one Pallas kernel that walks only the LIVE chunks of a step.
+form) as one Pallas kernel that walks only the LIVE chunks of a step, on
+the step's rows as they lie.
 
-A mixed step hands a recurrent layer the per-slot view ``[B, S, H, K]`` of
-which a slot's first ``q_lens[b]`` rows hold a token: one slot a prompt
-chunk of ``S`` rows, the others one decode row or none. The kernel's grid
-is (slot, group of heads, chunk of ``CHUNK`` rows), the chunk axis
-innermost and sequential, with ``(q_lens, seq_lens)`` scalar-prefetched:
+**The row axis.** A mixed step hands a recurrent layer its rows on ONE
+packed axis ``[T, H, K]``: slot ``b``'s rows are the ``q_lens[b]`` from
+``start[b]`` on (``cache_layout.RowMap.start``), one slot a prompt chunk,
+the others one decode row or none. The kernel addresses them there:
+``(start, q_lens, seq_lens)`` are scalar-prefetched, chunk ``c`` of slot
+``b`` is the :data:`CHUNK` rows from ``start[b] + CHUNK c`` on, and no
+``[B, S, H, K]`` operand and no ``[B, S, H, V]`` output exist. The
+per-slot form ``[B, S, ...]`` (a plain forward, the tests) is the same
+call on the row axis ``[B S]`` with ``start[b] = b S``.
 
-- **Only live chunks do work.** A grid step whose chunk starts at or past
-  ``q_lens[b]`` computes nothing and its operands' index maps stay on the
-  slot's last live block, so nothing is fetched; it stores zeros, which is
-  what every dead row's output is. A slot without a live row reads and
-  writes no state: its blocks map to a neighbouring live slot's, which
-  stay where they are, and its state is aliased through.
+**The walk is a flat table of the live (slot, chunk) pairs.** The grid is
+(group of heads, step of the table), the head group OUTERMOST. The table
+(:func:`_table`, made from ``q_lens`` by the wrapper and prefetched) lists
+the chunks that hold a live row, slots ascending: at most
+:func:`table_steps` of them, ``(T + 63 B) / 64`` and not ``B x chunks a
+slot``. The steps past its end do nothing and stay on the blocks of the
+last live one, so they move nothing.
+
+- **A head group's rows are resident.** Its operand blocks ``(T + CHUNK,
+  hg, K)`` and its output block have the index ``(0, h, 0)``: fetched once
+  and written once a head group, whatever the slots hold. A chunk is read
+  and written at a dynamic offset on the LEADING axis of the block, which
+  no tiling covers, so a slot's rows may start anywhere: no gather, no
+  alignment. ``beta`` comes as ``[T, 1, H]`` for the same reason (in two
+  dimensions its rows would lie on the sublanes). The blocks are a chunk
+  LONGER than the arrays (a chunk read from a slot's last rows may end
+  past row ``T``): only the arrays' rows are fetched and written, so the
+  operands are padded nowhere. A chunk's rows past the slot's own are the
+  rows of later slots, or nobody's, or past the axis (whatever VMEM held):
+  q, k, v, g and beta are masked on the way in, and what the chunk stores
+  there (zeros) a later slot's chunk writes over. A row no chunk covered
+  is never written: the wrapper sends every row that holds no token back
+  as zero.
+- **Where the rows do not fit VMEM at once** (:data:`_RESIDENT_BUDGET`:
+  the per-slot form of a long plain forward) the same kernel takes a
+  chunk a block, ``(CHUNK, hg, K)`` at block ``start[b] / CHUNK + c``,
+  which needs every slot's first row on a chunk's boundary: the per-slot
+  form pads ``S`` to one. Packed rows that large go through the per-slot
+  view (``RowMap.to_slots``), the one case that still builds it.
 - **The state stays in VMEM across a slot's chunks.** ``S`` ``[K, V]``
   float32 a head lives in the output block from the slot's first chunk to
   its last: read from HBM once and written once a (slot, head). A slot at
   position 0 (``seq_lens[b] == 0``) starts from zeros there, when its
-  first row comes.
+  first row comes. A slot without a live row is in no step of the table:
+  its state is aliased through, neither read nor written.
 - **No ``[C, C, K]`` tensor in HBM.** A chunk of 64 rows is eight
   sub-blocks of ``SUB`` = 8 (a sublane tile: at 16 a column's work is
   two registers of which one is masked half the time, and the kernel is a
@@ -68,6 +97,9 @@ CHUNK = 64
 SUB = 8
 _HEADS = 8
 _LANES = 128
+#: what a head group's resident row blocks (every one double-buffered) may
+#: take of VMEM; more rows than that go a chunk a block
+_RESIDENT_BUDGET = 64 << 20
 #: what :func:`grid_counts` counts, in order
 COUNTERS = ("kda_grid_steps", "kda_grid_live")
 
@@ -93,12 +125,21 @@ def heads_per_step(h):
     return _HEADS if h % _HEADS == 0 else h
 
 
-def grid_counts(q_lens, s):
-    """int32 [2] in :data:`COUNTERS` order: the (slot, chunk) grid steps
-    of one call over ``s`` rows a slot, and those that held a live row."""
-    n = -(-int(s) // CHUNK)
-    q = jnp.clip(q_lens.astype(jnp.int32), 0, int(s))
-    return jnp.stack([jnp.int32(q.shape[0] * n),
+def table_steps(slots, rows, width):
+    """Steps of the walk's table a head group, for ``slots`` slots of at
+    most ``width`` rows each on an axis of ``rows`` rows: the most chunks
+    that can hold a live row. A slot's last chunk may be one row full, so
+    ``sum(ceil(q / CHUNK)) <= (rows + (CHUNK - 1) slots) / CHUNK``."""
+    return max(1, min(slots * -(-int(width) // CHUNK),
+                      (int(rows) + (CHUNK - 1) * slots) // CHUNK))
+
+
+def grid_counts(q_lens, rows, width):
+    """int32 [2] in :data:`COUNTERS` order: the steps of the table one
+    call walks a head group (:func:`table_steps`), and those that hold a
+    live (slot, chunk) pair."""
+    q = jnp.clip(q_lens.astype(jnp.int32), 0, int(width))
+    return jnp.stack([jnp.int32(table_steps(q.shape[0], rows, width)),
                       jnp.sum((q + (CHUNK - 1)) // CHUNK)]).astype(jnp.int32)
 
 
@@ -107,56 +148,57 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _walk(q_lens, n_chunks, hg_last):
-    """Where each slot's grid steps point their operands, from ``q_lens``
-    [B] (clipped): (block slot [B], head group [B] or -1, chunk [B]). A
-    live slot addresses its own blocks (head group -1: the grid's), the
-    chunk index capped at its last live chunk. An idle slot addresses the
-    block the walk is on when it gets there -- the last block of the live
-    slot before it, or the first block of the first live slot when none is
-    before it (slot 0's when every slot is idle) -- so its steps move
-    nothing."""
-    b = q_lens.shape[0]
-    live = q_lens > 0
-    idx = jnp.arange(b, dtype=jnp.int32)
-    prev = jax.lax.cummax(jnp.where(live, idx, -1))           # [B]
-    first = jnp.where(jnp.any(live), jnp.argmax(live), 0).astype(jnp.int32)
-    src = jnp.where(prev >= 0, prev, first)
-    last = jnp.maximum((q_lens + (CHUNK - 1)) // CHUNK - 1, 0)
-    last = jnp.minimum(last, n_chunks - 1)
-    hgrp = jnp.where(live, -1, jnp.where(prev >= 0, hg_last, 0))
-    chunk = jnp.where(live | (prev >= 0), last[src], 0)
-    return src.astype(jnp.int32), hgrp.astype(jnp.int32), \
-        chunk.astype(jnp.int32)
+def _table(q_lens, n):
+    """The walk of one head group, from ``q_lens`` [B] (clipped): (slot
+    [n], chunk [n], live steps [1]). Step ``t`` below the live count is
+    chunk ``chunk[t]`` of slot ``slot[t]``, the live chunks in order, slots
+    ascending; a step past it repeats the last live one (the last slot's
+    chunk 0 where nothing is live), so its blocks are already there."""
+    per = (q_lens + (CHUNK - 1)) // CHUNK
+    ends = jnp.cumsum(per)
+    t = jnp.minimum(jnp.arange(n, dtype=jnp.int32),
+                    jnp.maximum(ends[-1] - 1, 0))
+    done = t[:, None] >= ends[None, :]         # [n, B] the slots before
+    slot = jnp.minimum(jnp.sum(done, axis=1), q_lens.shape[0] - 1)
+    chunk = t - jnp.sum(jnp.where(done, per[None, :], 0), axis=1)
+    return slot.astype(jnp.int32), chunk.astype(jnp.int32), \
+        ends[-1:].astype(jnp.int32)
 
 
-def _in_map(b, h, c, ql, lens, src, hgrp, chunk):
-    idle = hgrp[b] >= Z
-    return (src[b], jnp.where(idle, chunk[b], jnp.minimum(c, chunk[b])),
-            jnp.where(idle, hgrp[b], h), Z)
+def _row_map(resident, every_head):
+    """The index map of a row block ``(rows, heads, width)``. Resident: a
+    head group's rows of every slot are one block for the whole of its
+    walk, fetched and written once; else the step's chunk, which lies on
+    a block's boundary. ``every_head``: the block holds all heads
+    (``beta``'s), not the grid's group."""
+    def im(h, t, ql, lens, start, slot, chunk, total):
+        row = Z if resident else \
+            start[slot[t]] // np.int32(CHUNK) + chunk[t]
+        return (row, Z if every_head else h, Z)
+    return im
 
 
-def _beta_map(b, h, c, ql, lens, src, hgrp, chunk):
-    return _in_map(b, h, c, ql, lens, src, hgrp, chunk)[:2] + (Z,)
+def _state_map(h, t, ql, lens, start, slot, chunk, total):
+    return (slot[t], h, Z, Z)
 
 
-def _state_map(b, h, c, ql, lens, src, hgrp, chunk):
-    return (src[b], jnp.where(hgrp[b] >= Z, hgrp[b], h), Z, Z)
-
-
-def _out_map(b, h, c, ql, lens, src, hgrp, chunk):
-    return (b, c, h, Z)
-
-
-def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
-            v_ref, g_ref, beta_ref, s_in_ref, o_ref, s_out_ref, *, hg, kd):
+def _kernel(ql_ref, lens_ref, start_ref, slot_ref, chunk_ref, total_ref,
+            q_ref, k_ref, v_ref, g_ref, beta_ref, s_in_ref, o_ref, s_out_ref,
+            *, hg, kd, resident):
     f32 = jnp.float32
-    b, h, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    C = q_ref.shape[0]
+    h, t = pl.program_id(0), pl.program_id(1)
+    C = CHUNK
+    b, c = slot_ref[t], chunk_ref[t]
     n = ql_ref[b]
+    live = t < total_ref[0]
     here = n - c * np.int32(C)                # live rows of this chunk
     first = c == Z
     fresh = lens_ref[b] == Z
+    # where the chunk's rows lie in the row blocks
+    row0 = start_ref[b] + c * np.int32(C) if resident else 0
+
+    def span(rows):
+        return pl.ds(row0, rows) if resident else slice(0, rows)
 
     def each_head(fn):
         # an int32 index (a ``fori_loop`` between two constants counts in
@@ -170,7 +212,7 @@ def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
 
     def beta_of(i, rows):
         """beta of head ``i`` of this step's group, [rows, 1]."""
-        blk = beta_ref[0:rows, :]
+        blk = beta_ref[span(rows), 0, :]
         lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
         mine = lane == h * np.int32(hg) + i
         return jnp.sum(jnp.where(mine, blk, 0.0), axis=1, keepdims=True)
@@ -180,19 +222,14 @@ def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
         return jnp.where(first & fresh, 0.0,
                          jnp.where(first, s_in_ref[i], s_out_ref[i]))
 
-    @pl.when(here <= Z)
-    def _dead():
-        o_ref[...] = jnp.zeros(o_ref.shape, f32)
-
-    @pl.when((b == Z) & (h == Z) & first & (ql_ref[src_ref[b]] == Z))
+    @pl.when((t == Z) & (total_ref[0] == Z))
     def _all_idle():
-        # slot 0 addresses an idle slot only when no slot is live: the one
-        # state block such a walk addresses goes back as it came
+        # no slot is live: the one state block such a walk addresses goes
+        # back as it came
         s_out_ref[...] = s_in_ref[...]
 
-    @pl.when((here > Z) & (n == 1))
+    @pl.when(live & (n == 1))
     def _one_row():
-        o_ref[...] = jnp.zeros(o_ref.shape, f32)
         eye = jax.lax.broadcasted_iota(jnp.int32, (kd, kd), 0) == \
             jax.lax.broadcasted_iota(jnp.int32, (kd, kd), 1)
 
@@ -200,17 +237,17 @@ def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
             return jnp.sum(jnp.where(eye, x, 0.0), axis=1, keepdims=True)
 
         def head(i):
-            S = state_of(i) * col(jnp.exp(g_ref[0:1, i, :]))
-            kc = col(k_ref[0:1, i, :])
-            u = beta_of(i, 1) * (v_ref[0:1, i, :]
+            S = state_of(i) * col(jnp.exp(g_ref[span(1), i, :]))
+            kc = col(k_ref[span(1), i, :])
+            u = beta_of(i, 1) * (v_ref[span(1), i, :]
                                  - jnp.sum(S * kc, axis=0, keepdims=True))
             S = S + kc * u
             s_out_ref[i] = S
-            o_ref[0:1, i, :] = jnp.sum(S * col(q_ref[0:1, i, :]), axis=0,
-                                       keepdims=True)
+            o_ref[span(1), i, :] = jnp.sum(
+                S * col(q_ref[span(1), i, :]), axis=0, keepdims=True)
         each_head(head)
 
-    @pl.when((here > Z) & (n > 1))
+    @pl.when(live & (n > 1))
     def _chunk():
         row = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
         alive = row < here
@@ -224,8 +261,12 @@ def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
         neg = np.float32(-np.inf)
 
         def head(i):
-            q, k = q_ref[:, i, :], k_ref[:, i, :]
-            g = jnp.where(alive, g_ref[:, i, :], 0.0)
+            # rows past the slot's own are a later slot's or nobody's
+            # (past the axis: whatever the block holds there)
+            q = jnp.where(alive, q_ref[span(C), i, :], 0.0)
+            k = jnp.where(alive, k_ref[span(C), i, :], 0.0)
+            v = jnp.where(alive, v_ref[span(C), i, :], 0.0)
+            g = jnp.where(alive, g_ref[span(C), i, :], 0.0)
             beta = jnp.where(alive, beta_of(i, C), 0.0)
             G = _dot(tri, g, _NN)                         # running sum
             # A (r < s) and B (r <= s), a sub-block of rows at a time
@@ -258,7 +299,7 @@ def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
             eG = jnp.exp(G)
             kq = _dot(jnp.concatenate([k * eG, q * eG]), S, _NN)
             # U: forward elimination of beta (V - k_in S), 8 rows a tile
-            rhs = beta * (v_ref[:, i, :] - kq[:C])
+            rhs = beta * (v - kq[:C])
             u = [rhs[t:t + 8] for t in range(0, C, 8)]
             lt = [L[t:t + 8] for t in range(0, C, 8)]
             for r in range(C - 1):
@@ -267,7 +308,7 @@ def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
                     u[t] = u[t] - lt[t][:, r:r + 1] * ur
             U = jnp.concatenate(u)
             o = kq[C:] + _dot(Bm, U, _NN)
-            o_ref[:, i, :] = jnp.where(alive, o, 0.0)
+            o_ref[span(C), i, :] = jnp.where(alive, o, 0.0)
             g_end = G[C - 1:C]
             dec = jnp.sum(jnp.where(eye, jnp.exp(g_end), 0.0), axis=1,
                           keepdims=True)                  # [K, 1]
@@ -275,59 +316,116 @@ def _kernel(ql_ref, lens_ref, src_ref, hgrp_ref, chunk_ref, q_ref, k_ref,
         each_head(head)
 
 
-def kda_chunk_walk(q, k, v, g, beta, state, q_lens, seq_lens):
+def kda_chunk_walk(q, k, v, g, beta, state, q_lens, seq_lens, rows=None):
     """q, k, g: [B, S, H, K]; v: [B, S, H, V]; beta: [B, S, H]; state: [B,
     H, K, V] float32; q_lens: [B] live rows of each slot (its first);
     seq_lens: [B] tokens a slot holds before them (0: the slot starts from
     zeros). Returns (o [B, S, H, V] float32, the state after each slot's
     live rows): what :func:`kda.kda_recurrent` gives on the live rows; a
     dead row's output is 0, and a slot without a live row keeps its state
-    as it is (zeroed only when its first row comes)."""
-    return _walk_call(q, k, v, g, beta, state, q_lens, seq_lens,
-                      interpret=_interpret())
+    as it is (zeroed only when its first row comes). With ``rows`` (a
+    mixed step's ``cache_layout.RowMap``) q, k, g, v and beta are the
+    packed ``[T, H, ...]`` as the layer computed them, slot ``b``'s rows
+    the ``q_lens[b]`` from ``rows.start[b]`` on, and so is ``o``, ``[T, H,
+    V]``: a row that holds no token comes back zero."""
+    if rows is None:
+        return _walk_call(q, k, v, g, beta, state, q_lens, seq_lens,
+                          interpret=_interpret())
+    if not _resident(q.shape[0], q.shape[1], q.shape[2], v.shape[2]):
+        # more rows than a head group holds at once, at starts that are
+        # no chunk's: through the per-slot view
+        o, state = _walk_call(*(rows.to_slots(a) for a in (q, k, v, g, beta)),
+                              state, q_lens, seq_lens, interpret=_interpret())
+        return jnp.where(rows.live[:, None, None], rows.from_slots(o),
+                         0.0), state
+    return _walk_rows(q, k, v, g, beta, state, q_lens, seq_lens, rows.start,
+                      width=rows.width, every=None, interpret=_interpret())
+
+
+def _resident(t, h, k, v):
+    """Whether the ``t`` rows of an axis (and the chunk of room behind
+    them) fit :data:`_RESIDENT_BUDGET` as a head group's resident blocks:
+    q, k, g, v and o, and ``beta`` a (sublane, lane) tile a row."""
+    hg = heads_per_step(h)
+    return 2 * 4 * (t + CHUNK) * (hg * (3 * k + 2 * v) + 8 * _LANES) \
+        <= _RESIDENT_BUDGET
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
 def _walk_call(q, k, v, g, beta, state, q_lens, seq_lens, *, interpret):
-    """The walk and the Pallas call, under one inlined inner jit so that a
-    model's layers share a trace (``latent_attention._append_call``)."""
-    f32 = jnp.float32
-    B, S, H, K = q.shape
-    V = v.shape[-1]
+    """The per-slot form ``[B, S, ...]``: the row axis ``[B * S]`` with
+    slot ``b``'s rows from ``b * S``, ``S`` padded to whole chunks."""
+    B, S = q.shape[:2]
     pad = (-S) % CHUNK
-    n_chunks = (S + pad) // CHUNK
+    Sp = S + pad
+
+    def flat(a):
+        if pad:
+            a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        return a.reshape((B * Sp,) + a.shape[2:])
+
+    o, state = _walk_rows(flat(q), flat(k), flat(v), flat(g), flat(beta),
+                          state, q_lens, seq_lens,
+                          jnp.arange(B, dtype=jnp.int32) * np.int32(Sp),
+                          width=S, every=Sp, interpret=interpret)
+    return o.reshape((B, Sp) + o.shape[1:])[:, :S], state
+
+
+@functools.partial(jax.jit, static_argnames=("width", "every", "interpret"),
+                   inline=True)
+def _walk_rows(q, k, v, g, beta, state, q_lens, seq_lens, start, *, width,
+               every, interpret):
+    """The table and the ONE Pallas call, under an inlined inner jit so
+    that a model's layers share a trace
+    (``latent_attention._append_rows``). ``q`` [T, H, K]: slot ``b``'s rows
+    are the ``min(q_lens[b], width)`` from ``start[b]`` on, slots
+    ascending; ``every``: the starts are known to be this many rows apart
+    (None: they are not)."""
+    f32 = jnp.float32
+    T, H, K = q.shape
+    V = v.shape[-1]
+    B = state.shape[0]
     hg = heads_per_step(H)
-
-    def rows(a):
-        a = a.astype(f32)
-        return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2)) \
-            if pad else a
-
-    ql = jnp.clip(q_lens.astype(jnp.int32), 0, S)
-    walk = _walk(ql, n_chunks, H // hg - 1)
-    wide = lambda w: pl.BlockSpec((None, CHUNK, hg, w),  # noqa: E731
-                                  _in_map)
+    ql = jnp.clip(q_lens.astype(jnp.int32), 0, int(width))
+    start = start.astype(jnp.int32)
+    resident = _resident(T, H, K, V)
+    on_chunks = every is not None and every % CHUNK == 0
+    if not (resident or on_chunks):
+        raise ValueError(
+            f"kda_chunk_walk: {T} rows do not fit a head group's resident "
+            f"blocks, and a chunk a block needs the slots {CHUNK} rows "
+            f"apart (every={every})")
+    n = table_steps(B, T, width)
+    # a chunk read from a slot's last rows may end past the axis: the
+    # resident blocks are a chunk longer than the arrays, whose rows are
+    # all that is fetched and written
+    held = CHUNK if not resident else T if on_chunks else T + CHUNK
+    wide = lambda w: pl.BlockSpec(  # noqa: E731
+        (held, hg, w), _row_map(resident, False))
+    state_spec = pl.BlockSpec((None, hg, K, V), _state_map)
     o, state = pl.pallas_call(
-        functools.partial(_kernel, hg=hg, kd=K),
+        functools.partial(_kernel, hg=hg, kd=K, resident=resident),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(B, H // hg, n_chunks),
+            num_scalar_prefetch=6,
+            grid=(H // hg, n),
             in_specs=[wide(K), wide(K), wide(V), wide(K),
-                      pl.BlockSpec((None, CHUNK, H), _beta_map),
-                      pl.BlockSpec((None, hg, K, V), _state_map)],
-            out_specs=[pl.BlockSpec((None, CHUNK, hg, V), _out_map),
-                       pl.BlockSpec((None, hg, K, V), _state_map)],
+                      pl.BlockSpec((held, 1, H), _row_map(resident, True)),
+                      state_spec],
+            out_specs=[wide(V), state_spec],
         ),
-        out_shape=[jax.ShapeDtypeStruct((B, S + pad, H, V), f32),
+        out_shape=[jax.ShapeDtypeStruct((T, H, V), f32),
                    jax.ShapeDtypeStruct(state.shape, f32)],
-        input_output_aliases={10: 1},
+        input_output_aliases={11: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             # the blocks double-buffered, and room for a head's values
-            vmem_limit_bytes=8 * (CHUNK * (hg * (3 * K + 2 * V) + H)
+            vmem_limit_bytes=8 * (held * (hg * (3 * K + 2 * V) + 8 * _LANES)
                                   + 2 * hg * K * V) + (16 << 20)),
         name="kda_chunk_walk",
         interpret=interpret,
-    )(ql, seq_lens.astype(jnp.int32), *walk, rows(q), rows(k), rows(v),
-      rows(g), rows(beta), state.astype(f32))
-    return o[:, :S], state
+    )(ql, seq_lens.astype(jnp.int32), start, *_table(ql, n), q.astype(f32),
+      k.astype(f32), v.astype(f32), g.astype(f32),
+      beta.astype(f32)[:, None, :], state.astype(f32))
+    at = jnp.arange(T, dtype=jnp.int32)[:, None] - start[None, :]
+    live = jnp.any((at >= 0) & (at < ql[None, :]), axis=1)
+    return jnp.where(live[:, None, None], o, 0.0), state

@@ -571,3 +571,78 @@ def test_the_packed_latent_append_compiles_for_the_v5e(one_chip, cell, b, t,
             {cell: jax.jit(fn)}, {cell: args}, cell).as_text()
     assert "tpu_custom_call" in text and "latent_attention_append" in text
     assert " gather(" not in text
+
+
+@pytest.mark.parametrize("cell,config,program,slots,t,chunk,heads", [
+    ("kimi_long_docs", "kimi-linear-48b-a3b-ep4-d8", "kimi_linear", 8, 272,
+     256, 32),
+    ("solar_long_reports", "solar-open2-250b-ep8-d4", "solar_open2", 16, 528,
+     512, 64),
+])
+def test_the_packed_kda_layer_compiles_for_the_v5e(one_chip, monkeypatch,
+                                                   cell, config, program,
+                                                   slots, t, chunk, heads):
+    """A KDA layer of the two cells that have one, on a mixed step's
+    packed rows ``[1, T, hidden]`` at the cell's slots, rows and heads,
+    compiled by the TPU compiler installed here for a described v5e
+    (nothing runs): ``kda_chunk_walk`` takes the packed q, k, v, g and
+    beta as the layer computed them (Mosaic accepts a head group's
+    resident blocks read at ``start[b] + 64 c`` on the leading axis), so
+    the program holds no gather but the convolution's own and nothing of
+    the per-slot view's size ``[slots, chunk, heads, 128]``: no broadcast
+    that fills one, no ``dynamic-update-slice`` that writes a slot into
+    one, no operand."""
+    from benchmark.harness import loader
+    from benchmark.tests import brumby_aot
+    from paddle_tpu.core.tensor import Tensor, functional_mode
+    from paddle_tpu.jit.functional_call import bind_state
+    from paddle_tpu.models import cache_layout as CL
+    from paddle_tpu.models.kimi_linear import KimiDeltaAttention
+    from paddle_tpu.ops.kernels import kda_chunk_walk as walk
+    from paddle_tpu.ops.kernels import paged_attention
+    # this process sees a CPU: route the kernel to Mosaic all the same
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    with paddle.LazyGuard():
+        model = loader.module("programs", program).build(
+            loader.data("configs", config))
+    model.eval()
+    layer = next(block.self_attn for block in model.model.layers
+                 if isinstance(block.self_attn, KimiDeltaAttention))
+    assert layer.H == heads and walk.serves(layer.K, layer.K)
+    params = [p for _, p in layer.named_parameters()]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    def fn(vals, x, S, conv, lens, q_lens):
+        rows = CL.RowMap(q_lens, lens, t, chunk)
+        cache = CL.RecurrentCache({"S": S, "conv": conv}, lens, q_lens,
+                                  None, rows)
+        with paddle.no_grad(), functional_mode(), bind_state(params, vals):
+            out, new = layer(Tensor(x), cache)
+        return out._value, new.state["S"], new.state["conv"]
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    state = layer.state_shapes(np.dtype(bf16))
+    args = ([shape(p._value.shape, bf16) for p in params],
+            shape((1, t, model.config.hidden_size), bf16),
+            shape((slots,) + state["S"][0], state["S"][1]),
+            shape((slots,) + state["conv"][0], state["conv"][1]),
+            shape((slots,), i32), shape((slots,), i32))
+    with jax.default_matmul_precision("default"):
+        text = brumby_aot.compile_for_the_chip(
+            {cell: jax.jit(fn)}, {cell: args}, cell).as_text()
+    assert "tpu_custom_call" in text and "kda_chunk_walk" in text
+    # the gathers that are left read the convolution's tails, under
+    # ``pt.conv``: none moves a row to a slot or back
+    for line in text.splitlines():
+        if " gather(" in line:
+            assert "self_attn/pt.conv/" in line, line
+    view = f"[{slots},{chunk},{heads},128]"
+    assert view not in text, [ln for ln in text.splitlines()
+                              if view in ln][:3]
+    for line in text.splitlines():
+        if "pt.view" in line:
+            assert " broadcast(" not in line or f"{slots},{chunk}," \
+                not in line, line
+            assert "dynamic-update-slice(" not in line, line

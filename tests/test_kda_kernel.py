@@ -1,8 +1,9 @@
 """The KDA kernel (``paddle_tpu/ops/kernels/kda_chunk_walk.py``) interpreted
 on the CPU at small widths against the one-token form ``kda_recurrent`` and
 the XLA chunked form ``kda_chunk``: every decay, every mix of live rows a
-slot, a state carried through many calls, where an idle slot's grid steps
-point, the grid counts, the layer and the engine taking the kernel."""
+slot, a state carried through many calls, the walk's table of live (slot,
+chunk) pairs, the grid counts, the packed entry against the per-slot one
+bit for bit, the layer and the engine taking the kernel."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,10 +82,27 @@ def test_the_kernel_is_the_recurrence_on_the_live_rows(alpha, mix):
             np.testing.assert_array_equal(s[b], s0[b])
 
 
+def _pack(args, q_lens, t):
+    """The per-slot ``[B, S, ...]`` operands as a mixed step's packed rows
+    ``[t, ...]`` (garbage where no slot's row lies) and their RowMap."""
+    rows = CL.RowMap(q_lens, jnp.zeros_like(q_lens), t, args[0].shape[1])
+    rng = np.random.default_rng(99)
+    live = np.asarray(rows.live)
+    slot, col = np.asarray(rows.slot), np.asarray(rows.col)
+    packed = []
+    for a in args:
+        x = rng.normal(size=(t,) + a.shape[2:]).astype(np.float32)
+        x[live] = np.asarray(a)[slot[live], col[live]]
+        packed.append(jnp.asarray(x))
+    return packed, rows
+
+
+@pytest.mark.parametrize("entry", ["per_slot", "packed"])
 @pytest.mark.parametrize("alpha", [0.5, 0.99, 1e-4])
-def test_a_stream_in_calls_is_one_recurrent_pass(alpha):
+def test_a_stream_in_calls_is_one_recurrent_pass(alpha, entry):
     """4,096 rows of slot 0 in 256-row calls, slot 1 one row a call, slot
-    2 idle throughout: the state rides through 16 calls."""
+    2 idle throughout: the state rides through 16 calls, of the per-slot
+    entry and of the packed one (272 rows: 257 live, the rest garbage)."""
     t, chunk = 4096, 256
     n = t // chunk
     big = _inputs(alpha, 3, b=1, t=t)
@@ -98,13 +116,25 @@ def test_a_stream_in_calls_is_one_recurrent_pass(alpha):
     lens = jnp.zeros((3,), jnp.int32)
     q_lens = jnp.asarray([chunk, 1, 0], jnp.int32)
     call = jax.jit(W.kda_chunk_walk)
+
+    @jax.jit
+    def packed_call(*a):
+        rows = CL.RowMap(a[6], a[7], 272, chunk)
+        o, s = W.kda_chunk_walk(*a, rows)
+        return o, s, rows.to_slots(o)
     for c in range(n):
         args = []
         for a_big, a_small in zip(big, small):
             x = jnp.zeros((3, chunk) + a_big.shape[2:], jnp.float32)
             x = x.at[0].set(a_big[0, c * chunk:(c + 1) * chunk])
             args.append(x.at[1, 0].set(a_small[0, c]))
-        o, s = call(*args, s, q_lens, lens)
+        if entry == "packed":
+            o, s, o_slots = packed_call(*_pack(args, q_lens, 272)[0], s,
+                                        q_lens, lens)
+            assert float(jnp.abs(o[chunk + 1:]).max()) == 0.0
+            o = o_slots
+        else:
+            o, s = call(*args, s, q_lens, lens)
         lens = lens + q_lens
         _close(o[0], o_big[0, c * chunk:(c + 1) * chunk], f"o, call {c}")
         _close(o[1, 0], o_small[0, c], f"decode row, call {c}")
@@ -113,32 +143,101 @@ def test_a_stream_in_calls_is_one_recurrent_pass(alpha):
     np.testing.assert_array_equal(s[2], s_idle[2])
 
 
-@pytest.mark.parametrize("q_lens,steps,live", [
-    ([256, 1, 1, 1, 1, 1, 1, 1], 32, 11),     # kimi_long_docs' mixed step
-    ([0, 0, 0], 12, 0),
-    ([65, 64, 300], 12, 2 + 1 + 4),           # clipped to the rows there are
-    ([17], 4, 1),
+#: mixes of a packed step: (q_lens, rows of the axis, the view's width, heads)
+PACKED = {
+    # one full chunk beside decode rows
+    "chunk_and_rows": ([64, 1, 1, 1], 80, 64, 2),
+    # two partial chunks at starts that are no multiple of 8, decode rows
+    "two_partial": ([1, 300, 212, 1, 1], 528, 512, 2),
+    "idle_first_middle_last": ([0, 70, 0, 0, 1, 130, 0], 208, 192, 2),
+    "every_slot_idle": ([0, 0, 0], 48, 64, 2),
+    # a slot's rows end with the axis: its last chunk reads the padding
+    "to_the_last_row": ([1, 1, 1, 77], 80, 128, 2),
+    # two head groups of eight
+    "two_head_groups": ([3, 100, 0, 1], 112, 128, 16),
+}
+
+
+@pytest.mark.parametrize("fit", ["resident", "chunk_blocks"])
+@pytest.mark.parametrize("mix", sorted(PACKED))
+def test_the_packed_entry_is_the_per_slot_entry_bit_for_bit(mix, fit,
+                                                            monkeypatch):
+    """The kernel on a mixed step's packed rows against the same rows in
+    the per-slot view ``[B, S, ...]``: every live row and every state
+    equal to the last bit, every other row zero; slots 0 and 2 fresh
+    (``seq_lens`` 0). ``chunk_blocks``: with no room for resident blocks
+    the per-slot entry takes a chunk a block and the packed rows go
+    through the view, to the same bits."""
+    q_lens, t, width, heads = PACKED[mix]
+    b = len(q_lens)
+    rng = np.random.default_rng(31)
+    per_slot = list(KIMI._kda_inputs(0.9, rng, b=b, t=width, h=heads, k=K))
+    s0 = jnp.asarray(rng.normal(size=(b, heads, K, K)), jnp.float32)
+    q_lens = jnp.asarray(q_lens, jnp.int32)
+    lens = jnp.asarray([0, 5, 0, 9, 2, 40, 1][:b], jnp.int32)
+    packed, rows = _pack(per_slot, q_lens, t)
+    if mix == "two_partial":
+        assert [int(x) % 8 for x in rows.start[1:3]] == [1, 5]
+    want_o, want_s = W.kda_chunk_walk(*per_slot, s0, q_lens, lens)
+    if fit == "chunk_blocks":
+        monkeypatch.setattr(W, "_RESIDENT_BUDGET", 0)
+        W._walk_rows.clear_cache()
+        W._walk_call.clear_cache()
+    try:
+        o, s = W.kda_chunk_walk(*packed, s0, q_lens, lens, rows)
+        slot_o, slot_s = W.kda_chunk_walk(*per_slot, s0, q_lens, lens)
+    finally:
+        W._walk_rows.clear_cache()
+        W._walk_call.clear_cache()
+    assert o.shape == (t, heads, K) and o.dtype == jnp.float32
+    live = np.asarray(rows.live)
+    np.testing.assert_array_equal(
+        np.asarray(o)[live],
+        np.asarray(want_o)[np.asarray(rows.slot)[live],
+                           np.asarray(rows.col)[live]])
+    assert float(jnp.abs(o[~live]).max() if (~live).any() else 0.0) == 0.0
+    np.testing.assert_array_equal(s, want_s)
+    # the two ways of holding the rows are one kernel
+    np.testing.assert_array_equal(slot_o, want_o)
+    np.testing.assert_array_equal(slot_s, want_s)
+    for i, n in enumerate(PACKED[mix][0]):
+        if n == 0:
+            np.testing.assert_array_equal(s[i], s0[i])
+
+
+@pytest.mark.parametrize("q_lens,rows,width,steps,live", [
+    # kimi_long_docs' mixed step: (272 + 63 x 8) / 64 table steps where
+    # the per-slot grid had 8 x 4
+    ([256, 1, 1, 1, 1, 1, 1, 1], 272, 256, 12, 11),
+    # solar_long_reports': 24 where 16 x 8
+    ([512] + [1] * 15, 528, 512, 24, 23),
+    ([0, 0, 0], 48, 256, 3, 0),
+    # the per-slot form: never more than slots x chunks a slot
+    ([65, 64, 300], 3 * 256, 256, 12, 2 + 1 + 4),     # clipped to the width
+    ([17], 256, 256, 4, 1),
+    ([1], 16, 16, 1, 1),
 ])
-def test_grid_counts_are_the_slot_chunk_steps_and_the_live_ones(
-        q_lens, steps, live):
-    got = W.grid_counts(jnp.asarray(q_lens, jnp.int32), 256)
+def test_grid_counts_are_the_tables_steps_and_the_live_ones(
+        q_lens, rows, width, steps, live):
+    got = W.grid_counts(jnp.asarray(q_lens, jnp.int32), rows, width)
     assert got.dtype == jnp.int32 and [int(x) for x in got] == [steps, live]
+    assert W.table_steps(len(q_lens), rows, width) == steps >= live
 
 
-@pytest.mark.parametrize("q_lens,src,hgrp,chunk", [
-    # live slots address themselves, capped at their last live chunk
-    ([256, 1, 130], [0, 1, 2], [-1, -1, -1], [3, 0, 2]),
-    # idle slots before the first live one wait on ITS first block; those
-    # after a live one stay on its last block (last head group: 3)
-    ([0, 0, 70, 0, 1, 0], [2, 2, 2, 2, 4, 4], [0, 0, -1, 3, -1, 3],
-     [0, 0, 1, 1, 0, 0]),
-    # nothing live: every step stays on slot 0's first block
-    ([0, 0, 0], [0, 0, 0], [0, 0, 0], [0, 0, 0]),
+@pytest.mark.parametrize("q_lens,n,slot,chunk,total", [
+    # the live chunks in order, slots ascending; the steps past them
+    # repeat the last live one
+    ([256, 1, 130], 9, [0, 0, 0, 0, 1, 2, 2, 2, 2],
+     [0, 1, 2, 3, 0, 0, 1, 2, 2], 8),
+    # an idle slot is in no step of the table
+    ([0, 0, 70, 0, 1, 0], 5, [2, 2, 4, 4, 4], [0, 1, 0, 0, 0], 3),
+    # nothing live: every step on one block, none of them live
+    ([0, 0, 0], 3, [2, 2, 2], [0, 0, 0], 0),
 ])
-def test_an_idle_slots_steps_address_a_block_that_is_already_there(
-        q_lens, src, hgrp, chunk):
-    got = W._walk(jnp.asarray(q_lens, jnp.int32), 4, 3)
-    assert [[int(x) for x in a] for a in got] == [src, hgrp, chunk]
+def test_the_table_lists_the_live_chunks_and_then_stays_where_it_is(
+        q_lens, n, slot, chunk, total):
+    got = W._table(jnp.asarray(q_lens, jnp.int32), n)
+    assert [[int(x) for x in a] for a in got] == [slot, chunk, [total]]
     assert all(a.dtype == jnp.int32 for a in got)
 
 

@@ -249,6 +249,40 @@ def test_engine_serves_what_the_reference_would(case):
     assert sum(d["live_tiles"] for d in mixed) == s["attn_tile_steps"]
 
 
+def test_the_engine_serves_two_chunks_a_step_through_the_kda_kernel(
+        monkeypatch):
+    """The rule on shapes lifted (the kernel interpreted at the toy
+    width), a budget of two chunks: a mixed step's packed axis holds two
+    slots' prompt chunks back to back beside a decode row, and
+    ``kda_chunk_walk`` takes each slot's rows off it by ``(start, q_lens,
+    seq_lens)`` at beta up to 2. The served tokens are the plain
+    reference's, as on the XLA path; the kernel's table of two slots'
+    chunks and a row is what the counters book."""
+    monkeypatch.setattr(kda_chunk_walk, "serves", lambda k, v: True)
+    seed = 17
+    model, _ = build(TOY, seed)
+    rng = np.random.default_rng(6)
+
+    def doc(n):
+        return rng.integers(1, 256, size=n).astype(np.int32)
+    arrivals = {0: [(doc(21), 20)], 2: [(doc(70), 9), (doc(61), 8)]}
+    done, eng = _serve(model, arrivals, max_step_tokens=64)
+    assert eng.mixed_rows == 64
+    assert any(d.get("prefill_rows", 0) > 32 and d["decode_rows"] == 1
+               for d in eng.dispatched)
+    out = R.served_gaps(seed, TOY, list(done.values()), pad_to=64)
+    assert np.concatenate(out["gaps"]).max() < 1e-3 * out["logit_std"]
+    s = eng.stats
+    kda_layers = sum(kind == "kda" for kind in
+                     (model.config.layer_kind(i)
+                      for i in range(model.config.num_hidden_layers)))
+    # 3 slots of at most one 64-row chunk each on 64 packed rows
+    steps = kda_chunk_walk.table_steps(3, 64, 32)
+    assert steps == 3 and kda_layers > 0
+    assert s["kda_grid_steps"] == kda_layers * steps * s["fused_steps"]
+    assert 0 < s["kda_grid_live"] <= s["kda_grid_steps"]
+
+
 # ---- (b) the share test ---------------------------------------------------
 
 def test_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
