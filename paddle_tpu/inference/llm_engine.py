@@ -45,7 +45,8 @@ import numpy as np
 
 from ..analysis import lock_watchdog as _lockwatch
 from ..core.tensor import Tensor, functional_mode
-from ..models.cache_layout import RowMap, collect_counts, packed_rows
+from ..models.cache_layout import (Layout, RowMap, collect_counts,
+                                   packed_rows)
 from ..models.llama import SlotKVCache, _sample_logits_device
 from ..models.lora import lora_scope
 from ..profiler import scope, span
@@ -112,32 +113,9 @@ def default_engine_stats():
             "pool_blocks_used": 0, "pool_blocks_total": 0,
             "decode_ctx_tokens": 0, "decode_rows": 0,
             "decode_iterations": 0,
-            # device-side counts of an expert layer that holds a share of
-            # the published experts (ops/kernels/moe_dropless.py), summed
-            # over layers and steps, read beside the tokens: live rows x
-            # experts a token; those that landed on a held expert; rows
-            # the grouped product ran, padding included; the fullest held
-            # expert's rows; held assignments beyond the product's rows
-            # (stays 0: the routing is dropless); and the held experts
-            # that got a row, of the experts held (the share of the held
-            # weights the grouped product has to read)
-            "moe_assignments": 0, "moe_assignments_held": 0,
-            "moe_rows_computed": 0, "moe_expert_peak": 0,
-            "moe_assignments_dropped": 0,
-            "moe_experts_nonempty": 0, "moe_experts_held": 0,
             # slots assigned into zeroed recurrent state (a layout with a
             # recurrent layer): admissions and preemption replays alike
             "state_resets": 0,
-            # device-side counts of a power-retention layer
-            # (ops/kernels/power_retention.py), summed over layers and
-            # steps: slot states the core read and wrote (every slot in a
-            # one-token step; in a mixed step every slot once for the
-            # one-row slots' pass and each slot with a chunk once more);
-            # those of them with a live row;
-            # live rows through the chunk form and through the one-token
-            # form
-            "ret_state_walked": 0, "ret_state_live": 0,
-            "ret_rows_chunk": 0, "ret_rows_step": 0,
             # a (seconds, count) pair: takes a slot -> first prefill
             # grant dispatched (accepted -> takes a slot is telemetry's
             # queue_wait_s histogram, the server's)
@@ -431,9 +409,9 @@ class LLMEngine:
     a layout with a paged latent pool or a recurrent state a slot is
     served by the fused scheduler over the paged allocator, and every
     option whose code assumes "state is a list of K/V blocks" refuses it
-    at construction (``_refuse_for_layout``). A layout with NO paged
+    at construction (``cache_layout.REFUSALS``). A layout with NO paged
     layer (every layer a recurrent state a slot) goes the same way with
-    nothing to page: see ``_has_paged``."""
+    nothing to page: see ``cache_layout.Layout.has_paged``."""
 
     def __init__(self, model, max_batch=4, max_seq_len=None, chunk_size=64,
                  top_k=0, stream_callback=None, horizon=1, speculative_k=1,
@@ -561,54 +539,35 @@ class LLMEngine:
         self._lock_checks = _lockwatch.enabled()
         self._pool_owner = None
         c = model.config
-        #: THE seam: the model's decoder and one state kind a layer
+        #: THE seam: the model's decoder, and the layout of its layers'
+        #: state kinds, which answers whatever the engine asks of them
         self._decoder = model.decoder
-        self._layout = list(model.cache_layout())
-        #: every layer holds K and V: the pools, programs and options of
-        #: the llama family, unchanged
-        self._kv_only = all(k.kind == "paged_kv" for k in self._layout)
-        self._has_recurrent = any(k.kind == "recurrent"
-                                  for k in self._layout)
-        #: some layer keeps its state in pool blocks. False (every layer
-        #: a fixed-size recurrent state a slot): NO pool is allocated and
-        #: admission is bounded by slots and ``max_seq_len`` alone. The
-        #: host allocator and the block tables are still built, as an
-        #: empty formality: ``max_batch x ceil(capacity / block_size)``
-        #: block ids that back no device memory, so every slot can always
-        #: cover its whole capacity, the pool never runs dry, nothing is
-        #: ever preempted for room, and the scheduler, the step programs'
-        #: ``tables`` argument and reset() stay the code every other
-        #: layout runs. ``kv_pool_blocks`` is refused (there is no pool
-        #: to size), ``kv_pool_nbytes()`` / ``kv_bytes_per_block()`` are
-        #: 0, the capacity need not be a multiple of the chunk, and the
-        #: pool's and the attention grid's counters book nothing.
-        self._has_paged = any(k.paged for k in self._layout)
-        #: the kind of the layers whose state is K and V pools that the
-        #: paged kernels read, once a token or once a loop step (None: no
-        #: layer's is). The pools' head counts and the append kernel's
-        #: tile counts are of these layers, however many others (a
-        #: recurrent state a slot) stand beside them
-        self._kv_kind = next(
-            (k for k in self._layout
-             if k.kind in ("paged_kv", "paged_kv_looped")), None)
-        #: runs of a weight layer a token, each with K/V of its own under
-        #: the slot's ONE block id (a looped layout; else 1): what a
-        #: block, a token and a grid walk cost multiplies by it
-        self._loop_steps = max(getattr(k, "loop_steps", 1)
-                               for k in self._layout)
+        self._layout = Layout(model.cache_layout())
         #: device-side counts the model's layers make during a step; they
-        #: leave the step program beside the tokens (booked at emit)
+        #: leave the step program beside the tokens (booked at emit), and
+        #: the ids some of them ride a step's emit span under
         self._step_counter_names = tuple(
             getattr(model, "step_counter_names", ()))
-        if not self._kv_only:
-            self._refuse_for_layout(
-                scheduler=scheduler, cache_impl=cache_impl, mesh=mesh,
-                enable_prefix_cache=enable_prefix_cache,
-                kv_host_swap=kv_host_swap,
-                kv_host_spill_bytes=kv_host_spill_bytes,
-                speculative_k=speculative_k, kv_cache_dtype=kv_cache_dtype,
-                adapter_store=adapter_store, horizon=horizon,
-                kv_pool_blocks=kv_pool_blocks)
+        self._step_emit_ids = dict(getattr(model, "step_emit_ids", {}))
+        #: tensor-parallel serving (the multichip subsystem, serving/
+        #: cluster.py): a mesh with a "tp" axis turns the engine's KV
+        #: buffers into REAL NamedShardings — kv-heads shard across the
+        #: axis (the paged pool's head dim / the dense buffers' head
+        #: dim), block tables and the allocator stay host-global, and
+        #: logits/lens/tokens stay replicated (the step's in-graph
+        #: sample consumes replicated logits, so the vocab-sharded lm
+        #: head all-gathers exactly once per step). Any other mesh keeps
+        #: the legacy multi-process behavior: replicated global buffers.
+        self._tp_size = int(mesh.shape["tp"]) if mesh is not None \
+            and "tp" in tuple(mesh.axis_names) else 1
+        self._tp_axis = "tp" if self._tp_size > 1 else None
+        self._layout.refuse(
+            scheduler=scheduler, cache_impl=cache_impl, horizon=horizon,
+            kv_pool_blocks=kv_pool_blocks,
+            enable_prefix_cache=enable_prefix_cache,
+            kv_host_tier=kv_host_swap or kv_host_spill_bytes,
+            speculative_k=speculative_k, kv_cache_dtype=kv_cache_dtype,
+            adapter_store=adapter_store, mesh=self._tp_axis)
         self.B = int(max_batch)
         # decode horizon: tokens decoded per step() call as one compiled
         # lax.scan — amortizes the per-step host sync K-fold at the cost of
@@ -669,12 +628,11 @@ class LLMEngine:
         self._state = params + buffers
         self._state_vals = read_values(self._state)
 
-        if self._kv_only:
-            kvh, head_dim = self._kv_kind.kv_heads, self._kv_kind.head_dim
-        else:
-            kvh = head_dim = 0     # the kinds size their own state
+        # the K/V layers' heads: what the dense slot buffers and a
+        # tensor-parallel mesh are sized by (the kinds size the pools)
+        kv = self._layout.kv
+        kvh, head_dim = (kv.kv_heads, kv.head_dim) if kv else (0, 0)
         dt = self._decoder.embed_tokens.weight.dtype
-        L = len(self._layout)
         # a prefill window is always a full `chunk` wide, so it must fit the
         # buffer (the final window slides BACK over already-written
         # positions instead of padding the time axis — see _admit)
@@ -693,26 +651,11 @@ class LLMEngine:
         self.mixed_rows = packed_rows(self.max_step_tokens, self.B,
                                       self.chunk, self.speculative_k)
         self._mesh = mesh
-        #: tensor-parallel serving (the multichip subsystem, serving/
-        #: cluster.py): a mesh with a "tp" axis turns the engine's KV
-        #: buffers into REAL NamedShardings — kv-heads shard across the
-        #: axis (the paged pool's head dim / the dense buffers' head
-        #: dim), block tables and the allocator stay host-global, and
-        #: logits/lens/tokens stay replicated (the step's in-graph
-        #: sample consumes replicated logits, so the vocab-sharded lm
-        #: head all-gathers exactly once per step). Any other mesh keeps
-        #: the legacy multi-process behavior: replicated global buffers.
-        self._tp_axis = None
-        self._tp_size = 1
-        if mesh is not None and "tp" in tuple(mesh.axis_names) \
-                and int(mesh.shape["tp"]) > 1:
-            self._tp_axis = "tp"
-            self._tp_size = int(mesh.shape["tp"])
-            if kvh % self._tp_size:
-                raise ValueError(
-                    f"num_key_value_heads {kvh} must divide by the tp "
-                    f"mesh axis ({self._tp_size}) — kv-heads are the "
-                    f"natural shard dim of the KV pools")
+        if kvh % self._tp_size:
+            raise ValueError(
+                f"num_key_value_heads {kvh} must divide by the tp "
+                f"mesh axis ({self._tp_size}) — kv-heads are the "
+                f"natural shard dim of the KV pools")
         if self.speculative_k > 1:
             # speculation is served by the fused scheduler's verify
             # grants (any cache backend) or the legacy dense scan; the
@@ -735,7 +678,6 @@ class LLMEngine:
         self._head_dim = head_dim
         self._vocab = c.vocab_size
         self._np_dt = np.dtype(dt) if mesh is not None else dt
-        self._n_layers = L
         if mesh is not None:
             from jax.sharding import PartitionSpec
             self._kv_spec = PartitionSpec(None, self._tp_axis) \
@@ -803,11 +745,14 @@ class LLMEngine:
             if self.chunk % self.block_size:
                 raise ValueError(f"chunk_size {self.chunk} must be a "
                                  f"multiple of block_size {self.block_size}")
-            if self.capacity % self.chunk and self._has_paged:
+            if self.capacity % self.chunk and self._layout.has_paged:
                 raise ValueError(f"capacity {self.capacity} must be a "
                                  f"multiple of chunk_size {self.chunk} "
                                  f"under paged KV")
             self._max_blocks = -(-self.capacity // self.block_size)
+            #: table entries a grid step walks, a paged kind of the layout
+            self._grid_entries = self._layout.entries_per_step(
+                self._max_blocks, self.block_size)
             full = self.B * self._max_blocks
             self.n_blocks = int(kv_pool_blocks or full)
             #: pool-invariant debug audit (satellite): on under
@@ -898,174 +843,12 @@ class LLMEngine:
         #: window; 0.0 outside a readout walk and for 1-row steps) — the
         #: serving layer reads it inside its stream callback
         self.emit_backdate_s = 0.0
-        self.stats = default_engine_stats()
+        self.stats = dict(default_engine_stats(),
+                          **dict.fromkeys(self._step_counter_names, 0))
 
     # ------------------------------------------------------------------
     # device state (built at __init__, REBUILT by reset())
     # ------------------------------------------------------------------
-    def _refuse_for_layout(self, **opt):
-        """A layout with a layer that is not plain paged K/V (a paged
-        latent pool, a recurrent state a slot, K/V kept once a loop step;
-        a layout of latent pools alone or of recurrent states alone too)
-        is served by the fused scheduler over the paged allocator, with
-        ``readout_stride``, pipelining and pool oversubscription (a
-        preempted request replays from its first token, as paged KV
-        does). Every option whose code assumes "a slot's state is a list
-        of K/V blocks" raises here, naming its mechanism, instead of
-        serving a wrong token. Where the reason differs, the first is a
-        recurrent layer's beside a pool, of latents or of plain K and V
-        alike (its state is in no block), the
-        second a latent-only layout's (its state IS a list of blocks, of
-        ONE pool a layer, which that option's code does not read yet)
-        and the third a recurrent-ONLY layout's (no layer is paged: there
-        is no block, no pool and no K/V at all). A looped layout has ONE
-        reason for them all (:meth:`_looped_reason`)."""
-        kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
-        recurrent = self._has_recurrent
-        #: what a recurrent-only layout's reasons start from
-        no_pool = ("a recurrent-only layout (no layer is paged: every "
-                   "layer keeps one fixed-size state a slot, and the "
-                   "engine allocates no pool)")
-
-        def refuse(option, why, latent_only=None, recurrent_only=None):
-            if self._loop_steps > 1:
-                why = self._looped_reason()
-            elif not self._has_paged and recurrent_only is not None:
-                why = f"{no_pool} {recurrent_only}"
-            elif not recurrent and latent_only is not None:
-                why = latent_only
-            raise ValueError(
-                f"{option} cannot serve a model whose cache layout has "
-                f"{kinds} layers: {why}")
-        if opt["scheduler"] != "fused":
-            refuse("scheduler='legacy'",
-                   "legacy admission prefills a whole prompt through "
-                   "StaticKVCache slot buffers of K and V; a recurrent "
-                   "state or a latent pool advances only in the fused "
-                   "step programs (scheduler='fused')",
-                   recurrent_only="has no K and V for legacy admission's "
-                   "StaticKVCache slot buffers to hold; its state "
-                   "advances only in the fused step programs "
-                   "(scheduler='fused')")
-        if opt["cache_impl"] != "paged":
-            refuse(f"cache_impl={opt['cache_impl']!r}",
-                   "the dense slot buffers are [max_batch, capacity, "
-                   "kv_heads, head_dim] K and V arrays; latents live in a "
-                   "paged pool and a recurrent state is not a sequence of "
-                   "positions (cache_impl='paged')",
-                   recurrent_only="has no K and V to put in the dense "
-                   "[max_batch, capacity, kv_heads, head_dim] slot "
-                   "buffers: a recurrent state is not a sequence of "
-                   "positions (cache_impl='paged' names the fused step "
-                   "programs' state seam; it allocates nothing here)")
-        if opt["horizon"] and int(opt["horizon"]) > 1:
-            refuse("horizon > 1",
-                   "the horizon scan belongs to the legacy scheduler; use "
-                   "readout_stride")
-        if opt["kv_pool_blocks"] is not None and not self._has_paged:
-            refuse("kv_pool_blocks", "",
-                   recurrent_only="has no pool to size: admission is "
-                   "bounded by max_batch slots and max_seq_len alone, and "
-                   "a slot's state costs the same whatever its length")
-        if opt["enable_prefix_cache"]:
-            refuse("enable_prefix_cache",
-                   "a cached block holds its tokens' K/V, but a recurrent "
-                   "layer's state after a shared prefix is in no block: a "
-                   "hit would skip the rows that build it (prefix hashing "
-                   "assumes state is a list of blocks)",
-                   "the content store adopts, copies and spills a block "
-                   "as a (K, V) pair of pools a layer; a latent layer has "
-                   "one pool and no V, and that path is not written for "
-                   "it (ROADMAP Queue 2)",
-                   recurrent_only="has no blocks for the content store to "
-                   "hash, share or evict: the state after a shared prefix "
-                   "is one array a (slot, layer), and a hit would need it "
-                   "snapshotted at the prefix's end, which is not written")
-        if opt["kv_host_swap"] or opt["kv_host_spill_bytes"]:
-            refuse("kv_host_swap / kv_host_spill_bytes",
-                   "swap and spill copy a slot's list of pool blocks; its "
-                   "recurrent state and convolution tail are not blocks "
-                   "and would be lost (a preempted request replays from "
-                   "its first token instead)",
-                   "swap and spill gather a slot's blocks out of a (K, V) "
-                   "pair of pools a layer; a latent layer has one pool "
-                   "and no V, and that path is not written for it (a "
-                   "preempted request replays from its first token "
-                   "instead; ROADMAP Queue 2)",
-                   recurrent_only="has no pool blocks to swap out or "
-                   "spill: a slot's whole state is its recurrent state, "
-                   "whose copy to the host is not written, and with no "
-                   "pool to run dry nothing is preempted for room (a "
-                   "preempted request would replay from its first token)")
-        if int(opt["speculative_k"] or 1) > 1:
-            refuse("speculative_k > 1",
-                   "a rejected draft rolls the slot's length back over "
-                   "rows already computed; a recurrent state that has "
-                   "absorbed them cannot be rolled back",
-                   "the verify grants are wired through PagedKVCache "
-                   "alone; a latent pool's rejected rows could be rolled "
-                   "back by its block table, but that path is not written",
-                   recurrent_only="cannot roll a rejected draft back: the "
-                   "state has absorbed the draft's rows, and there is no "
-                   "block table whose length could forget them")
-        if opt["kv_cache_dtype"] is not None:
-            refuse("kv_cache_dtype",
-                   "pool quantization keeps one scale per (block, kv "
-                   "head) of K and V pools; a latent pool and a float32 "
-                   "recurrent state have no such scales",
-                   recurrent_only="has no K/V pool to quantize: a float32 "
-                   "recurrent state has no (block, kv head) scales")
-        if opt["adapter_store"] is not None:
-            refuse("adapter_store",
-                   "batched LoRA adds its deltas to the llama family's "
-                   "q/k/v/o and gate/up/down projections by name",
-                   recurrent_only="is not the llama family's attention: "
-                   "batched LoRA adds its deltas inside that family's "
-                   "q/k/v/o and gate/up/down forwards by name, and a "
-                   "recurrent layer's projections do not read the "
-                   "adapter scope")
-        mesh = opt["mesh"]
-        if mesh is not None and "tp" in tuple(mesh.axis_names) \
-                and int(mesh.shape["tp"]) > 1:
-            refuse("a tensor-parallel mesh",
-                   "kv heads are the shard dimension of K/V pools; a "
-                   "latent pool has one shared head and a recurrent state "
-                   "is held per slot (experts over chips with their "
-                   "exchange are not written)",
-                   recurrent_only="has no K/V pools, whose kv heads are "
-                   "what the mesh shards: a recurrent state is held whole "
-                   "a slot, and its form sharded by head is not written")
-
-    def _looped_reason(self):
-        """Why an option written for "one pool block a block id" cannot
-        serve a looped layout, whichever option it is."""
-        return (f"a looped layout keeps {self._loop_steps} runs of K/V "
-                f"under one block id (the loop step is part of a pool "
-                f"block's address, and the steps run as a loop inside the "
-                f"fused paged step programs); this option's code moves, "
-                f"copies, scales or shards ONE pool block a block id, and "
-                f"its form over all the runs is not written")
-
-    def _refuse_kv_shipping(self, what):
-        if not self._kv_only:
-            kinds = sorted({k.kind for k in self._layout} - {"paged_kv"})
-            if self._loop_steps > 1:
-                raise ValueError(f"{what} cannot serve a model whose cache "
-                                 f"layout has {kinds} layers: "
-                                 f"{self._looped_reason()}")
-            if not self._has_paged:
-                raise ValueError(
-                    f"{what} ships a request's list of K/V blocks; a "
-                    f"recurrent-only layout ({kinds} layers, no layer "
-                    f"paged) has no blocks at all: a request's state is "
-                    f"one fixed-size array a (slot, layer), whose export "
-                    f"and import are not written")
-            raise ValueError(
-                f"{what} ships a request's list of K/V blocks; a cache "
-                f"layout with {kinds} layers keeps state that is not in "
-                f"blocks of K and V (a recurrent state a slot, one latent "
-                f"pool a layer), so it cannot be exported or imported")
-
     def _make_zeros(self, shape, dtype, spec=None):
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -1081,56 +864,14 @@ class LLMEngine:
         :meth:`reset` — after a crash the old buffers may be donated-away
         or mid-flight, so recovery rebuilds rather than trusts them. The
         compiled programs survive (same shapes, same shardings)."""
-        L = self._n_layers
+        L = len(self._layout)
         if self.cache_impl == "paged":
-            # +1 trailing SCRATCH block the allocator never hands out: the
-            # Pallas paged-attention kernel's fused new-token write routes
-            # invalid (-1) targets there — a freed slot keeps stale lens
-            # with a wiped table row, and its garbage write must not land
-            # on a real block (the XLA fallback drops such rows with an
-            # out-of-range scatter; a kernel block write needs a real
-            # destination)
-            if not self._kv_only:
-                # one state pair a layer, built by its kind: a latent
-                # pool on the allocator's blocks (with the same trailing
-                # scratch block), a recurrent state a slot
-                pairs = [kind.alloc(self._make_zeros, self.n_blocks,
-                                    self.block_size, self.B, self._np_dt)
-                         for kind in self._layout]
-                self._k = [a for a, _ in pairs]
-                self._v = [b for _, b in pairs]
-            elif self.kv_quant:
-                # QUANTIZED pools: int8 payload (int4 nibble-packs two
-                # head-dim elements per byte) + one fp32 scale per
-                # (physical block, kv head), bundled as (pool, scale)
-                # tuples so every step program, donation list and
-                # sharding pin carries the pair as one pytree leaf-set.
-                # Zero pools under zero scales dequantize to exact zeros
-                # — the same cold state as the bf16 pools. The scale
-                # array shares the paged _kv_spec (axis 1 = kv heads).
-                from ..ops.kernels.paged_attention import kv_packed_dim
-                dp = kv_packed_dim(self._head_dim, self.kv_quant)
-                pool_shape = (self.n_blocks + 1, self._kvh,
-                              self.block_size, dp)
-                scale_shape = (self.n_blocks + 1, self._kvh)
-
-                def quant_pool():
-                    return (self._make_zeros(pool_shape, np.int8,
-                                             self._kv_spec),
-                            self._make_zeros(scale_shape, np.float32,
-                                             self._kv_spec))
-
-                self._k = [quant_pool() for _ in range(L)]
-                self._v = [quant_pool() for _ in range(L)]
-            else:
-                pool_shape = (self.n_blocks + 1, self._kvh,
-                              self.block_size, self._head_dim)
-                self._k = [self._make_zeros(pool_shape, self._np_dt,
-                                            self._kv_spec)
-                           for _ in range(L)]
-                self._v = [self._make_zeros(pool_shape, self._np_dt,
-                                            self._kv_spec)
-                           for _ in range(L)]
+            # one state pair a layer, built by its kind: pools on the
+            # allocator's blocks (quantized and sharded as the K/V kind
+            # does it), a recurrent state a slot
+            self._k, self._v = self._layout.alloc(
+                self._make_zeros, self.n_blocks, self.block_size, self.B,
+                self._np_dt, self.kv_quant, self._kv_spec)
             self._tables = np.full((self.B, self._max_blocks), -1, np.int32)
             #: min-heap of free physical blocks: allocation always pops
             #: the SMALLEST free index, so physical layout is a pure
@@ -1323,21 +1064,21 @@ class LLMEngine:
             ids["live_tiles"] = int(live_tiles)
         return ids
 
+    @property
+    def _loop_steps(self):
+        return self._layout.loop_steps
+
     def _attn_tile_steps(self, q_lens):
         """``(run, grid)`` row-tile steps of the append kernel for a
         mixed paged step granting ``q_lens``, per kv head and K/V layer
         (they multiply both alike; a layout's other layers run no such
         kernel): the kernel module's own count over the host's lens
         mirror, so call this BEFORE the mirrors grow. The group (query
-        heads a kv head) is the K/V kind's where it states its query
-        heads, else the model config's."""
+        heads a kv head) is the K/V kind's to say."""
         from ..ops.kernels.paged_attention import append_tile_steps
-        kind = self._kv_kind
-        q_heads = getattr(kind, "q_heads", None) or \
-            self.model.config.num_attention_heads
         lens = [0 if s is None else s.sched_len() for s in self.slots]
         return append_tile_steps(
-            lens, q_lens, q_heads // kind.kv_heads,
+            lens, q_lens, self._layout.kv.group(self.model.config),
             self.chunk, self.block_size, self._tables.shape[1])
 
     def _book_kv_grid(self, iterations):
@@ -1351,21 +1092,16 @@ class LLMEngine:
         layer of each paged kind the layout has: the layers of a kind
         multiply both counts alike, and so do heads in the decode kernel,
         and a recurrent layer beside them walks no table and adds
-        nothing. A latent pool's kernel walks its table in wide entries
-        (``entries_per_step`` of them a grid step, asked of the kernel
-        module): both counts are in its grid steps, one live when it
-        holds a live entry. A looped layout walks the table once a loop
-        step: both counts times R. Beside them, the pool's blocks in use
-        at this dispatch."""
-        if not self._has_paged:
+        nothing. A kind whose kernel walks its table in wide entries
+        says how many of them a grid step takes: both counts are in its
+        grid steps, one live when it holds a live entry. A looped layout
+        walks the table once a loop step: both counts times R. Beside
+        them, the pool's blocks in use at this dispatch."""
+        if not self._layout.has_paged:
             return      # no pool, no attention grid: nothing to book
         bs = self.block_size
-        iterations *= self._loop_steps
-        for kind in sorted({k.kind for k in self._layout if k.paged}):
-            n = 1       # K/V pools: a grid step a table entry
-            if kind == "paged_latent":
-                from ..ops.kernels.latent_attention import entries_per_step
-                n = entries_per_step(self._tables.shape[1], bs)
+        iterations *= self._layout.loop_steps
+        for n in self._grid_entries:
             live = sum(-(-s.sched_len() // (bs * n))
                        for s in self.slots if s is not None)
             grid = self._tables.size // n
@@ -1411,7 +1147,7 @@ class LLMEngine:
             return
         model = self.model
         decoder = self._decoder
-        layout, kv_only = self._layout, self._kv_only
+        layout = self._layout
         n_ctr = len(self._step_counter_names)
         ctr_zero = jnp.zeros((n_ctr,), jnp.int32)
         #: the scheduler's bound on a mixed step's live rows over all
@@ -1457,46 +1193,6 @@ class LLMEngine:
                 return x
 
         kvq = self.kv_quant
-
-        def paged_caches(kb, vb, tables, lens, q_lens=None, active=None,
-                         rows=None):
-            """Per-layer cache list of one traced dispatch. All-K/V
-            layouts: PagedKVCache — THE one place that unpacks the
-            quantized (payload, scale) pool bundles, so no step body can
-            forget the scales. Any other layout: each layer's kind makes
-            its own (``q_lens`` None is the one-token step: a slot that
-            is not ``active`` has no live row). ``rows``: the mixed
-            step's RowMap, which every cache object then carries."""
-            if not kv_only:
-                budget = row_budget if q_lens is not None else B
-                return [kind.cache(a, b, tables, lens, q_lens, active,
-                                   budget, rows)
-                        for kind, a, b in zip(layout, kb, vb)]
-            from ..models.llama import PagedKVCache
-            if kvq:
-                return [PagedKVCache(k[0], v[0], tables, lens, q_lens,
-                                     k_scale=k[1], v_scale=v[1],
-                                     quant=kvq, rows=rows)
-                        for k, v in zip(kb, vb)]
-            return [PagedKVCache(k, v, tables, lens, q_lens, rows=rows)
-                    for k, v in zip(kb, vb)]
-
-        def unpack_kv(new_caches):
-            """Updated (k_bufs, v_bufs) lists off a model call's returned
-            caches — re-bundling (payload, scale) tuples on quantized
-            engines. Works for every cache class (dense slot buffers
-            have no scales and kvq is then always None)."""
-            def val(x):
-                return x._value if isinstance(x, Tensor) else x
-            if not kv_only:
-                pairs = [kind.unpack(cc)
-                         for kind, cc in zip(layout, new_caches)]
-                return [a for a, _ in pairs], [b for _, b in pairs]
-            if kvq:
-                return ([(val(cc.k), val(cc.k_scale)) for cc in new_caches],
-                        [(val(cc.v), val(cc.v_scale)) for cc in new_caches])
-            return ([val(cc.k) for cc in new_caches],
-                    [val(cc.v) for cc in new_caches])
 
         K = self.horizon
 
@@ -1548,8 +1244,8 @@ class LLMEngine:
                     caches = [SlotKVCache(k, v, lens)
                               for k, v in zip(k_bufs, v_bufs)]
                 else:
-                    caches = paged_caches(k_bufs, v_bufs, tables, lens,
-                                          active=active)
+                    caches = layout.caches(k_bufs, v_bufs, tables, lens,
+                                           None, active, B)
                 with collect_counts() as counted:
                     hidden, new_caches = decoder(
                         Tensor(nxt[:, None]), kv_caches=caches,
@@ -1559,7 +1255,7 @@ class LLMEngine:
             # an INACTIVE row's carried logits must survive the remaining
             # scan iterations — a slot deactivated non-terminally (pool
             # budget clamp) samples from them next step
-            kb, vb = unpack_kv(new_caches)
+            kb, vb = layout.unpack(new_caches)
             with scope("pt.readout"):
                 new_logits = jnp.where(active[:, None], new_logits, logits)
                 new_lens = jnp.where(active, lens + 1, lens)
@@ -1826,14 +1522,14 @@ class LLMEngine:
                             caches = [ChunkKVCache(k, v, ln, q_eff)
                                       for k, v in zip(kb, vb)]
                         else:
-                            caches = paged_caches(kb, vb, tables, ln,
-                                                  q_eff)
+                            caches = layout.caches(
+                                kb, vb, tables, ln, q_eff, None, row_budget)
                         hidden, new_caches = decoder(
                             Tensor(window), kv_caches=caches,
                             position_offset=Tensor(ln))
                         logits_win = model._logits(hidden)._value \
                             .astype(jnp.float32)          # [B, Kspec, V]
-                    kb, vb = unpack_kv(new_caches)
+                    kb, vb = layout.unpack(new_caches)
                     counts, _, new_lg = verify_window(
                         logits_win, draft, ln, q_eff, rng, temps, top_ps,
                         rids, act)
@@ -1957,8 +1653,8 @@ class LLMEngine:
                     caches = [ChunkKVCache(k, v, lens, q_eff, rows)
                               for k, v in zip(k_bufs, v_bufs)]
                 else:
-                    caches = paged_caches(k_bufs, v_bufs, tables, lens,
-                                          q_eff, rows=rows)
+                    caches = layout.caches(k_bufs, v_bufs, tables, lens,
+                                           q_eff, None, row_budget, rows)
                 with scope("pt.pack"):
                     packed_ids = Tensor(rows.from_slots(ids)[None])
                     packed_pos = Tensor(rows.pos[None])
@@ -1993,7 +1689,7 @@ class LLMEngine:
                 pooled = pooled + jnp.einsum(
                     "bt,th->bh", emb_mask.astype(jnp.float32),
                     hidden.astype(jnp.float32))
-            kb, vb = unpack_kv(new_caches)
+            kb, vb = layout.unpack(new_caches)
             if spec_ks is None:
                 with scope("pt.readout"):
                     new_logits = jnp.where(active[:, None], new_logits,
@@ -2433,14 +2129,9 @@ class LLMEngine:
         ``export_kv``: stage the request's committed KV as a staged
         export entry at its finish (disaggregated serving — see
         :meth:`export_kv`)."""
-        if export_kv:
-            self._refuse_kv_shipping("add_request(export_kv=True)")
-        if kind == "embed" and not self._kv_only:
-            raise ValueError(
-                "kind='embed' pools the hidden rows of a K/V decoder's "
-                "prefill; it is not wired for a cache layout with other "
-                "state kinds (a latent pool, a recurrent state, a looped "
-                "layout)")
+        self._layout.refuse(
+            kv_shipping=export_kv and "add_request(export_kv=True)",
+            request_kind=kind)
         ids = np.asarray(
             prompt_ids.numpy() if hasattr(prompt_ids, "numpy")
             else prompt_ids, dtype=np.int32).reshape(-1)
@@ -3229,7 +2920,7 @@ class LLMEngine:
         materialization only reads already-gathered host-bound staging
         arrays, never the pool. Returns the plain-numpy staged entry
         (serializable by ``serving.kv_transport``), or None."""
-        self._refuse_kv_shipping("export_kv()")
+        self._layout.refuse(kv_shipping="export_kv()")
         if self.cache_impl != "paged":
             return None
         entry = self._export_store.pop(request_id, None)
@@ -3254,7 +2945,7 @@ class LLMEngine:
         Callable from ANY thread (one GIL-atomic dict write). Returns
         True when staged, False on a compatibility reject — the router
         falls back to plain re-prefill."""
-        self._refuse_kv_shipping("import_kv()")
+        self._layout.refuse(kv_shipping="import_kv()")
         if self.cache_impl != "paged" or self.scheduler != "fused":
             return False
         if not entry.get("ready") or entry.get("n_blocks", 0) <= 0:
@@ -3294,7 +2985,7 @@ class LLMEngine:
         (an eviction re-registered under the SAME hash is harmless by
         content addressing). Returns entries for the servable prefix
         only, stopping at the first miss."""
-        self._refuse_kv_shipping("export_prefix_blocks()")
+        self._layout.refuse(kv_shipping="export_prefix_blocks()")
         out = []
         if self.cache_impl != "paged" or not self.prefix_cache:
             return out
@@ -3349,7 +3040,7 @@ class LLMEngine:
         request submitted right after the import hits them. Requires an
         armed spill store (``kv_host_spill_bytes > 0``); entries are
         dropped otherwise. Returns the number queued."""
-        self._refuse_kv_shipping("import_prefix_blocks()")
+        self._layout.refuse(kv_shipping="import_prefix_blocks()")
         if self.cache_impl != "paged" or not self.prefix_cache or \
                 not self.kv_host_spill_bytes:
             return 0
@@ -3541,12 +3232,12 @@ class LLMEngine:
         scale overhead). The ``kv_pool_effective_blocks`` Prometheus
         gauge samples this: capacity dashboards read one number that is
         comparable across pool dtypes."""
-        if self.cache_impl != "paged" or not self._has_paged:
+        if self.cache_impl != "paged" or not self._layout.has_paged:
             return 0
         if not self.kv_quant:
             return self.n_blocks
-        unquant = self._n_layers * 2 * self._kvh * self.block_size * \
-            self._head_dim * np.dtype(self._np_dt).itemsize
+        unquant = self.block_size * self._layout.bytes_per_token(
+            np.dtype(self._np_dt).itemsize)
         return int(self.n_blocks * unquant
                    // max(self.kv_bytes_per_block(), 1))
 
@@ -3875,7 +3566,7 @@ class LLMEngine:
         # probe hit
         self._lens = self._set_len_fn(self._lens, np.int32(slot_idx),
                                       np.int32(hit))
-        if self._has_recurrent:
+        if self._layout.has_recurrent:
             # a recurrent layer starts a slot whose length is 0 from
             # zeros in-graph (cache_layout.Recurrent): setting the length
             # IS the reset, and costs the host nothing more in this phase
@@ -4779,7 +4470,7 @@ class LLMEngine:
         # pools, few or all (a looped layout's too: loop steps multiply
         # both counts alike)
         tiles = self._attn_tile_steps(q_lens) \
-            if self.cache_impl == "paged" and self._kv_kind is not None \
+            if self.cache_impl == "paged" and self._layout.kv is not None \
             else None
         t0 = self._to("dispatch", **self._dispatch_ids(
             "mixed", self.mixed_rows, int(q_lens.sum()), q_lens > 0,
@@ -5001,19 +4692,11 @@ class LLMEngine:
             booked = dict(zip(self._step_counter_names,
                               (int(v) for v in ctr_np)))
             for name, v in booked.items():
-                self.stats[name] = self.stats.get(name, 0) + v
-            if "moe_assignments_held" in booked:
-                # what the host could not know at dispatch
-                ids["held_rows"] = booked["moe_assignments_held"]
-            if "moe_experts_nonempty" in booked:
-                ids["experts_read"] = booked["moe_experts_nonempty"]
-                ids["experts_held"] = booked["moe_experts_held"]
-            if "ret_state_live" in booked:
-                # summed over the layers: (slot, layer) states with a
-                # live row, and the live rows through either form
-                ids["live_states"] = booked["ret_state_live"]
-                ids["ret_rows"] = booked["ret_rows_chunk"] + \
-                    booked["ret_rows_step"]
+                self.stats[name] += v
+            # what the host could not know at dispatch, summed over the
+            # layers
+            for eid, names in self._step_emit_ids.items():
+                ids[eid] = sum(booked[name] for name in names)
         now_pc = t0 = self._to("emit", **ids)
         if toks_np.shape[0] > 1 and pending.t_dispatch is not None \
                 and n_exec > 1:

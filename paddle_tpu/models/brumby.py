@@ -194,6 +194,7 @@ class BrumbyDecoder(StateDecoder):
 class BrumbyForCausalLM(StateCausalLM):
     #: device-side counts of a step: the retention layers' own
     step_counter_names = _ret.COUNTERS
+    step_emit_ids = _ret.EMIT_IDS
 
     def __init__(self, config: BrumbyConfig):
         c = config

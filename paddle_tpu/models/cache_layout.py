@@ -1,6 +1,7 @@
 """What a decoder keeps a layer between steps, as the serving engine sees
-it: the model names a KIND a layer and the engine builds, donates, pins and
-unpacks the per-layer state from that, knowing no family.
+it: the model names a KIND a layer, and the engine asks the :class:`Layout`
+of those kinds for everything it needs to know of a layer's state. It
+knows no family, compares no kind's name and keeps no flag of its own.
 
 A model that :class:`paddle_tpu.inference.LLMEngine` serves has
 
@@ -12,15 +13,22 @@ A model that :class:`paddle_tpu.inference.LLMEngine` serves has
 
 and may declare ``step_counter_names``: device-side counts its layers
 :func:`count` during a step, which leave the step program beside the
-tokens and are booked into ``engine.stats`` at readout.
+tokens and are booked into ``engine.stats`` at readout (every name is
+there at 0 from the engine's construction), and ``step_emit_ids``: id ->
+the counters whose sum of one step rides on its ``pt:engine.emit`` span.
+The module that owns the counters declares both (``COUNTERS``,
+``EMIT_IDS``: ``ops/kernels/moe_dropless.py``, ``power_retention.py``,
+``kda_chunk_walk.py``).
 
 A kind makes the layer's state ``(a, b)`` (two pytrees of device arrays,
 ``b`` possibly None: the engine carries every layer's pair through its
-programs as it carried K and V pools), the cache object the layer is
-handed for one dispatch, and takes the pair back off the returned cache.
+programs as two lists), the cache object the layer is handed for one
+dispatch, and takes the pair back off the returned cache. A paged kind
+also says what a token costs and how many table entries one grid step of
+its kernel walks; a kind that is not paged, what a slot costs.
 
-**The layouts the engine serves** (a layout is the list of kinds, one a
-layer; ``LLMEngine._refuse_for_layout`` names what each cannot take):
+**The layouts the engine serves** (:attr:`Layout.shape`; the table
+:data:`REFUSALS` names what each cannot take):
 
 1. every layer :class:`PagedKV` (the llama family; the one layout with
    the legacy scheduler, dense buffers, quantized and sharded pools,
@@ -33,6 +41,17 @@ layer; ``LLMEngine._refuse_for_layout`` names what each cannot take):
    zeroed in the graph;
 4. :class:`LoopedPagedKV` in every layer (a looped stack);
 5. :class:`Recurrent` in every layer (no pool at all).
+
+**What a new kind of state costs**, as the places it is written:
+
+1. the kind's class here (``alloc`` / ``cache`` / ``unpack``, ``paged``,
+   what a token or a slot costs, ``entries_per_step`` of a paged one);
+2. its column in :data:`REFUSALS` where a layout with it reads a reason
+   of its own, with its line in :attr:`Layout.shape`;
+3. the model's ``cache_layout()``.
+
+A model that composes the kinds that exist writes the third alone;
+``inference/llm_engine.py`` is edited for none of the three.
 
 **What the decoder is handed in a mixed step.** A one-token step hands it
 ``ids[B, 1]``. A mixed step (the fused scheduler's prefill chunks and
@@ -59,6 +78,7 @@ zero.
 from __future__ import annotations
 
 import contextlib
+import copy
 import threading
 
 import numpy as np
@@ -75,36 +95,74 @@ def _val(x):
 class PagedKV:
     """Paged K and V pools ``[n_blocks + 1, kv_heads, block, head_dim]``
     on the engine's block tables
-    (:class:`paddle_tpu.models.llama.PagedKVCache`): THE general K/V kind,
-    with the three methods every kind has, so a layer of a mixed layout
-    holds K and V pools beside layers that hold a recurrent state or a
-    latent pool. (A layout of this kind alone is also served by the
-    engine's older all-K/V branch, which builds the same pools itself and
-    alone carries their quantized, sharded, swapped and shared forms.)
-    ``q_heads``: the query heads that read the ``kv_heads`` (None: the
-    model's config says, ``num_attention_heads``); the append kernel's
-    row tile follows the group, and so do the engine's tile counts."""
+    (:class:`paddle_tpu.models.llama.PagedKVCache`): THE K/V kind, of the
+    llama family's layers and of a K/V layer beside recurrent or latent
+    ones alike. The pools' format is this kind's: ``alloc`` is told the
+    engine's ``kv_cache_dtype`` (``quant``) and the pools' sharding
+    (``spec``: kv heads are axis 1). A quantized pool is ONE ``(payload,
+    scale)`` bundle (int8, int4 nibble-packed on the head dim; a float32
+    scale a (block, kv head); all zeros is the plain pool's cold state),
+    so every step program, donation list and sharding pin carries the
+    pair as one set of leaves, and ``cache`` / ``unpack`` are THE places
+    that take it apart and bundle it again: no step body can forget the
+    scales. ``q_heads``: the query heads that read the ``kv_heads``
+    (None: the model's config says, ``num_attention_heads``); the append
+    kernel's row tile follows the group."""
     kind = "paged_kv"
     paged = True
+    #: runs of the layer a token, each with K and V of its own
+    loop_steps = 1
 
     def __init__(self, kv_heads, head_dim, q_heads=None):
         self.kv_heads, self.head_dim = int(kv_heads), int(head_dim)
         self.q_heads = None if q_heads is None else int(q_heads)
+        self.quant = None
+
+    def group(self, config):
+        """Query heads a kv head."""
+        return (self.q_heads or config.num_attention_heads) // self.kv_heads
+
+    def entries_per_step(self, max_blocks, block_size):
+        """Table entries one grid step of the attention kernels walks."""
+        return 1
 
     def bytes_per_token(self, itemsize):
-        return 2 * self.kv_heads * self.head_dim * itemsize
+        return 2 * self.kv_heads * self.head_dim * itemsize * self.loop_steps
 
-    def alloc(self, zeros, n_blocks, block_size, batch, dtype):
-        shape = (n_blocks + 1, self.kv_heads, block_size, self.head_dim)
-        return zeros(shape, dtype), zeros(shape, dtype)
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype, quant=None,
+              spec=None):
+        from ..ops.kernels.paged_attention import kv_packed_dim
+        self.quant = quant
+        # +1: a trailing SCRATCH block the allocator never hands out, where
+        # the kernels' fused write sends a -1 target (a freed slot's stale
+        # lens over a wiped table row must not land on a real block)
+        shape = (self.loop_steps * (n_blocks + 1), self.kv_heads, block_size,
+                 kv_packed_dim(self.head_dim, quant))
+        pin = () if spec is None else (spec,)
+
+        def pool():
+            if quant:
+                return (zeros(shape, np.int8, *pin),
+                        zeros(shape[:2], np.float32, *pin))
+            return zeros(shape, dtype, *pin)
+        return pool(), pool()
 
     def cache(self, a, b, tables, lens, q_lens, active, row_budget,
               rows=None):
         from .llama import PagedKVCache
+        scales = {}
+        if self.quant:
+            (a, k_scale), (b, v_scale) = a, b
+            scales = dict(k_scale=k_scale, v_scale=v_scale, quant=self.quant)
         return PagedKVCache(a, b, tables, lens, _q_lens(q_lens, active),
-                            rows=rows, row_budget=row_budget)
+                            rows=rows, row_budget=row_budget, **scales)
 
     def unpack(self, cache):
+        """Works for every cache class with ``k`` and ``v`` (the dense
+        slot buffers' too, which have no scales: ``quant`` is then None)."""
+        if self.quant:
+            return ((_val(cache.k), _val(cache.k_scale)),
+                    (_val(cache.v), _val(cache.v_scale)))
         return _val(cache.k), _val(cache.v)
 
 
@@ -123,22 +181,15 @@ class LoopedPagedKV(PagedKV):
     family's :class:`~paddle_tpu.models.llama.PagedKVCache` (so are the
     kernels); in a one-token step its ``q_lens`` says which slots hold a
     live row. :class:`PagedKV` is the general kind and this one is it
-    with what only a loop needs: R runs of blocks in ``alloc`` and in a
-    token's cost, :meth:`at_step`, and a ``kind`` of its own, by which
-    the engine refuses options for it with one reason."""
+    with what only a loop needs: ``loop_steps`` (R runs of blocks in
+    ``alloc`` and in a token's cost), :meth:`at_step`, and a ``kind`` of
+    its own for the messages; a layout with it reads the ``looped`` column
+    of :data:`REFUSALS`, one reason for every option."""
     kind = "paged_kv_looped"
 
     def __init__(self, kv_heads, head_dim, loop_steps):
         super().__init__(kv_heads, head_dim)
         self.loop_steps = int(loop_steps)
-
-    def bytes_per_token(self, itemsize):
-        return super().bytes_per_token(itemsize) * self.loop_steps
-
-    def alloc(self, zeros, n_blocks, block_size, batch, dtype):
-        shape = (self.loop_steps * (n_blocks + 1), self.kv_heads,
-                 block_size, self.head_dim)
-        return zeros(shape, dtype), zeros(shape, dtype)
 
     def at_step(self, cache, t, k=None, v=None):
         """``cache`` as loop step ``t`` (traced or not) sees it: the same
@@ -163,10 +214,16 @@ class PagedLatent:
     def __init__(self, width):
         self.width = int(width)
 
+    def entries_per_step(self, max_blocks, block_size):
+        """The latent kernel walks its table in wide entries."""
+        from ..ops.kernels.latent_attention import entries_per_step
+        return entries_per_step(max_blocks, block_size)
+
     def bytes_per_token(self, itemsize):
         return self.width * itemsize
 
-    def alloc(self, zeros, n_blocks, block_size, batch, dtype):
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype, quant=None,
+              spec=None):
         return zeros((n_blocks + 1, block_size, self.width), dtype), None
 
     def cache(self, a, b, tables, lens, q_lens, active, row_budget,
@@ -194,7 +251,8 @@ class Recurrent:
         return sum(int(np.prod(s)) * dt.itemsize
                    for s, dt in self.shapes.values())
 
-    def alloc(self, zeros, n_blocks, block_size, batch, dtype):
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype, quant=None,
+              spec=None):
         return {k: zeros((batch,) + s, dt)
                 for k, (s, dt) in self.shapes.items()}, None
 
@@ -213,6 +271,256 @@ def _q_lens(q_lens, active):
     if q_lens is not None:
         return q_lens
     return jnp.asarray(active).astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# the layout: what the engine asks
+# ---------------------------------------------------------------------------
+
+class Layout:
+    """A model's kinds, one a layer, and every answer the serving engine
+    needs of them: built once from ``model.cache_layout()`` and asked; the
+    engine keeps no flag of its own. The kinds are copied: what ``alloc``
+    tells a kind (a pool's format) is this engine's, not the model's."""
+
+    def __init__(self, kinds):
+        self.kinds = [copy.copy(k) for k in kinds]
+        #: some layer keeps its state in pool blocks. False: NO pool is
+        #: allocated, and the engine's allocator and block tables are an
+        #: empty formality (``max_batch x ceil(capacity / block_size)``
+        #: ids that back no memory), so the pool never runs dry, nothing
+        #: is preempted for room and the pool's counters book nothing
+        self.has_paged = any(k.paged for k in self.kinds)
+        self.has_recurrent = any(isinstance(k, Recurrent)
+                                 for k in self.kinds)
+        #: the kind of the layers that hold K and V pools, few or all
+        #: (None: no layer does): the append kernel's tile counts are theirs
+        self.kv = next((k for k in self.kinds if isinstance(k, PagedKV)),
+                       None)
+        #: runs of a weight layer a token under the slot's ONE block id:
+        #: what a block, a token and a grid walk cost multiplies by it
+        self.loop_steps = max(getattr(k, "loop_steps", 1)
+                              for k in self.kinds)
+        #: every layer holds plain K and V: the llama family's options
+        self.plain_kv = self.loop_steps == 1 and all(
+            isinstance(k, PagedKV) for k in self.kinds)
+
+    def __iter__(self):
+        return iter(self.kinds)
+
+    def __len__(self):
+        return len(self.kinds)
+
+    def names(self):
+        """The kinds a message names: those that are not plain K/V."""
+        return sorted({k.kind for k in self.kinds} - {PagedKV.kind})
+
+    def bytes_per_token(self, itemsize):
+        """What one token costs the pools, over the paged layers."""
+        return sum(k.bytes_per_token(itemsize) for k in self.kinds
+                   if k.paged)
+
+    def bytes_per_slot(self):
+        """What one slot costs beside the pools, over the other layers."""
+        return sum(k.bytes_per_slot() for k in self.kinds if not k.paged)
+
+    def entries_per_step(self, max_blocks, block_size):
+        """Table entries one grid step of the attention kernel walks, for
+        ONE layer of each paged kind the layout has (the layers of a kind
+        multiply a walk's counts alike)."""
+        return [k.entries_per_step(max_blocks, block_size) for k in
+                {k.kind: k for k in self.kinds if k.paged}.values()]
+
+    # ---- one way to build, carry and unpack a layer's state -----------
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype, quant=None,
+              spec=None):
+        """Every layer's state pair, as two lists. ``quant`` / ``spec``:
+        the K/V pools' format; a kind without one never reads them (it
+        was refused both, :data:`REFUSALS`)."""
+        pairs = [k.alloc(zeros, n_blocks, block_size, batch, dtype, quant,
+                         spec) for k in self.kinds]
+        return [a for a, _ in pairs], [b for _, b in pairs]
+
+    def caches(self, kb, vb, tables, lens, q_lens, active, row_budget,
+               rows=None):
+        """Per-layer cache list of one traced dispatch (``q_lens`` None is
+        the one-token step: a slot that is not ``active`` has no live
+        row; ``rows``: a mixed step's RowMap, which every object carries)."""
+        return [k.cache(a, b, tables, lens, q_lens, active, row_budget,
+                        rows) for k, a, b in zip(self.kinds, kb, vb)]
+
+    def unpack(self, new_caches):
+        """The updated two lists off a model call's returned caches."""
+        pairs = [k.unpack(c) for k, c in zip(self.kinds, new_caches)]
+        return [a for a, _ in pairs], [b for _, b in pairs]
+
+    # ---- what a layout cannot take -------------------------------------
+    @property
+    def shape(self):
+        """The column of :data:`REFUSALS` this layout reads (None: plain
+        K/V, nothing is refused). THE selection, written once."""
+        if self.plain_kv:
+            return None
+        if self.loop_steps > 1:
+            return "looped"
+        if not self.has_paged:
+            return "recurrent_only"
+        return "beside" if self.has_recurrent else "latent_only"
+
+    def refuse(self, **options):
+        """Raise ValueError for the first of ``options`` (name=value, in
+        the table's order) that this layout cannot take, naming the
+        mechanism, instead of serving a wrong token."""
+        shape = self.shape
+        if shape is None:
+            return
+        for name, (label, asked, reasons) in REFUSALS.items():
+            if name not in options or not asked(options[name]):
+                continue
+            col = shape if shape in reasons else "beside"
+            if col not in reasons:
+                continue
+            why = _LOOPED if (shape, col) == ("looped", "beside") \
+                else reasons[col]
+            if not isinstance(why, _Whole):
+                why = _START[col] + why
+            raise ValueError(why.format(
+                option=label.format(options[name]), kinds=self.names(),
+                loop_steps=self.loop_steps))
+
+
+#: what a reason starts with, by the column it stands in (a reason that is
+#: a :class:`_Whole` message starts with nothing)
+_CANNOT = ("{option} cannot serve a model whose cache layout has {kinds} "
+           "layers: ")
+_START = {"beside": _CANNOT, "latent_only": _CANNOT,
+          "recurrent_only": _CANNOT + (
+              "a recurrent-only layout (no layer is paged: every layer "
+              "keeps one fixed-size state a slot, and the engine allocates "
+              "no pool) ")}
+#: a looped layout's ONE reason for every option written for "one pool
+#: block a block id", whichever option it is
+_LOOPED = (
+    "a looped layout keeps {loop_steps} runs of K/V under one block id "
+    "(the loop step is part of a pool block's address, and the steps run "
+    "as a loop inside the fused paged step programs); this option's code "
+    "moves, copies, scales or shards ONE pool block a block id, and its "
+    "form over all the runs is not written")
+
+
+class _Whole(str):
+    """A reason that is the whole message."""
+
+
+_SHIPS = "{option} ships a request's list of K/V blocks; "
+
+
+#: option -> (its name in the message, ``{}`` its value; whether a value
+#: asks for the mechanism; the reason by :attr:`Layout.shape`), for every
+#: option whose code assumes "a slot's state is a list of K/V blocks". A
+#: shape with no column of its own reads ``beside`` (the state of a
+#: recurrent layer beside a pool, of latents or of plain K and V alike, is
+#: in no block), a looped layout :data:`_LOOPED` in its place; without
+#: ``beside`` an option is refused for the shapes it lists alone.
+#: ``latent_only``: the state IS a list of blocks, of ONE pool a layer,
+#: which that option's code does not read yet. A new kind adds its column.
+REFUSALS = {
+    "scheduler": ("scheduler='legacy'", lambda v: v != "fused", {
+        "beside": "legacy admission prefills a whole prompt through "
+            "StaticKVCache slot buffers of K and V; a recurrent state or a "
+            "latent pool advances only in the fused step programs "
+            "(scheduler='fused')",
+        "recurrent_only": "has no K and V for legacy admission's "
+            "StaticKVCache slot buffers to hold; its state advances only in "
+            "the fused step programs (scheduler='fused')"}),
+    "cache_impl": ("cache_impl={!r}", lambda v: v != "paged", {
+        "beside": "the dense slot buffers are [max_batch, capacity, kv_heads, "
+            "head_dim] K and V arrays; latents live in a paged pool and a "
+            "recurrent state is not a sequence of positions "
+            "(cache_impl='paged')",
+        "recurrent_only": "has no K and V to put in the dense [max_batch, "
+            "capacity, kv_heads, head_dim] slot buffers: a recurrent state is "
+            "not a sequence of positions (cache_impl='paged' names the fused "
+            "step programs' state seam; it allocates nothing here)"}),
+    "horizon": ("horizon > 1", lambda v: v and int(v) > 1, {
+        "beside": "the horizon scan belongs to the legacy scheduler; use "
+            "readout_stride"}),
+    "kv_pool_blocks": ("kv_pool_blocks", lambda v: v is not None, {
+        "recurrent_only": "has no pool to size: admission is bounded by "
+            "max_batch slots and max_seq_len alone, and a slot's state costs "
+            "the same whatever its length"}),
+    "enable_prefix_cache": ("enable_prefix_cache", bool, {
+        "beside": "a cached block holds its tokens' K/V, but a recurrent "
+            "layer's state after a shared prefix is in no block: a hit would "
+            "skip the rows that build it (prefix hashing assumes state is a "
+            "list of blocks)",
+        "latent_only": "the content store adopts, copies and spills a block "
+            "as a (K, V) pair of pools a layer; a latent layer has one pool "
+            "and no V, and that path is not written for it (ROADMAP Queue 2)",
+        "recurrent_only": "has no blocks for the content store to hash, share "
+            "or evict: the state after a shared prefix is one array a (slot, "
+            "layer), and a hit would need it snapshotted at the prefix's end, "
+            "which is not written"}),
+    "kv_host_tier": ("kv_host_swap / kv_host_spill_bytes", bool, {
+        "beside": "swap and spill copy a slot's list of pool blocks; its "
+            "recurrent state and convolution tail are not blocks and would be "
+            "lost (a preempted request replays from its first token instead)",
+        "latent_only": "swap and spill gather a slot's blocks out of a (K, V) "
+            "pair of pools a layer; a latent layer has one pool and no V, and "
+            "that path is not written for it (a preempted request replays "
+            "from its first token instead; ROADMAP Queue 2)",
+        "recurrent_only": "has no pool blocks to swap out or spill: a slot's "
+            "whole state is its recurrent state, whose copy to the host is "
+            "not written, and with no pool to run dry nothing is preempted "
+            "for room (a preempted request would replay from its first "
+            "token)"}),
+    "speculative_k": ("speculative_k > 1", lambda v: int(v or 1) > 1, {
+        "beside": "a rejected draft rolls the slot's length back over rows "
+            "already computed; a recurrent state that has absorbed them "
+            "cannot be rolled back",
+        "latent_only": "the verify grants are wired through PagedKVCache "
+            "alone; a latent pool's rejected rows could be rolled back by its "
+            "block table, but that path is not written",
+        "recurrent_only": "cannot roll a rejected draft back: the state has "
+            "absorbed the draft's rows, and there is no block table whose "
+            "length could forget them"}),
+    "kv_cache_dtype": ("kv_cache_dtype", lambda v: v is not None, {
+        "beside": "pool quantization keeps one scale per (block, kv head) of "
+            "K and V pools; a latent pool and a float32 recurrent state have "
+            "no such scales",
+        "recurrent_only": "has no K/V pool to quantize: a float32 recurrent "
+            "state has no (block, kv head) scales"}),
+    "adapter_store": ("adapter_store", lambda v: v is not None, {
+        "beside": "batched LoRA adds its deltas to the llama family's q/k/v/o "
+            "and gate/up/down projections by name",
+        "recurrent_only": "is not the llama family's attention: batched LoRA "
+            "adds its deltas inside that family's q/k/v/o and gate/up/down "
+            "forwards by name, and a recurrent layer's projections do not "
+            "read the adapter scope"}),
+    "mesh": ("a tensor-parallel mesh", bool, {
+        "beside": "kv heads are the shard dimension of K/V pools; a latent "
+            "pool has one shared head and a recurrent state is held per slot "
+            "(experts over chips with their exchange are not written)",
+        "recurrent_only": "has no K/V pools, whose kv heads are what the mesh "
+            "shards: a recurrent state is held whole a slot, and its form "
+            "sharded by head is not written"}),
+    # the value is the caller's name: add_request(export_kv=True),
+    # export_kv(), import_kv(), export_prefix_blocks(), ...
+    "kv_shipping": ("{}", bool, {
+        "beside": _Whole(_SHIPS + "a cache layout with {kinds} layers keeps "
+            "state that is not in blocks of K and V (a recurrent state a "
+            "slot, one latent pool a layer), so it cannot be exported or "
+            "imported"),
+        "recurrent_only": _Whole(_SHIPS + "a recurrent-only layout ({kinds} "
+            "layers, no layer paged) has no blocks at all: a request's state "
+            "is one fixed-size array a (slot, layer), whose export and import "
+            "are not written")}),
+    "request_kind": ("{}", lambda v: v == "embed", dict.fromkeys(
+        ("beside", "looped"), _Whole(
+            "kind='embed' pools the hidden rows of a K/V decoder's prefill; "
+            "it is not wired for a cache layout with other state kinds (a "
+            "latent pool, a recurrent state, a looped layout)"))),
+}
 
 
 class LatentPagedCache:

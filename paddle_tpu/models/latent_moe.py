@@ -325,8 +325,9 @@ class StateCausalLM(Layer):
     ``model(ids)`` builds a one-call state. Serving only: the backward of
     latent attention is not written (ROADMAP Queue 2)."""
     #: device-side counts of a step (``cache_layout.count``), booked into
-    #: ``engine.stats`` under these names
+    #: ``engine.stats`` under these names, and the ids of a step's emit span
     step_counter_names = _moe.COUNTERS
+    step_emit_ids = _moe.EMIT_IDS
 
     def __init__(self, config, decoder):
         super().__init__()
@@ -349,12 +350,9 @@ class StateCausalLM(Layer):
         lens = jnp.zeros((batch,), jnp.int32)
         q_lens = jnp.full((batch,), seq, jnp.int32)
         dt = self.model.embed_tokens.weight.dtype
-        zeros = lambda shape, dtype: jnp.zeros(shape, dtype)  # noqa: E731
-        out = []
-        for kind in self.cache_layout():
-            a, b = kind.alloc(zeros, batch * mb, block_size, batch, dt)
-            out.append(kind.cache(a, b, tables, lens, q_lens, None, None))
-        return out
+        layout = CL.Layout(self.cache_layout())
+        a, b = layout.alloc(jnp.zeros, batch * mb, block_size, batch, dt)
+        return layout.caches(a, b, tables, lens, q_lens, None, None)
 
     def forward(self, input_ids, labels=None, attn_mask=None):
         if labels is not None:

@@ -40,6 +40,11 @@ HI = jax.lax.Precision.HIGHEST
 COUNTERS = ("moe_assignments", "moe_assignments_held", "moe_rows_computed",
             "moe_expert_peak", "moe_assignments_dropped", "moe_rows_held",
             "moe_experts_nonempty", "moe_experts_held")
+#: id on a step's ``pt:engine.emit`` span -> the counters whose sum of that
+#: step it carries (what the host could not know at dispatch)
+EMIT_IDS = {"held_rows": ("moe_assignments_held",),
+            "experts_read": ("moe_experts_nonempty",),
+            "experts_held": ("moe_experts_held",)}
 
 
 def route(x, w_router, bias, top_k, scale, renormalize=True,

@@ -66,6 +66,11 @@ STATE_PRECISION = jax.lax.Precision.HIGHEST
 #: live rows through the one-token form
 COUNTERS = ("ret_state_walked", "ret_state_live", "ret_rows_chunk",
             "ret_rows_step")
+#: id on a step's ``pt:engine.emit`` span -> the counters whose sum of that
+#: step it carries, over the layers: (slot, layer) states with a live row,
+#: and the live rows through either form
+EMIT_IDS = {"live_states": ("ret_state_live",),
+            "ret_rows": ("ret_rows_chunk", "ret_rows_step")}
 
 
 def feature_dim(d):

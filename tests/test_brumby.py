@@ -412,7 +412,8 @@ def test_a_bfloat16_state_is_past_the_tolerance(monkeypatch):
 def test_what_the_engine_builds_for_a_layout_without_a_paged_layer():
     model, _ = build(TOY, 1)
     eng = LLMEngine(model, **ENGINE)
-    assert eng._has_recurrent and not eng._has_paged and not eng._kv_only
+    lay = eng._layout
+    assert lay.has_recurrent and not lay.has_paged and not lay.plain_kv
     # the state a (slot, layer), and nothing else: no pool
     for layer in range(3):
         assert set(eng._k[layer]) == {"S", "z"} and eng._v[layer] is None

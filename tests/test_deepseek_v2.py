@@ -431,7 +431,7 @@ def test_engine_serves_what_the_reference_would(case):
 def test_the_pools_are_latent_only_and_sized_by_the_layout():
     model, _ = build(TOY, 1)
     eng = LLMEngine(model, **KIMI.ENGINE)
-    assert not eng._kv_only and not eng._has_recurrent
+    assert not eng._layout.plain_kv and not eng._layout.has_recurrent
     for layer in range(3):
         assert eng._k[layer].shape == (eng.n_blocks + 1, 16, 32 + 8)
         assert eng._v[layer] is None

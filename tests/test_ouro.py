@@ -226,7 +226,7 @@ def test_engine_serves_what_the_reference_would(case):
     assert _carried_logits_match(eng, done, params) >= min(len(done), 2)
     # the pool: R runs of (n_blocks + 1) blocks a weight layer, one table
     assert eng._k[0].shape == (3 * (eng.n_blocks + 1), 4, 16, 16)
-    assert len(eng._k) == len(eng._v) == 3 and not eng._kv_only
+    assert len(eng._k) == len(eng._v) == 3 and not eng._layout.plain_kv
     assert eng.kv_pool_nbytes() == 3 * 2 * 3 * (eng.n_blocks + 1) \
         * 4 * 16 * 16 * 4
 
@@ -441,10 +441,17 @@ def test_the_counters_add_up():
 
 #: sha256 of the lowered text (no source positions in it) and its lines,
 #: read at the parent of the PR that brought the looped kind (PR 34), of a
-#: tiny llama's paged step programs under this suite's settings
+#: tiny llama's paged step programs under this suite's settings.
+#: ``multi_step`` was read again at PR 44 (fc26eaa7ede29675, 1131 before):
+#: two lines more, one ``stablehlo.convert`` of ``active`` a layer in the
+#: loop's body. The llama family's caches are made by ``cache_layout.PagedKV``
+#: since then, which hands a one-token step's cache object the live rows a
+#: slot as every kind does; no llama layer reads them, XLA drops them, and
+#: the COMPILED text of all three programs is the parent's (CHANGES.md,
+#: PR 44: plain, bf16, int8, int4, tp = 2, speculative)
 LLAMA_PROGRAMS = {"fused_step": ("6e881ab004d879c4", 1412),
                   "step": ("760958861cb0a471", 1102),
-                  "multi_step": ("fc26eaa7ede29675", 1131)}
+                  "multi_step": ("94ba12370fe91d78", 1133)}
 
 
 def _llama_digests():

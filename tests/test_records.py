@@ -47,3 +47,21 @@ def test_engine_stats_keys_each_have_a_reader():
     unread = sorted(k for k in default_engine_stats()
                     if not re.search(rf"\b{k}\b", text))
     assert not unread, f"engine.stats keys nobody reads: {unread}"
+
+
+def test_engine_names_no_state_kind_or_model_counter():
+    """``llm_engine.py`` asks ``cache_layout.Layout`` and books what the
+    model declares: it compares no kind's name, names no counter of a
+    model's layers and keeps no flag derived from a layout."""
+    with open(os.path.join(REPO, "paddle_tpu/inference/llm_engine.py")) as f:
+        source = f.read()
+    for what, pattern in (
+            ("a state kind's name",
+             r'"paged_kv(_looped)?"|"paged_latent"|"recurrent"'),
+            ("a model's counter", r"\b(moe_|ret_state_|ret_rows_)\w*"),
+            ("a flag of its own over the layout",
+             r"_kv_only|_has_paged|_has_recurrent|_kv_kind")):
+        found = sorted({m.group(0) for m in re.finditer(pattern, source)})
+        assert not found, f"llm_engine.py names {what}: {found}"
+    from paddle_tpu.inference import LLMEngine
+    assert isinstance(LLMEngine._loop_steps, property)

@@ -469,7 +469,8 @@ def test_a_kv_layer_beside_recurrent_layers_builds_and_is_sized_by_kind():
     model, _ = build(TOY, 1)
     eng = LLMEngine(model, **ENGINE)
     nb = eng.n_blocks
-    assert not eng._kv_only and eng._has_paged and eng._has_recurrent
+    lay = eng._layout
+    assert not lay.plain_kv and lay.has_paged and lay.has_recurrent
     assert eng._k[0].shape == eng._v[0].shape == (nb + 1, 2, 16, 32)
     for layer in (1, 2, 3):
         assert eng._k[layer]["S"].shape == (3, 4, 16, 16)
