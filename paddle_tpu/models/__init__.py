@@ -17,3 +17,5 @@ from .ouro import OuroConfig, OuroForCausalLM  # noqa: F401
 from .brumby import BrumbyConfig, BrumbyForCausalLM  # noqa: F401
 from .solar_open2 import (  # noqa: F401
     SolarOpen2Config, SolarOpen2ForCausalLM)
+from .dots3_note import (  # noqa: F401
+    Dots3NoteConfig, Dots3NoteForCausalLM)

@@ -40,7 +40,12 @@ its kernel walks; a kind that is not paged, what a slot costs.
    hold ``[max_batch, ...]`` states, and a replayed slot's state is
    zeroed in the graph;
 4. :class:`LoopedPagedKV` in every layer (a looped stack);
-5. :class:`Recurrent` in every layer (no pool at all).
+5. :class:`Recurrent` in every layer (no pool at all);
+6. :class:`IndexedLatent` layers (a latent pool AND an index pool on the
+   one block table; a row attends the positions a learned indexer
+   selects) beside :class:`WindowedLatent` layers of another width (a
+   ring of the last positions a slot, on a table derived in the graph):
+   a token costs the indexed layers' two pools alone, a slot the rings.
 
 **What a new kind of state costs**, as the places it is written:
 
@@ -51,7 +56,14 @@ its kernel walks; a kind that is not paged, what a slot costs.
 3. the model's ``cache_layout()``.
 
 A model that composes the kinds that exist writes the third alone;
-``inference/llm_engine.py`` is edited for none of the three.
+``inference/llm_engine.py`` is edited for none of the three. The sixth
+layout confirmed the list (PR 45: two kinds, one column, one line of
+:attr:`Layout.shape`, no edit to the engine) and added what it does not
+say: a kind whose layer needs more of a dispatch than the cache object
+carried also writes that into the cache class (:class:`LatentPagedCache`
+gained ``index_pool``, ``window`` and ``base``, and :class:`RowMap`
+``shifted``), and a kind that is not paged and not :class:`Recurrent`
+(a ring a slot) is reset by nobody: it has to need no reset.
 
 **What the decoder is handed in a mixed step.** A one-token step hands it
 ``ids[B, 1]``. A mixed step (the fused scheduler's prefill chunks and
@@ -70,8 +82,10 @@ per slot, through the two gathers :meth:`RowMap.to_slots` and
 finite garbage nobody reads, as those kernels' contracts always said.
 A core that reads ``(start, q_lens, seq_lens)`` itself takes the packed
 rows as they are and builds no view: power retention's walk, the latent
-pool's write and attention (``ops/kernels/latent_attention.py``), and
-KDA's convolution and chunk walk (``kda.causal_conv_packed``,
+pool's write and attention (``ops/kernels/latent_attention.py``), the
+learned indexer's scores, selection and gathered attention
+(``ops/kernels/sparse_latent_attention.py``), and KDA's convolution and
+chunk walk (``kda.causal_conv_packed``,
 ``ops/kernels/kda_chunk_walk.py``), whose rows without a token come back
 zero.
 """
@@ -235,6 +249,102 @@ class PagedLatent:
         return _val(cache.pool), None
 
 
+class IndexedLatent(PagedLatent):
+    """A paged latent pool with an INDEX pool beside it on the same block
+    tables: ``a`` the ``[n_blocks + 1, block, width]`` latents, ``b`` the
+    ``[n_blocks + 1, block, index_width]`` index keys a learned indexer
+    scores to choose the positions a row attends
+    (``ops/kernels/sparse_latent_attention.py``). A token costs both."""
+    kind = "paged_latent_indexed"
+
+    def __init__(self, width, index_width):
+        super().__init__(width)
+        self.index_width = int(index_width)
+
+    def entries_per_step(self, max_blocks, block_size):
+        """The indexer scores its table a tile of entries at a time."""
+        from ..ops.kernels.sparse_latent_attention import entries_per_step
+        return entries_per_step(max_blocks, block_size)
+
+    def bytes_per_token(self, itemsize):
+        return (self.width + self.index_width) * itemsize
+
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype, quant=None,
+              spec=None):
+        return (zeros((n_blocks + 1, block_size, self.width), dtype),
+                zeros((n_blocks + 1, block_size, self.index_width), dtype))
+
+    def cache(self, a, b, tables, lens, q_lens, active, row_budget,
+              rows=None):
+        return LatentPagedCache(a, tables, lens, _q_lens(q_lens, active),
+                                row_budget, rows, index_pool=b)
+
+    def unpack(self, cache):
+        return _val(cache.pool), _val(cache.index_pool)
+
+
+class WindowedLatent:
+    """Latent entries of a layer that attends a WINDOW of ``window``
+    positions (a row, itself and the ``window - 1`` before it): state a
+    SLOT, not a token. A ring of ``ring`` rows of ``width`` values a slot,
+    held as a small pool of its own ``[max_batch * ring / block + 1,
+    block, width]`` and addressed by ``position mod ring``: slot ``b``
+    owns ring blocks ``[b R, (b + 1) R)``, ``R = ring / block``. The
+    engine's block table is not read: the layer's table is DERIVED in the
+    graph from ``seq_lens`` (:meth:`cache`): entry ``j`` is the logical
+    block ``first + j``, ``first`` the block of the oldest position any
+    row of the step attends, and maps to ring block ``b R + (first + j)
+    mod R`` up to the block of the step's last row, else -1; the cache
+    object carries ``base = first * block``, which the layer takes off
+    the positions it hands the pool's write and the attention kernel
+    (attention depends on differences of positions alone). ``ring >=
+    window - 1 + rows`` for the most ``rows`` a slot is granted a step
+    (the layer checks): the ring then holds every position some row
+    attends, and whatever else a ring row holds reads as a position that
+    is masked, outside the window or past the row: a replayed or newly
+    assigned slot needs no reset."""
+    kind = "windowed_latent"
+    paged = False
+
+    def __init__(self, width, window, ring, dtype):
+        self.width, self.window, self.ring = int(width), int(window), \
+            int(ring)
+        self.dtype = np.dtype(dtype)
+
+    def bytes_per_slot(self):
+        return self.ring * self.width * self.dtype.itemsize
+
+    def alloc(self, zeros, n_blocks, block_size, batch, dtype, quant=None,
+              spec=None):
+        if self.ring % block_size:
+            raise ValueError(f"a ring of {self.ring} rows is not a whole "
+                             f"number of blocks of {block_size}")
+        return zeros((batch * (self.ring // block_size) + 1, block_size,
+                      self.width), dtype), None
+
+    def cache(self, a, b, tables, lens, q_lens, active, row_budget,
+              rows=None):
+        bs = a.shape[1]
+        per = self.ring // bs
+        q = _q_lens(q_lens, active).astype(jnp.int32)
+        L = lens.astype(jnp.int32)
+        first = jnp.maximum(L - np.int32(self.window - 1), 0) // np.int32(bs)
+        last = (L + jnp.maximum(q, 1) - 1) // np.int32(bs)
+        # entries: the ring's blocks and the one a window's ends share,
+        # rounded up to the latent kernel's widest entry
+        j = jnp.arange(-(-(per + 1) // 4) * 4, dtype=jnp.int32)[None, :]
+        block = first[:, None] + j
+        slot = jnp.arange(L.shape[0], dtype=jnp.int32)[:, None]
+        derived = jnp.where(block <= last[:, None],
+                            slot * np.int32(per) + block % np.int32(per), -1)
+        return LatentPagedCache(a, derived, lens, q, row_budget, rows,
+                                window=self.window,
+                                base=first * np.int32(bs))
+
+    def unpack(self, cache):
+        return _val(cache.pool), None
+
+
 class Recurrent:
     """A fixed-size state a slot: ``shapes`` = {name: (shape, dtype)} a
     slot, held as ``[max_batch, *shape]`` arrays
@@ -293,6 +403,10 @@ class Layout:
         self.has_paged = any(k.paged for k in self.kinds)
         self.has_recurrent = any(isinstance(k, Recurrent)
                                  for k in self.kinds)
+        #: some layer keeps an index pool beside its latents, or a ring a
+        #: slot in place of blocks a token
+        self.has_indexed = any(isinstance(k, (IndexedLatent, WindowedLatent))
+                               for k in self.kinds)
         #: the kind of the layers that hold K and V pools, few or all
         #: (None: no layer does): the append kernel's tile counts are theirs
         self.kv = next((k for k in self.kinds if isinstance(k, PagedKV)),
@@ -365,7 +479,9 @@ class Layout:
             return "looped"
         if not self.has_paged:
             return "recurrent_only"
-        return "beside" if self.has_recurrent else "latent_only"
+        if self.has_recurrent:
+            return "beside"
+        return "indexed_windowed" if self.has_indexed else "latent_only"
 
     def refuse(self, **options):
         """Raise ValueError for the first of ``options`` (name=value, in
@@ -394,6 +510,7 @@ class Layout:
 _CANNOT = ("{option} cannot serve a model whose cache layout has {kinds} "
            "layers: ")
 _START = {"beside": _CANNOT, "latent_only": _CANNOT,
+          "indexed_windowed": _CANNOT,
           "recurrent_only": _CANNOT + (
               "a recurrent-only layout (no layer is paged: every layer "
               "keeps one fixed-size state a slot, and the engine allocates "
@@ -457,6 +574,11 @@ REFUSALS = {
         "latent_only": "the content store adopts, copies and spills a block "
             "as a (K, V) pair of pools a layer; a latent layer has one pool "
             "and no V, and that path is not written for it (ROADMAP Queue 2)",
+        "indexed_windowed": "a cached block would have to hold a prefix's "
+            "latents AND its index keys (two pools a layer on one table), "
+            "and a windowed layer's ring a slot holds only the last "
+            "positions, in no block: a hit would skip the rows that fill "
+            "it; neither is written (ROADMAP Queue 2)",
         "recurrent_only": "has no blocks for the content store to hash, share "
             "or evict: the state after a shared prefix is one array a (slot, "
             "layer), and a hit would need it snapshotted at the prefix's end, "
@@ -469,6 +591,11 @@ REFUSALS = {
             "pair of pools a layer; a latent layer has one pool and no V, and "
             "that path is not written for it (a preempted request replays "
             "from its first token instead; ROADMAP Queue 2)",
+        "indexed_windowed": "swap and spill gather a slot's blocks out of a "
+            "(K, V) pair of pools a layer; here a block is a latent pool's "
+            "and an index pool's, and a windowed layer's ring a slot is in "
+            "no block and would be lost (a preempted request replays from "
+            "its first token instead; ROADMAP Queue 2)",
         "recurrent_only": "has no pool blocks to swap out or spill: a slot's "
             "whole state is its recurrent state, whose copy to the host is "
             "not written, and with no pool to run dry nothing is preempted "
@@ -481,6 +608,11 @@ REFUSALS = {
         "latent_only": "the verify grants are wired through PagedKVCache "
             "alone; a latent pool's rejected rows could be rolled back by its "
             "block table, but that path is not written",
+        "indexed_windowed": "a rejected draft rolls the slot's length back "
+            "over rows already computed; a windowed layer's ring has by then "
+            "overwritten the positions one turn back, which a shorter "
+            "length would attend again, and the verify grants are wired "
+            "through PagedKVCache alone",
         "recurrent_only": "cannot roll a rejected draft back: the state has "
             "absorbed the draft's rows, and there is no block table whose "
             "length could forget them"}),
@@ -488,6 +620,9 @@ REFUSALS = {
         "beside": "pool quantization keeps one scale per (block, kv head) of "
             "K and V pools; a latent pool and a float32 recurrent state have "
             "no such scales",
+        "indexed_windowed": "pool quantization keeps one scale per (block, kv "
+            "head) of K and V pools; a latent pool, an index pool and a ring "
+            "of latents have no such scales",
         "recurrent_only": "has no K/V pool to quantize: a float32 recurrent "
             "state has no (block, kv head) scales"}),
     "adapter_store": ("adapter_store", lambda v: v is not None, {
@@ -501,6 +636,10 @@ REFUSALS = {
         "beside": "kv heads are the shard dimension of K/V pools; a latent "
             "pool has one shared head and a recurrent state is held per slot "
             "(experts over chips with their exchange are not written)",
+        "indexed_windowed": "kv heads are the shard dimension of K/V pools; a "
+            "latent pool, its index pool and a windowed layer's ring have one "
+            "shared head (experts over chips with their exchange are not "
+            "written)",
         "recurrent_only": "has no K/V pools, whose kv heads are what the mesh "
             "shards: a recurrent state is held whole a slot, and its form "
             "sharded by head is not written"}),
@@ -511,12 +650,16 @@ REFUSALS = {
             "state that is not in blocks of K and V (a recurrent state a "
             "slot, one latent pool a layer), so it cannot be exported or "
             "imported"),
+        "indexed_windowed": _Whole(_SHIPS + "a cache layout with {kinds} "
+            "layers keeps a latent pool with an index pool beside it and a "
+            "ring of the last positions a slot, none of them a block of K "
+            "and V, so it cannot be exported or imported"),
         "recurrent_only": _Whole(_SHIPS + "a recurrent-only layout ({kinds} "
             "layers, no layer paged) has no blocks at all: a request's state "
             "is one fixed-size array a (slot, layer), whose export and import "
             "are not written")}),
     "request_kind": ("{}", lambda v: v == "embed", dict.fromkeys(
-        ("beside", "looped"), _Whole(
+        ("beside", "looped", "indexed_windowed"), _Whole(
             "kind='embed' pools the hidden rows of a K/V decoder's prefill; "
             "it is not wired for a cache layout with other state kinds (a "
             "latent pool, a recurrent state, a looped layout)"))),
@@ -532,15 +675,28 @@ class LatentPagedCache:
     ``rows``: the :class:`RowMap` of a mixed step, whose ``x`` is the
     packed ``[1, T, ...]`` (None: ``x`` is ``[B, S, ...]``); the pool's
     write and the attention kernel are handed it with the packed rows,
-    and read ``start``, ``slot``, ``pos`` and ``live`` off it."""
+    and read ``start``, ``slot``, ``pos`` and ``live`` off it.
+    ``index_pool`` [NB, block, index width]: an indexed layer's index keys
+    (:class:`IndexedLatent`). ``window`` / ``base`` [B]: a windowed
+    layer's window and the position its derived table starts at
+    (:class:`WindowedLatent`); ``seq_lens`` and ``rows.pos`` stay
+    absolute."""
     __slots__ = ("pool", "block_tables", "seq_lens", "q_lens", "row_budget",
-                 "rows")
+                 "rows", "index_pool", "window", "base")
 
     def __init__(self, pool, block_tables, seq_lens, q_lens,
-                 row_budget=None, rows=None):
+                 row_budget=None, rows=None, index_pool=None, window=None,
+                 base=None):
         self.pool, self.block_tables = pool, block_tables
         self.seq_lens, self.q_lens = seq_lens, q_lens
         self.row_budget, self.rows = row_budget, rows
+        self.index_pool, self.window, self.base = index_pool, window, base
+
+    def with_pools(self, pool, index_pool=None):
+        """This dispatch's cache with the pools a layer leaves."""
+        return LatentPagedCache(pool, self.block_tables, self.seq_lens,
+                                self.q_lens, self.row_budget, self.rows,
+                                index_pool, self.window, self.base)
 
 
 class RecurrentCache:
@@ -620,6 +776,13 @@ class RowMap:
         self.col = jnp.where(self.live, t - self.start[self.slot], 0)
         self.pos = jnp.where(
             self.live, seq_lens.astype(jnp.int32)[self.slot] + self.col, 0)
+
+    def shifted(self, base):
+        """This map with every live row's position less ``base[slot]``
+        (a windowed layer's derived table starts there)."""
+        out = copy.copy(self)
+        out.pos = jnp.where(self.live, self.pos - base[self.slot], 0)
+        return out
 
     def last(self):
         """[B] a slot's last live packed row (row 0 of the axis for a

@@ -1,5 +1,6 @@
 """The layers that the latent-attention, sparse-expert decoder families
-share (``models/kimi_linear.py``, ``models/deepseek_v2.py``): multi-head
+share (``models/kimi_linear.py``, ``models/deepseek_v2.py``,
+``models/dots3_note.py``): multi-head
 latent attention served in its absorbed form over a paged latent pool, a
 SwiGLU feed-forward, the held share of a layer's routed experts with its
 router and shared expert, the pre-norm block around them, and the decoder
@@ -21,7 +22,12 @@ values (``ops/kernels/latent_attention.py``), ``W_kvb``'s value half
 after. A row's position is ``RowMap.pos`` in a mixed step's packed form
 and ``seq_lens + i`` in the per-slot forms (the one-token step is S = 1);
 the pool's write and the kernel take the packed rows as they are, so a
-mixed step's latent layer never builds the per-slot view.
+mixed step's latent layer never builds the per-slot view. What one family
+adds (latents rescaled after their norms, a gate a head, a window over a
+ring a slot, a learned indexer that selects the positions a row attends:
+:class:`Indexer`, ``ops/kernels/sparse_latent_attention.py``) is a
+constructor argument of :class:`LatentAttention` too, and traces nothing
+where it is not asked for.
 
 Experts: ``ops/kernels/moe_dropless.py``; a layer holds experts
 ``[offset, offset + held)`` of ``published`` and routes over all of them.
@@ -31,11 +37,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
+from ..nn import Layer, LayerNorm, Linear, Embedding, RMSNorm, LayerList
 from ..nn.initializer import Constant, Normal
-from ..core.tensor import Tensor, dispatch
+from ..core.tensor import dispatch
 from ..ops.kernels import latent_attention as _lat
 from ..ops.kernels import moe_dropless as _moe
+from ..ops.kernels import sparse_latent_attention as _dsa
 from ..profiler import scope
 from . import cache_layout as CL
 
@@ -67,18 +74,69 @@ def _linear(i, o):
     return Linear(i, o, bias_attr=False)
 
 
+class Indexer(Layer):
+    """A learned indexer's weights (``ops/kernels/
+    sparse_latent_attention.py`` has its equations): ``heads`` index
+    queries of ``dim`` from the layer's compressed query ``c_q`` (``wq_b``),
+    one index key a token from the layer's input (``wk``, then a LayerNorm
+    with scale and bias), a weight a head from the input
+    (``weights_proj``); a row attends the ``top_k`` positions it scores
+    highest. The first ``pe`` values of queries and keys are rotated by
+    the layer's ``rotary``."""
+
+    def __init__(self, hidden, q_rank, heads, dim, top_k, norm_eps=1e-6):
+        super().__init__()
+        self.heads, self.dim, self.top_k = heads, dim, int(top_k)
+        self.norm_eps = norm_eps
+        self.wq_b = _linear(q_rank, heads * dim)
+        self.wk = _linear(hidden, dim)
+        self.k_norm = LayerNorm(dim, norm_eps)
+        self.weights_proj = _linear(hidden, heads)
+
+    def leaves(self):
+        return (self.wq_b.weight, self.wk.weight, self.k_norm.weight,
+                self.k_norm.bias, self.weights_proj.weight)
+
+
+def _layer_norm(x, w, b, eps):
+    x = x.astype(F32)
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * w.astype(F32) \
+        + b.astype(F32)
+
+
 class LatentAttention(Layer):
     """``q_rank``: the compressed query's width (None: one projection).
     ``rotary(x, pos)``: rotates the last axis of float32 ``x`` by the
     positions ``pos`` of its leading axes (None: nothing is rotated).
-    ``softmax_scale``: None is ``(nope + pe)^-1/2``."""
+    ``softmax_scale``: None is ``(nope + pe)^-1/2``. ``rescale`` ``(a_q,
+    a_kv)``: constants the compressed query and the latent are multiplied
+    by after their norms (None: neither). ``head_gate``: ``g_proj``, a
+    sigmoid gate a HEAD from the layer's input on the heads' outputs
+    before ``o_proj``. ``window``: a row attends itself and the ``window -
+    1`` positions before it (the cache is a
+    ``cache_layout.WindowedLatent``'s ring). ``indexer`` (an
+    :class:`Indexer`; needs ``q_rank``): a row attends the positions the
+    indexer selects, and the cache carries an index pool
+    (``cache_layout.IndexedLatent``); a table that cannot hold more than
+    ``top_k`` positions is attended whole, which is then the same set.
+    None of the four traces anything when it is not asked for."""
 
     def __init__(self, hidden, heads, kv_rank, nope, pe, v_dim, eps,
-                 q_rank=None, rotary=None, softmax_scale=None):
+                 q_rank=None, rotary=None, softmax_scale=None, rescale=None,
+                 head_gate=False, window=None, indexer=None):
         super().__init__()
         self.H, self.r, self.dn, self.dp, self.dv = heads, kv_rank, nope, \
             pe, v_dim
         self.eps, self.rotary = eps, rotary
+        self.rescale = None if rescale is None else tuple(
+            float(a) for a in rescale)
+        self.window = None if window is None else int(window)
+        if indexer is not None and (q_rank is None or window is not None):
+            raise ValueError("an indexer reads the compressed query "
+                             "(q_rank) and selects in a whole context (no "
+                             "window)")
         self.scale = float(softmax_scale if softmax_scale is not None
                            else (nope + pe) ** -0.5)
         if q_rank is None:
@@ -91,6 +149,10 @@ class LatentAttention(Layer):
         self.kv_a_layernorm = RMSNorm(kv_rank, eps)
         self.kv_b_proj = _linear(kv_rank, heads * (nope + v_dim))
         self.o_proj = _linear(heads * v_dim, hidden)
+        if head_gate:
+            self.g_proj = _linear(hidden, heads)
+        if indexer is not None:
+            self.indexer = indexer
 
     @property
     def width(self):
@@ -107,10 +169,20 @@ class LatentAttention(Layer):
     def forward(self, x, cache):
         H, r, dn, dp, dv, eps = self.H, self.r, self.dn, self.dp, self.dv, \
             self.eps
-        rotary, scale = self.rotary, self.scale
+        rotary, scale, rescale = self.rotary, self.scale, self.rescale
         rows = CL.packed(cache)
+        window = self.window
+        ix = getattr(self, "indexer", None)
+        if (window is None) != (cache.window is None) or \
+                (window is not None and window != cache.window):
+            raise ValueError(f"a layer of window {window} was handed a cache "
+                             f"of window {cache.window}")
+        if (ix is None) != (cache.index_pool is None):
+            raise ValueError("an indexed layer needs a cache with an index "
+                             "pool, and no other layer takes one")
 
-        def fn(x, pool, tables, lens, q_lens, wq, wkva, nw, wkvb, wo):
+        def fn(x, pool, ipool, tables, lens, q_lens, base, wq, wkva, nw,
+               wkvb, wo, wgate, wix):
             # everything on x's own rows: [B, S], or a mixed step's
             # packed [1, T], which no part of the layer leaves
             lead = x.shape[:2]
@@ -118,12 +190,18 @@ class LatentAttention(Layer):
                 if len(wq) == 1:
                     q = mm(x, wq[0])
                 else:
-                    q = mm(rms(mm(x, wq[0]), wq[1], eps).astype(x.dtype),
-                            wq[2])
+                    cq = rms(mm(x, wq[0]), wq[1], eps)
+                    if rescale is not None:
+                        cq = cq * jnp.float32(rescale[0])
+                    cq = cq.astype(x.dtype)
+                    q = mm(cq, wq[2])
                 q = q.reshape(lead + (H, dn + dp))
             with scope("kv_a_proj"):
                 kv = mm(x, wkva)
-                c = rms(kv[..., :r], nw, eps).astype(x.dtype)
+                c = rms(kv[..., :r], nw, eps)
+                if rescale is not None:
+                    c = c * jnp.float32(rescale[1])
+                c = c.astype(x.dtype)
                 k_pe = kv[..., r:]
             if rotary is not None:
                 with scope("pt.rope"):
@@ -144,6 +222,12 @@ class LatentAttention(Layer):
             if rotary is not None:
                 with scope("pt.rope"):
                     q_pe = rotary(q_pe, pos)
+            # a windowed layer's derived table starts at ``base``: the
+            # pool's write and the kernel see positions less it
+            lens_k, rows_k = lens, rows
+            if base is not None:
+                lens_k = lens.astype(jnp.int32) - base
+                rows_k = None if rows is None else rows.shifted(base)
             with scope("pt.view"):
                 qc = (jnp.concatenate([q_abs, q_pe], -1) *
                       jnp.float32(scale)).astype(x.dtype)
@@ -151,27 +235,88 @@ class LatentAttention(Layer):
                     # the packed rows as they are: the pool's write and
                     # the kernel read the row map themselves
                     entry, qc = entry[0], qc[0]
-                pool = _lat.latent_pool_write(pool, entry, tables, lens,
-                                              q_lens, rows)
-            with scope("pt.core"):
-                o = _lat.latent_attention_append(
-                    qc, pool, tables, lens, q_lens, r, rows)
+                pool = _lat.latent_pool_write(pool, entry, tables, lens_k,
+                                              q_lens, rows_k)
+            capacity = tables.shape[1] * pool.shape[1]
+            if window is not None:
+                granted = rows.width if rows is not None else lead[1]
+                ring = (pool.shape[0] - 1) // tables.shape[0] * pool.shape[1]
+                if window - 1 + granted > ring:
+                    raise ValueError(
+                        f"a ring of {ring} rows a slot cannot hold a window "
+                        f"of {window} behind {granted} new rows a step: it "
+                        f"needs {window - 1 + granted} "
+                        f"(cache_layout.WindowedLatent)")
+            if ix is not None:
+                with scope("pt.index"):
+                    wqi, wki, knw, knb, ww = wix
+                    J, di = ix.heads, ix.dim
+                    with scope("index_proj"):
+                        qi = mm32(cq, wqi).reshape(lead + (J, di))
+                        ki = mm32(x, wki)
+                        wt = mm32(x, ww) * jnp.float32((J * di) ** -0.5)
+                    ki = _layer_norm(ki, knw, knb, ix.norm_eps)
+                    if rotary is not None:
+                        with scope("pt.rope"):
+                            qi = jnp.concatenate(
+                                [rotary(qi[..., :dp], pos), qi[..., dp:]], -1)
+                            ki = jnp.concatenate(
+                                [rotary(ki[..., :dp], pos), ki[..., dp:]], -1)
+                    qi, ki = qi.astype(x.dtype), ki.astype(x.dtype)
+                    if rows is not None:
+                        qi, ki, wt = qi[0], ki[0], wt[0]
+                    else:
+                        qi = qi.reshape((-1, J, di))
+                        wt = wt.reshape((-1, J))
+                    ipool = _lat.latent_pool_write(ipool, ki, tables, lens,
+                                                   q_lens, rows)
+            counts = None
+            if ix is not None or window is not None:
+                geo = _dsa.Rows(lead, lens, q_lens, rows)
+                counts = _dsa.counts(
+                    geo, None if ix is None else ix.top_k, window)
+            if ix is not None and capacity > ix.top_k:
+                with scope("pt.index"):
+                    scores = _dsa.index_scores(qi, wt, ipool, tables, geo)
+                with scope("pt.select"):
+                    idx, ok = _dsa.select(scores, geo, ix.top_k)
+                with scope("pt.core"), scope("pt.sparse"):
+                    o = _dsa.sparse_attend(
+                        qc.reshape((-1, H, r + dp)), pool, tables, geo, idx,
+                        ok, r).reshape(qc.shape[:-1] + (r,))
+            else:
+                with scope("pt.core"):
+                    o = _lat.latent_attention_append(
+                        qc, pool, tables, lens_k, q_lens, r, rows_k,
+                        window=window)
             if rows is not None:
                 o = o[None]
             with scope("kv_b_proj"):
                 o = jnp.einsum("bshc,chv->bshv", o, wkvb[..., dn:],
                                preferred_element_type=F32).astype(x.dtype)
+            if wgate is not None:
+                with scope("g_proj"):
+                    g = mm32(x, wgate)
+                with scope("pt.gate"):
+                    o = (o.astype(F32) * jax.nn.sigmoid(g)[..., None]
+                         ).astype(x.dtype)
             with scope("o_proj"):
-                return mm(o.reshape(lead + (H * dv,)), wo), pool
+                return mm(o.reshape(lead + (H * dv,)), wo), pool, ipool, \
+                    counts
 
-        out, pool = dispatch(
-            fn, (x, cache.pool, cache.block_tables, cache.seq_lens,
-                 cache.q_lens, self._query_leaves(), self.kv_a_proj.weight,
+        gate = getattr(self, "g_proj", None)
+        out, pool, ipool, counts = dispatch(
+            fn, (x, cache.pool, cache.index_pool, cache.block_tables,
+                 cache.seq_lens, cache.q_lens, cache.base,
+                 self._query_leaves(), self.kv_a_proj.weight,
                  self.kv_a_layernorm.weight, self.kv_b_proj.weight,
-                 self.o_proj.weight), {}, name="latent_attention")
-        return out, CL.LatentPagedCache(pool, cache.block_tables,
-                                        cache.seq_lens, cache.q_lens,
-                                        cache.row_budget, rows)
+                 self.o_proj.weight, None if gate is None else gate.weight,
+                 None if ix is None else ix.leaves()), {},
+            name="latent_attention")
+        if counts is not None:
+            CL.count(CL._val(counts),
+                     at=len(StateCausalLM.step_counter_names))
+        return out, cache.with_pools(pool, ipool)
 
 
 def swiglu(x, wg, wu, wd):
@@ -272,7 +417,7 @@ class SparseMoE(Layer):
                  self.experts.down_proj, sh.gate_proj.weight,
                  sh.up_proj.weight, sh.down_proj.weight), {},
             name="sparse_moe")
-        CL.count(counts._value if isinstance(counts, Tensor) else counts)
+        CL.count(CL._val(counts))
         return out
 
 
@@ -342,6 +487,10 @@ class StateCausalLM(Layer):
     def _logits(self, hidden):
         return self.lm_head(hidden)
 
+    def _fresh_layout(self, seq, block_size):
+        """The kinds of a one-call state over ``seq`` rows a slot."""
+        return self.cache_layout()
+
     def fresh_caches(self, batch, seq, block_size=64):
         """Per-layer state for ONE call over ``seq`` new positions from
         position 0 (the plain forward's; the engine builds its own)."""
@@ -350,7 +499,7 @@ class StateCausalLM(Layer):
         lens = jnp.zeros((batch,), jnp.int32)
         q_lens = jnp.full((batch,), seq, jnp.int32)
         dt = self.model.embed_tokens.weight.dtype
-        layout = CL.Layout(self.cache_layout())
+        layout = CL.Layout(self._fresh_layout(seq, block_size))
         a, b = layout.alloc(jnp.zeros, batch * mb, block_size, batch, dt)
         return layout.caches(a, b, tables, lens, q_lens, None, None)
 

@@ -48,6 +48,15 @@ entries (:func:`entries_per_step`) whose blocks make one key tile of ``n
 one update a row tile. On a CPU the dense fallback gathers the slot's
 context, through the per-slot view where the rows come packed (tests
 only; :func:`latent_attention_enabled`).
+
+``window`` (static, None by default): a row at position ``t`` attends
+``t - window < s <= t`` alone. The mask gains that rule beside the causal
+one and a wide entry that ends before the first row's window is skipped;
+None traces none of it. A windowed layer's cache is a ring a slot
+(``cache_layout.WindowedLatent``): the table it hands this kernel is a
+short one derived from the lengths, and ``seq_lens`` and a packed row's
+position come less the position that table starts at, which changes
+nothing here: the kernel reads differences of positions alone.
 """
 from __future__ import annotations
 
@@ -123,7 +132,8 @@ def latent_pool_write(pool, new, block_tables, seq_lens, q_lens, rows=None):
     return out.reshape(pool.shape)
 
 
-def latent_attention_dense(q, pool, block_tables, seq_lens, q_lens, dv):
+def latent_attention_dense(q, pool, block_tables, seq_lens, q_lens, dv,
+                           window=None):
     """The plain-XLA form: gather each slot's context from the pool and
     attend with a mask. For the CPU tests; gathers a whole context."""
     b, s, h, d = q.shape
@@ -137,6 +147,8 @@ def latent_attention_dense(q, pool, block_tables, seq_lens, q_lens, dv):
         jnp.arange(s, dtype=jnp.int32)[None, :]
     t = jnp.arange(mb * bs, dtype=jnp.int32)
     mask = t[None, None, :] <= pos[:, :, None]
+    if window is not None:
+        mask &= t[None, None, :] > pos[:, :, None] - np.int32(window)
     p = jax.nn.softmax(jnp.where(mask[:, None], sc, NEG_INF), axis=-1)
     out = jnp.einsum("bhst,btd->bshd", p, ctx[..., :dv].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
@@ -234,7 +246,7 @@ def _pool_index_map(bs, mb, n, i):
 
 
 def _kernel(tables_ref, lens_ref, qlens_ref, start_ref, q_ref, *rest, bs, mb,
-            n, s_chunk, g, tr, ts, dv):
+            n, s_chunk, g, tr, ts, dv, window):
     k_refs, (o_ref, m_ref, l_ref, acc_ref) = rest[:n], rest[n:]
     f32 = jnp.float32
     b = pl.program_id(1)
@@ -255,6 +267,10 @@ def _kernel(tables_ref, lens_ref, qlens_ref, start_ref, q_ref, *rest, bs, mb,
             for e in ents]
     live = (ents[0] <= j_last) & functools.reduce(jnp.logical_or, held) \
         & (QL > Z)
+    if window is not None:
+        # a wide entry whose last latent lies before the first row's window
+        # is nobody's
+        live &= j * kt_i + np.int32(kt - 1 + window) > L
     t_lo, t_end = _tile_span(L, QL, j, g, kt, tr, jnp, _div_i32)
 
     def tiles(lo, hi, fn):
@@ -299,7 +315,11 @@ def _kernel(tables_ref, lens_ref, qlens_ref, start_ref, q_ref, *rest, bs, mb,
                 # row r (chunk index r // g) sees position p iff
                 # (p - lens) * g <= r
                 r = r0 + jax.lax.broadcasted_iota(jnp.int32, (nr, kt), 0)
-                s = jnp.where(rel * np.int32(g) <= r, s, NEG_INF)
+                seen = rel * np.int32(g) <= r
+                if window is not None:
+                    # and iff p - lens > r // g - window
+                    seen &= (rel + np.int32(window)) * np.int32(g) > r
+                s = jnp.where(seen, s, NEG_INF)
             m_prev = m_ref[rows, :]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -324,6 +344,9 @@ def _kernel(tables_ref, lens_ref, qlens_ref, start_ref, q_ref, *rest, bs, mb,
     # live row sees all of it, unless one of its table entries is ``-1``
     needs_mask = (j * kt_i + np.int32(kt - 1) >= L) | \
         jnp.logical_not(functools.reduce(jnp.logical_and, held))
+    if window is not None:
+        # ... and its first latent inside the last row's window
+        needs_mask |= j * kt_i + np.int32(window) <= L + QL - np.int32(1)
 
     @pl.when(live & needs_mask)
     def _attend_window():
@@ -346,7 +369,7 @@ def _kernel(tables_ref, lens_ref, qlens_ref, start_ref, q_ref, *rest, bs, mb,
 
 
 def latent_attention_append(q, pool, block_tables, seq_lens, q_lens, dv,
-                            rows=None):
+                            rows=None, window=None):
     """q: [B, S, H, D], scaled and with the key up-projection absorbed;
     pool: [NB, BS, D] holding every position below ``seq_lens + q_lens``
     (write the step's rows first: :func:`latent_pool_write`);
@@ -356,38 +379,47 @@ def latent_attention_append(q, pool, block_tables, seq_lens, q_lens, dv,
     step's ``cache_layout.RowMap``) q is the packed [T, H, D], slot b's
     rows the ``q_lens[b]`` from ``rows.start[b]`` on, and so is what
     comes back, [T, H, dv]. A row that holds no token (at or past
-    ``q_lens`` of its slot, the packed axis' padding) comes back zero."""
+    ``q_lens`` of its slot, the packed axis' padding) comes back zero.
+    ``window`` (static; None: the whole context): a row at position ``t``
+    attends ``t - window < s <= t`` alone, itself and the ``window - 1``
+    before it; None lowers to the kernel without one."""
+    window = None if window is None else int(window)
     if latent_attention_enabled():
         if rows is None:
             return _append_call(q, pool, block_tables, seq_lens, q_lens,
-                                dv=int(dv), interpret=_interpret())
+                                dv=int(dv), window=window,
+                                interpret=_interpret())
         return _append_rows(q, pool, block_tables, seq_lens, q_lens,
                             rows.start, width=rows.width, dv=int(dv),
-                            every=None, interpret=_interpret())
+                            every=None, window=window,
+                            interpret=_interpret())
     if rows is None:
         return latent_attention_dense(q, pool, block_tables, seq_lens,
-                                      q_lens, dv)
+                                      q_lens, dv, window)
     o = rows.from_slots(latent_attention_dense(
-        rows.to_slots(q), pool, block_tables, seq_lens, q_lens, dv))
+        rows.to_slots(q), pool, block_tables, seq_lens, q_lens, dv, window))
     return jnp.where(rows.live[:, None, None], o, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("dv", "interpret"), inline=True)
-def _append_call(q, pool, block_tables, seq_lens, q_lens, *, dv, interpret):
+@functools.partial(jax.jit, static_argnames=("dv", "window", "interpret"),
+                   inline=True)
+def _append_call(q, pool, block_tables, seq_lens, q_lens, *, dv, interpret,
+                 window=None):
     """The per-slot form ``q [B, S, H, D]``: the row axis ``[B * S]`` with
     slot ``b``'s rows from ``b * S``."""
     B, S, H, D = q.shape
     start = jnp.arange(B, dtype=jnp.int32) * np.int32(S)
     out = _append_rows(q.reshape(B * S, H, D), pool, block_tables, seq_lens,
                        q_lens, start, width=S, dv=dv, every=S,
-                       interpret=interpret)
+                       window=window, interpret=interpret)
     return out.reshape(B, S, H, dv)
 
 
 @functools.partial(jax.jit, static_argnames=("width", "dv", "every",
-                                             "interpret"), inline=True)
+                                             "window", "interpret"),
+                   inline=True)
 def _append_rows(q, pool, block_tables, seq_lens, q_lens, start, *, width,
-                 dv, every, interpret):
+                 dv, every, interpret, window=None):
     """The rows head-major with every slot's first row on a sublane tile,
     and the Pallas call, under one inlined inner jit so that a model's
     layers share a trace (``paged_attention._append_call``). ``q`` [T, H,
@@ -423,7 +455,7 @@ def _append_rows(q, pool, block_tables, seq_lens, q_lens, start, *, width,
     q3 = jnp.transpose(q.reshape(rows, HG, hq, D),
                        (1, 0, 2, 3)).reshape(HG, held, D)
     kernel = functools.partial(_kernel, bs=BS, mb=MB, n=n, s_chunk=S, g=hq,
-                               tr=tr, ts=ts, dv=dv)
+                               tr=tr, ts=ts, dv=dv, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
