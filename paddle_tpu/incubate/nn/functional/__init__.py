@@ -629,6 +629,23 @@ def block_multihead_attention(qkv, key_cache, value_cache, seq_lens_encoder,
     :func:`~paddle_tpu.ops.kernels.paged_attention.paged_attention_append`
     on TPU; the dense scatter+gather+einsum below is the CPU fallback.
 
+    Packed append form (the reference's own layout, ``qkv [token_num,
+    ...]`` with ``cu_seqlens_q``; what the fused scheduler's mixed step
+    hands a layer since it packs its granted rows on one axis,
+    ``models/cache_layout.py``'s ``RowMap``): qkv ``[T, (Hq + 2*Hkv)*D]``
+    with ``seq_lens_this_time`` [B] AND ``cu_seqlens_q`` [B], a sequence's
+    first row (the exclusive running sum of ``seq_lens_this_time``,
+    ``RowMap.start``): sequence b's rows are the ``seq_lens_this_time[b]``
+    from ``cu_seqlens_q[b]`` on, in position order, sequences ascending
+    and no two overlapping. ``max_seq_len`` (static, required in this
+    form) is the most rows one sequence holds in a step (the chunk).
+    Same semantics a row as the ``[B, S, ...]`` form, and the same
+    returns with ``out [T, Hq*D]``: a row that holds no token comes back
+    zero. On TPU the rows go to the kernel as they lie (its packed
+    entry: no per-sequence view is built); the CPU fallback slices the
+    ``[B, max_seq_len, ...]`` view out at ``cu_seqlens_q``, runs the
+    dense form and takes the rows back.
+
     Quantized pools (``cache_quant_type="int8"|"int4"`` — the serving
     engine's ``kv_cache_dtype``; the reference signature's
     ``cache_k_quant_scales``/``cache_v_quant_scales`` carry the
@@ -649,14 +666,16 @@ def block_multihead_attention(qkv, key_cache, value_cache, seq_lens_encoder,
                   or cache_v_quant_scales is None):
         raise ValueError("cache_quant_type needs cache_k_quant_scales and "
                          "cache_v_quant_scales ([num_blocks, Hkv] fp32)")
-    if len(qkv.shape) == 3:
+    if len(qkv.shape) == 3 or cu_seqlens_q is not None:
         if seq_lens_this_time is None:
-            raise ValueError("append-step block_mha (3-D qkv) requires "
+            raise ValueError("append-step block_mha (3-D qkv, or packed "
+                             "rows with cu_seqlens_q) requires "
                              "seq_lens_this_time (per-sequence q_lens)")
         return _block_mha_append(qkv, key_cache, value_cache,
                                  seq_lens_decoder, seq_lens_this_time,
                                  block_tables, cache_k_quant_scales,
-                                 cache_v_quant_scales, quant)
+                                 cache_v_quant_scales, quant,
+                                 cu_seqlens_q, max_seq_len)
     def fn(qkv_v, kc, vc, lens, tables, *qargs):
         from ....ops.kernels.paged_attention import (
             current_paged_tp, paged_attention_decode,
@@ -770,7 +789,7 @@ def _quant_head_dim(qkv_width, Hkv, Dp, quant):
 
 def _block_mha_append(qkv, key_cache, value_cache, seq_lens, q_lens,
                       block_tables, k_scales=None, v_scales=None,
-                      quant=None):
+                      quant=None, start=None, width=None):
     """Append-step paged attention (see block_multihead_attention): S new
     positions per sequence against the block pools, causal within the
     chunk. Dense fallback = scatter the valid rows into their blocks
@@ -779,45 +798,66 @@ def _block_mha_append(qkv, key_cache, value_cache, seq_lens, q_lens,
     reference semantics the decode form uses, extended along S.
     ``quant`` + scale arrays: quantized pools (dequant-on-read, window
     blocks re-quantized under fresh absmax scales; return grows the
-    updated scale arrays)."""
+    updated scale arrays). ``start`` [B]: the packed form, qkv ``[T, W]``
+    with sequence b's rows from ``start[b]`` on and at most ``width`` of
+    them (static)."""
+    packed = start is not None
+    if packed:
+        if width is None or tuple(start.shape) != tuple(q_lens.shape):
+            raise ValueError(
+                "packed append-step block_mha needs max_seq_len (the most "
+                "rows of a sequence) and cu_seqlens_q shaped as "
+                f"seq_lens_this_time, got {width} and {tuple(start.shape)}")
+        width = int(width)
+
     def fn(qkv_v, kc, vc, lens, qlens, tables, *qargs):
         from ....ops.kernels.paged_attention import (
             current_paged_tp, paged_attention_append,
             paged_attention_append_tp, paged_attention_enabled)
 
+        T = qkv_v.shape[0]
+        view = packed and not paged_attention_enabled()
+        if packed:
+            first, qargs = qargs[0].astype(jnp.int32), qargs[1:]
+        if view:
+            # the dense form below on the per-sequence view: ``width``
+            # rows from each sequence's first (zeros past the axis' end)
+            padded = jnp.concatenate(
+                [qkv_v, jnp.zeros((width,) + qkv_v.shape[1:], qkv_v.dtype)])
+            qkv_v = jax.vmap(lambda at: jax.lax.dynamic_slice_in_dim(
+                padded, at, width))(first)
         nb, Hkv, bs, Dp = kc.shape
-        b, S = qkv_v.shape[0], qkv_v.shape[1]
         max_blocks = tables.shape[1]
         if quant:
             ks, vs = (a.astype(jnp.float32) for a in qargs)
-            D = _quant_head_dim(qkv_v.shape[2], Hkv, Dp, quant)
+            D = _quant_head_dim(qkv_v.shape[-1], Hkv, Dp, quant)
         else:
             ks = vs = None
             D = Dp
-        Hq = qkv_v.shape[2] // D - 2 * Hkv
-        q = qkv_v[:, :, :Hq * D].reshape(b, S, Hq, D)
-        knew = qkv_v[:, :, Hq * D:(Hq + Hkv) * D].reshape(b, S, Hkv, D)
-        vnew = qkv_v[:, :, (Hq + Hkv) * D:].reshape(b, S, Hkv, D)
+        Hq = qkv_v.shape[-1] // D - 2 * Hkv
+        # [B, S] rows, or the packed [T]
+        lead = qkv_v.shape[:-1]
+        q = qkv_v[..., :Hq * D].reshape(lead + (Hq, D))
+        knew = qkv_v[..., Hq * D:(Hq + Hkv) * D].reshape(lead + (Hkv, D))
+        vnew = qkv_v[..., (Hq + Hkv) * D:].reshape(lead + (Hkv, D))
         lens = lens.astype(jnp.int32)
         qlens = qlens.astype(jnp.int32)
         tables = tables.astype(jnp.int32)
 
         if paged_attention_enabled():
             tp = current_paged_tp()
+            kw = dict(k_scale=ks, v_scale=vs, quant=quant)
+            if packed:
+                kw.update(start=first, width=width)
             if tp is not None:
                 outs = paged_attention_append_tp(
                     q, kc, vc, tables, lens, qlens, knew, vnew,
-                    mesh=tp[0], axis=tp[1], k_scale=ks, v_scale=vs,
-                    quant=quant)
+                    mesh=tp[0], axis=tp[1], **kw)
             else:
                 outs = paged_attention_append(
-                    q, kc, vc, tables, lens, qlens, knew, vnew,
-                    k_scale=ks, v_scale=vs, quant=quant)
-            if quant:
-                out, kc, vc, ks, vs = outs
-                return out.reshape(b, S, Hq * D), kc, vc, ks, vs
-            out, kc, vc = outs
-            return out.reshape(b, S, Hq * D), kc, vc
+                    q, kc, vc, tables, lens, qlens, knew, vnew, **kw)
+            return (outs[0].reshape(lead + (Hq * D,)),) + tuple(outs[1:])
+        b, S = lead
 
         # scatter valid rows: row i of sequence b lands at absolute
         # position lens[b]+i when i < qlens[b]; padding / unallocated /
@@ -873,11 +913,24 @@ def _block_mha_append(qkv, key_cache, value_cache, seq_lens, q_lens,
         probs = jax.nn.softmax(logits, axis=-1).astype(vseq.dtype)
         out = jnp.einsum("bhsgt,bthd->bshgd", probs, vseq)
         if quant:
-            return (out.astype(qkv_v.dtype).reshape(b, S, Hq * D),
-                    kc, vc, ks, vs)
-        return out.reshape(b, S, Hq * D), kc, vc
+            out = out.astype(qkv_v.dtype)
+        out = out.reshape(b, S, Hq * D)
+        if view:
+            # back to the packed rows: row t is sequence b's iff it lies in
+            # [first[b], first[b] + qlens[b]); a row of nobody's is zero
+            t = jnp.arange(T, dtype=jnp.int32)[:, None]
+            held = (t >= first[None]) & (
+                t < (first + jnp.minimum(qlens, S))[None])
+            seq = jnp.argmax(held, axis=1).astype(jnp.int32)
+            at = seq * S + (t[:, 0] - first[seq])
+            out = jnp.where(jnp.any(held, axis=1)[:, None],
+                            jnp.take(out.reshape(b * S, Hq * D), at, axis=0,
+                                     mode="clip"), 0.0)
+        return (out, kc, vc, ks, vs) if quant else (out, kc, vc)
 
     args = (qkv, key_cache, value_cache, seq_lens, q_lens, block_tables)
+    if packed:
+        args += (start,)
     if quant:
         args += (k_scales, v_scales)
         return dispatch(fn, args, {}, name="block_mha_append_quant")

@@ -1074,12 +1074,20 @@ class LLMEngine:
         (they multiply both alike; a layout's other layers run no such
         kernel): the kernel module's own count over the host's lens
         mirror, so call this BEFORE the mirrors grow. The group (query
-        heads a kv head) is the K/V kind's to say."""
+        heads a kv head) is the K/V kind's to say. The step's rows are
+        packed (``RowMap``: a slot's first row is the grants before it),
+        and the kernel's tiles lie on that axis. The host's grants, as
+        every count here: a slot the step's own capacity guard takes out
+        in the graph (pipelined over-dispatch; the read-out's margin
+        retires a slot before it fires) is still counted, and the slots
+        after it as if it held its rows."""
         from ..ops.kernels.paged_attention import append_tile_steps
         lens = [0 if s is None else s.sched_len() for s in self.slots]
+        q_lens = np.asarray(q_lens, np.int64)
         return append_tile_steps(
             lens, q_lens, self._layout.kv.group(self.model.config),
-            self.chunk, self.block_size, self._tables.shape[1])
+            self.chunk, self.block_size, self._tables.shape[1],
+            np.cumsum(q_lens) - q_lens)
 
     def _book_kv_grid(self, iterations):
         """One paged dispatch's attention grid against what it holds: the

@@ -84,10 +84,13 @@ A core that reads ``(start, q_lens, seq_lens)`` itself takes the packed
 rows as they are and builds no view: power retention's walk, the latent
 pool's write and attention (``ops/kernels/latent_attention.py``), the
 learned indexer's scores, selection and gathered attention
-(``ops/kernels/sparse_latent_attention.py``), and KDA's convolution and
+(``ops/kernels/sparse_latent_attention.py``), KDA's convolution and
 chunk walk (``kda.causal_conv_packed``,
-``ops/kernels/kda_chunk_walk.py``), whose rows without a token come back
-zero.
+``ops/kernels/kda_chunk_walk.py``), and the paged K/V append
+(``ops/kernels/paged_attention.py``'s packed entry, through the
+attention op's packed form), whose rows without a token come back zero.
+No served layer builds the view any more: what is left on it are the
+forms below, off the chip's path.
 """
 from __future__ import annotations
 
@@ -751,16 +754,20 @@ class RowMap:
     indexes out of a table), ``start`` [B] a slot's first packed row,
     ``q_lens`` [B], ``width`` the per-slot view's S.
 
-    **Who still calls** :meth:`to_slots` / :meth:`from_slots`: the paged
-    K/V append's layers (``models/llama.py``'s attention, Solar's
-    ``GatedAttention``: ``ops/kernels/paged_attention.py`` takes ``[B, S,
-    H, D]``), ``models/lora.py``'s per-slot adapter gather, the engine's
-    read-out and id packing, and the fallbacks that run a per-slot XLA
-    form on packed rows (``latent_attention_append`` on a CPU,
-    ``KimiDeltaAttention`` at a head width off the lanes,
+    **Who still calls** :meth:`to_slots` / :meth:`from_slots`:
+    ``models/lora.py``'s per-slot adapter gather, the engine's read-out
+    and id packing, ``models/llama.py``'s attention over the DENSE chunk
+    cache (``cache_impl="dense"``), and the fallbacks that run a per-slot
+    XLA form on packed rows (``latent_attention_append``'s dense form on
+    a CPU, ``KimiDeltaAttention`` at a head width off the lanes,
     ``kda_chunk_walk`` for more packed rows than VMEM holds). The kernels
-    of power retention, latent attention and KDA read ``start`` and
-    ``q_lens`` and call neither."""
+    of power retention, latent attention, KDA and, since PR 46, the paged
+    K/V append (``paged_attention_append``'s packed entry: ``start``
+    scalar-prefetched beside ``seq_lens`` and ``q_lens``, slot ``b``'s
+    rows read and written at ``start[b]`` of a head's resident block)
+    read ``start`` and ``q_lens`` and call neither; the attention op's
+    dense form on a CPU slices its own view out at ``cu_seqlens_q`` (the
+    op layer imports nothing from ``models/``)."""
     __slots__ = ("slot", "col", "pos", "live", "start", "q_lens", "width")
 
     def __init__(self, q_lens, seq_lens, n_rows, width):

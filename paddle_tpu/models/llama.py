@@ -207,7 +207,8 @@ class PagedKVCache:
 
     ``rows`` (a :class:`~paddle_tpu.models.cache_layout.RowMap`): the
     layer's ``x`` is the mixed step's packed ``[1, T, ...]`` and not
-    ``[B, S, ...]``; the attention takes the per-slot view through it.
+    ``[B, S, ...]``; the attention op takes the rows as they lie, with
+    ``rows.start`` (its packed form).
 
     ``row_budget``: the dispatcher's static bound on the step's live rows
     over all slots, as the other kinds' cache objects carry it (None:
@@ -354,9 +355,12 @@ class LlamaAttention(Layer):
     def forward(self, x, rope_cache, attn_mask=None, kv_cache=None, position_offset=0):
         b, s = x.shape[0], x.shape[1]
         lora = active_lora()
-        #: a mixed step's packed row axis: x is [1, T, ...], the cache
-        #: branches below want the per-slot view [B, S, ...]
+        #: a mixed step's packed row axis: x is [1, T, ...]. The paged
+        #: pools' attention takes the rows as they lie; the dense chunk
+        #: cache (``cache_impl="dense"``) wants the per-slot view [B, S,
+        #: ...] (``views``)
         rows = packed(kv_cache)
+        views = rows is not None and not isinstance(kv_cache, PagedKVCache)
         if self.fused:
             if lora is not None:
                 raise ValueError(
@@ -385,7 +389,7 @@ class LlamaAttention(Layer):
             v = ops.reshape(vf, [b, s, self.num_kv_heads, self.head_dim])
 
         def o_proj(t):
-            if rows is not None:
+            if views:
                 with scope("pt.view"):
                     t = dispatch(lambda y: rows.from_slots(y)[None], (t,),
                                  {}, name="rows_from_slots")
@@ -407,7 +411,7 @@ class LlamaAttention(Layer):
                     lambda qq, kk: apply_rope(qq, kk, cos, sin,
                                               position_offset),
                     (q, k), {}, name="rope")
-        if rows is not None:
+        if views:
             # what is above ran on the granted rows; o_proj takes the
             # attention's output back to them
             with scope("pt.view"):
@@ -426,18 +430,24 @@ class LlamaAttention(Layer):
             qargs = dict(cache_k_quant_scales=kv_cache.k_scale,
                          cache_v_quant_scales=kv_cache.v_scale,
                          cache_quant_type=kvq) if kvq else {}
-            if s != 1:
+            if s != 1 or rows is not None:
                 # fused mixed step: S rows per slot, q_lens of them real —
                 # the APPEND form of the op (Pallas append kernel on TPU,
-                # dense scatter+gather fallback on CPU)
+                # dense scatter+gather fallback on CPU); its PACKED form
+                # where the step's rows come on one axis: slot b's are
+                # the q_lens[b] from rows.start[b] on
                 if kv_cache.q_lens is None:
                     raise ValueError(
                         "PagedKVCache with seq len > 1 is the fused "
                         "append step and needs per-slot q_lens")
+                lead = [b, s] if rows is None else [s]
+                if rows is not None:
+                    qargs.update(cu_seqlens_q=rows.start,
+                                 max_seq_len=rows.width)
                 with scope("pt.view"):
-                    qkv = ops.concat([ops.reshape(q, [b, s, H * D]),
-                                      ops.reshape(k, [b, s, Hkv * D]),
-                                      ops.reshape(v, [b, s, Hkv * D])],
+                    qkv = ops.concat([ops.reshape(q, lead + [H * D]),
+                                      ops.reshape(k, lead + [Hkv * D]),
+                                      ops.reshape(v, lead + [Hkv * D])],
                                      axis=-1)
                 with scope("pt.core"):
                     outs = IF.block_multihead_attention(

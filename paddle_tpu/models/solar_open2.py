@@ -93,9 +93,9 @@ class GatedAttention(Layer):
     """Softmax attention of ``heads`` query heads over ``kv_heads`` K/V
     heads of ``head_dim``, no positions, a sigmoid gate a channel on the
     heads' outputs before ``o_proj``. ``x`` is ``[B, S, hidden]`` or a
-    mixed step's packed ``[1, T, hidden]``: the projections and the gate
-    run on x's own rows, and the per-slot view is taken around the paged
-    attention only."""
+    mixed step's packed ``[1, T, hidden]``: the projections, the gate and
+    the paged attention all run on x's own rows (the attention op's
+    packed form, with ``rows.start``), and no per-slot view is built."""
 
     def __init__(self, hidden, heads, kv_heads, head_dim):
         super().__init__()
@@ -113,11 +113,18 @@ class GatedAttention(Layer):
         q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
         with scope("pt.view"):
             qkv = ops.concat([q, k, v], axis=-1)
-            if rows is not None:
-                qkv = dispatch(lambda t: rows.to_slots(t[0]), (qkv,), {},
-                               name="rows_to_slots")
             b, s, width = qkv.shape
-        if s != 1:
+        if rows is not None:
+            # the packed append form: slot b's rows are the q_lens[b]
+            # from rows.start[b] on
+            with scope("pt.view"):
+                qkv = ops.reshape(qkv, [s, width])
+            with scope("pt.core"):
+                o, kc, vc = IF.block_multihead_attention(
+                    qkv, cache.k, cache.v, None, cache.seq_lens,
+                    cache.q_lens, cu_seqlens_q=rows.start,
+                    block_tables=cache.block_tables, max_seq_len=rows.width)
+        elif s != 1:
             # the append form: S rows a slot, q_lens of them live
             with scope("pt.core"):
                 o, kc, vc = IF.block_multihead_attention(
@@ -130,13 +137,10 @@ class GatedAttention(Layer):
                 o, kc, vc = IF.block_multihead_attention(
                     qkv, cache.k, cache.v, None, cache.seq_lens, None,
                     block_tables=cache.block_tables)
-            with scope("pt.view"):
-                o = ops.reshape(o, [b, 1, self.H * self.D])
+        with scope("pt.view"):
+            o = ops.reshape(o, [b, s, self.H * self.D])
 
         def gated(o, x, wg):
-            if rows is not None:
-                with scope("pt.view"):
-                    o = rows.from_slots(o)[None]
             with scope("g_proj"):
                 g = mm32(x, wg)
             with scope("pt.gate"):

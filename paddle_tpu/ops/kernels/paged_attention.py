@@ -301,38 +301,41 @@ def paged_attention_decode_tp(q, k_pool, v_pool, block_tables, seq_lens,
 def paged_attention_append_tp(q, k_pool, v_pool, block_tables, seq_lens,
                               q_lens, new_k, new_v, mesh, axis="tp",
                               scale=None, k_scale=None, v_scale=None,
-                              quant=None):
+                              quant=None, start=None, width=None):
     """:func:`paged_attention_append` sharded over a tensor-parallel mesh
     axis — the mixed prefill+decode step's kernel under the TP serving
     engine. Same layout contract as the decode wrapper: pools/new-KV/q
     (and, quantized, the per-(block, head) scale arrays) shard on their
-    head dims, tables/seq_lens/q_lens replicated, output heads stay
-    sharded for the row-parallel o_proj to reduce."""
+    head dims, tables/seq_lens/q_lens (and the packed entry's ``start``)
+    replicated, output heads stay sharded for the row-parallel o_proj to
+    reduce."""
     from jax.sharding import PartitionSpec as P
 
     pool_spec = P(None, axis, None, None)
     scale_spec = P(None, axis)
-    q_spec = P(None, None, axis, None)          # [B, S, Hq, D]
-    in_specs = [q_spec, pool_spec, pool_spec, P(), P(), P()]
+    # [B, S, Hq, D], or the packed entry's [T, Hq, D]
+    q_spec = P(*[None] * (q.ndim - 2), axis, None)
+    starts = jnp.zeros_like(q_lens) if start is None else start
+    in_specs = [q_spec, pool_spec, pool_spec, P(), P(), P(), P()]
     out_specs = [q_spec, pool_spec, pool_spec]
-    args = [q, k_pool, v_pool, block_tables, seq_lens, q_lens]
+    args = [q, k_pool, v_pool, block_tables, seq_lens, q_lens, starts]
     if quant:
         in_specs += [scale_spec, scale_spec]
         out_specs += [scale_spec, scale_spec]
         args += [k_scale, v_scale]
-    in_specs += [q_spec, q_spec]                # new_k/new_v [B, S, Hkv, D]
+    in_specs += [q_spec, q_spec]                # new_k/new_v, as q
     args += [new_k, new_v]
 
-    def body(q_s, k_s, v_s, tables, lens, qlens, *rest):
+    def body(q_s, k_s, v_s, tables, lens, qlens, starts_s, *rest):
         if quant:
             ks_s, vs_s, nk_s, nv_s = rest
         else:
             ks_s = vs_s = None
             nk_s, nv_s = rest
-        return paged_attention_append(q_s, k_s, v_s, tables, lens, qlens,
-                                      nk_s, nv_s, scale=scale,
-                                      k_scale=ks_s, v_scale=vs_s,
-                                      quant=quant)
+        return paged_attention_append(
+            q_s, k_s, v_s, tables, lens, qlens, nk_s, nv_s, scale=scale,
+            k_scale=ks_s, v_scale=vs_s, quant=quant,
+            start=None if start is None else starts_s, width=width)
 
     return _tp_shard_map(body, mesh, axis, in_specs, out_specs)(*args)
 
@@ -885,6 +888,9 @@ _HEADS_INTERLEAVED = 8
 #: step (85 MiB planned) take 18.3, bit for bit the same output (read on
 #: the v5e at 16 x 400 blocks, PERF.md section 6, PR 41)
 _APPEND_VMEM_BUDGET = 92 << 20
+#: rows of a sublane tile of the widest packing (bf16; f32's is 8): what a
+#: dynamic row offset into a VMEM block has to be a multiple of
+_SUBLANE = 16
 
 
 def _row_tile(g, s):
@@ -909,17 +915,41 @@ def _row_subtile(tr):
     return _ROW_SUBTILE if tr % _ROW_SUBTILE == 0 else tr
 
 
-def _append_vmem_bytes(hb, g, s, d, bs, dk, q_isz, pool_isz, new_isz):
+def _append_vmem_bytes(hb, g, s, d, bs, dk, q_isz, pool_isz, new_isz,
+                       resident=None):
     """VMEM one grid step of the append call holds with ``hb`` kv heads a
-    step: q and out tiles, pool blocks in and out and the chunk's K/V
+    step: q and out blocks, pool blocks in and out and the chunk's K/V
     (each double-buffered by the pipeline), and the f32 scratch (m and l
-    are one lane wide and pad to 128)."""
-    rows = g * s
+    are one lane wide and pad to 128). The per-slot plan's q and out
+    blocks are one slot's ``g * s`` rows and its K/V block the slot's
+    ``s``; the packed plan's are a head's rows of EVERY slot,
+    ``resident`` = (rows of q, rows of K/V), and its scratch is a row
+    tile longer (a slot's first row lies anywhere in its first tile)."""
+    rows, new_rows = resident or (g * s, s)
     q_out = 2 * 2 * hb * rows * d * q_isz
     pools = 2 * 2 * 2 * hb * bs * dk * pool_isz
-    new = 2 * 2 * hb * s * d * new_isz
-    scratch = hb * rows * (d + 2 * 128) * 4
+    new = 2 * 2 * hb * new_rows * d * new_isz
+    scratch = hb * _scratch_rows(g, s, bool(resident)) * (d + 2 * 128) * 4
     return q_out + pools + new + scratch
+
+
+def _packed_row_tile(g, s):
+    """Rows of a row tile of the packed plan: :func:`_row_tile`'s and one
+    sublane tile more. A slot's rows lie up to ``_SUBLANE - 1`` rows into
+    their first tile, and with the longer tile they still fit the
+    ``g * s // _row_tile`` tiles they fill when they start one: a tile's
+    update is a chain whose latency the chip pays whatever the rows, so
+    an extra tile an entry for 15 rows would cost half a full one (read
+    on the v5e, PERF.md section 6, PR 46)."""
+    return _row_tile(g, s) + _SUBLANE
+
+
+def _scratch_rows(g, s, packed):
+    """Rows of a slot's f32 accumulator: its ``g * s``, or on the packed
+    axis as many tiles of :func:`_packed_row_tile`."""
+    if not packed:
+        return g * s
+    return g * s // _row_tile(g, s) * _packed_row_tile(g, s)
 
 
 def _heads_per_step(hkv, *shape):
@@ -939,38 +969,62 @@ def _div_i32(a, b):
     return jax.lax.div(a, np.int32(b))
 
 
-def _tile_span(L, QL, jj, g, bs, tr, xp, div):
+def _tile_span(L, QL, jj, g, bs, tr, xp, div, off=0):
     """THE skip rule of the append kernel: the row tiles ``[t_lo, t_end)``
     of a slot that table entry ``jj`` has work for. Rows are
     position-major (row ``i * g + q_head``), so the ``QL * g`` live rows
     are a prefix and ``t_end`` tiles cover it; row ``r`` sees kv position
     ``p`` iff ``(p - L) * g <= r``, so a block that starts ``d`` positions
     into the chunk is wholly masked for the tiles before row ``d * g``'s.
-    The kernel (traced i32 scalars) and :func:`append_tile_steps` (numpy
-    arrays) both call this, so the counter cannot drift from the rule."""
-    t_end = div(QL * g + (tr - 1), tr)
-    t_lo = div(xp.maximum(jj * bs - L, 0) * g, tr)
+    ``off``: the rows before the slot's first in its first tile (the
+    packed entry, whose tiles lie on the packed axis' own grid; 0 where a
+    slot's rows start a tile): every row moves up by it. The kernel
+    (traced i32 scalars) and :func:`append_tile_steps` (numpy arrays)
+    both call this, so the counter cannot drift from the rule."""
+    t_end = div(QL * g + off + (tr - 1), tr)
+    t_lo = div(xp.maximum(jj * bs - L, 0) * g + off, tr)
     return t_lo, t_end
 
 
+def _tile_offset(row):
+    """Rows between ``row`` (a slot's first on the packed axis: ``start *
+    g`` of a head's q rows, ``start`` of the chunk's K/V) and the tile
+    boundary at or below it: tiles start on multiples of
+    :data:`_SUBLANE`, a power of two (one ``and``: the kernel asks at
+    every grid step)."""
+    return row & np.int32(_SUBLANE - 1)
+
+
 def append_tile_steps(seq_lens, q_lens, group, chunk, block_size,
-                      max_blocks):
+                      max_blocks, start=None):
     """``(run, grid)`` of one :func:`paged_attention_append` call, per kv
     head: ``run`` = (row tile, table entry) pairs the kernel computes —
     for each slot with ``q_lens > 0``, over the entries up to the block of
     its window's last position, the tiles of :func:`_tile_span` — and
     ``grid`` = every row tile against every entry of every slot, which is
-    what a kernel blind to ``(seq_lens, q_lens)`` would compute. Host
-    side, numpy; a ``-1`` entry inside a live context (the kernel skips
-    it) is not looked for: the scheduler allocates before it grants."""
+    what a kernel blind to ``(seq_lens, q_lens)`` would compute. ``start``
+    [B] (the packed entry: a slot's first packed row): the tiles are the
+    packed axis' own, :func:`_packed_row_tile` rows each from the tile
+    boundary at or below the slot's first row, never more of them than
+    the slot's rows fill when they start a tile. ``grid`` is the same
+    NUMBER either way, ``group * chunk // _row_tile`` tiles a (slot,
+    entry): what a blind kernel of the same plan would run, so its tiles
+    are the plan's too (the packed plan's are ``_SUBLANE`` rows taller
+    than the per-slot plan's, and as many). Host side, numpy; a ``-1`` entry inside a live
+    context (the kernel skips it) is not looked for: the scheduler
+    allocates before it grants."""
     L = np.asarray(seq_lens, np.int64).reshape(-1, 1)
     QL = np.minimum(np.asarray(q_lens, np.int64), chunk).reshape(-1, 1)
-    tr = _row_tile(group, chunk)
+    tr = tile = _row_tile(group, chunk)
+    off = 0
+    if start is not None:
+        tile = _packed_row_tile(group, chunk)
+        off = _tile_offset(np.asarray(start, np.int64).reshape(-1, 1) * group)
     j_last = np.minimum((L + np.maximum(QL - 1, 0)) // block_size,
                         max_blocks - 1)
     jj = np.arange(max_blocks, dtype=np.int64)[None, :]
-    t_lo, t_end = _tile_span(L, QL, jj, group, block_size, tr, np,
-                             np.floor_divide)
+    t_lo, t_end = _tile_span(L, QL, jj, group, block_size, tile, np,
+                             np.floor_divide, off)
     walked = (jj <= j_last) & (QL > 0)
     run = int(np.sum(np.where(walked, t_end - t_lo, 0)))
     return run, int(L.size * max_blocks * (group * chunk // tr))
@@ -996,12 +1050,18 @@ def _apd_walk(lens_ref, qlens_ref, b, j, bs, mb):
     return jnp.where(qlens_ref[b] > Z, jnp.minimum(j, j_last), j_last)
 
 
-def _apd_q_index_map(b, h, j, tables_ref, lens_ref, qlens_ref):
+def _apd_q_index_map(b, h, j, *refs):
     return (b, h, Z, Z)
 
 
+def _apd_rows_index_map(b, h, j, *refs):
+    # the packed plan: a head group's rows of every slot, one block for
+    # the whole of its walk, fetched and written once
+    return (Z, h, Z, Z)
+
+
 def _apd_kv_index_map(bs, mb):
-    def im(b, h, j, tables_ref, lens_ref, qlens_ref):
+    def im(b, h, j, tables_ref, lens_ref, qlens_ref, start_ref):
         jj = _apd_walk(lens_ref, qlens_ref, b, j, bs, mb)
         return (jnp.maximum(tables_ref[b, jj], Z), h, Z, Z)
     return im
@@ -1014,7 +1074,7 @@ def _apd_pool_out_index_map(bs, mb, nb):
     the overlapped blocks (each merged + stored in the kernel) pay a
     write. -1 targets (a freed slot's wiped table row) route to the
     pool's trailing scratch block, as in the decode kernel."""
-    def im(b, h, j, tables_ref, lens_ref, qlens_ref):
+    def im(b, h, j, tables_ref, lens_ref, qlens_ref, start_ref):
         w0 = _apd_blk(lens_ref, qlens_ref, b, bs, mb, False)
         w1 = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
         phys = tables_ref[b, jnp.clip(j, w0, w1)]
@@ -1022,13 +1082,35 @@ def _apd_pool_out_index_map(bs, mb, nb):
     return im
 
 
-def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
-                   *rest, scale, bs, mb, nb, s_chunk, g, tr, ts, hb,
-                   quant=None):
+def _heads_outermost(im):
+    """An index map written for the grid (slot, head group, entry), on
+    the packed plan's grid (head group, slot, entry)."""
+    return lambda h, b, j, *refs: im(b, h, j, *refs)
+
+
+def _append_kernel(tables_ref, lens_ref, qlens_ref, start_ref, q_ref, k_ref,
+                   v_ref, *rest, scale, bs, mb, nb, s_chunk, g, tr, ts, hb,
+                   kw=None, quant=None):
     """One grid step = one table entry of one slot, for ``hb`` kv heads.
     All vector work sits under a ``pl.when`` read from the slot's
     ``(seq_lens, q_lens)``: a step that is neither live nor in the append
-    window does none."""
+    window does none.
+
+    ``kw`` None is the per-slot plan: grid (slot, head group, entry), the
+    q, output and chunk K/V blocks one slot's, its rows from row 0 of
+    them. ``kw`` (static: the rows of the chunk K/V window) is the packed
+    plan: grid (head group, slot, entry), those blocks a head's rows of
+    EVERY slot, and slot ``b``'s the ones from ``start[b] * g`` on. A
+    dynamic row offset has to lie on a sublane tile, so the slot's row
+    tiles start at the multiple of :data:`_SUBLANE` at or below its first
+    row: its first tile begins with ``off`` rows of the slots before it
+    (computed like its own and dropped at the store), every row index
+    below is the slot's own plus ``off``, and its last tile is read and
+    written whole (the rows past its own are later slots', which the grid
+    has yet to come to, or nobody's). ``tr`` is then
+    :func:`_packed_row_tile`'s, a sublane tile longer, so that the rows
+    and ``off`` fit the tiles the rows alone would. None of it changes a
+    live row's arithmetic."""
     if quant:
         (ks_ref, vs_ref, nk_ref, nv_ref, o_ref, ko_ref, vo_ref, kso_ref,
          vso_ref, m_ref, l_ref, acc_ref) = rest
@@ -1036,14 +1118,23 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         (nk_ref, nv_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref,
          acc_ref) = rest
     f32 = jnp.float32
-    b = pl.program_id(0)
-    hg = pl.program_id(1)
+    packed = kw is not None
+    b, hg = pl.program_id(int(packed)), pl.program_id(int(not packed))
     j = pl.program_id(2)
     bs_i = np.int32(bs)
     tr_i = np.int32(tr)
     d = q_ref.shape[3]
     L = lens_ref[b]
     QL = jnp.minimum(qlens_ref[b], np.int32(s_chunk))
+    if packed:
+        # the slot's first row of a head and the tile boundary at or
+        # below it
+        first = start_ref[b] * np.int32(g)
+        base = pl.multiple_of(first - _tile_offset(first), _SUBLANE)
+        # an idle slot has no tile: it stores nothing
+        off = jnp.where(QL > Z, first - base, Z)
+    else:
+        off = base = 0
     j_last = _apd_blk(lens_ref, qlens_ref, b, bs, mb, True)
     w0 = _apd_blk(lens_ref, qlens_ref, b, bs, mb, False)
     jj = _apd_walk(lens_ref, qlens_ref, b, j, bs, mb)
@@ -1053,8 +1144,15 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
     dst = jnp.where(phys < Z, np.int32(nb - 1), phys)
     live = (j <= j_last) & (phys >= Z) & (QL > Z)
     in_window = (j >= w0) & (j <= j_last)
-    t_lo, t_end = _tile_span(L, QL, jj, g, bs, tr, jnp, _div_i32)
+    t_lo, t_end = _tile_span(L, QL, jj, g, bs, tr, jnp, _div_i32, off)
     h0 = hg * np.int32(hb)                 # first kv head of this step
+
+    def block_rows(r0, n):
+        """The slot's rows ``[r0, r0 + n)`` (its own plus ``off``) in
+        the q and output blocks."""
+        if not packed:
+            return pl.ds(r0, n)
+        return pl.ds(pl.multiple_of(base + r0, math.gcd(tr, _SUBLANE)), n)
 
     def heads(fn):
         """``fn(h)`` on each kv head of this step. ``h`` is the loop's
@@ -1106,9 +1204,19 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         # dynamic gather), so attention sees the whole new chunk this
         # step and the merged block writes back through the aliased pool
         # outputs. Only window blocks pay it.
-        row = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 0)
-        ci = jax.lax.broadcasted_iota(jnp.int32, (bs, s_chunk), 1)
-        sel = ((jj * bs_i + row - L) == ci) & (ci < QL)
+        # The packed plan reads the chunk through a window of ``kw``
+        # rows from the tile boundary ``k0`` at or below the slot's first
+        # (and ending inside the block), which hold it from row ``koff``
+        if packed:
+            k0 = pl.multiple_of(jnp.minimum(
+                start_ref[b] - _tile_offset(start_ref[b]),
+                np.int32(nk_ref.shape[2] - kw)), _SUBLANE)
+            koff, nkw = start_ref[b] - k0, kw
+        else:
+            k0, koff, nkw = 0, 0, s_chunk
+        row = jax.lax.broadcasted_iota(jnp.int32, (bs, nkw), 0)
+        ci = jax.lax.broadcasted_iota(jnp.int32, (bs, nkw), 1) - koff
+        sel = ((jj * bs_i + row - L) == ci) & (ci >= Z) & (ci < QL)
         # block row r takes a chunk row iff its chunk index lands in
         # [0, q_lens) — index math, not a bool reduction over ``sel``
         # (Mosaic has no i1 reduce)
@@ -1120,7 +1228,7 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
             # stored value times 1: the operand rule changes no bit here
             dt = _mxu_dtype(new_ref.dtype, blk.dtype, quant)
             m = jax.lax.dot_general(
-                sel.astype(dt), new_ref[0, h].astype(dt),
+                sel.astype(dt), new_ref[0, h, pl.ds(k0, nkw), :].astype(dt),
                 (((1,), (0,)), ((), ())), preferred_element_type=f32)
             return jnp.where(has_new, m.astype(blk.dtype), blk)
 
@@ -1172,12 +1280,12 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
             that is not."""
             rows = pl.ds(r0, n)
             if masked:
-                # row r (chunk index r // g) sees kv position p iff
-                # (p - lens) * g <= r — no vector division
+                # row r (chunk index (r - off) // g) sees kv position p
+                # iff (p - lens) * g + off <= r — no vector division
                 rel = jj * bs_i - L + jax.lax.broadcasted_iota(
                     jnp.int32, (n, bs), 1)
                 r = r0 + jax.lax.broadcasted_iota(jnp.int32, (n, bs), 0)
-                seen = rel * np.int32(g) <= r
+                seen = rel * np.int32(g) + off <= r
 
             def head(_, h):
                 k_blk, v_blk = kr[0, h], vr[0, h]
@@ -1185,7 +1293,8 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
                     k_blk = dequant(k_blk, kso_ref, h)
                     v_blk = dequant(v_blk, vso_ref, h)
                 dt = _mxu_dtype(q_ref.dtype, k_blk.dtype, quant)
-                s = _scores(q_ref[0, h, rows, :], k_blk.astype(dt), scale, dt)
+                s = _scores(q_ref[0, h, block_rows(r0, n), :],
+                            k_blk.astype(dt), scale, dt)
                 if masked:
                     s = jnp.where(seen, s, NEG_INF)
                 m_prev = m_ref[h, rows, :]
@@ -1218,7 +1327,7 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
             # decode row's, a verify window's, a chunk's tail) runs
             # those alone; its other rows keep their initial state
             # and finalize to zeros
-            short = QL * np.int32(g) - r0 <= np.int32(ts)
+            short = QL * np.int32(g) + off - r0 <= np.int32(ts)
             pl.when(short)(lambda: update(r0, ts))
             pl.when(jnp.logical_not(short))(lambda: update(r0, tr))
         tiles(t_lo, t_end, tile)
@@ -1239,20 +1348,29 @@ def _append_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
             def live_tile(r0):
                 rows = pl.ds(r0, tr)
                 l = jnp.maximum(l_ref[h, rows, :], np.float32(1e-30))
-                o_ref[0, h, rows, :] = (acc_ref[h, rows, :] / l).astype(
-                    o_ref.dtype)
+                o = (acc_ref[h, rows, :] / l).astype(o_ref.dtype)
+                if packed:
+                    # the first tile's rows of earlier slots stay as those
+                    # slots stored them
+                    r = r0 + jax.lax.broadcasted_iota(jnp.int32, (tr, d), 0)
+                    o = jnp.where(r < off, o_ref[0, h, block_rows(r0, tr), :],
+                                  o)
+                o_ref[0, h, block_rows(r0, tr), :] = o
 
             def idle_tile(r0):
                 o_ref[0, h, pl.ds(r0, tr), :] = jnp.zeros((tr, d),
                                                           o_ref.dtype)
             tiles(Z, t_end, live_tile)
-            tiles(t_end, np.int32(q_ref.shape[2] // tr), idle_tile)
+            if not packed:
+                # (the packed entry's wrapper zeroes the rows that hold no
+                # token: a tile past a slot's last is another slot's)
+                tiles(t_end, np.int32(q_ref.shape[2] // tr), idle_tile)
         heads(head)
 
 
 def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
                            q_lens, new_k, new_v, scale=None, k_scale=None,
-                           v_scale=None, quant=None):
+                           v_scale=None, quant=None, start=None, width=None):
     """Append attention off the block pools: one fused prefill+decode step.
 
     q: [B, S, Hq, D] — up to S new positions per sequence (rows past
@@ -1304,13 +1422,37 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
 
     Returns (out [B, S, Hq, D] in q.dtype, k_pool, v_pool).
 
+    **The packed entry** (``start`` [B] given: a mixed step's rows as the
+    decoder holds them, ``cache_layout.RowMap.start``): q is ``[T, Hq,
+    D]`` and new_k/new_v ``[T, Hkv, D]`` on ONE row axis, slot ``b``'s
+    rows the ``q_lens[b]`` from ``start[b]`` on, in position order, slots
+    ascending and no two overlapping; ``width`` (static, required with
+    ``start``) is the most rows a slot may hold, the per-slot entry's S
+    (the engine's chunk, what :func:`append_tile_steps` is asked with),
+    from which the row tile is derived. ``start`` is scalar-prefetched beside
+    ``(seq_lens, q_lens)``. The wrapper moves the T rows alone to the
+    head-major layout (``[T, Hkv, G, D] -> [Hkv, T * G, D]``), the grid is
+    (head group, slot, table entry), and q, the output and the chunk's K/V
+    are blocks RESIDENT a head group, fetched and written once a call:
+    slot ``b``'s row tiles are read and stored at ``start[b] * G`` of
+    them, on the row axis' own 16-row grid (``_append_kernel``'s
+    docstring: the slot's first tile holds the last rows of the slots
+    before it, which are kept at the store). The walk, the skip rule, the
+    merge and the pools are the per-slot entry's lines, and a live row's
+    arithmetic is the same in both: the outputs on live rows and the
+    pools are bit-equal. Returns (out ``[T, Hq, D]``, k_pool, v_pool):
+    a row that holds no token comes back zero. Which entry a call takes
+    is a shape of the call; the per-slot entry ``[B, S, ...]`` (a
+    one-process ``generate()``, the legacy scheduler, the tests) keeps
+    its blocks a slot, which is what fits when every slot's S rows exist.
+
     ``quant`` + ``k_scale``/``v_scale`` [num_blocks, Hkv]: quantized
     pools exactly as in :func:`paged_attention_decode` — blocks dequant
     in VMEM for the walk, every window block re-quantizes in VMEM with
     its new per-head absmax scale, and the return grows to
     ``(out, k_pool, v_pool, k_scale, v_scale)``.
     """
-    B, S, Hq, D = q.shape
+    Hq, D = q.shape[-2:]
     NB, Hkv, BS, Dk = k_pool.shape
     if quant:
         assert k_scale is not None and v_scale is not None
@@ -1319,58 +1461,99 @@ def paged_attention_append(q, k_pool, v_pool, block_tables, seq_lens,
         assert k_scale is None and v_scale is None
         assert D == Dk, (q.shape, k_pool.shape)
     assert Hq % Hkv == 0, f"GQA needs Hq % Hkv == 0, got {Hq=} {Hkv=}"
+    assert q.ndim == (4 if start is None else 3), (q.shape, start)
+    if start is not None:
+        assert width is not None, "the packed entry needs the slot's width"
+        assert start.shape == q_lens.shape, (start.shape, q_lens.shape)
+        width = int(width)
     return _append_call(
         q, k_pool, v_pool, block_tables, seq_lens, q_lens, new_k, new_v,
-        k_scale, v_scale,
+        k_scale, v_scale, start,
         scale=float(scale) if scale is not None else 1.0 / math.sqrt(D),
-        quant=quant, interpret=_interpret())
+        quant=quant, interpret=_interpret(), width=width)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "quant", "interpret"),
-                   inline=True)
+@functools.partial(jax.jit, static_argnames=("scale", "quant", "interpret",
+                                             "width"), inline=True)
 def _append_call(q, k_pool, v_pool, block_tables, seq_lens, q_lens, new_k,
-                 new_v, k_scale, v_scale, *, scale, quant, interpret):
+                 new_v, k_scale, v_scale, start=None, *, scale, quant,
+                 interpret, width=None):
     """:func:`paged_attention_append`'s transposes and Pallas call.
     Jitted so that a model's layers, which call it with the same shapes,
     share ONE trace of the kernel (a bare ``pallas_call`` re-traces its
     kernel at every call site: 16 layers cost 16 traces in the step
     program's build), and ``inline``: left as a call in the step program,
     XLA ran the 16-layer mixed step 3.5 ms slower on the v5e (PERF.md
-    section 6, PR 26)."""
-    B, S, Hq, D = q.shape
+    section 6, PR 26). ``start`` None: the per-slot plan; given, the
+    packed plan with ``width`` the most rows of a slot."""
+    packed = start is not None
+    Hq, D = q.shape[-2:]
     NB, Hkv, BS, Dk = k_pool.shape
     G = Hq // Hkv
-    MB = block_tables.shape[1]
-
-    # [B, S, Hq, D] -> [B, Hkv, S*G, D]: row r = i*G + g of kv head h is
-    # position i of q head h*G + g (position-major, so live rows are a
-    # prefix; the (Hkv, G) grouping is the decode kernel's)
+    B, MB = block_tables.shape
     nk_dt = k_pool.dtype if not quant else new_k.dtype
-    q4 = jnp.transpose(q.reshape(B, S, Hkv, G, D),
-                       (0, 2, 1, 3, 4)).reshape(B, Hkv, S * G, D)
-    nk = jnp.transpose(new_k, (0, 2, 1, 3)).astype(nk_dt)
-    nv = jnp.transpose(new_v, (0, 2, 1, 3)).astype(nk_dt)
     tables = block_tables.astype(jnp.int32)
     lens = seq_lens.astype(jnp.int32)
     qlens = q_lens.astype(jnp.int32)
 
-    tr = _row_tile(G, S)
+    # rows of a kv head are position-major: row r = i*G + g is position i
+    # of q head h*G + g, so live rows are a prefix (the (Hkv, G) grouping
+    # is the decode kernel's)
+    if packed:
+        # [T, Hq, D] -> [1, Hkv, T*G, D] (T to a whole sublane tile). The
+        # q and output BLOCKS are a row tile longer than the arrays, for a
+        # slot's last tile to reach into: only the arrays' rows are
+        # fetched and written, what lies past them is nobody's, and no
+        # row's arithmetic reads another's. The chunk's K/V [T, Hkv, D] ->
+        # [1, Hkv, TK, D], zeros past T (the merge sums over a window's
+        # rows): a window of ``kw`` rows of it holds any slot's chunk
+        # from a tile boundary on
+        T, S = q.shape[0], width
+        tr = _packed_row_tile(G, S)
+        t16 = -(-T // _SUBLANE) * _SUBLANE
+        kw = -(-min(S, T) // _SUBLANE) * _SUBLANE + _SUBLANE
+        tk = max(t16, kw)
+        resident = (-(-(t16 * G + tr) // _SUBLANE) * _SUBLANE, tk)
+        q4 = jnp.pad(q.reshape(T, Hkv, G, D),
+                     [(0, t16 - T), (0, 0), (0, 0), (0, 0)])
+        q4 = jnp.transpose(q4, (1, 0, 2, 3)).reshape(1, Hkv, t16 * G, D)
+        nk, nv = (jnp.transpose(
+            jnp.pad(x.astype(nk_dt), [(0, tk - T), (0, 0), (0, 0)]),
+            (1, 0, 2))[None] for x in (new_k, new_v))
+        starts = start.astype(jnp.int32)
+        order = _heads_outermost
+    else:
+        # [B, S, Hq, D] -> [B, Hkv, S*G, D], a slot a block
+        S = q.shape[1]
+        tr = _row_tile(G, S)
+        kw, resident = None, None
+        q4 = jnp.transpose(q.reshape(B, S, Hkv, G, D),
+                           (0, 2, 1, 3, 4)).reshape(B, Hkv, S * G, D)
+        nk = jnp.transpose(new_k, (0, 2, 1, 3)).astype(nk_dt)
+        nv = jnp.transpose(new_v, (0, 2, 1, 3)).astype(nk_dt)
+        starts = jnp.zeros((B,), jnp.int32)      # not read
+        order = lambda im: im  # noqa: E731
+
     shape = (G, S, D, BS, Dk, q.dtype.itemsize, k_pool.dtype.itemsize,
-             jnp.dtype(nk_dt).itemsize)
+             jnp.dtype(nk_dt).itemsize, resident)
+    q_rows, new_rows = resident or (G * S, S)
     hb = _heads_per_step(Hkv, *shape)
+    HG = Hkv // hb
     pool_spec = pl.BlockSpec((1, hb, BS, Dk),
-                             _apd_pool_out_index_map(BS, MB, NB))
-    kv_spec = pl.BlockSpec((1, hb, BS, Dk), _apd_kv_index_map(BS, MB))
-    q_spec = pl.BlockSpec((1, hb, G * S, D), _apd_q_index_map)
-    new_spec = pl.BlockSpec((1, hb, S, D), _apd_q_index_map)
+                             order(_apd_pool_out_index_map(BS, MB, NB)))
+    kv_spec = pl.BlockSpec((1, hb, BS, Dk),
+                           order(_apd_kv_index_map(BS, MB)))
+    rows_map = order(_apd_rows_index_map if packed else _apd_q_index_map)
+    q_spec = pl.BlockSpec((1, hb, q_rows, D), rows_map)
+    new_spec = pl.BlockSpec((1, hb, new_rows, D), rows_map)
     in_specs = [q_spec, kv_spec, kv_spec]
     out_specs = [q_spec, pool_spec, pool_spec]
-    out_shape = [jax.ShapeDtypeStruct((B, Hkv, G * S, D), q.dtype),
+    out_shape = [jax.ShapeDtypeStruct(q4.shape, q.dtype),
                  jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                  jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)]
-    inputs = [tables, lens, qlens, q4, k_pool, v_pool]
+    inputs = [tables, lens, qlens, starts, q4, k_pool, v_pool]
     # flat input indices INCLUDE the scalar-prefetch operands
-    io_aliases = {4: 1, 5: 2}
+    io_aliases = {5: 1, 6: 2}
     if quant:
         in_specs += [_scale_spec(NB, Hkv)] * 2
         out_specs += [_scale_spec(NB, Hkv)] * 2
@@ -1378,39 +1561,52 @@ def _append_call(q, k_pool, v_pool, block_tables, seq_lens, q_lens, new_k,
                       jax.ShapeDtypeStruct((NB, Hkv), jnp.float32)]
         inputs += [k_scale.astype(jnp.float32),
                    v_scale.astype(jnp.float32)]
-        io_aliases = {4: 1, 5: 2, 6: 3, 7: 4}
+        io_aliases = {5: 1, 6: 2, 7: 3, 8: 4}
     in_specs += [new_spec, new_spec]
     inputs += [nk, nv]
 
     kernel = functools.partial(_append_kernel, scale=scale, bs=BS, mb=MB,
                                nb=NB, s_chunk=S, g=G, tr=tr,
-                               ts=_row_subtile(tr), hb=hb, quant=quant)
+                               ts=_row_subtile(_row_tile(G, S)), hb=hb,
+                               kw=kw, quant=quant)
+    acc_rows = _scratch_rows(G, S, packed)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, Hkv // hb, MB),
+            num_scalar_prefetch=4,
+            grid=(HG, B, MB) if packed else (B, HG, MB),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((hb, G * S, 1), jnp.float32),  # running max m
-                pltpu.VMEM((hb, G * S, 1), jnp.float32),  # running norm l
-                pltpu.VMEM((hb, G * S, D), jnp.float32),  # out accumulator
+                pltpu.VMEM((hb, acc_rows, 1), jnp.float32),  # running max m
+                pltpu.VMEM((hb, acc_rows, 1), jnp.float32),  # running norm l
+                pltpu.VMEM((hb, acc_rows, D), jnp.float32),  # out accumulator
             ],
         ),
         out_shape=out_shape,
         input_output_aliases=io_aliases,
         # sequential everywhere: scratch carries over blocks and clamped
-        # write destinations may collide across batch windows
+        # write destinations may collide across batch windows; the packed
+        # plan's slots store into one resident block in ascending order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=max(32 << 20,
-                                 _append_vmem_bytes(hb, *shape) + (16 << 20))),
+            vmem_limit_bytes=max(
+                32 << 20,
+                _append_vmem_bytes(hb, *shape) + (16 << 20))),
         name="paged_attention_append",
         interpret=interpret,
     )(*inputs)
-    out = outs[0].reshape(B, Hkv, S, G, D)
-    out = jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, Hq, D)
+    if packed:
+        out = outs[0][0, :, :T * G].reshape(Hkv, T, G, D)
+        out = jnp.transpose(out, (1, 0, 2, 3)).reshape(T, Hq, D)
+        # a row that holds no token: whatever a neighbour's tile left
+        t = jnp.arange(T, dtype=jnp.int32)[:, None]
+        held_by = (t >= starts[None]) & (
+            t < (starts + jnp.minimum(qlens, np.int32(S)))[None])
+        out = jnp.where(jnp.any(held_by, axis=1)[:, None, None], out, 0.0)
+    else:
+        out = outs[0].reshape(B, Hkv, S, G, D)
+        out = jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, Hq, D)
     if quant:
         return out, outs[1], outs[2], outs[3], outs[4]
     return out, outs[1], outs[2]

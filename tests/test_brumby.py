@@ -647,3 +647,85 @@ def test_the_packed_kda_layer_compiles_for_the_v5e(one_chip, monkeypatch,
             assert " broadcast(" not in line or f"{slots},{chunk}," \
                 not in line, line
             assert "dynamic-update-slice(" not in line, line
+
+@pytest.mark.parametrize("cell,config,program,slots,t,chunk,heads,kv", [
+    ("doc_batch", "mistral-7b-v0.3-d16", "llama", 8, 272, 256, 32, 8),
+    ("solar_long_reports", "solar-open2-250b-ep8-d4", "solar_open2", 16, 528,
+     512, 64, 8),
+])
+def test_the_packed_gqa_layer_compiles_for_the_v5e(one_chip, monkeypatch,
+                                                   cell, config, program,
+                                                   slots, t, chunk, heads, kv):
+    """A GQA layer of the two cells whose every mixed step runs the paged
+    append (``LlamaAttention`` at Mistral's widths, Solar's
+    ``GatedAttention``) on the step's packed rows ``[1, T, hidden]`` at
+    the cell's slots, rows, heads and table, compiled by the TPU compiler
+    installed here for a described v5e (nothing runs):
+    ``paged_attention_append`` takes the packed q, k, v as the layer
+    computed them with ``rows.start`` prefetched (Mosaic accepts a head
+    group's resident block read at ``start[b] * G`` rounded down to a
+    sublane tile), so the program holds no ``rows_to_slots`` /
+    ``rows_from_slots``, no gather but rope's of its table, and nothing of
+    the per-slot view's size ``[slots, chunk, heads, 128]`` or of its
+    head-major form ``[slots, kv, chunk * G, 128]``."""
+    from benchmark.harness import loader
+    from benchmark.tests import brumby_aot
+    from paddle_tpu.core.tensor import Tensor, functional_mode
+    from paddle_tpu.jit.functional_call import bind_state
+    from paddle_tpu.models import cache_layout as CL
+    from paddle_tpu.models.llama import LlamaAttention, PagedKVCache
+    from paddle_tpu.ops.kernels import paged_attention
+    # this process sees a CPU: route the op to the kernel, and the kernel
+    # to Mosaic, all the same
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    cfg = loader.data("configs", config)
+    with paddle.LazyGuard():
+        model = loader.module("programs", program).build(cfg)
+    model.eval()
+    layer = next(block.self_attn for block in model.decoder.layers
+                 if isinstance(block.self_attn, LlamaAttention)
+                 or type(block.self_attn).__name__ == "GatedAttention")
+    params = [p for _, p in layer.named_parameters()]
+    llama = isinstance(layer, LlamaAttention)
+    bs = int(cfg["engine"]["block_size"])
+    mb = int(cfg["engine"]["max_seq_len"]) // bs
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    def fn(vals, x, k_pool, v_pool, tables, lens, q_lens, cos, sin):
+        rows = CL.RowMap(q_lens, lens, t, chunk)
+        cache = PagedKVCache(Tensor(k_pool), Tensor(v_pool), Tensor(tables),
+                             Tensor(lens), Tensor(q_lens), rows=rows)
+        with paddle.no_grad(), functional_mode(), bind_state(params, vals):
+            if llama:
+                out, new = layer(Tensor(x), (cos, sin), None, cache,
+                                 Tensor(rows.pos[None]))
+            else:
+                out, new = layer(Tensor(x), cache)
+        return out._value, new.k._value, new.v._value
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = shape((slots * mb + 1, kv, bs, 128), bf16)
+    rope = shape((int(cfg["max_position_embeddings"]), 128), jnp.float32)
+    args = ([shape(p._value.shape, bf16) for p in params],
+            shape((1, t, model.config.hidden_size), bf16), pool, pool,
+            shape((slots, mb), i32), shape((slots,), i32),
+            shape((slots,), i32), rope, rope)
+    with jax.default_matmul_precision("default"):
+        text = brumby_aot.compile_for_the_chip(
+            {cell: jax.jit(fn, donate_argnums=(2, 3))}, {cell: args},
+            cell).as_text()
+    assert "tpu_custom_call" in text and "paged_attention_append" in text
+    assert "rows_to_slots" not in text and "rows_from_slots" not in text
+    # the gathers that are left read rope's table, one row of it a packed
+    # row: none moves a row of q, k or v to a slot or back
+    for line in text.splitlines():
+        if " gather(" in line:
+            assert f" = f32[{t},128]" in line, line
+    group = heads // kv
+    for view in (f"[{slots},{chunk},{heads},128]",
+                 f"[{slots},{chunk},{kv},{group},128]",
+                 f"[{slots},{kv},{chunk * group},128]"):
+        assert view not in text, [ln for ln in text.splitlines()
+                                  if view in ln][:3]

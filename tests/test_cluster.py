@@ -244,6 +244,34 @@ def test_tp_kernel_append_follows_q_lens(tp_mesh, rng):
     np.testing.assert_array_equal(np.asarray(got[1])[blk, :, row], nk[0, 0])
 
 
+def test_tp_kernel_packed_append_is_the_unsharded_one(tp_mesh, rng):
+    """The packed entry (a mixed step's rows on one axis, ``start``
+    replicated beside the lens) under shard_map: a decode row, a partial
+    chunk that starts off a 16-row tile and an idle slot; each shard's
+    kernel hands back its heads of the unsharded kernel's rows, pools and
+    zeros, bit for bit."""
+    from paddle_tpu.ops.kernels.paged_attention import (
+        paged_attention_append, paged_attention_append_tp)
+    _, kp, vp, _, _ = _kernel_inputs(rng, NB=13)
+    tables = np.array([[0, 1, 2, -1], [3, 4, 5, -1], [6, 7, -1, -1]],
+                      np.int32)
+    lens = np.array([19, 10, 3], np.int32)
+    qlens = np.array([1, 7, 0], np.int32)
+    T, S = 16, 8
+    start = (np.cumsum(qlens) - qlens).astype(np.int32)
+    qa = rng.standard_normal((T, 8, 16)).astype(np.float32)
+    nk = rng.standard_normal((T, 4, 16)).astype(np.float32)
+    nv = rng.standard_normal((T, 4, 16)).astype(np.float32)
+    ref = paged_attention_append(qa, kp.copy(), vp.copy(), tables, lens,
+                                 qlens, nk, nv, start=start, width=S)
+    got = paged_attention_append_tp(qa, kp.copy(), vp.copy(), tables, lens,
+                                    qlens, nk, nv, tp_mesh, start=start,
+                                    width=S)
+    assert np.asarray(ref[0])[:8].any() and not np.asarray(ref[0])[8:].any()
+    for r, g in zip(ref, got):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+
+
 # ---------------------------------------------------------------------------
 # Level 2 — the ReplicaRouter
 # ---------------------------------------------------------------------------

@@ -227,6 +227,44 @@ def test_append_kernel_follows_q_lens_over_quantized_pools(rng, quant, name):
                                 wiped=wiped)
 
 
+@pytest.mark.parametrize("quant,group", [("int8", 4), ("int4", 1)])
+def test_the_packed_append_over_quantized_pools_is_the_per_slot_one(
+        rng, quant, group):
+    """The packed entry (``start`` prefetched; a chunk after a decode row
+    and an idle slot, so it starts off a 16-row tile) re-quantizes the
+    same window blocks under the same scales and hands back the same live
+    rows as the per-slot entry, bit for bit."""
+    Hkv, D, BS, S, T = 2, 32, 8, 16, 32
+    q_lens = np.asarray([1, 0, 13, 1], np.int32)
+    kc, vc, ks, vs, tables, lens = _quant_pools(
+        rng, [16, 17, 7, 30], q_lens, Hkv, D, BS, quant)
+    B = len(q_lens)
+    qa, ka, va = (rng.standard_normal((B, S, h, D)).astype(np.float32)
+                  for h in (Hkv * group, Hkv, Hkv))
+    common = [jnp.asarray(a) for a in (kc, vc, tables, lens, q_lens)]
+    scales = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs),
+                  quant=quant)
+    want = paged_attention_append(jnp.asarray(qa), *common, jnp.asarray(ka),
+                                  jnp.asarray(va), **scales)
+    start = np.cumsum(q_lens) - q_lens
+
+    def pack(x):
+        out = np.zeros((T,) + x.shape[2:], x.dtype)
+        for b, n in enumerate(q_lens):
+            out[start[b]:start[b] + n] = x[b, :n]
+        return jnp.asarray(out)
+    got = paged_attention_append(
+        pack(qa), *common, pack(ka), pack(va), **scales,
+        start=jnp.asarray(start, jnp.int32), width=S)
+    for a, b in zip(want[1:], got[1:]):     # pools, then scales
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for b, n in enumerate(q_lens):
+        np.testing.assert_array_equal(
+            np.asarray(got[0])[start[b]:start[b] + n],
+            np.asarray(want[0])[b, :n])
+    assert not np.asarray(got[0])[int(q_lens.sum()):].any()
+
+
 def test_scale_update_on_fused_write(rng):
     """A new token whose magnitude dwarfs the block's content must GROW
     the written block's scale in-kernel (fresh absmax over the merged

@@ -448,8 +448,14 @@ def test_the_counters_add_up():
 #: since then, which hands a one-token step's cache object the live rows a
 #: slot as every kind does; no llama layer reads them, XLA drops them, and
 #: the COMPILED text of all three programs is the parent's (CHANGES.md,
-#: PR 44: plain, bf16, int8, int4, tp = 2, speculative)
-LLAMA_PROGRAMS = {"fused_step": ("6e881ab004d879c4", 1412),
+#: PR 44: plain, bf16, int8, int4, tp = 2, speculative).
+#: ``fused_step`` was read again at PR 46 (6e881ab004d879c4, 1412 before):
+#: its attention takes the packed rows as they lie (the op's packed form),
+#: so the three ``rows_to_slots`` slices a layer and ``rows_from_slots``'
+#: gather are gone and the CPU's dense form slices the one view of the
+#: concatenated ``qkv`` out at ``cu_seqlens_q`` inside the op; ``step`` and
+#: ``multi_step``, which have no row map, are what they were
+LLAMA_PROGRAMS = {"fused_step": ("88aa9dbe92e86a9b", 1401),
                   "step": ("760958861cb0a471", 1102),
                   "multi_step": ("94ba12370fe91d78", 1133)}
 

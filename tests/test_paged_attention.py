@@ -473,6 +473,220 @@ def test_append_tile_steps_is_the_brute_force_count(G, S, BS, rng):
 
 
 # ---------------------------------------------------------------------------
+# the packed entry: a mixed step's rows on one axis, (start, q_lens, seq_lens)
+# ---------------------------------------------------------------------------
+
+def _pack(x, qlens, T, gap=0):
+    """The per-slot ``x[B, S, ...]`` on one row axis of ``T`` rows: slot
+    b's first ``qlens[b]`` rows from ``start[b]`` (the grants before it,
+    and ``gap`` rows of nobody's a slot before it) on, the rows that hold
+    no token random (the kernel must not let them into a live row, and
+    hands them back zero)."""
+    start = np.cumsum(qlens) - qlens + gap * np.arange(len(qlens))
+    out = np.random.default_rng(int(np.sum(qlens)) + T).standard_normal(
+        (T,) + x.shape[2:]).astype(x.dtype)
+    for b, n in enumerate(qlens):
+        out[start[b]:start[b] + n] = x[b, :n]
+    return out, start.astype(np.int32)
+
+
+def _assert_packed_is_per_slot(q, kc, vc, tables, lens, qlens, kn, vn, T,
+                               gap=0, **quant):
+    """The packed entry against the per-slot entry on the same step:
+    live rows and every pool (and scale) BIT-equal, zeros on the rows
+    that hold no token. Returns the two outputs."""
+    S = q.shape[1]
+    dev = [jnp.asarray(a) for a in (kc, vc, tables, lens, qlens)]
+    qkw = {k: jnp.asarray(v) if k.endswith("scale") else v
+           for k, v in quant.items()}
+    want = paged_attention_append(jnp.asarray(q), *dev, jnp.asarray(kn),
+                                  jnp.asarray(vn), **qkw)
+    (qp, start), (kp, _), (vp, _) = (_pack(x, qlens, T, gap)
+                                     for x in (q, kn, vn))
+    got = paged_attention_append(
+        jnp.asarray(qp), *dev, jnp.asarray(kp), jnp.asarray(vp),
+        start=jnp.asarray(start), width=S, **qkw)
+    assert got[0].shape == (T,) + q.shape[2:]
+    for a, b in zip(want[1:], got[1:]):         # pools, then scales
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    o, op = np.asarray(want[0], np.float32), np.asarray(got[0], np.float32)
+    live = np.zeros(T, bool)
+    for b, n in enumerate(qlens):
+        live[start[b]:start[b] + n] = True
+        np.testing.assert_array_equal(op[start[b]:start[b] + n], o[b, :n])
+    assert not op[~live].any()
+    return o, op
+
+
+# a step's (lens, q_lens, the slot whose table row is wiped or None), at
+# block 8 and a chunk of 16. A slot's first packed row is the grants before
+# it, so the starts are whatever the mix makes them: 16, 17, 18 after a full
+# chunk, 5 after a tail. Times the group that is 16 / 68 / 136, 5 / 20 / 40:
+# on and off a 16-row tile at every group
+_PACKED = {
+    "a_full_chunk_beside_decode_rows": ([24, 17, 7, 40], [16, 1, 1, 1], None),
+    "a_chunks_tail_then_decode_rows": ([32, 9, 63, 5], [5, 1, 1, 1], None),
+    "idle_slots_between_the_live": ([3, 11, 30, 8, 2], [0, 7, 0, 1, 0],
+                                    None),
+    "two_partial_chunks": ([0, 21, 50, 13], [9, 1, 11, 1], None),
+    "a_window_crosses_two_block_boundaries": ([6, 15, 7, 3], [13, 1, 3, 0],
+                                              None),
+    "a_freed_slot_with_a_wiped_table_row": ([5, 18, 9], [0, 4, 1], 0),
+    "every_slot_idle": ([12, 0, 31], [0, 0, 0], 2),
+}
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("name", list(_PACKED))
+def test_the_packed_append_is_the_per_slot_append_bit_for_bit(name, group,
+                                                              rng):
+    """``paged_attention_append`` on a mixed step's packed rows ``[T, Hq,
+    D]`` with ``start`` prefetched (the interpreted kernel) against its
+    per-slot entry: the same live rows, the same pools, bit for bit, and
+    zeros elsewhere, whatever tile a slot's first row falls into."""
+    lens, qlens, wiped = _PACKED[name]
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, lens, qlens, Hq=2 * group, Hkv=2, S=16)
+    if wiped is not None:
+        tables[wiped, :] = -1
+    _assert_packed_is_per_slot(q, kc, vc, tables, lens, qlens, kn, vn, T=32)
+
+
+def test_the_packed_append_over_bf16_pools_and_two_row_tiles(rng):
+    """The served form: bf16 q against bf16 pools (the operands go to the
+    MXU as stored), a slot of two row tiles (96 x 4 = 384 rows = 2 x 192)
+    after three decode rows, so its tiles start 12 rows into the packed
+    axis' grid and its last tile reaches past the axis' last row."""
+    import ml_dtypes
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, [17, 40, 9, 64], [1, 1, 1, 96], Hq=8, Hkv=2, S=96)
+    bf = lambda a: a.astype(ml_dtypes.bfloat16)  # noqa: E731
+    _assert_packed_is_per_slot(bf(q), bf(kc), bf(vc), tables, lens, qlens,
+                               bf(kn), bf(vn), T=112)
+
+
+def test_the_packed_append_needs_no_more_rows_than_it_is_given(rng):
+    """A chunk that ends at the row axis' last row (16 rows, a slot's
+    ``width`` of them) reads its K/V window from a clamped offset: the
+    same rows and pools as the per-slot entry."""
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, [9, 30], [3, 13], Hq=4, Hkv=2, S=16)
+    _assert_packed_is_per_slot(q, kc, vc, tables, lens, qlens, kn, vn, T=16)
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_the_packed_append_reads_start_and_not_a_running_sum(group, rng):
+    """``start`` is read as given: three rows of nobody's between one
+    slot's rows and the next's (no exclusive running sum of ``q_lens``)
+    are left out of every live row and come back zero."""
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, [24, 17, 7, 40], [9, 1, 0, 5], Hq=2 * group, Hkv=2, S=16)
+    _assert_packed_is_per_slot(q, kc, vc, tables, lens, qlens, kn, vn, T=32,
+                               gap=3)
+
+
+def test_the_packed_append_is_refused_without_its_width(rng):
+    """The row tile is derived from the most rows a slot may hold, which
+    the row axis does not say: the packed entry without ``width``, or
+    with a ``start`` that is not a slot's one number, is refused, by the
+    kernel module and by the op."""
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, [9, 30], [3, 13], Hq=4, Hkv=2, S=16)
+    (qp, start), (kp, _), (vp, _) = (_pack(x, qlens, 16) for x in (q, kn, vn))
+    dev = [jnp.asarray(a) for a in (kc, vc, tables, lens, qlens)]
+    new = [jnp.asarray(kp), jnp.asarray(vp)]
+    with pytest.raises(AssertionError, match="width"):
+        paged_attention_append(jnp.asarray(qp), *dev, *new,
+                               start=jnp.asarray(start))
+    with pytest.raises(AssertionError):
+        paged_attention_append(jnp.asarray(qp), *dev, *new, width=16,
+                               start=jnp.asarray(np.append(start, 16)))
+    qkv = paddle.to_tensor(np.concatenate(
+        [x.reshape(16, -1) for x in (qp, kp, vp)], axis=-1))
+    op = [paddle.to_tensor(a) for a in (kc, vc)] + [
+        None, paddle.to_tensor(lens), paddle.to_tensor(qlens)]
+    with pytest.raises(ValueError, match="max_seq_len"):
+        IF.block_multihead_attention(
+            qkv, *op, cu_seqlens_q=paddle.to_tensor(start),
+            block_tables=paddle.to_tensor(tables))
+    with pytest.raises(ValueError, match="cu_seqlens_q"):
+        IF.block_multihead_attention(
+            qkv, *op, cu_seqlens_q=paddle.to_tensor(np.append(start, 16)),
+            block_tables=paddle.to_tensor(tables), max_seq_len=16)
+
+
+@pytest.mark.parametrize("G,S,BS", [(4, 96, 8), (1, 256, 16), (8, 64, 32),
+                                    (3, 128, 8)])
+def test_append_tile_steps_follow_the_packed_axis_own_tiles(G, S, BS, rng):
+    """``append_tile_steps`` with ``start``: a (row tile, table entry)
+    pair runs iff some live row of the TILE OF THE PACKED AXIS sees some
+    key of the entry's block, a slot's rows lying ``off`` = ``start * G
+    mod 16`` into its first tile and a tile 16 rows longer than the
+    per-slot entry's, so that a slot never has more tiles than its rows
+    fill there; ``grid`` is what it was."""
+    from paddle_tpu.ops.kernels.paged_attention import (
+        _packed_row_tile, _row_tile, append_tile_steps)
+    tr, tile, MB = _row_tile(G, S), _packed_row_tile(G, S), 20
+    assert tile == tr + 16
+    for _ in range(12):
+        B = int(rng.integers(1, 6))
+        lens = rng.integers(0, MB * BS - S, size=B)
+        qlens = rng.integers(0, S + 1, size=B)
+        qlens[rng.integers(0, B)] = rng.choice([0, 1, S])
+        start = np.cumsum(qlens) - qlens
+        run = 0
+        for L, n, at in zip(lens, qlens, start):
+            if n == 0:
+                continue
+            off = at * G % 16
+            n_tiles = -(-(n * G + off) // tile)
+            assert n_tiles <= -(-n * G // tr)
+            j_last = min((L + n - 1) // BS, MB - 1)
+            for jj in range(j_last + 1):
+                keys = jj * BS + np.arange(BS)
+                for t in range(n_tiles):
+                    r = np.arange(max(t * tile, off),
+                                  min((t + 1) * tile, n * G + off))
+                    if r.size and (keys[None, :]
+                                   <= L + (r[:, None] - off) // G).any():
+                        run += 1
+        assert append_tile_steps(lens, qlens, G, S, BS, MB, start) == \
+            (run, B * MB * (G * S // tr))
+
+
+@pytest.mark.parametrize("gap", [0, 1], ids=["a_running_sum", "gapped"])
+def test_the_packed_form_of_the_op_is_its_per_slot_form(gap, rng):
+    """``block_multihead_attention`` on ``qkv [T, W]`` with
+    ``cu_seqlens_q`` (the reference's own layout; on a CPU the dense
+    fallback on the view sliced out at ``cu_seqlens_q``, which it reads
+    as given) returns the ``[B, S, W]`` form's live rows and pools, and
+    zeros on the rows that hold no token."""
+    q, kc, vc, tables, lens, qlens, kn, vn = _append_case(
+        rng, [24, 17, 7, 40], [8, 1, 0, 3], Hq=4, Hkv=2)
+    ref_out, ref_kc, ref_vc = _append_oracle(q, kc, vc, tables, lens, qlens,
+                                             kn, vn)
+    B, S, T = q.shape[0], q.shape[1], 16
+    (qp, start), (kp, _), (vp, _) = (_pack(x, qlens, T, gap)
+                                     for x in (q, kn, vn))
+    qkv = np.concatenate([qp.reshape(T, -1), kp.reshape(T, -1),
+                          vp.reshape(T, -1)], axis=-1)
+    out, kc2, vc2 = IF.block_multihead_attention(
+        paddle.to_tensor(qkv), paddle.to_tensor(kc), paddle.to_tensor(vc),
+        None, paddle.to_tensor(lens), paddle.to_tensor(qlens),
+        cu_seqlens_q=paddle.to_tensor(start),
+        block_tables=paddle.to_tensor(tables), max_seq_len=S)
+    out = np.array(out._value)
+    assert out.shape == (T, ref_out.shape[-1])
+    np.testing.assert_array_equal(np.asarray(kc2._value), ref_kc)
+    np.testing.assert_array_equal(np.asarray(vc2._value), ref_vc)
+    for b, n in enumerate(qlens):
+        np.testing.assert_array_equal(out[start[b]:start[b] + n],
+                                      ref_out[b, :n])
+        out[start[b]:start[b] + n] = 0
+    assert not out.any()
+
+
+# ---------------------------------------------------------------------------
 # the operand rule: stored 16-bit operands go to the MXU as they are stored
 # ---------------------------------------------------------------------------
 

@@ -348,7 +348,9 @@ def test_the_attention_grid_holds_at_least_its_live_blocks(counted):
 def test_attn_tile_steps_grow_by_the_kernels_own_count(counted):
     """Each mixed paged dispatch books what ``append_tile_steps`` says of
     the lens and grants the step program was really called with (the
-    device's lens, not the host's mirror of them)."""
+    device's lens, not the host's mirror of them), on the packed row axis
+    the kernel's tiles lie on: a slot's first row is the grants before
+    it."""
     from paddle_tpu.ops.kernels.paged_attention import append_tile_steps
     cache, _, eng, stats, _ = counted
     if cache == "dense":
@@ -357,7 +359,8 @@ def test_attn_tile_steps_grow_by_the_kernels_own_count(counted):
     cfg = eng.model.config
     want = np.sum([append_tile_steps(
         lens, q_lens, cfg.num_attention_heads // cfg.num_key_value_heads,
-        eng.chunk, eng.block_size, eng._tables.shape[1])
+        eng.chunk, eng.block_size, eng._tables.shape[1],
+        np.cumsum(q_lens) - np.asarray(q_lens))
         for lens, q_lens in eng.mixed_steps], axis=0)
     assert len(eng.mixed_steps) == stats["fused_steps"] > 0
     assert (stats["attn_tile_steps"], stats["attn_tile_steps_grid"]) == \
