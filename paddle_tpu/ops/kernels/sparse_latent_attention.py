@@ -20,9 +20,11 @@ says which slot and position each row is):
    largest score a row, ties to the lower position, as positions ``[T,
    k]`` in ascending order and which of them a short row has. By a
    search for the ``k``-th largest value over the float32 bits and a
-   compaction, in plain XLA. ``jax.lax.top_k`` gives the same set and on
-   the TPU sorts the whole row (20 ms for 528 rows of 33k against 11;
-   PERF.md section 6, PR 45): the tests hold the search to it.
+   two-level compaction, in plain XLA and without a gather: a block's
+   counts reach an output by a product with a one-hot on the MXU
+   (:func:`_compact`). ``jax.lax.top_k`` gives the same set and on the
+   TPU sorts the whole row (20 ms for 528 rows of 33k against 3.4;
+   PERF.md section 6, PRs 45 and 47): the tests hold the search to it.
 3. :func:`sparse_attend`: absorbed latent attention of a row over ITS
    ``k`` selected latents, values the first ``dv`` columns. A row's
    latents are ``k`` separate entries of the pool, and a copy an entry is
@@ -327,11 +329,18 @@ def _block_ranks(mask):
 def _compact(sel, k):
     """The positions of the first ``k`` True of each row of ``sel`` [T, S]
     in ascending order, [T, k] int32 (past a row's count: unspecified
-    positions inside [0, S)), with dense compares and ONE gather of a
-    block's mask an output: the block that holds output ``r`` is the
-    number of blocks whose inclusive count is ``<= r``, and its place
-    inside the block the number of places whose inclusive count is ``<=``
-    what is left."""
+    positions inside [0, S)), with dense compares and NO gather: the block
+    that holds output ``r`` is the number of blocks whose inclusive count
+    is ``<= r``, and its place inside the block the number of places whose
+    inclusive count is ``<=`` what is left. The block's 128 inclusive
+    counts are read by a product with the one-hot of the block's index,
+    ``[k, n] @ [n, 128]`` a row on the MXU: exact (one term of a sum is
+    not zero, and it is an integer ``<= 128``), and on the TPU one fusion
+    with the compare that makes the one-hot and the count that reads the
+    product, so that neither is ever held: 0.63 ms a layer at the cell's
+    shapes (528 rows, ``k`` 2,048, 260 blocks), where a gather of the
+    block an output, 1.08 M rows of 128 bytes, was a copy a row and 8.33
+    (PERF.md section 6, PR 47)."""
     T, S = sel.shape
     n = S // _BLOCK
     blocks = sel.reshape(T, n, _BLOCK)
@@ -346,9 +355,13 @@ def _compact(sel, k):
     left = r[..., 0] - jnp.sum(
         jnp.where(passed, inside[..., -1][:, None, :], 0), axis=2,
         dtype=jnp.int32)
-    rank = jnp.take_along_axis(inside.astype(jnp.uint8), blk[:, :, None],
-                               axis=1)
-    place = jnp.sum(rank.astype(jnp.int32) <= left[:, :, None], axis=2,
+    here = blk[:, :, None] == jnp.arange(n, dtype=jnp.int32)
+    # ``dot_general`` (batch t), not ``einsum``, whose call would be a
+    # scope of its own inside the caller's
+    rank = jax.lax.dot_general(                           # tkn,tnc->tkc
+        here.astype(jnp.bfloat16), inside.astype(jnp.bfloat16),
+        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
+    place = jnp.sum(rank <= left[:, :, None].astype(jnp.float32), axis=2,
                     dtype=jnp.int32)
     return blk * np.int32(_BLOCK) + jnp.minimum(place, np.int32(_BLOCK - 1))
 
@@ -362,8 +375,10 @@ def select(scores, rows, top_k):
     ``k``-th largest value over the float32 bits (:func:`_kth_largest`),
     everything above it and the lowest positions that tie with it, and a
     compaction (:func:`_compact`); ``jax.lax.top_k`` gives the same SET and
-    on the TPU sorts the whole row (20 ms for 528 rows of 33k against 12
-    in plain XLA; PERF.md section 6, PR 45)."""
+    on the TPU sorts the whole row (20 ms for 528 rows of 33k against 3.4
+    in plain XLA, and 12.1 while the compaction gathered; of the device's
+    2.1 ms a layer in the cell's step the 32 passes are 0.6 and the
+    compaction's product 0.6; PERF.md section 6, PRs 45 and 47)."""
     T, S = scores.shape
     k = min(int(top_k), S)
     Sp = -(-S // _BLOCK) * _BLOCK

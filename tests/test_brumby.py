@@ -11,6 +11,7 @@ counters, and the first layout without a paged layer: what the engine builds
 for it, how it admits, and every option it refuses, by name."""
 import json
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -729,3 +730,39 @@ def test_the_packed_gqa_layer_compiles_for_the_v5e(one_chip, monkeypatch,
                  f"[{slots},{kv},{chunk * group},128]"):
         assert view not in text, [ln for ln in text.splitlines()
                                   if view in ln][:3]
+
+
+def test_the_exact_top_k_compiles_for_the_v5e_without_a_gather(one_chip):
+    """``sparse_latent_attention.select`` at ``dots3_long_answers``' mixed
+    step (528 packed rows against 33,280 positions, ``top_k`` 2,048) under
+    the scope the layer calls it in, compiled by the TPU compiler
+    installed here for a described v5e (nothing runs; in this file for
+    its ``one_chip`` fixture): a block's 128 ranks reach an output by a
+    product with the one-hot of the block's index, so the program holds no
+    ``gather`` (a copy a row on the chip: 1.08 M of them a layer), no
+    ``[528, 2048, 128]`` of ranks in ``u8``, and, the compare, the product
+    and the count being one fusion, neither of the product's operands
+    (562 MB) nor its result (277 MB and up) among its temporaries."""
+    from benchmark.tests import brumby_aot
+    from paddle_tpu.ops.kernels import sparse_latent_attention as dsa
+    from paddle_tpu.profiler import scope
+    t, s, k = 528, 33280, 2048
+
+    def fn(scores, pos, live):
+        with scope("pt.select"):
+            return dsa.select(
+                scores, types.SimpleNamespace(pos=pos, live=live), k)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    args = (shape((t, s), jnp.float32), shape((t,), jnp.int32),
+            shape((t,), jnp.bool_))
+    # one-pass bfloat16 products, as the served program traces them
+    with jax.default_matmul_precision("default"):
+        compiled = brumby_aot.compile_for_the_chip(
+            {"select": jax.jit(fn)}, {"select": args}, "select")
+    text = compiled.as_text()
+    assert "pt.select" in text
+    assert " gather(" not in text
+    assert f"u8[{t},{k},128]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6

@@ -11,9 +11,10 @@ packed step, the one-token step, ``multi_step`` scans, a request preempted
 mid-prompt and replayed) compared as ``served_gaps`` compares, with
 contexts well past ``index_topk`` and past a turn of the ring; (b) the
 selected sets equal the reference's at float32, by the sort and by the
-threshold search; (c) contexts within ``index_topk`` are dense latent
-attention, to the bit; (d) ``latent_attention_append(window=w)`` against
-a masked dense form, ``window=None`` unchanged; (e) the two new kinds:
+threshold search, and the compaction alone is ``flatnonzero`` a row;
+(c) contexts within ``index_topk`` are dense latent attention, to the
+bit; (d) ``latent_attention_append(window=w)`` against a masked dense
+form, ``window=None`` unchanged; (e) the two new kinds:
 what they allocate, what a token and a slot cost, the derived table and
 the ring's rule; (f) the eight expert shares add up; (g) every flag the
 program does not compute, and every option this layout cannot take, is
@@ -465,6 +466,61 @@ def test_ties_go_to_the_lower_position(width):
     assert idx[2][ok[2]].tolist() == list(range(6)) and ok[2][:6].all()
     top = np.lexsort((np.arange(width), -sc[3]))[:100]
     assert idx[3][ok[3]].tolist() == sorted(top)
+
+
+def compact_case(case):
+    """``(mask [T, S] bool, k)`` of one case of the compaction's test: the
+    masks ``select`` never hands it in the tests above."""
+    rng = np.random.default_rng(47)
+    s, k = 1024, 100
+    if case == "one_block":
+        s, k = 128, 48
+        sel = rng.random((5, s)) < np.asarray(
+            [0.0, 0.1, 0.4, 0.9, 1.0])[:, None]
+    elif case.startswith("cell_"):
+        # a row of the shipped cell: 260 blocks, the 2,048 selected of them
+        s, k = 33280, 2048
+        sel = rng.random((6, s)) < float(case[5:])
+    elif case == "empty_rows":
+        sel = np.zeros((3, s), bool)
+        sel[1, 517] = True                      # one True between empty rows
+    elif case == "fewer_than_k":
+        sel = rng.random((4, s)) < np.asarray(
+            [0.01, 0.03, 0.06, 0.09])[:, None]
+        assert (sel.sum(1) < k).all()
+    elif case == "exactly_k":
+        sel = np.zeros((4, s), bool)
+        for row in sel[:3]:
+            row[rng.choice(s, size=k, replace=False)] = True
+        sel[3, -k:] = True                      # the last k positions
+    elif case == "every_position":
+        sel = np.ones((2, s), bool)
+    else:
+        assert case == "last_block_only"
+        sel = np.zeros((3, s), bool)
+        sel[0, -128:] = True                    # more than k of them
+        sel[1, -128::3] = True                  # fewer
+        sel[2, -1] = True
+    return sel, k
+
+
+@pytest.mark.parametrize("case", [
+    "empty_rows", "fewer_than_k", "exactly_k", "every_position",
+    "last_block_only", "one_block", "cell_0.06", "cell_0.12"])
+def test_the_compaction_is_flatnonzero_a_row(case):
+    """``_compact`` alone against ``numpy.flatnonzero`` a row: the first
+    ``k`` True in ascending order, every entry a row has equal, and every
+    entry (those past a row's count too) a position inside ``[0, S)``. The
+    block's counts come by a product with a one-hot in bfloat16: exact at
+    every count up to a full block of 128."""
+    sel, k = compact_case(case)
+    got = np.asarray(jax.jit(DSA._compact, static_argnums=1)(
+        jnp.asarray(sel), k))
+    assert got.shape == (sel.shape[0], k) and got.dtype == np.int32
+    assert (got >= 0).all() and (got < sel.shape[1]).all()
+    for row, out in zip(sel, got):
+        want = np.flatnonzero(row)[:k]
+        assert out[:len(want)].tolist() == want.tolist()
 
 
 @pytest.mark.parametrize("q_lens,lens", [
