@@ -19,3 +19,5 @@ from .solar_open2 import (  # noqa: F401
     SolarOpen2Config, SolarOpen2ForCausalLM)
 from .dots3_note import (  # noqa: F401
     Dots3NoteConfig, Dots3NoteForCausalLM)
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM)

@@ -37,6 +37,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import ops
 from ..nn import Layer, LayerNorm, Linear, Embedding, RMSNorm, LayerList
 from ..nn.initializer import Constant, Normal
 from ..core.tensor import dispatch
@@ -364,20 +365,25 @@ class Router(Layer):
 class SparseMoE(Layer):
     """``held`` experts from ``offset`` of the ``published`` the router
     scores, ``top_k`` a row, beside one shared expert of ``shared_width``
-    that every row takes. ``scoring``, ``selection_bias``, ``n_group`` /
-    ``topk_group`` and ``renormalize``: :func:`moe_dropless.route`."""
+    that every row takes (0: the family has NO shared expert; no leaf is
+    made and nothing is traced for one). ``scoring``, ``selection_bias``,
+    ``n_group`` / ``topk_group``, ``renormalize`` and ``renorm_eps``:
+    :func:`moe_dropless.route`."""
 
     def __init__(self, hidden, width, held, published, offset, top_k,
                  scale, shared_width, scoring="sigmoid", selection_bias=True,
-                 n_group=1, topk_group=1, renormalize=True):
+                 n_group=1, topk_group=1, renormalize=True, renorm_eps=0.0):
         super().__init__()
         self.held, self.offset, self.top_k, self.scale = held, offset, \
             top_k, scale
         self.routing = dict(renormalize=renormalize, scoring=scoring,
                             n_group=n_group, topk_group=topk_group)
+        if renorm_eps:
+            self.routing["renorm_eps"] = float(renorm_eps)
         self.gate = Router(hidden, published, selection_bias)
         self.experts = Experts(held, hidden, width)
-        self.shared_experts = SwiGLU(hidden, shared_width)
+        if shared_width:
+            self.shared_experts = SwiGLU(hidden, shared_width)
 
     def forward(self, x, cache=None):
         k, held, offset, scale, routing = self.top_k, self.held, \
@@ -386,7 +392,7 @@ class SparseMoE(Layer):
         q_lens = getattr(cache, "q_lens", None)
         rmap = CL.packed(cache)
 
-        def fn(x, q_lens, wr, bias, wg, wu, wd, sg, su, sd):
+        def fn(x, q_lens, wr, bias, wg, wu, wd, shared):
             b, s, h = x.shape
             n = b * s
             if rmap is not None:
@@ -403,19 +409,22 @@ class SparseMoE(Layer):
             rows = (budget or n) * min(k, held)
             y, counts = _moe.held_expert_ffn(
                 xf, idx, w, live, wg, wu, wd, offset, rows)
-            with scope("pt.shared"):
-                shared = swiglu(xf, sg, su, sd).astype(F32)
+            if shared is not None:
+                with scope("pt.shared"):
+                    shared = swiglu(xf, *shared).astype(F32)
             with scope("pt.combine"):
-                out = shared + y
+                out = y if shared is None else shared + y
                 return out.astype(x.dtype).reshape(b, s, h), counts
 
-        sh = self.shared_experts
+        sh = getattr(self, "shared_experts", None)
         out, counts = dispatch(
             fn, (x, q_lens, self.gate.weight,
                  getattr(self.gate, "e_score_correction_bias", None),
                  self.experts.gate_proj, self.experts.up_proj,
-                 self.experts.down_proj, sh.gate_proj.weight,
-                 sh.up_proj.weight, sh.down_proj.weight), {},
+                 self.experts.down_proj,
+                 None if sh is None else (
+                     sh.gate_proj.weight, sh.up_proj.weight,
+                     sh.down_proj.weight)), {},
             name="sparse_moe")
         CL.count(CL._val(counts))
         return out
@@ -465,26 +474,33 @@ class StateDecoder(Layer):
 
 
 class StateCausalLM(Layer):
-    """A :class:`StateDecoder` (``self.model``) under an untied head, as :class:`paddle_tpu.inference.LLMEngine` serves it:
-    ``decoder``, ``cache_layout()`` (the family's), ``_logits``. A plain
-    ``model(ids)`` builds a one-call state. Serving only: the backward of
-    latent attention is not written (ROADMAP Queue 2)."""
+    """A :class:`StateDecoder` (``self.model``) under a head of its own
+    (``tied``: under the embedding's matrix, and no ``lm_head`` leaf), as
+    :class:`paddle_tpu.inference.LLMEngine` serves it: ``decoder``,
+    ``cache_layout()`` (the family's), ``_logits``. A plain ``model(ids)``
+    builds a one-call state. Serving only: the backward of latent
+    attention is not written (ROADMAP Queue 2)."""
     #: device-side counts of a step (``cache_layout.count``), booked into
     #: ``engine.stats`` under these names, and the ids of a step's emit span
     step_counter_names = _moe.COUNTERS
     step_emit_ids = _moe.EMIT_IDS
 
-    def __init__(self, config, decoder):
+    def __init__(self, config, decoder, tied=False):
         super().__init__()
         self.config = config
         self.model = decoder
-        self.lm_head = _linear(config.hidden_size, config.vocab_size)
+        if not tied:
+            self.lm_head = _linear(config.hidden_size, config.vocab_size)
 
     @property
     def decoder(self):
         return self.model
 
     def _logits(self, hidden):
+        if not hasattr(self, "lm_head"):
+            with scope("lm_head"):
+                return ops.matmul(hidden, self.model.embed_tokens.weight,
+                                  transpose_y=True)
         return self.lm_head(hidden)
 
     def _fresh_layout(self, seq, block_size):

@@ -48,15 +48,16 @@ EMIT_IDS = {"held_rows": ("moe_assignments_held",),
 
 
 def route(x, w_router, bias, top_k, scale, renormalize=True,
-          scoring="sigmoid", n_group=1, topk_group=1):
+          scoring="sigmoid", n_group=1, topk_group=1, renorm_eps=0.0):
     """x: [N, h]; w_router: [h, E_all]; bias: [E_all], used to SELECT only,
     or None. Scores (``scoring``: "sigmoid", or "softmax" over the
     experts) and selection in float32. With ``n_group`` > 1 the experts
     are ``n_group`` runs of consecutive ids; a group scores what its best
     expert scores, the ``topk_group`` best groups keep their experts and
     the others' selection scores are 0 before the ``top_k`` are taken.
-    The weights are the selected experts' scores, divided by their sum if
-    ``renormalize``, times ``scale``. Returns (idx [N, k] int32, weights
+    The weights are the selected experts' scores, divided by their sum
+    (plus ``renorm_eps``, where a family adds one) if ``renormalize``,
+    times ``scale``. Returns (idx [N, k] int32, weights
     [N, k] float32)."""
     logits = jnp.matmul(x.astype(jnp.float32), w_router.astype(jnp.float32),
                         precision=HI)
@@ -73,7 +74,8 @@ def route(x, w_router, bias, top_k, scale, renormalize=True,
     _, idx = jax.lax.top_k(sel, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if renormalize:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (total + jnp.float32(renorm_eps) if renorm_eps else total)
     return idx.astype(jnp.int32), w * jnp.float32(scale)
 
 

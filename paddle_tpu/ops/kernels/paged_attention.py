@@ -238,6 +238,33 @@ def current_paged_tp():
     return getattr(_TP_CTX, "value", None)
 
 
+#: trace-time plan of the decode call: the kv heads one grid step serves,
+#: while a layer that knows its pools better than their shapes say traces
+#: its call, else None (``_decode_heads_per_step`` decides, from the
+#: shapes alone). THREAD-LOCAL for the reason ``_TP_CTX`` is.
+_DECODE_PLAN = threading.local()
+
+
+@contextlib.contextmanager
+def decode_heads_a_step(hb):
+    """Trace the decode calls of the body with ``hb`` kv heads a grid step
+    WHATEVER their group (``_decode_kernel_heads`` on ``hb x group`` query
+    rows). For a layer whose pool rows hold several narrow heads
+    (``models/lfm2_moe.py``: two heads of 64 a row of 128 lanes, so four
+    "heads" at a group of eight): at 128 slots the one-head call is
+    16,384 grid steps of fixed cost, 6.2 ms where the rows it reads take
+    0.3 (PERF.md section 6, PR 48). The shapes alone cannot tell such a
+    call from the grouped calls of the cells at a head size of 128, whose
+    programs stay what they were; so the layer says it. Trace-time state:
+    once the program is compiled it is a thread-local set and reset."""
+    prev = getattr(_DECODE_PLAN, "value", None)
+    _DECODE_PLAN.value = int(hb)
+    try:
+        yield
+    finally:
+        _DECODE_PLAN.value = prev
+
+
 def _tp_shard_map(fn, mesh, axis, in_specs, out_specs):
     if isinstance(in_specs, list):
         in_specs = tuple(in_specs)
@@ -595,8 +622,11 @@ def _decode_heads_per_step(hkv, g, bs, dk, pool_isz, quant):
 
 def _decode_kernel_heads(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
                          scale, bs, mb, write_new):
-    """``_decode_kernel`` for ``hb`` kv heads a grid step at a group of one
-    (16-bit or f32 pools, not quantized): the heads of a table entry are
+    """``_decode_kernel`` for ``hb`` kv heads a grid step (16-bit or f32
+    pools, not quantized; a group of one by ``_decode_heads_per_step``'s
+    rule, any group under ``decode_heads_a_step``: the ``hb x group`` query
+    rows of the heads lie together, row ``r`` reads head ``r // group``):
+    the heads of a table entry are
     attended in ONE pair of matmuls, their blocks stacked as
     ``[hb * bs, D]`` keys, the heads' query rows against all of them, and
     every score of a row against another head's keys masked like a dead
@@ -647,8 +677,11 @@ def _decode_kernel_heads(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         k2 = k_blk.reshape(hb * bs, d)
         v2 = v_blk.reshape(hb * bs, d)
         dt = _mxu_dtype(q_ref.dtype, k2.dtype, None)
-        s = _scores(q_ref[0, 0], k2.astype(dt), scale, dt)     # [hb, hb*bs]
+        s = _scores(q_ref[0, 0], k2.astype(dt), scale, dt)   # [hb*g, hb*bs]
         head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        g = s.shape[0] // hb
+        if g > 1:
+            head = _div_i32(head, g)
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         within = col - head * bs_i           # the key's place in ITS block
         seen = (within >= Z) & (within < bs_i) & (jj * bs_i + within <= L)
@@ -714,7 +747,13 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
     write_new = new_k is not None
     assert (new_v is not None) == write_new
 
-    hb = _decode_heads_per_step(Hkv, G, BS, Dk, k_pool.dtype.itemsize, quant)
+    hb = getattr(_DECODE_PLAN, "value", None)
+    if hb is None:
+        hb = _decode_heads_per_step(Hkv, G, BS, Dk, k_pool.dtype.itemsize,
+                                    quant)
+    elif quant or Hkv % hb:
+        raise ValueError(f"decode_heads_a_step({hb}): {Hkv} kv heads, "
+                         f"quant={quant!r}")
     if hb > 1:
         return _decode_heads_call(q, k_pool, v_pool, block_tables, seq_lens,
                                   scale, new_k, new_v, hb)
@@ -796,22 +835,24 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
 
 def _decode_heads_call(q, k_pool, v_pool, block_tables, seq_lens, scale,
                        new_k, new_v, hb):
-    """The decode call at a group of one with ``hb`` kv heads a grid step
+    """The decode call with ``hb`` kv heads a grid step
     (``_decode_kernel_heads``): grid ``(B, Hkv / hb, MB)``, the index maps
-    of the one-head call with a block of ``hb`` heads where it has one."""
-    B, H, D = q.shape
-    NB, _, BS, Dk = k_pool.shape
+    of the one-head call with a block of ``hb`` heads, and of their ``hb x
+    group`` query rows, where it has one."""
+    B, Hq, D = q.shape
+    NB, H, BS, Dk = k_pool.shape
+    rows = hb * (Hq // H)
     MB = block_tables.shape[1]
     tables = block_tables.astype(jnp.int32)
     lens = seq_lens.astype(jnp.int32)
     write_new = new_k is not None
 
-    q_spec = pl.BlockSpec((1, 1, hb, D), _q_index_map)
+    q_spec = pl.BlockSpec((1, 1, rows, D), _q_index_map)
     kv_spec = pl.BlockSpec((1, hb, BS, Dk), _kv_index_map(BS, MB))
     in_specs = [q_spec, kv_spec, kv_spec]
     out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((B, H // hb, hb, D), q.dtype)]
-    inputs = [tables, lens, q.reshape(B, H // hb, hb, D), k_pool, v_pool]
+    out_shape = [jax.ShapeDtypeStruct((B, H // hb, rows, D), q.dtype)]
+    inputs = [tables, lens, q.reshape(B, H // hb, rows, D), k_pool, v_pool]
     io_aliases = {}
     if write_new:
         new_spec = pl.BlockSpec((1, hb, 1, D), _new_kv_index_map)
@@ -833,9 +874,9 @@ def _decode_heads_call(q, k_pool, v_pool, block_tables, seq_lens, scale,
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((hb, 1), jnp.float32),
-                pltpu.VMEM((hb, 1), jnp.float32),
-                pltpu.VMEM((hb, D), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, D), jnp.float32),
             ],
         ),
         out_shape=out_shape,
@@ -846,7 +887,7 @@ def _decode_heads_call(q, k_pool, v_pool, block_tables, seq_lens, scale,
         name="paged_attention_decode",
         interpret=_interpret(),
     )(*inputs)
-    out = outs[0].reshape(B, H, D)
+    out = outs[0].reshape(B, Hq, D)
     return (out, outs[1], outs[2]) if write_new else out
 
 

@@ -766,3 +766,67 @@ def test_the_exact_top_k_compiles_for_the_v5e_without_a_gather(one_chip):
     assert " gather(" not in text
     assert f"u8[{t},{k},128]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+@pytest.mark.parametrize("form,rows", [("mixed", 640), ("one_token", 1)])
+def test_the_lfm2_attention_layer_compiles_on_pools_of_128_lanes(
+        one_chip, monkeypatch, form, rows):
+    """``Lfm2Attention`` at ``lfm2_agent_turns``' sizes (32 query heads on
+    8 K/V heads of SIXTY-FOUR, 128 slots of 32 blocks of 64, a mixed
+    step's 640 packed rows or a decode scan's row a slot), compiled by the
+    TPU compiler installed here for a described v5e (nothing runs; in this
+    file for its ``one_chip`` fixture): two heads share a pool row, so the
+    pools are ``[4097, 4, 64, 128]`` and reach the paged kernels in the
+    row-major tiles they lie in, and no copy of a pool (268 MB) is among
+    the temporaries. The pool ``[4097, 8, 64, 64]`` that the published
+    head size would give is held block-axis-minor and copied whole into
+    128 padded lanes around every call of either kernel (1.08 GB of
+    temporaries): ``benchmark/tests/test_aot_lfm2.py`` compiles that
+    too."""
+    from benchmark.harness import loader
+    from benchmark.tests import brumby_aot
+    from paddle_tpu.core.tensor import Tensor, functional_mode
+    from paddle_tpu.jit.functional_call import bind_state
+    from paddle_tpu.models import cache_layout as CL
+    from paddle_tpu.models.llama import PagedKVCache
+    from paddle_tpu.ops.kernels import paged_attention
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    cfg = loader.data("configs", "lfm2-24b-a2b-pp4-d10")
+    with paddle.LazyGuard():
+        model = loader.module("programs", "lfm2_moe").build(cfg)
+    model.eval()
+    layer = model.decoder.layers[2].self_attn
+    kind = layer.kind()
+    assert (layer.pack, kind.kv_heads, kind.head_dim) == (2, 4, 128)
+    params = [p for _, p in layer.named_parameters()]
+    slots, chunk, bs, mb = 128, 512, 64, 32
+    nb = slots * mb + 1
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    def fn(vals, x, k_pool, v_pool, tables, lens, q_lens):
+        row_map = CL.RowMap(q_lens, lens, rows, chunk) \
+            if form == "mixed" else None
+        cache = PagedKVCache(Tensor(k_pool), Tensor(v_pool), Tensor(tables),
+                             Tensor(lens), Tensor(q_lens), rows=row_map)
+        with paddle.no_grad(), functional_mode(), bind_state(params, vals):
+            out, new = layer(Tensor(x), cache)
+        return out._value, new.k._value, new.v._value
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = shape((nb, 4, bs, 128), bf16)
+    lead = (1, rows) if form == "mixed" else (slots, 1)
+    args = ([shape(p._value.shape, bf16) for p in params],
+            shape(lead + (2048,), bf16), pool, pool, shape((slots, mb), i32),
+            shape((slots,), i32), shape((slots,), i32))
+    with jax.default_matmul_precision("default"):
+        compiled = brumby_aot.compile_for_the_chip(
+            {form: jax.jit(fn, donate_argnums=(2, 3))}, {form: args}, form)
+    text = compiled.as_text()
+    kernel = "paged_attention_append" if form == "mixed" \
+        else "paged_attention_decode"
+    assert "tpu_custom_call" in text and kernel in text
+    assert f"bf16[{nb},4,64,128]{{3,2,1,0:" in text
+    assert "pt.qk_norm" in text and "pt.rope" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
