@@ -63,6 +63,9 @@ def default_engine_stats():
     the full set: a hand-copied dict would silently drift the next time
     a counter is added."""
     return {"steps": 0, "prefill_chunks": 0, "tokens_generated": 0,
+            # dispatched step programs with a live row at temperature > 0:
+            # the others ran sample_next's argmax alone (pick_tokens)
+            "sampling_steps": 0,
             "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
             "preemptions": 0,
             "fused_steps": 0, "multi_steps": 0,
@@ -1204,12 +1207,35 @@ class LLMEngine:
 
         K = self.horizon
 
+        def pick_tokens(logits, temps, draw):
+            """THE greedy/sampled select of ``sample_next`` and
+            ``row_sample``, gated on what the step's ``temps`` describe:
+            ``logits`` [B, ..., V], ``temps`` [B], ``draw()`` -> the
+            filtered categorical's tokens [B, ...]. A step with a row at
+            ``temperature > 0`` runs argmax, ``draw()`` and the per-row
+            select; an all-greedy step (free slots carry 0.0) runs the
+            argmax alone — no sort over the vocabulary, no random bits.
+            The ``lax.cond`` sits outside every ``vmap`` (under one it
+            would lower to a select that runs both sides), and its
+            predicate is the program's own input: one compiled program
+            either way."""
+            with scope("pt.sample"):
+                def greedy():
+                    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+                def sampled():
+                    cold = (temps <= 0.0).reshape(
+                        temps.shape + (1,) * (logits.ndim - 2))
+                    return jnp.where(cold, greedy(), draw())
+                return jax.lax.cond(jnp.any(temps > 0.0), sampled, greedy)
+
         def sample_next(logits, key, temps, top_ps, rids, lens):
             """THE sample-from-carried-logits prologue: greedy rows argmax,
             sampling rows the filtered categorical, per-slot select. One
             copy consumed by one_step, the spec verify windows, AND the
             fused mixed step (the carried-logits fix once had to be
-            applied in several copies of this code).
+            applied in several copies of this code). An all-greedy step
+            runs the argmax alone (``pick_tokens``).
 
             Sampling keys derive as ``fold_in(fold_in(key, rid), pos)``
             instead of advancing one global split stream: the token
@@ -1223,16 +1249,15 @@ class LLMEngine:
             same per-position keys instead of advancing a shared stream —
             leaves ``key`` untouched across steps, so resumption is
             token-exact in sampled mode too (docs/architecture.md)."""
-            with scope("pt.sample"):
-                greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            def draw():
                 keys = jax.vmap(lambda r, p: jax.random.fold_in(
                     jax.random.fold_in(key, r), p))(rids, lens)
-                sampled = jax.vmap(
+                return jax.vmap(
                     lambda k, row, t, tp: _sample_logits_device(
                         row, k, jnp.maximum(t, 1e-6), top_k, tp, False,
                         True)
                 )(keys, logits, temps, top_ps)
-                return jnp.where(temps <= 0.0, greedy_tok, sampled)
+            return pick_tokens(logits, temps, draw)
 
         def one_step(k_bufs, v_bufs, logits, lens, active, rng, state_vals,
                      temps, top_ps, eos_ids, rids, tables, lora=None):
@@ -1376,18 +1401,19 @@ class LLMEngine:
             accepted iff it EQUALS this token, so a speculative stream
             is token-identical to the non-spec engine's — greedy AND
             sampled — and restart/failover resumption needs no
-            acceptance-randomness replay (there is none)."""
-            greedy_tok = jnp.argmax(logits_rows, axis=-1).astype(jnp.int32)
-
+            acceptance-randomness replay (there is none). An all-greedy
+            step runs the argmax alone (``pick_tokens``)."""
             def per_slot(k_rid, rows, t, tp, ps):
                 return jax.vmap(lambda p, row: _sample_logits_device(
                     row, jax.random.fold_in(k_rid, p),
                     jnp.maximum(t, 1e-6), top_k, tp, False, True))(ps, rows)
 
-            k_rids = jax.vmap(lambda r: jax.random.fold_in(key, r))(rids)
-            sampled = jax.vmap(per_slot)(k_rids, logits_rows, temps,
-                                         top_ps, poss)
-            return jnp.where((temps <= 0.0)[:, None], greedy_tok, sampled)
+            def draw():
+                k_rids = jax.vmap(
+                    lambda r: jax.random.fold_in(key, r))(rids)
+                return jax.vmap(per_slot)(k_rids, logits_rows, temps,
+                                          top_ps, poss)
+            return pick_tokens(logits_rows, temps, draw)
 
         def verify_window(logits_win, draft, lens, q_eff, key, temps,
                           top_ps, rids, active):
@@ -4147,9 +4173,12 @@ class LLMEngine:
         copy of the array construction (temps, top_ps, eos_ids, rids,
         and optionally remaining budgets) shared by the all-decode,
         speculative and mixed dispatch builders, so a new per-request
-        field can never silently desynchronize one path."""
+        field can never silently desynchronize one path. Called once a
+        dispatched program, so ``sampling_steps`` is counted here: the
+        host's copy of the predicate ``pick_tokens`` reads on the device."""
         temps = np.array([s.req.temperature if s else 0.0
                           for s in self.slots], np.float32)
+        self.stats["sampling_steps"] += bool((temps > 0.0).any())
         top_ps = np.array([s.req.top_p if s else 1.0
                            for s in self.slots], np.float32)
         eos_ids = np.array([(s.req.eos_token_id if s and

@@ -114,7 +114,8 @@ _COUNTERS = ("requests_submitted", "requests_admitted", "requests_finished",
              "requests_rejected_queue_full", "requests_rejected_validation",
              "requests_shed_deadline", "requests_resumed",
              "engine_restarts", "faults_injected", "tokens_emitted",
-             "engine_steps", "multi_steps", "preemptions", "prefill_tokens",
+             "engine_steps", "multi_steps", "sampling_steps", "preemptions",
+             "prefill_tokens",
              "prefix_hit_tokens", "prefix_cow_blocks",
              "prefix_evicted_blocks",
              "adapter_cache_hits", "adapter_cache_misses", "adapter_swaps",

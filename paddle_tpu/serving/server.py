@@ -927,7 +927,8 @@ class AsyncLLMServer:
         s_pre = eng.stats["preemptions"]
         s_ptok = eng.stats["prefill_tokens"]
         s_multi = eng.stats["multi_steps"]
-        s_pfx = {k: eng.stats[k] for k in ("prefix_hit_tokens",
+        s_pfx = {k: eng.stats[k] for k in ("sampling_steps",
+                                           "prefix_hit_tokens",
                                            "prefix_cow_blocks",
                                            "prefix_evicted_blocks",
                                            "adapter_cache_hits",
@@ -950,9 +951,10 @@ class AsyncLLMServer:
             tel.inc("prefill_tokens", d_ptok)
         for key, before in s_pfx.items():
             # prefix-cache activity (hits at admission, COW clones, LRU
-            # evictions) AND adapter-cache activity (hit/miss/swap at
-            # admission) all happen inside step_begin — the deltas land
-            # on the matching telemetry counters
+            # evictions), adapter-cache activity (hit/miss/swap at
+            # admission) AND a dispatch with a sampling row all happen
+            # inside step_begin — the deltas land on the matching
+            # telemetry counters
             if eng.stats[key] > before:
                 tel.inc(key, eng.stats[key] - before)
         if eng.stats["preemptions"] > s_pre:

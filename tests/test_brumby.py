@@ -524,8 +524,12 @@ def test_the_step_programs_compile_for_the_v5e_with_the_state_in_place(
     temporaries fit the chip, every state is aliased to its output, and no
     instruction but the core's own update makes a state-shaped array
     (``benchmark/tests/test_aot_brumby.py`` holds the same for each of the
-    three programs apart)."""
+    three programs apart). And the TPU compiler keeps ``sample_next``'s
+    ``lax.cond`` a ``conditional``: the sort over the 151,936 ids of the 16
+    rows is an instruction of its sampling branch alone, so a step whose
+    rows are all greedy does not run it."""
     from benchmark.tests import brumby_aot
+    from test_device_scopes import sampling_branches
     eng, raw, args = brumby_aot.engine(one_chip)
     assert eng.B == 16 and eng.mixed_rows == 528
     assert eng.kv_pool_nbytes() == 0
@@ -534,6 +538,14 @@ def test_the_step_programs_compile_for_the_v5e_with_the_state_in_place(
         arguments, temporaries = brumby_aot.held_in_place(compiled, eng)
         assert arguments > 16 * 8 * brumby_aot.STATE_BYTES + 8.39e9
         assert temporaries < 1e9
+        greedy, sampling, comps = sampling_branches(compiled.as_text())
+        vocabulary_sorts = [line for lines in comps.values()
+                            for line in lines
+                            if " sort(" in line and "[16,151936]" in line]
+        assert len(vocabulary_sorts) == 1
+        assert vocabulary_sorts[0] in sampling
+        assert not [line for line in greedy
+                    if " sort(" in line or "rng" in line]
 
 
 @pytest.mark.parametrize("cell,b,t,s,h,mb,hq", [

@@ -287,3 +287,75 @@ def test_a_train_steps_operations_carry_forward_and_backward():
     assert {"mixer.core", "mixer.proj", "ffn", "block", "head",
             "loss"} <= backward, backward
     assert not {"optimizer", "clip"} & backward
+
+
+# ---- the sampling prologue's conditional ------------------------------------
+
+_CALLED = re.compile(r"(?:to_apply|calls|body|condition|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+#: what only the filtered categorical does: the sort over the vocabulary,
+#: the nucleus' cumulative sum, the keys and their bits
+_DRAWS = re.compile(r"sort|cumsum|threefry|random_|rng-bit")
+
+
+def sampling_branches(text):
+    """(the greedy branch's lines, the sampling branch's lines, every
+    computation's lines) of the ONE ``conditional`` under ``pt.sample`` in
+    an HLO module's text (metadata printed): a branch is its computation
+    with everything that computation calls."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+
+    def names(group):
+        return [c.strip().lstrip("%") for c in group.split(",")]
+
+    def reach(name):
+        seen, todo = set(), [name]
+        while todo:
+            c = todo.pop()
+            if c not in seen:
+                seen.add(c)
+                for line in comps[c]:
+                    todo.extend(_CALLED.findall(line))
+                    for group in _BRANCHES.findall(line):
+                        todo.extend(names(group))
+        return [line for c in sorted(seen) for line in comps[c]]
+    (gate,) = [line for lines in comps.values() for line in lines
+               if " conditional(" in line and "/pt.sample/cond" in line]
+    greedy, sampling = map(reach, names(_BRANCHES.search(gate).group(1)))
+    return greedy, sampling, comps
+
+
+@pytest.mark.parametrize("name", ["fused_step", "multi_step"])
+def test_a_step_programs_sort_sits_in_one_branch_of_a_conditional(
+        programs, name):
+    """``sample_next`` takes the filtered categorical behind ONE
+    ``lax.cond`` on the step's ``temps``: the lowered program holds a real
+    ``conditional`` under ``pt.sample`` whose one branch holds the sort
+    over the vocabulary (every ``pt.sample`` sort of the program is there)
+    and whose other branch holds the argmax and no sort, no cumulative sum
+    and no random bits."""
+    raw, args, _, _ = programs
+    greedy, sampled, comps = sampling_branches(
+        raw[name].lower(*args[name]).compiler_ir(dialect="hlo")
+        .get_hlo_module().to_string())
+    assert not [line for line in greedy if _DRAWS.search(line)]
+    assert any("argmax" in line for line in greedy)
+    assert any(" sort(" in line for line in sampled)
+    for what in ("sort", "cumsum", "threefry"):
+        assert any("/pt.sample/cond/branch_1_fun/" in line and what in line
+                   for line in sampled), what
+    # and nowhere else does the program sort under ``pt.sample``
+    inside = set(sampled)
+    assert not [line for lines in comps.values() for line in lines
+                if "sort" in line and "pt.sample" in line
+                and line not in inside]

@@ -159,6 +159,35 @@ def test_sampled_token_exact(tiny_model):
     assert got == want
 
 
+@pytest.mark.parametrize("cache_impl", ["dense", "paged"])
+def test_a_sampled_row_beside_a_greedy_row_through_the_gate(
+        tiny_model, greedy_ref, cache_impl):
+    """``row_sample`` draws behind the same ``lax.cond`` on the step's
+    ``temps`` as ``sample_next`` (``pick_tokens``): with one sampled row
+    of two the verify windows' coupled targets are still the tokens the
+    non-speculative engine samples one position at a time, the greedy row
+    beside it stays the reference's, and once the sampled request retires
+    the programs run their argmax alone (``sampling_steps`` stops)."""
+    prompts = _prompts(5)
+
+    def serve(spec_k):
+        eng = _engine(tiny_model, spec_k, cache_impl, sampling_seed=3)
+        rids = [eng.add_request(prompts[0], max_new_tokens=6,
+                                temperature=0.8, top_p=0.9, request_id=40),
+                eng.add_request(prompts[1], max_new_tokens=20,
+                                request_id=41)]
+        while eng.has_unfinished():
+            eng.step()
+        return [eng.finished_outputs[r].token_ids for r in rids], eng.stats
+
+    want, _ = serve(1)
+    got, stats = serve(4)
+    assert got == want
+    assert got[1] == greedy_ref([prompts[1]], 20)[0]
+    assert stats["spec_proposed_tokens"] > 0
+    assert 0 < stats["sampling_steps"] < stats["steps"]
+
+
 @pytest.mark.slow
 def test_spec_mixes_with_embed_and_generate(tiny_model, greedy_ref):
     """One token-budget walk serves speculative generation AND

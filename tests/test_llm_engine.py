@@ -595,3 +595,154 @@ class TestPagedKV:
         assert len(out1.token_ids) == 24
         assert eng.stats["preemptions"] >= 1
         assert len(eng._free_blocks) == 6  # all blocks returned
+
+
+# ---- the sampling prologue does the work its ``temps`` describe -----------
+# ``sample_next`` / ``row_sample`` take the filtered categorical behind ONE
+# ``lax.cond`` on the step's ``temps`` (``pick_tokens``): an all-greedy step
+# runs the argmax alone, a step with a sampling row what every step ran
+# before, on the same per-(rid, position) keys.
+
+#: how each step program is reached: the legacy scheduler's decode step; the
+#: fused scheduler's mixed step (one-token scans behind it); the same with
+#: the strided all-decode loop behind it
+_GATE_PROGRAMS = {"one_step": {}, "fused_step": dict(scheduler="fused"),
+                  "multi_step": dict(scheduler="fused", readout_stride=4)}
+_GATE_CASES = [(p, c) for p in _GATE_PROGRAMS for c in ("dense", "paged")]
+#: the short prompt first: it decodes while the longer ones still ramp in
+_GATE_PROMPTS = [
+    [5, 35, 81],
+    [57, 5, 38, 50, 60, 86, 63, 57, 2, 46, 74, 30, 95],
+    [1, 49, 16, 11, 25, 85, 6, 60, 4]]
+#: what the tree before the gate served for them, greedy, every program
+_GATE_GREEDY = [[29, 6, 26, 26, 26, 74, 81, 65],
+                [3, 27, 3, 44, 44, 44, 3, 73],
+                [74, 74, 74, 74, 74, 74, 74, 74]]
+#: and for the first at temperature 0.8 / top_p 0.9 as request 5 under
+#: ``sampling_seed=11``, the other two greedy beside it
+_GATE_SAMPLED = [29, 49, 68, 45, 32, 41, 38, 89]
+_TEMP, _TOP_P = 0.8, 0.9
+
+
+@pytest.fixture(scope="module")
+def gate_engine(tiny_model):
+    """One engine a (program, cache) case, compiled once for the module."""
+    made = {}
+
+    def get(program, cache_impl):
+        if (program, cache_impl) not in made:
+            opts = dict(max_batch=3, max_seq_len=64, chunk_size=4,
+                        sampling_seed=11, **_GATE_PROGRAMS[program])
+            if cache_impl == "paged":
+                opts.update(cache_impl="paged", block_size=4)
+            made[program, cache_impl] = LLMEngine(tiny_model, **opts)
+        eng = made[program, cache_impl]
+        assert not eng.has_unfinished()
+        eng.finished_outputs.clear()
+        eng.reset_stats()
+        return eng
+    return get
+
+
+def _serve(eng, temps, n=8, only=None):
+    """The gate's prompts (or those ``only`` lists) as requests 5, 6, 7 at
+    ``temps`` -> their streams by prompt index."""
+    rids = {}
+    for i, prompt in enumerate(_GATE_PROMPTS):
+        if only is None or i in only:
+            hot = temps[i] > 0
+            rids[i] = eng.add_request(
+                np.asarray(prompt, np.int32),
+                max_new_tokens=n if np.isscalar(n) else n[i],
+                temperature=temps[i], top_p=_TOP_P if hot else 1.0,
+                request_id=5 + i)
+    while eng.has_unfinished():
+        eng.step()
+    return {i: eng.finished_outputs.pop(r).token_ids
+            for i, r in rids.items()}
+
+
+def _ran(eng, program):
+    """The step program the case is named for did run."""
+    s = eng.stats
+    return {"one_step": s["fused_steps"] == 0 and s["multi_steps"] == 0,
+            "fused_step": s["fused_steps"] > 0 and s["multi_steps"] == 0,
+            "multi_step": s["fused_steps"] > 0 and s["multi_steps"] > 0,
+            }[program]
+
+
+@pytest.mark.parametrize("program,cache_impl", _GATE_CASES)
+def test_an_all_greedy_batch_serves_the_pinned_tokens(
+        gate_engine, program, cache_impl):
+    eng = gate_engine(program, cache_impl)
+    out = _serve(eng, [0.0, 0.0, 0.0])
+    assert [out[i] for i in range(3)] == _GATE_GREEDY
+    assert _ran(eng, program)
+    # no program opened the branch
+    assert eng.stats["sampling_steps"] == 0 < eng.stats["steps"]
+
+
+@pytest.mark.parametrize("program,cache_impl", _GATE_CASES)
+def test_a_sampled_row_among_greedy_rows_is_the_keyed_categorical(
+        tiny_model, gate_engine, program, cache_impl):
+    """Row 0 at temperature 0.8 / top_p 0.9 beside two greedy rows: each of
+    its tokens is ``_sample_logits_device`` under ``fold_in(fold_in(key,
+    rid), position)`` of the model's logits over the prefix it was drawn
+    after, and each greedy token is the argmax of its own."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama import _sample_logits_device
+    eng = gate_engine(program, cache_impl)
+    out = _serve(eng, [_TEMP, 0.0, 0.0])
+    assert _ran(eng, program)
+    assert out[0] == _GATE_SAMPLED
+    assert [out[1], out[2]] == _GATE_GREEDY[1:]
+    key = jax.random.PRNGKey(11)
+    np.testing.assert_array_equal(np.asarray(eng._rng_key), np.asarray(key))
+    for i, stream in out.items():
+        ids = list(_GATE_PROMPTS[i])
+        for tok in stream:
+            logits = tiny_model(paddle.to_tensor(
+                np.asarray(ids, np.int32)[None]))._value[0, -1] \
+                .astype(jnp.float32)
+            if i == 0:
+                k = jax.random.fold_in(jax.random.fold_in(key, 5 + i),
+                                       len(ids))
+                want = _sample_logits_device(
+                    logits, k, jnp.float32(_TEMP), 0, jnp.float32(_TOP_P),
+                    False, True)
+            else:
+                want = jnp.argmax(logits)
+            assert int(want) == tok, (i, len(ids))
+            ids.append(tok)     # teacher-forced on what was served
+    # the short sampling request may retire before the others
+    assert 0 < eng.stats["sampling_steps"] <= eng.stats["steps"]
+
+
+@pytest.mark.parametrize("program,cache_impl", _GATE_CASES)
+def test_a_sampled_request_alone_and_in_a_full_batch_is_one_stream(
+        gate_engine, program, cache_impl):
+    """(key, rid, position) decide a sampled token, through the gate too:
+    alone, beside greedy rows and beside sampling rows."""
+    eng = gate_engine(program, cache_impl)
+    alone = _serve(eng, [_TEMP, 0.0, 0.0], only=[0])[0]
+    assert alone == _GATE_SAMPLED
+    assert eng.stats["sampling_steps"] == eng.stats["steps"]
+    assert _serve(eng, [_TEMP, 1.1, 0.6])[0] == alone
+
+
+@pytest.mark.parametrize("program,cache_impl", _GATE_CASES)
+def test_sampling_steps_counts_the_programs_that_sampled(
+        gate_engine, program, cache_impl):
+    """0 for greedy traffic (above), ``steps`` when every request samples,
+    and in between when the one sampling request retires first: counted on
+    the host where the dispatch's ``temps`` are built."""
+    eng = gate_engine(program, cache_impl)
+    _serve(eng, [_TEMP, _TEMP, _TEMP])
+    assert eng.stats["sampling_steps"] == eng.stats["steps"] > 0
+    eng.reset_stats()
+    out = _serve(eng, [_TEMP, 0.0, 0.0], n=[2, 12, 12])
+    assert 0 < eng.stats["sampling_steps"] < eng.stats["steps"]
+    # and the rows that outlived it stayed on their argmax
+    assert [out[1][:8], out[2][:8]] == _GATE_GREEDY[1:]
+    assert out[0] == _GATE_SAMPLED[:2]

@@ -454,10 +454,17 @@ def test_the_counters_add_up():
 #: so the three ``rows_to_slots`` slices a layer and ``rows_from_slots``'
 #: gather are gone and the CPU's dense form slices the one view of the
 #: concatenated ``qkv`` out at ``cu_seqlens_q`` inside the op; ``step`` and
-#: ``multi_step``, which have no row map, are what they were
-LLAMA_PROGRAMS = {"fused_step": ("88aa9dbe92e86a9b", 1401),
-                  "step": ("760958861cb0a471", 1102),
-                  "multi_step": ("94ba12370fe91d78", 1133)}
+#: ``multi_step``, which have no row map, are what they were.
+#: All three were read again at PR 49 (88aa9dbe92e86a9b, 1401;
+#: 760958861cb0a471, 1102; 94ba12370fe91d78, 1133 before): thirteen lines
+#: more each, ``sample_next``'s ``lax.cond`` on the step's ``temps`` (the
+#: predicate and its ``any``, the ``stablehlo.case`` with its two regions'
+#: returns, the greedy branch's own call of ``@argmax``) in ``main`` (in
+#: ``step`` in the scan body's ``closed_call``); every other function of
+#: the three modules, the decoder's 40 / 27 / 27, is the parent's text
+LLAMA_PROGRAMS = {"fused_step": ("7ed2c7ca1cd1f5f0", 1414),
+                  "step": ("a6932061904449e1", 1115),
+                  "multi_step": ("22e3f284562418a2", 1146)}
 
 
 def _llama_digests():
