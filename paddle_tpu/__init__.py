@@ -8,7 +8,12 @@ instead of CUDA/NCCL.
 """
 from __future__ import annotations
 
-import jax as _jax
+import sys as _sys
+import time as _time
+
+_import_t0, _jax_preimported = _time.perf_counter(), "jax" in _sys.modules
+
+import jax as _jax  # noqa: E402
 
 # float64/int64 parity with the reference (paddle supports fp64; indices are int64).
 # TPU code paths use fp32/bf16 throughout; fp64 arrays are CPU-only like the reference's
@@ -719,3 +724,7 @@ _bind("set_", _tensor_set_)
 _bind("resize_", _tensor_resize_)
 _bind("create_tensor", _method(lambda self, *a, **k: create_tensor(*a, **k)))
 _bind("top_p_sampling", _method(top_p_sampling))
+
+#: ``profiler.startup()``'s ``(import_s, jax_preimported)``: this file's wall,
+#: first line to last, and whether jax (imported first thing) was loaded already
+_IMPORTED = (_time.perf_counter() - _import_t0, _jax_preimported)

@@ -49,7 +49,7 @@ from ..models.cache_layout import (Layout, RowMap, collect_counts,
                                    packed_rows)
 from ..models.llama import SlotKVCache, _sample_logits_device
 from ..models.lora import lora_scope
-from ..profiler import scope, span
+from ..profiler import build, build_retraced, scope, span, watch_gc
 
 __all__ = ["LLMEngine", "GenerationRequest", "RequestOutput", "PendingStep",
            "PoolCapacityError", "default_engine_stats"]
@@ -127,6 +127,13 @@ def default_engine_stats():
             # jit cache: their wall; a program's first is traced as
             # pt:engine.build
             "program_build_time_s": 0.0, "programs_built": 0,
+            # the host wall of the engine's construction (the model seam,
+            # the pools reserved whole, tables, allocator)
+            "engine_init_time_s": 0.0,
+            # the cyclic collector's pauses while this engine lived
+            # (pt:host.gc, booked by profiler.watch_gc): their wall,
+            # their count and the longest
+            "gc_pause_time_s": 0.0, "gc_pauses": 0, "gc_pause_max_s": 0.0,
             # transfer-guard sanitizer (PADDLE_TPU_TRANSFER_CHECKS=1):
             # all-decode strides whose dispatch->readout window ran
             # under jax.transfer_guard("disallow") — each counted
@@ -517,6 +524,7 @@ class LLMEngine:
         same rid. Disaggregated serving sets the SAME seed on every
         replica: a request migrated mid-stream (same rid, same
         positions) then re-samples token-exactly on the destination."""
+        t_init = time.perf_counter()    # -> stats["engine_init_time_s"]
         from ..jit.functional_call import collect_state, read_values
 
         self.model = model
@@ -825,7 +833,7 @@ class LLMEngine:
         self.fault_injector = None
         self._rec_ctx = None       # per-step_begin wall-split anchors
         self._rec_preempted = []   # rids parked by _preempt_slot this step
-        self._phase = None         # (name, entered at, span) — see _to
+        self._phase = None    # (name, entered at, span, ids) — see _to
         #: compiled multi-step decode programs, keyed by stride K (one
         #: program per distinct effective stride; survives reset())
         self._multi_fns = {}
@@ -848,6 +856,8 @@ class LLMEngine:
         self.emit_backdate_s = 0.0
         self.stats = dict(default_engine_stats(),
                           **dict.fromkeys(self._step_counter_names, 0))
+        watch_gc(self)
+        self.stats["engine_init_time_s"] = time.perf_counter() - t_init
 
     # ------------------------------------------------------------------
     # device state (built at __init__, REBUILT by reset())
@@ -1028,7 +1038,7 @@ class LLMEngine:
         last one they entered, on the thread that entered it."""
         now = time.perf_counter()
         if self._phase is not None:
-            name, t0, ann = self._phase
+            name, t0, ann, _ = self._phase
             for key in _PHASES[name][1]:
                 self.stats[key] += now - t0
             ann.__exit__(None, None, None)
@@ -1039,7 +1049,7 @@ class LLMEngine:
                 ids["pc_ns"] = int(now * 1e9)
             ann = span(_PHASES[phase][0], **ids)
             ann.__enter__()
-            self._phase = (phase, now, ann)
+            self._phase = (phase, now, ann, ids)
         return now
 
     def _dispatch_ids(self, kind, rows, live_tokens, granted,
@@ -1131,21 +1141,30 @@ class LLMEngine:
         of a program is a build before it starts, so it runs under a
         ``pt:engine.build`` span; a later retrace (a new argument
         structure, e.g. the first LoRA batch) is known only once the
-        call is back and is booked without one."""
+        call is back and is booked without one. Either way the build
+        leaves a record in ``profiler.builds()`` (its wall split into
+        trace, lowering and compile-or-load) that names the step it
+        rode on; a retrace's also goes to the log."""
         pid, first = PROGRAMS.index(name), True
+
+        def step_id():
+            return self._phase[3].get("step_id") if self._phase else None
 
         def call(*args, **kw):
             nonlocal first
             size, t0 = fn._cache_size(), time.perf_counter()
             if first:
                 first = False
-                with span("pt:engine.build", program=pid):
+                with build("pt:engine.build", name, step_id(), program=pid):
                     out = fn(*args, **kw)
+                wall = time.perf_counter() - t0
             else:
                 out = fn(*args, **kw)
                 if fn._cache_size() == size:
                     return out
-            self.stats["program_build_time_s"] += time.perf_counter() - t0
+                wall = time.perf_counter() - t0
+                build_retraced("pt:engine.build", name, t0, wall, step_id())
+            self.stats["program_build_time_s"] += wall
             self.stats["programs_built"] += 1
             return out
         return call

@@ -23,7 +23,7 @@ from ..core.tensor import Tensor, functional_mode, no_grad
 from ..core import random as _random
 from ..nn.layer_base import Layer
 from ..optimizer.optimizer import stored_placements
-from ..profiler import scope, span
+from ..profiler import build, scope, span
 from .functional_call import collect_state, bind_state, read_values
 
 
@@ -508,7 +508,7 @@ class TrainStep:
             rng_key = _random.next_key()
 
         fn = self._cache.get(key)
-        with span("pt:train.build") if fn is None \
+        with build("pt:train.build", "train_step") if fn is None \
                 else contextlib.nullcontext():
             if fn is None:
                 fn = self._cache[key] = self._build_step_jit(

@@ -17,6 +17,8 @@ import time
 from enum import Enum
 
 from ._span import scope, span  # noqa: F401
+from ._build import build, build_retraced, builds, startup  # noqa: F401
+from ._host_gc import watch_gc  # noqa: F401
 from .timer import benchmark  # noqa: F401
 from .serving_telemetry import (  # noqa: F401
     LABELED_GAUGE_FAMILIES, LatencyHistogram, ServingTelemetry)
@@ -33,6 +35,7 @@ from .slo import (  # noqa: F401
 
 __all__ = [
     "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent", "span", "scope",
+    "build", "build_retraced", "builds", "startup", "watch_gc",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
     "SummaryView", "benchmark", "merge_profile",
     "ServingTelemetry", "LatencyHistogram", "LABELED_GAUGE_FAMILIES",

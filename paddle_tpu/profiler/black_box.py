@@ -35,6 +35,8 @@ import os
 import threading
 import time
 
+from ._build import builds
+
 __all__ = ["BlackBox", "collect_bundle", "write_bundle",
            "BUNDLE_SCHEMA", "TRIGGER_REASONS"]
 
@@ -161,6 +163,9 @@ def collect_bundle(server=None, engine=None, recorder=None,
                 fi.snapshot() if hasattr(fi, "snapshot")
                 else list(fi.fired))
     bundle["engine"] = _engine_snapshot(engine)
+    #: the process's program builds (profiler.builds()): which program
+    #: compiled when, on which step, and where its wall went
+    bundle["builds"] = builds()[-ring_tail:]
     if recorder is not None:
         try:
             tail = recorder.explain_tail(0.0, top=tail_top)
@@ -200,6 +205,9 @@ def _shrink(bundle):
             if isinstance(tail, list) and len(tail) > 1:
                 s["tail"] = tail[-(len(tail) // 2):]
                 shrunk = True
+    if len(bundle.get("builds") or ()) > 1:
+        bundle["builds"] = bundle["builds"][-(len(bundle["builds"]) // 2):]
+        shrunk = True
     return shrunk
 
 
@@ -214,6 +222,7 @@ def write_bundle(bundle, path, max_bytes=262144):
             # header + server/engine state, and say so
             bundle["flight_recorder"] = None
             bundle["metrics"] = None
+            bundle["builds"] = None
             bundle["truncated"] = True
             data = json.dumps(bundle, sort_keys=True, indent=1)
             break

@@ -39,6 +39,7 @@ import contextlib
 import threading
 import time
 
+from ._build import startup
 from ._span import span
 
 __all__ = ["LatencyHistogram", "ServingTelemetry", "STAGES", "GAUGES",
@@ -86,6 +87,10 @@ GAUGES = ("queue_depth", "engine_waiting", "running_slots",
           # the spill store's byte occupancy — same store as
           # kv_host_spill_blocks, in the unit its bound is set in
           "kv_host_spill_bytes",
+          # the engine's construction wall (engine.stats), set once at
+          # the server's start: with snapshot()'s ``startup`` block, what
+          # a cold start cost
+          "engine_init_time_s",
           # gauge STALENESS: seconds since the serve loop last sampled
           # the point-in-time gauges (mark_gauge_sample). Computed at
           # READ time from the sampling stamp — a hung/idle loop's
@@ -517,11 +522,15 @@ class ServingTelemetry:
             out["prefill_token_share"] = round(
                 prefill / (prefill + decode), 4) if prefill + decode else 0.0
         out["attribution"] = self.attribution(wall_s)
+        #: the process's program builds and import (profiler.startup()):
+        #: a cold start, and a retrace in production, show here
+        out["startup"] = startup()
         return out
 
     def prometheus_text(self, prefix="paddle_tpu_serving"):
         """Prometheus text exposition: counters, gauges, stage-seconds
-        counters, latency histograms. With ``replica`` set, every line
+        counters, latency histograms, and ``profiler.startup()``'s totals
+        as ``<prefix>_startup_<name>`` gauges. With ``replica`` set, every line
         carries ``replica="i"`` so a multi-replica scrape endpoint can
         concatenate N replicas' dumps without series collisions."""
         with self._lock:
@@ -598,4 +607,8 @@ class ServingTelemetry:
                                                    else "")
                     lines.extend(th.prometheus_lines(
                         f"{prefix}_{name}", labels=tlbl, type_line=False))
+        for name, val in sorted(startup().items()):
+            full = f"{prefix}_startup_{name}"
+            lines.append(f"# TYPE {full} gauge")
+            lines.append(f"{full}{brace} {float(val):g}")
         return "\n".join(lines) + "\n"

@@ -293,6 +293,9 @@ class AsyncLLMServer:
         self._hung = False
         self._recovering = False
         self.telemetry.reset()
+        self.telemetry.set_gauge(
+            "engine_init_time_s",
+            self.engine.stats.get("engine_init_time_s", 0.0))
         self._thread = threading.Thread(target=self._loop,
                                         name="paddle-tpu-serving",
                                         daemon=True)

@@ -161,7 +161,7 @@ def test_a_models_counters_are_in_stats_from_the_start_and_only_its_own():
                     chunk_size=16, cache_impl="paged", block_size=8,
                     scheduler="fused")
     assert set(eng.stats) == set(default_engine_stats())
-    assert len(eng.stats) == 50
+    assert len(eng.stats) == 54
     assert not [k for k in eng.stats
                 if k.startswith(("moe_", "ret_", "kda_", "loop_"))]
     eng.generate([np.arange(1, 9, dtype=np.int32)], max_new_tokens=2)

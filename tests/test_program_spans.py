@@ -63,9 +63,11 @@ class Span:
             a = a.parent
 
 
-def traced(tmp_path, body):
+def traced(tmp_path, body, gc_spans=None):
     """Run ``body()`` under a profile; returns the ``pt:`` spans of each
-    host thread, nested (a thread's spans never cross)."""
+    host thread, nested (a thread's spans never cross). The collector's
+    ``pt:host.gc`` spans come when it pleases, on whichever thread tripped
+    it: they are set aside, into ``gc_spans`` where one is given."""
     o = jax.profiler.ProfileOptions()
     o.python_tracer_level, o.host_tracer_level = 0, 1
     jax.profiler.start_trace(str(tmp_path), profiler_options=o)
@@ -82,6 +84,9 @@ def traced(tmp_path, body):
                       e.name, dict(e.stats))
                  for e in line.events if e.name.startswith("pt:")),
                 key=lambda s: (s.start, -s.end))
+            if gc_spans is not None:
+                gc_spans += [s for s in rows if s.name == "pt:host.gc"]
+            rows = [s for s in rows if s.name != "pt:host.gc"]
             stack = []
             for s in rows:
                 while stack and stack[-1].end <= s.start:
